@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from k8s_distributed_deeplearning_tpu import backend
 from k8s_distributed_deeplearning_tpu.models import generate as gen_lib
 from k8s_distributed_deeplearning_tpu.models import llama
 from k8s_distributed_deeplearning_tpu.train import Checkpointer
@@ -45,6 +46,7 @@ def main(argv: list[str] | None = None) -> dict:
     ap.add_argument("--dtype", default="float32", choices=["float32", "bfloat16"])
     ap.add_argument("--remat", action="store_true")
     args = ap.parse_args(argv)
+    backend.use_compile_cache()
     # Decode always uses the XLA attention path against the KV cache; the
     # training-time attention impl is irrelevant here (build_config compat).
     args.attention = "xla"

@@ -15,7 +15,7 @@ Examples:
   # single host, 8-chip FSDP x TP:
   python examples/train_llama.py --preset small --fsdp 4 --tp 2
   # CPU CI (8 virtual devices), tiny model, ring attention:
-  JAX_PLATFORM_NAME=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
       python examples/train_llama.py --preset tiny --dp 2 --sp 4 \
           --attention ring --num-steps 20
 """
@@ -30,7 +30,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
-from k8s_distributed_deeplearning_tpu import config as cfg
+from k8s_distributed_deeplearning_tpu import backend, config as cfg
 from k8s_distributed_deeplearning_tpu.models import llama
 from k8s_distributed_deeplearning_tpu.parallel import (
     context_parallel as cp,
@@ -54,7 +54,7 @@ PRESETS = {
     "tiny": dict(vocab_size=256, dim=64, n_layers=2, n_heads=4, n_kv_heads=2,
                  mlp_dim=128, max_seq_len=512),
     # small: remat 'dots' + unrolled layers measured fastest at S=2048
-    # (BENCHMARKS.md round 3: 108.8k tok/s/chip vs 85.2k scanned/no-remat).
+    # (round 3, on chip: 108.8k tok/s/chip vs 85.2k scanned/no-remat).
     # Unrolling changes the checkpoint tree (block_0..block_11 instead of
     # the scanned blocks/[L,...]) — resume pre-round-3 runs with
     # --scan-layers, and --pp forces the scanned layout back on.
@@ -112,7 +112,7 @@ def main(argv: list[str] | None = None) -> dict:
                         "at O(P) memory (invalid slots cond-skipped — "
                         "measured 6x less temp at M=16, P=4); interleaved "
                         "= virtual-stage 1f1b, bubble (P-1)/(MV+P-1) — "
-                        "fastest AND smallest (BENCHMARKS.md)")
+                        "fastest AND smallest (round-4 timings)")
     parser.add_argument("--pp-virtual", type=int, default=2,
                         help="virtual chunks per stage for "
                         "--pp-schedule interleaved")
@@ -138,7 +138,7 @@ def main(argv: list[str] | None = None) -> dict:
                         choices=["auto", "xla", "flash", "ring", "ulysses"],
                         default="auto",
                         help="auto = measured crossover: Pallas flash on TPU "
-                        "at S>=1024, XLA otherwise (BENCHMARKS.md)")
+                        "at S>=1024, XLA otherwise (round-4 sweep)")
     parser.add_argument("--remat", action="store_true",
                         help="checkpoint each block (long-context memory lever)")
     parser.add_argument("--scan-layers", dest="scan_layers",
@@ -151,7 +151,7 @@ def main(argv: list[str] | None = None) -> dict:
     parser.add_argument("--no-scan-layers", dest="scan_layers",
                         action="store_false",
                         help="unroll layers (block_0..block_{L-1} params; "
-                        "measured faster at S=2048, BENCHMARKS.md)")
+                        "measured faster at S=2048, round 3)")
     parser.add_argument("--data-path", type=str, default=None,
                         help="byte-level corpus file; default synthetic tokens")
     parser.add_argument("--pack", action="store_true",
@@ -183,6 +183,7 @@ def main(argv: list[str] | None = None) -> dict:
     parser.set_defaults(grad_clip=1.0)   # LM pretraining hygiene default
     args = parser.parse_args(argv)
     conf = cfg.train_config_from_args(args)
+    backend.use_compile_cache()
 
     distributed.initialize_from_env()
     topo = mesh_lib.topology()
@@ -263,7 +264,7 @@ def main(argv: list[str] | None = None) -> dict:
     # tensor (V=128256) is the single largest activation in the step —
     # MoE included (moe.loss_fn composes since round 5; an 8B-vocab MoE
     # run has the same logits hazard). 32k-vocab presets gain nothing
-    # from it (BENCHMARKS), so their default stays off.
+    # from it (round-5 timings), so their default stays off.
     chunked = (args.chunked_ce if args.chunked_ce is not None
                else args.preset == "8b")
 
@@ -437,7 +438,7 @@ def main(argv: list[str] | None = None) -> dict:
                              "capacity_factor": moe_cfg.capacity_factor}}
                     if moe_cfg is not None else {}),
                  **metrics_extra,
-                 platform=topo.platform)
+                 **topo.device_fields())
 
     prefetchers: list = []
 

@@ -11,7 +11,7 @@ Flags are the reference's (``tensorflow_mnist.py:30-35``,
 
 Run single-host:   python examples/train_mnist.py --num-steps 200
 Fake an 8-chip DP mesh on CPU:
-  JAX_PLATFORM_NAME=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
       python examples/train_mnist.py --num-steps 100
 """
 from __future__ import annotations
@@ -25,7 +25,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
-from k8s_distributed_deeplearning_tpu import config as cfg
+from k8s_distributed_deeplearning_tpu import backend, config as cfg
 from k8s_distributed_deeplearning_tpu.models import mnist
 from k8s_distributed_deeplearning_tpu.parallel import (
     data_parallel as dp,
@@ -48,6 +48,7 @@ def main(argv: list[str] | None = None) -> dict:
     cfg.add_train_flags(parser)
     args = parser.parse_args(argv)
     conf = cfg.train_config_from_args(args)
+    backend.use_compile_cache()
 
     # Form the multi-host world before any device use (hvd.init() parity,
     # tensorflow_mnist.py:90).
@@ -118,8 +119,7 @@ def main(argv: list[str] | None = None) -> dict:
                                     seed=conf.seed)),
                 num_batches=max(1, n_val // 200))
     metrics.emit("start", world_size=world, num_steps=num_steps, lr=lr,
-                 reduction=reduction.value, platform=topo.platform,
-                 device_kind=topo.device_kind)
+                 reduction=reduction.value, **topo.device_fields())
 
     # Assemble host-local batches into global sharded arrays (multi-host
     # safe); resumable from any step for replay-free checkpoint restore.

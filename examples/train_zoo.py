@@ -27,7 +27,7 @@ import optax
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from k8s_distributed_deeplearning_tpu import config as cfg
+from k8s_distributed_deeplearning_tpu import backend, config as cfg
 from k8s_distributed_deeplearning_tpu.models import bert, moe, resnet, vit
 from k8s_distributed_deeplearning_tpu.models import llama as llama_lib
 from k8s_distributed_deeplearning_tpu.parallel import (
@@ -95,6 +95,7 @@ def main(argv: list[str] | None = None) -> dict:
     ap.set_defaults(grad_clip=1.0)       # transformer-training default
     args = ap.parse_args(argv)
     conf = cfg.train_config_from_args(args)
+    backend.use_compile_cache()
 
     distributed.initialize_from_env()
     topo = mesh_lib.topology()
@@ -236,7 +237,8 @@ def main(argv: list[str] | None = None) -> dict:
                  num_steps=num_steps, optimizer=args.optimizer,
                  schedule=args.schedule, global_batch_size=global_batch,
                  mesh={k: int(v) for k, v in
-                       zip(mesh.axis_names, mesh.devices.shape)})
+                       zip(mesh.axis_names, mesh.devices.shape)},
+                 **topo.device_fields())
     try:
         state = loop.fit(step_fn, state, global_batches, num_steps, rng,
                          metrics=metrics, checkpointer=ckpt,

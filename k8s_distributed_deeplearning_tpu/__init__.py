@@ -28,30 +28,4 @@ cite the reference files (``file:line``) they provide parity for.
 
 __version__ = "0.1.0"
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.5 ships shard_map under jax.experimental only, where the
-    # replication-check kwarg is still spelled check_rep (renamed check_vma
-    # when shard_map went public). Alias a translating wrapper so call
-    # sites can use the public spelling uniformly.
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def _shard_map_compat(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _experimental_shard_map(*args, **kwargs)
-
-    _jax.shard_map = _shard_map_compat
-
-if not hasattr(_jax.lax, "axis_size"):
-    # Same vintage gap: lax.axis_size arrived with the public shard_map.
-    # On jax < 0.5, core.axis_frame(name) returns the bound size directly.
-    import jax.core as _jax_core
-
-    def _axis_size_compat(axis_name):
-        return _jax_core.axis_frame(axis_name)
-
-    _jax.lax.axis_size = _axis_size_compat
-
 from k8s_distributed_deeplearning_tpu import config as config  # noqa: F401

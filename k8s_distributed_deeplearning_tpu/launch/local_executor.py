@@ -19,6 +19,11 @@ else — rank identity, world size, command line, script args — is consumed
 from the manifest, so a rendering bug (wrong fieldRef, wrong
 NUM_PROCESSES, broken script path) fails this execution the same way it
 would fail the real Job.
+
+A CPU facility. The workers inherit this environment, so on a TPU host every
+one of them claims every chip: the first wins, the rest fail at backend
+start-up, and the gang hangs at the coordinator (seen on a v5e, PR 21). On
+one TPU host the supported shape is ONE worker driving all visible chips.
 """
 from __future__ import annotations
 

@@ -40,7 +40,7 @@ embed_init = nn.initializers.normal(stddev=0.02)
 
 # Rematerialization policies (config knob `remat_policy`): "dots" keeps
 # matmul outputs through remat (skips recomputing the MXU work — measured
-# fastest at S=2048, BENCHMARKS.md round 3); "dots_attn" additionally saves
+# fastest at S=2048 on chip, round 3); "dots_attn" additionally saves
 # the flash-attention output (tagged `checkpoint_name` in Attention) — the
 # Pallas call is not a dot, so "dots" alone recomputes the whole attention
 # forward in the backward pass; saving it costs [B,S,D_model] bf16 per
@@ -96,9 +96,11 @@ class TransformerConfig:
                                         # | "paged_flash"; auto = measured
                                         # per-platform/seq-len rule
                                         # (ops.attention.default_impl) for
-                                        # training/prefill, and the fused
-                                        # paged decode kernel on TPU for the
-                                        # block-table decode branch.
+                                        # training/prefill, and for the
+                                        # block-table branch the fused
+                                        # paged kernel on TPU at the query
+                                        # widths it serves
+                                        # (paged_attention_impl below).
                                         # "paged_flash" forces that kernel
                                         # (interpret-mode off-TPU)
     remat: bool = False                 # checkpoint each block
@@ -262,6 +264,20 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
     r2 = x2 * cos_p + x1 * sin_p
     out = jnp.stack([r1, r2], axis=-1).reshape(x.shape)
     return out.astype(x.dtype)
+
+
+def paged_attention_impl(cfg: TransformerConfig, sq: int) -> str:
+    """Which implementation a block-table (paged) attention call with
+    ``sq`` query tokens per row resolves to: ``"paged_flash"`` (the Pallas
+    kernel) or ``"xla"`` (gather the row's pages, attend with the einsum
+    path). ``"auto"`` decides from the platform and ``sq``
+    (:func:`ops.pallas_paged_attn.default_impl`); ``"paged_flash"`` forces
+    the kernel; ``"xla"``/``"flash"`` take the gather. The model branches on
+    this and ``ServeEngine.attention_impls`` reports it per program, so
+    what a server says it runs is what it runs."""
+    if cfg.attention_impl == "auto":
+        return pallas_paged_attn.default_impl(sq)
+    return "paged_flash" if cfg.attention_impl == "paged_flash" else "xla"
 
 
 class Attention(nn.Module):
@@ -504,16 +520,15 @@ class Attention(nn.Module):
                 pool_v = pool_v.at[pg, off].set(
                     v.reshape(b, sq, kv * hd).astype(pool_v.dtype))
             cached_k.value, cached_v.value = pool_k, pool_v
-            if (cfg.attention_impl == "paged_flash"
-                    or (cfg.attention_impl == "auto"
-                        and pallas_paged_attn.on_tpu())):
+            if paged_attention_impl(cfg, sq) == "paged_flash":
                 # Fused gather+attend (ops/pallas_paged_attn.py): the
                 # kernel streams the row's pages straight from the pool
                 # via the scalar-prefetched block table, so the
                 # [B, n_blocks·page_tokens] virtual sequence never
                 # materializes in HBM. Off-TPU "paged_flash" runs the
                 # same kernel in interpret mode (parity tests); "auto"
-                # keeps CPU on the XLA gather below. Under kv_quant the
+                # picks it on TPU for the query widths it serves and
+                # the XLA gather below otherwise. Under kv_quant the
                 # kernel fuses the dequant into its page stream: int8
                 # pages and their scale pages ride the same prefetched
                 # block table, so dequantized K/V never hit HBM either.

@@ -179,6 +179,12 @@ class LocalProcessBackend:
     file, then returns a :class:`serve.transport.ReplicaClient` for
     :meth:`ServeGateway.add_replica`. ``stop_replica`` asks the server
     to shut down over the wire and reaps the child process.
+
+    Not for one TPU host: a chip belongs to one process, so every child
+    after the first dies at backend start-up (libtpu's lockfile; its
+    stderr goes to /dev/null here and the handshake reports only the exit
+    code). On TPUs each replica-server is its own pod
+    (:class:`K8sParallelismBackend`).
     """
 
     def __init__(self, heartbeat_dir: str, *,

@@ -32,6 +32,35 @@ def _drain_status(engines) -> dict:
             "draining": draining, "drained": drained}
 
 
+def preset_config(preset: str, max_seq_len: int):
+    """The model configuration behind ``--preset``: tiny (the test config,
+    f32) or small (the 124M llama config, bf16, unrolled layers)."""
+    import jax.numpy as jnp
+
+    from k8s_distributed_deeplearning_tpu.models import llama
+    if preset == "small":
+        return llama.config_tiny(
+            vocab_size=32000, dim=768, n_layers=12, n_heads=12, n_kv_heads=4,
+            mlp_dim=2048, max_seq_len=max_seq_len, dtype=jnp.bfloat16,
+            scan_layers=False)
+    return llama.config_tiny(max_seq_len=max_seq_len, dtype=jnp.float32)
+
+
+def _ran_on(engine) -> dict:
+    """What the summary says about the hardware: the device as JAX reports
+    it, per-device allocator bytes, and which paged-attention
+    implementation each of the engine's programs resolved to. A remote
+    gateway builds no engine and must not touch a backend (its
+    replica-servers own the chips): it reports nothing here."""
+    if engine is None:
+        return {}
+    from k8s_distributed_deeplearning_tpu import backend
+    from k8s_distributed_deeplearning_tpu.parallel import mesh as mesh_lib
+    return {**mesh_lib.topology().device_fields(),
+            "device_bytes_in_use": backend.device_bytes_in_use(),
+            "attention_impls": engine.attention_impls()}
+
+
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(
         prog="launch serve",
@@ -416,6 +445,9 @@ def main(argv: list[str] | None = None) -> int:
     import jax.numpy as jnp
     import numpy as np
 
+    from k8s_distributed_deeplearning_tpu import backend
+    backend.use_compile_cache()
+
     from k8s_distributed_deeplearning_tpu.models import llama
     from k8s_distributed_deeplearning_tpu.serve import (QueueFull, Request,
                                                         SamplingParams,
@@ -432,14 +464,7 @@ def main(argv: list[str] | None = None) -> int:
         except (OSError, ValueError) as e:
             ap.error(f"--tenants: {e}")
 
-    if args.preset == "small":
-        cfg = llama.config_tiny(
-            vocab_size=32000, dim=768, n_layers=12, n_heads=12, n_kv_heads=4,
-            mlp_dim=2048, max_seq_len=args.max_seq_len, dtype=jnp.bfloat16,
-            scan_layers=False)
-    else:
-        cfg = llama.config_tiny(max_seq_len=args.max_seq_len,
-                                dtype=jnp.float32)
+    cfg = preset_config(args.preset, args.max_seq_len)
     if not remote:
         model = llama.LlamaLM(cfg)
         params = model.init(jax.random.PRNGKey(args.seed),
@@ -758,7 +783,8 @@ def main(argv: list[str] | None = None) -> int:
             _time.sleep(0.02)
         logger.emit("replica_drained", replica=engine.replica_id)
         logger.emit("serve_summary", num_slots=args.slots,
-                    preset=args.preset, replicas=1, **stats.summary())
+                    preset=args.preset, replicas=1, **_ran_on(engine),
+                    **stats.summary())
         server.close()
         logger.close()
         return 0
@@ -866,7 +892,7 @@ def main(argv: list[str] | None = None) -> int:
                         else "r0")
     logger.emit("serve_summary", num_slots=args.slots,
                 preset=args.preset, replicas=args.replicas,
-                **stats.summary())
+                **_ran_on(engine), **stats.summary())
     if controller is not None:
         logger.emit("autoscale_summary", **controller.snapshot())
         reap = getattr(autoscale_backend, "reap_all", None)

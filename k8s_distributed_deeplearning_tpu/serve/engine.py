@@ -87,7 +87,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from k8s_distributed_deeplearning_tpu import faults as _faults
-from k8s_distributed_deeplearning_tpu.models import generate
+from k8s_distributed_deeplearning_tpu.models import generate, transformer
 from k8s_distributed_deeplearning_tpu.parallel import mesh as mesh_lib
 from k8s_distributed_deeplearning_tpu.parallel import sharding as sharding_lib
 from k8s_distributed_deeplearning_tpu.serve import quant as quant_lib
@@ -1964,6 +1964,29 @@ class ServeEngine:
         while b < n:
             b *= 2
         return min(b, self.max_seq_len)
+
+    def attention_impls(self) -> dict[str, str]:
+        """Which paged-attention implementation each serving program
+        resolves to (``"paged_flash"`` or ``"xla"``), keyed by program and
+        query width: decode, the speculative verify window, the
+        intermediate prefill chunk and every final-chunk bucket this
+        engine can compile. Asks the model's own rule
+        (``transformer.paged_attention_impl``), so it cannot drift from
+        what the programs trace."""
+        widths = {"decode": 1}
+        if self.spec_k:
+            widths["spec_verify"] = self.spec_k + 1
+        c = self.prefill_chunk_tokens
+        if c:
+            widths[f"chunk_{c}"] = c
+        top = self._bucket(c or self.max_seq_len)
+        b = self.min_bucket
+        while b < top:
+            widths[f"final_chunk_{b}"] = b
+            b *= 2
+        widths[f"final_chunk_{top}"] = top
+        return {name: transformer.paged_attention_impl(self.model.cfg, sq)
+                for name, sq in widths.items()}
 
     def _fits(self, req: Request) -> bool:
         """Admission-time page probe (the scheduler calls this on its

@@ -16,7 +16,8 @@ import re
 
 # name -> one-line meaning (the HELP string of the log plane).
 EVENTS: dict[str, str] = {
-    "start": "run began: world size, step budget, hyperparameters",
+    "start": "run began: world size, step budget, hyperparameters, and "
+             "the platform / device_kind / device_count it runs on",
     "restore": "checkpoint restore-on-start; step it resumed from",
     "train_step": "periodic training step record: loss, step time, "
                   "throughput, MFU",
@@ -26,8 +27,13 @@ EVENTS: dict[str, str] = {
     "eval_skipped": "an eval cadence point was skipped (and why)",
     "checkpoint": "a checkpoint write completed",
     "preempted": "SIGTERM consensus reached; checkpointed and exiting",
+    "device_memory": "end of a training run: allocator bytes_in_use per "
+                     "local device, trained state still resident (None "
+                     "where the backend keeps no stats, e.g. CPU)",
     "serve_request": "one serving request completed: tokens, TTFT, latency",
-    "serve_summary": "end-of-run serving aggregate: tokens/sec, percentiles",
+    "serve_summary": "end-of-run serving aggregate: tokens/sec, percentiles, "
+                     "the device it ran on and the attention "
+                     "implementation each program resolved to",
     "span": "a traced span closed: name, dur_ms, depth, parent, rank, "
             "thread",
     "request_trace": "sampled end-to-end request lifecycle: queue wait, "

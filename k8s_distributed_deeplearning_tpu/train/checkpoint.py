@@ -185,10 +185,7 @@ class Checkpointer:
                               moved_to=dst)
         # The manager caches its step list; after the rename it must
         # re-scan or later restores/saves reference a vanished dir.
-        try:
-            self._mgr.reload()
-        except Exception:   # older orbax: recreate instead of reload
-            pass
+        self._mgr.reload()
 
     def restore_best(self, abstract_state: PyTree) -> tuple[PyTree, int] | None:
         """Restore the best checkpoint by the tracked metric (best-model

@@ -17,6 +17,7 @@ from typing import Any, Callable, Iterator
 
 import jax
 
+from k8s_distributed_deeplearning_tpu import backend
 from k8s_distributed_deeplearning_tpu import faults as _faults
 from k8s_distributed_deeplearning_tpu.parallel import distributed
 from k8s_distributed_deeplearning_tpu.telemetry.heartbeat import (
@@ -249,6 +250,11 @@ def fit(
             checkpointer.wait()
             inj.fire("checkpoint_saved", step=num_steps,
                      path=checkpointer.directory)
+    if metrics:
+        # Per device, with the trained state still resident: on a multi-chip
+        # host this is where a layout that left a chip empty shows.
+        metrics.emit("device_memory", step=max(start_step, num_steps),
+                     bytes_in_use=backend.device_bytes_in_use())
     if quant_calib is not None and distributed.is_primary():
         n = dump_quant_calibration(getattr(state, "params", state),
                                    quant_calib)

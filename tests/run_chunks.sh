@@ -1,5 +1,5 @@
 #!/usr/bin/env bash
-# Full-green proof in bounded chunks (VERDICT r2 item 8).
+# Full-green proof in bounded chunks (round 2, item 8).
 #
 # The suite is compile-bound on a 1-core box: one monolithic pytest run
 # exceeds practical tool/CI timeouts, and ad-hoc manual chunking is exactly
@@ -12,7 +12,8 @@
 #
 # Exit code: 0 = every chunk green; nonzero = the failing chunk's status,
 # with the chunk named on stderr. The persistent XLA compile cache
-# (conftest.py) makes warm reruns ~6x faster.
+# (conftest.py: $JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache)
+# makes warm reruns ~6x faster.
 set -u
 cd "$(dirname "$0")/.."
 
@@ -25,7 +26,7 @@ FAST=0
 # the runner.
 CHUNK_TIMEOUT="${CHUNK_TIMEOUT:-900}"
 declare -A CHUNKS
-CHUNKS[core]="tests/test_model_mnist.py tests/test_model_zoo.py tests/test_transformer.py tests/test_pallas_flash.py tests/test_pallas_gmm.py tests/test_bench_gate.py"
+CHUNKS[core]="tests/test_model_mnist.py tests/test_model_zoo.py tests/test_transformer.py tests/test_pallas_flash.py tests/test_pallas_gmm.py tests/test_bench_gate.py tests/test_chip_smoke.py"
 CHUNKS[parallel1]="tests/test_collectives.py tests/test_data_parallel.py tests/test_sharding.py tests/test_8b_scale.py tests/test_mesh_attention.py"
 CHUNKS[parallel2]="tests/test_context_parallel.py tests/test_pipeline.py tests/test_pipeline_lm.py"
 # MoE grew its own chunk in round 5 (ragged grouped-GEMM dispatch tests):

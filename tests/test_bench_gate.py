@@ -1,6 +1,6 @@
 """The bench regression gate (bench.check_regression) as a pure function.
 
-VERDICT r2 item 1: a 2-3% headline slide shipped silently because bench.py
+Round 2: a 2-3% headline slide shipped silently because bench.py
 had no stored baseline. These tests prove the gate fires exactly when a
 metric drops below baseline*(1-band) — including for metrics nested in
 ``extra`` — without touching a TPU.
@@ -55,7 +55,7 @@ def test_extra_metrics_gated(bench):
 
 def test_would_have_caught_r2_dip_at_measured_band(bench):
     # With the band at the measured ~1% spread the r2 dip (85173 -> 83121,
-    # -2.4%) fails the gate — the VERDICT's acceptance criterion.
+    # -2.4%) fails the gate — the round-2 acceptance criterion.
     write_baseline(bench, {
         "llama_small_tokens_per_sec_per_chip":
             {"value": 85173, "band_pct": 1.5}})
@@ -72,12 +72,3 @@ def test_unknown_and_non_numeric_keys_ignored(bench):
     write_baseline(bench, {"m": {"value": 100.0}, "other": {"value": 5.0}})
     rec = {"metric": "m", "value": 100.0, "extra": {"cfg": {"a": 1}}}
     assert bench.check_regression(rec) == []
-
-
-def test_repo_baseline_file_is_valid():
-    with open(os.path.join(REPO, "BENCH_BASELINE.json")) as f:
-        base = json.load(f)
-    numeric = {k: v for k, v in base.items() if isinstance(v, dict)}
-    assert "llama_small_tokens_per_sec_per_chip" in numeric
-    for spec in numeric.values():
-        assert spec["value"] > 0 and 0 < spec.get("band_pct", 3.0) < 50

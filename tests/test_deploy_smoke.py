@@ -98,10 +98,8 @@ def test_rendered_job_executes_locally(tmp_path):
     results = local_executor.run_local(
         cfg, timeout=420, cwd=REPO,
         extra_env={
-            "JAX_PLATFORM_NAME": "cpu",
+            "JAX_PLATFORMS": "cpu",
             "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
-            "JAX_COMPILATION_CACHE_DIR":
-                os.environ.get("JAX_COMPILATION_CACHE_DIR", ""),
         })
     assert [r.returncode for r in results] == [0, 0], \
         results[0].stderr[-2000:] + results[1].stderr[-2000:]

@@ -16,10 +16,8 @@ from k8s_distributed_deeplearning_tpu.launch import elastic, local_executor
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 CPU_ENV = {
-    "JAX_PLATFORM_NAME": "cpu",
+    "JAX_PLATFORMS": "cpu",
     "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
-    "JAX_COMPILATION_CACHE_DIR":
-        os.environ.get("JAX_COMPILATION_CACHE_DIR", ""),
 }
 
 

@@ -144,10 +144,6 @@ def test_act_embed_rule_keeps_batch_on_both_axes():
     assert y.sharding.spec == P(("data", "fsdp"),)
 
 
-@pytest.mark.skipif(not hasattr(jax, "shard_map"),
-                    reason="jax.shard_map not in this jax version (the "
-                           "sharded path itself is untestable, same as the "
-                           "other mesh-path tests)")
 def test_mesh_attention_broadcast_batch_mask(mesh3):
     """A mask carrying a size-1 batch dim ([1, 1, s, s] — the common
     'same additive mask for every row' shape) must ride the SHARDED path:

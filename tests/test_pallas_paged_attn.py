@@ -178,3 +178,41 @@ def test_serving_engine_parity_on_kernel_path():
         return [outs[r.request_id].tokens for r in reqs]
 
     assert run(kmodel) == run(model)
+
+
+def test_auto_selection_rule_is_shape_and_platform():
+    """``attention_impl="auto"`` for block-table calls: the kernel on TPU at
+    the query widths it serves (decode, verify window, a 128-token prefill
+    chunk), the XLA gather for wider prefill buckets and off TPU — and the
+    engine reports, per program, exactly what the model will trace."""
+    from k8s_distributed_deeplearning_tpu.models.transformer import (
+        paged_attention_impl)
+    from k8s_distributed_deeplearning_tpu.ops.pallas_paged_attn import (
+        MAX_QUERY_TOKENS, default_impl)
+    assert MAX_QUERY_TOKENS == 128
+    for sq in (1, 5, 128):
+        assert default_impl(sq, platform="tpu") == "paged_flash"
+    assert default_impl(256, platform="tpu") == "xla"
+    assert default_impl(1024, platform="tpu") == "xla"
+    assert default_impl(1, platform="cpu") == "xla"
+    assert default_impl(1) == "xla"                     # CI runs on CPU
+
+    cfg = llama.config_tiny(dtype=jnp.float32, max_seq_len=256)
+    forced = llama.config_tiny(dtype=jnp.float32, max_seq_len=256,
+                               attention_impl="paged_flash")
+    assert paged_attention_impl(cfg, 1) == "xla"
+    assert paged_attention_impl(forced, 1024) == "paged_flash"
+    assert paged_attention_impl(
+        llama.config_tiny(attention_impl="flash"), 1) == "xla"
+
+    model = llama.LlamaLM(forced)
+    params = model.init(jax.random.key(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    eng = ServeEngine(model, params, num_slots=2, prefill_chunk_tokens=64)
+    assert eng.attention_impls() == {
+        "decode": "paged_flash", "chunk_64": "paged_flash",
+        "final_chunk_32": "paged_flash", "final_chunk_64": "paged_flash"}
+    plain = ServeEngine(llama.LlamaLM(cfg), params, num_slots=2)
+    assert plain.attention_impls() == {
+        "decode": "xla", "final_chunk_32": "xla", "final_chunk_64": "xla",
+        "final_chunk_128": "xla", "final_chunk_256": "xla"}

@@ -5,14 +5,6 @@ import signal
 import jax
 import jax.numpy as jnp
 import optax
-import pytest
-
-# Restoring a checkpoint and stepping the restored state in the SAME process
-# that trained+saved it crashes the XLA CPU runtime natively (SIGSEGV/SIGABRT,
-# not catchable) on jax < 0.5 — same vintage gating as the shard_map skips in
-# test_mesh_attention.py. Fresh-process restore (the production path, covered
-# by tests/test_faults.py gang tests and the train_mnist resume probe) works.
-_OLD_JAX = tuple(int(v) for v in jax.__version__.split(".")[:2]) < (0, 5)
 
 from k8s_distributed_deeplearning_tpu.models import mnist
 from k8s_distributed_deeplearning_tpu.parallel import data_parallel as dp
@@ -65,8 +57,6 @@ def test_sigterm_checkpoints_and_stops(tmp_path, mesh8):
         handler.uninstall()
 
 
-@pytest.mark.skipif(_OLD_JAX, reason="in-process restore-then-step crashes "
-                    "the XLA CPU runtime natively on jax<0.5")
 def test_preemption_flag_stops_loop_and_saves(tmp_path, mesh8):
     state, step, batches = _setup(mesh8)
     handler = PreemptionHandler()

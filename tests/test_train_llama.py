@@ -12,11 +12,6 @@ import jax
 
 from k8s_distributed_deeplearning_tpu.train import data as data_lib
 
-# See tests/test_preemption.py: in-process restore-then-step crashes the XLA
-# CPU runtime natively on jax < 0.5; fresh-process restore (the production
-# path) is covered by tests/test_faults.py.
-_OLD_JAX = tuple(int(v) for v in jax.__version__.split(".")[:2]) < (0, 5)
-
 
 def test_token_batcher_windows_disjoint_and_deterministic():
     toks = np.arange(1025, dtype=np.int32)
@@ -127,8 +122,6 @@ def test_train_llama_pp_flag_conflicts():
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(_OLD_JAX, reason="in-process restore-then-step crashes "
-                    "the XLA CPU runtime natively on jax<0.5")
 def test_train_llama_resume(tmp_path):
     import train_llama
     base = ["--preset", "tiny", "--num-steps", "10", "--batch-size", "8",
