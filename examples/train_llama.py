@@ -45,6 +45,7 @@ from k8s_distributed_deeplearning_tpu.train import (
     optim,
     prefetch,
 )
+from k8s_distributed_deeplearning_tpu.telemetry.trace import Tracer
 from k8s_distributed_deeplearning_tpu.train.preemption import PreemptionHandler
 from k8s_distributed_deeplearning_tpu.utils.metrics import MetricsLogger
 from k8s_distributed_deeplearning_tpu.utils.profiling import StepProfiler
@@ -476,6 +477,10 @@ def main(argv: list[str] | None = None) -> dict:
             flops_per_example=flops_per_example,
             peak_flops=mesh_lib.peak_flops_per_device(args.dtype),
             preemption=preemption, profiler=profiler,
+            # A record-only tracer (no JSONL line, no ring): inside the
+            # profiler's window its spans are written into the trace as
+            # program:<name> host annotations beside the device's timeline.
+            tracer=Tracer() if profiler is not None else None,
             eval_every=conf.eval_every, eval_fn=eval_fn,
         )
 

@@ -84,24 +84,33 @@ def main(argv: list[str] | None = None) -> int:
             print(f"graftlint: no such path: {p}", file=sys.stderr)
             return 2
     run_paths = args.paths or None
+    changed = None
     if args.changed is not None:
         try:
-            run_paths = analysis.changed_paths(args.changed, run_paths)
+            changed = set(analysis.changed_paths(args.changed, run_paths))
         except RuntimeError as e:
             print(f"graftlint: {e}", file=sys.stderr)
             return 2
-        if not run_paths:
+    try:
+        if changed is not None and not changed:
             # Nothing in the scan set changed — trivially clean, same
             # output/exit contract as an empty full run.
-            run_paths = []
-    try:
-        if run_paths == []:
             report = analysis.Report(findings=(), suppressed=())
         else:
             report = analysis.run(run_paths, select=select or None)
     except ValueError as e:
         print(f"graftlint: {e}", file=sys.stderr)
         return 2
+    if changed:
+        # The whole scan set is read (the registry passes hold contracts
+        # that span files: a changed events.py is judged against every
+        # emit site, not only the changed ones); findings are reported for
+        # the changed files alone.
+        report = analysis.Report(
+            findings=tuple(f for f in report.findings
+                           if os.path.abspath(f.path) in changed),
+            suppressed=tuple(f for f in report.suppressed
+                             if os.path.abspath(f.path) in changed))
 
     if args.as_json:
         print(json.dumps({

@@ -268,5 +268,8 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array,
                             + scale_bytes),
             transcendentals=b * h * sq * s_virt),
         interpret=interpret,
+        # The name the device trace carries for the kernel's events: chosen
+        # here, not inherited from whichever module scope calls the kernel.
+        name="paged_attn",
     )(tables, *operands)
     return out.reshape(b, sq, h, hd)

@@ -255,13 +255,18 @@ class ShardedTrainer:
             return jax.tree.map(one, tree)
 
         def step(state: TrainState, batch: PyTree, rng: jax.Array):
+            # The two scopes are stable names in the device trace (every
+            # operation's ``tf_op`` starts with one of them): device time
+            # splits into the model's passes and the optimizer's update.
             with nn.logical_axis_rules(rules):  # trace-time rule context
-                (loss, aux), grads = accumulate_gradients(
-                    loss_fn, state.params, batch, rng, microbatches,
-                    constrain=constrain if microbatches > 1 else None)
-                updates, opt_state = opt.update(grads, state.opt_state,
-                                                state.params)
-                params = optax.apply_updates(state.params, updates)
+                with jax.named_scope("forward_backward"):
+                    (loss, aux), grads = accumulate_gradients(
+                        loss_fn, state.params, batch, rng, microbatches,
+                        constrain=constrain if microbatches > 1 else None)
+                with jax.named_scope("optimizer"):
+                    updates, opt_state = opt.update(grads, state.opt_state,
+                                                    state.params)
+                    params = optax.apply_updates(state.params, updates)
                 return (TrainState(params, opt_state, state.step + 1),
                         loss, aux)
 

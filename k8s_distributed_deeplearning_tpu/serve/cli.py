@@ -785,6 +785,7 @@ def main(argv: list[str] | None = None) -> int:
         logger.emit("serve_summary", num_slots=args.slots,
                     preset=args.preset, replicas=1, **_ran_on(engine),
                     **stats.summary())
+        logger.emit("compile", **backend.compile_log().summary())
         server.close()
         logger.close()
         return 0
@@ -800,8 +801,10 @@ def main(argv: list[str] | None = None) -> int:
         bridge.serving_collector(registry, stats)
         if engines:
             # Remote mode has no local engines; replica-servers export
-            # their own serve_tp from their own /metrics.
+            # their own serve_tp (and compile seconds) from their own
+            # /metrics.
             bridge.tp_collector(registry, engines)
+            bridge.compile_collector(registry)
         if gateway is not None:
             bridge.gateway_collector(registry, gateway)
             if controller is not None:
@@ -893,6 +896,7 @@ def main(argv: list[str] | None = None) -> int:
     logger.emit("serve_summary", num_slots=args.slots,
                 preset=args.preset, replicas=args.replicas,
                 **_ran_on(engine), **stats.summary())
+    logger.emit("compile", **backend.compile_log().summary())
     if controller is not None:
         logger.emit("autoscale_summary", **controller.snapshot())
         reap = getattr(autoscale_backend, "reap_all", None)

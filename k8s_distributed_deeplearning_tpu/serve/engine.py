@@ -1449,20 +1449,34 @@ class ServeEngine:
         very iteration), and an expired request popped from the queue
         completes as "timeout" without ever prefilling. A hung client
         therefore costs at most one decode iteration of slot time past
-        its own budget, and never stalls the other slots."""
+        its own budget, and never stalls the other slots.
+
+        Every phase is a span of ``self.tracer``, nested under
+        ``engine_step``: ``sweep`` (the deadline sweeps), ``admission``
+        and ``prefill`` per request, ``grow`` (decode-growth pages),
+        ``decode`` (dispatch + fence), ``device_wait`` (ONLY the blocking
+        read of the device's result, inside ``decode`` and the final-chunk
+        ``prefill``), ``emit`` (per-slot bookkeeping and ``on_token``
+        after the fence) and ``epilogue``."""
+        with self.tracer.span("engine_step", step=self.stats.steps):
+            return self._step()
+
+    # graftlint: hot-path
+    def _step(self) -> list[RequestOutput]:
         outputs: list[RequestOutput] = []
-        now = time.perf_counter()
-        for slot, fl in enumerate(self._slots):
-            if fl is not None and self._expired(fl.req, now):
-                outputs.append(self._finish(slot, "timeout"))
-        for slot in list(self._pending):
-            if self._expired(self._pending[slot].req, now):
-                outputs.append(self._cancel_pending(slot, "timeout"))
-        # Queue-time deadline sweep: requests already dead stop consuming
-        # queue capacity (and their tenant's EDF head) NOW, not when a
-        # free slot happens to pop them.
-        for req in self.queue.sweep_expired(now):
-            outputs.append(self._timeout_unadmitted(req))
+        with self.tracer.span("sweep"):
+            now = time.perf_counter()
+            for slot, fl in enumerate(self._slots):
+                if fl is not None and self._expired(fl.req, now):
+                    outputs.append(self._finish(slot, "timeout"))
+            for slot in list(self._pending):
+                if self._expired(self._pending[slot].req, now):
+                    outputs.append(self._cancel_pending(slot, "timeout"))
+            # Queue-time deadline sweep: requests already dead stop
+            # consuming queue capacity (and their tenant's EDF head) NOW,
+            # not when a free slot happens to pop them.
+            for req in self.queue.sweep_expired(now):
+                outputs.append(self._timeout_unadmitted(req))
         self.last_step_prefill_tokens = 0
         self._step_prefill_budget = self.prefill_chunk_tokens
         flight_on = self.flight is not None and self.flight.enabled
@@ -1503,60 +1517,66 @@ class ServeEngine:
         # one any emitted token can occupy) — writes beyond that land in
         # the scratch page and the garbage selections they feed are
         # provably never emitted.
-        for slot, fl in enumerate(self._slots):
-            if fl is None:
-                continue
-            last = int(self._kv_lens[slot])
-            if self.spec_k:
-                limit = len(fl.req.prompt) + fl.req.max_new_tokens - 2
-                last = min(last + self.spec_k, limit)
-            for blk in range(int(self._kv_lens[slot]) // self.page_tokens,
-                             last // self.page_tokens + 1):
-                if self._tables[slot, blk] == 0:
-                    self._tables[slot, blk] = self.pool.alloc_reserved(1)[0]
-                    fl.grow_left -= 1
+        with self.tracer.span("grow"):
+            for slot, fl in enumerate(self._slots):
+                if fl is None:
+                    continue
+                last = int(self._kv_lens[slot])
+                if self.spec_k:
+                    limit = len(fl.req.prompt) + fl.req.max_new_tokens - 2
+                    last = min(last + self.spec_k, limit)
+                for blk in range(int(self._kv_lens[slot]) // self.page_tokens,
+                                 last // self.page_tokens + 1):
+                    if self._tables[slot, blk] == 0:
+                        self._tables[slot, blk] = (
+                            self.pool.alloc_reserved(1)[0])
+                        fl.grow_left -= 1
         inj = _faults.active()
         if inj is not None:
             inj.fire("serve_decode")
         t_dec = time.perf_counter() if flight_on else 0.0
         if self.spec_k:
             self._spec_decode(active, outputs)
-            if flight_on:
-                self._last_decode_ms = round(
-                    (time.perf_counter() - t_dec) * 1e3, 3)
-            self._step_epilogue()
-            return outputs
-        with self.tracer.span("decode", active=active):
-            nxt, keys, self._cache = self._decode_step()
-            # graftlint: disable=host-sync — the iteration's one honest
-            # sync: every slot's sampled token in a single device fence.
-            nxt = np.asarray(nxt)
-            # np.array (copy), not np.asarray: the zero-copy view of a jax
-            # CPU buffer is read-only, and admissions write per-slot keys
-            # in place.
-            # graftlint: disable=host-sync — rides the same fence as nxt
-            self._keys = np.array(keys)
+        else:
+            self._decode(active, outputs)
         if flight_on:
             self._last_decode_ms = round(
                 (time.perf_counter() - t_dec) * 1e3, 3)
-        self.stats.record_step(active, self.num_slots)
-        for slot, fl in enumerate(self._slots):
-            if fl is None:
-                continue
-            tok = int(nxt[slot])
-            # The PREVIOUS token was just written at kv_lens; the freshly
-            # sampled one becomes the next step's input.
-            self._kv_lens[slot] += 1
-            self._tokens[slot] = tok
-            fl.tokens.append(tok)
-            if fl.req.on_token is not None:
-                fl.req.on_token(tok)
-            if self.eos_id is not None and tok == self.eos_id:
-                outputs.append(self._finish(slot, "eos"))
-            elif len(fl.tokens) >= fl.req.max_new_tokens:
-                outputs.append(self._finish(slot, "length"))
         self._step_epilogue()
         return outputs
+
+    # graftlint: hot-path
+    def _decode(self, active: int, outputs: list[RequestOutput]) -> None:
+        """Advance every occupied slot one token: one dispatch, one fence,
+        then the host-side bookkeeping per slot."""
+        with self.tracer.span("decode", active=active):
+            nxt, keys, self._cache = self._decode_step()
+            with self.tracer.span("device_wait", kind="decode"):
+                # graftlint: disable=host-sync — the iteration's one honest
+                # sync: every slot's sampled token in a single device fence.
+                nxt = np.asarray(nxt)
+                # np.array (copy), not np.asarray: the zero-copy view of a
+                # jax CPU buffer is read-only, and admissions write per-slot
+                # keys in place.
+                # graftlint: disable=host-sync — rides the same fence as nxt
+                self._keys = np.array(keys)
+        with self.tracer.span("emit"):
+            self.stats.record_step(active, self.num_slots)
+            for slot, fl in enumerate(self._slots):
+                if fl is None:
+                    continue
+                tok = int(nxt[slot])
+                # The PREVIOUS token was just written at kv_lens; the
+                # freshly sampled one becomes the next step's input.
+                self._kv_lens[slot] += 1
+                self._tokens[slot] = tok
+                fl.tokens.append(tok)
+                if fl.req.on_token is not None:
+                    fl.req.on_token(tok)
+                if self.eos_id is not None and tok == self.eos_id:
+                    outputs.append(self._finish(slot, "eos"))
+                elif len(fl.tokens) >= fl.req.max_new_tokens:
+                    outputs.append(self._finish(slot, "length"))
 
     # graftlint: hot-path
     def _spec_decode(self, active: int,
@@ -1578,17 +1598,24 @@ class ServeEngine:
             window, self._draft_cache = self._spec_draft_step()
             sel, key_states, acc, self._cache = self._spec_verify_step(
                 window)
-            # graftlint: disable=host-sync — the iteration's one honest
-            # sync: every slot's window/selections in a single fence.
-            window = np.asarray(window)
-            # graftlint: disable=host-sync — rides the same fence
-            sel = np.asarray(sel)
-            # graftlint: disable=host-sync — rides the same fence
-            acc = np.asarray(acc)
-            # np.array (copy): the key register is written in place at
-            # admissions, and only the emitted-count column survives.
-            # graftlint: disable=host-sync — rides the same fence
-            key_states = np.array(key_states)
+            with self.tracer.span("device_wait", kind="spec"):
+                # graftlint: disable=host-sync — the iteration's one honest
+                # sync: every slot's window/selections in a single fence.
+                window = np.asarray(window)
+                # graftlint: disable=host-sync — rides the same fence
+                sel = np.asarray(sel)
+                # graftlint: disable=host-sync — rides the same fence
+                acc = np.asarray(acc)
+                # np.array (copy): the key register is written in place at
+                # admissions, and only the emitted-count column survives.
+                # graftlint: disable=host-sync — rides the same fence
+                key_states = np.array(key_states)
+        with self.tracer.span("emit"):
+            self._spec_emit(active, outputs, window, sel, acc, key_states)
+
+    def _spec_emit(self, active: int, outputs: list[RequestOutput],
+                   window, sel, acc, key_states) -> None:
+        """The host-side accept bookkeeping of one speculative step."""
         emitted_total = 0
         proposed = 0
         accepted_counts: list[int] = []
@@ -1808,8 +1835,8 @@ class ServeEngine:
         if req.on_finish is not None:
             req.on_finish(reason)
 
-    def _record_pool_gauges(self) -> None:
-        c = self.pool.counters()
+    def _record_pool_gauges(self, counters: dict | None = None) -> None:
+        c = counters if counters is not None else self.pool.counters()
         self.stats.record_kv_pool(c["pages_total"], c["pages_used"],
                                   c["pages_shared"],
                                   by_owner=self.pool.owners_summary())
@@ -1818,8 +1845,19 @@ class ServeEngine:
         """Every :meth:`step` return path funnels here: refresh the pool
         gauges, append this step's flight-recorder snapshot, and — once a
         draining engine runs out of work — run the one-shot drain
-        finalization (page-leak check + flight dump)."""
-        self._record_pool_gauges()
+        finalization (page-leak check + flight dump). The ``epilogue``
+        span's fields are the step's gauges (the pool's fill among them),
+        read before it opens: nothing writes to a span after the fact."""
+        c = self.pool.counters()
+        with self.tracer.span(
+                "epilogue", active=self.occupied_slots(),
+                queued=len(self.queue),
+                prefill_tokens=self.last_step_prefill_tokens,
+                pages_used=c["pages_used"], pages_total=c["pages_total"]):
+            self._epilogue(c)
+
+    def _epilogue(self, counters: dict) -> None:
+        self._record_pool_gauges(counters)
         fr = self.flight
         if fr is not None and fr.enabled:
             depths = getattr(self.queue, "depths", None)
@@ -2040,7 +2078,8 @@ class ServeEngine:
         bt = self.page_tokens
         hit, nodes = 0, []
         table = np.zeros(self.max_blocks, np.int32)
-        with self.tracer.span("admission", prompt_len=n, slot=slot):
+        with self.tracer.span("admission", prompt_len=n, slot=slot,
+                              request_id=req.request_id):
             if self.prefix_cache is not None:
                 hit, nodes = self.prefix_cache.acquire(prompt.tolist())
                 self.stats.record_prefix_lookup(hit, n)
@@ -2077,7 +2116,8 @@ class ServeEngine:
                     if budget is not None and budget < c:
                         break       # out of budget; resume next iteration
                     chunk = pend.prompt[None, pend.pos:pend.pos + c]
-                    with self.tracer.span("prefill", chunk=c, slot=slot):
+                    with self.tracer.span("prefill", chunk=c, slot=slot,
+                                          request_id=pend.req.request_id):
                         self._cache = self._chunk_step(
                             np.ascontiguousarray(chunk),
                             np.ascontiguousarray(table),
@@ -2130,7 +2170,8 @@ class ServeEngine:
         self._tables[slot, :] = pend.table
         table = self._tables[slot:slot + 1]
         with self.tracer.span("prefill", bucket=bucket, slot=slot,
-                              cached=pend.hit_tokens):
+                              cached=pend.hit_tokens,
+                              request_id=req.request_id):
             tok, key, self._cache = self._final_chunk_step(
                 chunk, np.ascontiguousarray(table), np.int32(pend.pos),
                 np.int32(rem), np.float32(sp.temperature),
@@ -2163,7 +2204,8 @@ class ServeEngine:
                     self.stats.record_prefix_evictions(evicted)
                 self.prefix_cache.release(pend.nodes)
                 pend.nodes = []
-            first = int(tok)
+            with self.tracer.span("device_wait", kind="first_token"):
+                first = int(tok)     # the admission's one fence
         del self._pending[slot]
         now = time.perf_counter()
         fl = _InFlight(req, first, now)

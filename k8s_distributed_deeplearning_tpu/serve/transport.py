@@ -107,7 +107,7 @@ from k8s_distributed_deeplearning_tpu.serve.request import (
     EngineDraining, QueueFull, Request, SamplingParams)
 from k8s_distributed_deeplearning_tpu.telemetry import heartbeat as hb
 from k8s_distributed_deeplearning_tpu.telemetry.bridge import (
-    sched_collector, serving_collector)
+    compile_collector, sched_collector, serving_collector)
 from k8s_distributed_deeplearning_tpu.telemetry.exporter import (
     MetricsExporter)
 from k8s_distributed_deeplearning_tpu.telemetry.fleet import (
@@ -242,6 +242,7 @@ class ReplicaServer:
             registry = MetricsRegistry()
             serving_collector(registry, engine.stats)
             sched_collector(registry, engine.queue)
+            compile_collector(registry)
             self._register_engine_gauges(registry)
         self.registry = registry
         routes = {

@@ -298,6 +298,29 @@ def serving_collector(registry: MetricsRegistry,
     registry.register_collector(collect)
 
 
+def compile_collector(registry: MetricsRegistry) -> None:
+    """Register a pull-time collector over ``backend.compile_log()``:
+    ``xla_compile_seconds_total{phase=...}`` (jaxpr trace, lowering,
+    backend compile, persistent-cache retrieval) — which restart paid a
+    minute before its first step, and in which phase. Each scrape adds what
+    the phase's wall seconds grew by since the last one."""
+    from k8s_distributed_deeplearning_tpu import backend
+    secs = registry.counter(
+        "xla_compile_seconds_total",
+        "wall seconds this process spent per compile phase (jax.monitoring)",
+        labelnames=("phase",))
+    log = backend.compile_log()
+    phases = [p for p in backend.COMPILE_PHASES.values()
+              if p != "cache_saved"]          # a derived figure, can be < 0
+
+    def collect() -> None:
+        for phase in phases:
+            child = secs.labels(phase=phase)
+            child.inc(max(0.0, log.seconds(phase) - child.value))
+
+    registry.register_collector(collect)
+
+
 def storm_collector(registry: MetricsRegistry, monitor,
                     injector=None) -> None:
     """Register a pull-time collector over a graftstorm

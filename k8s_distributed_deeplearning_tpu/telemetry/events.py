@@ -30,6 +30,9 @@ EVENTS: dict[str, str] = {
     "device_memory": "end of a training run: allocator bytes_in_use per "
                      "local device, trained state still resident (None "
                      "where the backend keeps no stats, e.g. CPU)",
+    "compile": "end of a training or serving run: count and seconds of "
+               "every compile phase since the process started (trace, "
+               "lower, backend_compile, cache_retrieval, cache_saved)",
     "serve_request": "one serving request completed: tokens, TTFT, latency",
     "serve_summary": "end-of-run serving aggregate: tokens/sec, percentiles, "
                      "the device it ran on and the attention "

@@ -39,6 +39,14 @@ Spans can optionally mirror into a Prometheus histogram
 the bridge between the log plane and the pull plane — and into an
 in-memory ring buffer (*ring_size*) that the exporter's ``/debug/spans``
 endpoint serves when the Loki pipeline itself is the thing that's down.
+
+**On the profiler's clock.** While a ``jax.profiler`` session that the
+program started is on (``utils.profiling.trace`` / ``StepProfiler``, the
+exporter's ``/debug/profile``), every span of an enabled tracer also enters
+a ``TraceAnnotation`` named ``program:<name>``: the device trace then shows
+what the host was doing beside each idle gap. ``utils.profiling`` flips the
+switch (:func:`profiler_session`) at start and stop; this module never
+imports jax, and with no session on a span pays one global read for it.
 """
 from __future__ import annotations
 
@@ -57,6 +65,20 @@ if TYPE_CHECKING:
 _SPAN_BUCKETS_MS = (1.0, 5.0, 10.0, 50.0, 100.0, 500.0, 1000.0, 5000.0,
                     30000.0, 120000.0)
 
+# The prefix the trace reductions select host spans by.
+ANNOTATION_PREFIX = "program:"
+# The annotation class while a profiler session is on, else None. Process-
+# wide on purpose: the profiler session is, too.
+_annotation = None
+
+
+def profiler_session(annotation) -> None:
+    """Called by ``utils.profiling`` when it starts (with
+    ``jax.profiler.TraceAnnotation``) and stops (with None) a profiler
+    session: spans opened in between are also written into its trace."""
+    global _annotation
+    _annotation = annotation
+
 
 class _NullSpan:
     """Shared no-op span: the disabled tracer's entire hot-path cost."""
@@ -74,13 +96,15 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "fields", "_t0", "parent", "depth")
+    __slots__ = ("_tracer", "name", "fields", "_t0", "_ann", "parent",
+                 "depth")
 
     def __init__(self, tracer: "Tracer", name: str, fields: dict[str, Any]):
         self._tracer = tracer
         self.name = name
         self.fields = fields
         self._t0 = 0.0
+        self._ann = None
         self.parent: str | None = None
         self.depth = 0
 
@@ -89,15 +113,21 @@ class _Span:
         self.parent = stack[-1].name if stack else None
         self.depth = len(stack)
         stack.append(self)
+        ann = _annotation
+        if ann is not None:
+            self._ann = ann(ANNOTATION_PREFIX + self.name)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        dur_ms = (time.perf_counter() - self._t0) * 1e3
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
-        self._tracer._closed(self, dur_ms)
+        self._tracer._closed(self, t1)
 
 
 class Tracer:
@@ -107,7 +137,9 @@ class Tracer:
     spans shorter than *min_dur_ms* are timed but not emitted (hot inner
     loops can trace without flooding Loki). *ring_size* > 0 additionally
     keeps the newest N span records in memory for
-    :meth:`recent_spans` / the exporter's ``/debug/spans`` endpoint."""
+    :meth:`recent_spans` / the exporter's ``/debug/spans`` endpoint (``ts``
+    is the wall clock at close; ``t0``/``t1`` are ``time.perf_counter``
+    at open and close, the clock span arithmetic is done on)."""
 
     def __init__(self, logger: "MetricsLogger | None" = None, *,
                  rank: int = 0, enabled: bool = True,
@@ -155,7 +187,8 @@ class Tracer:
         ``ring_size`` was 0) — the ``/debug/spans`` payload."""
         return list(self._ring) if self._ring is not None else []
 
-    def _closed(self, span: _Span, dur_ms: float) -> None:
+    def _closed(self, span: _Span, t1: float) -> None:
+        dur_ms = (t1 - span._t0) * 1e3
         self._local.last_span = span.name
         thread = threading.current_thread().name
         if self._hist is not None:
@@ -167,7 +200,8 @@ class Tracer:
                                "dur_ms": round(dur_ms, 3),
                                "depth": span.depth, "parent": span.parent,
                                "rank": self.rank, "thread": thread,
-                               "ts": time.time(), **span.fields})
+                               "ts": time.time(), "t0": span._t0, "t1": t1,
+                               **span.fields})
         if self.logger is None:
             return
         self.spans_emitted += 1

@@ -113,9 +113,14 @@ def fit(
     (``ModelCheckpoint save_best_only`` parity, ``:160-163``).
 
     *tracer*: a :class:`telemetry.trace.Tracer` adding the loop's built-in
-    spans — ``data_wait`` (host blocked on the batch source), ``step``
-    (dispatch of the jitted step; async, so this measures host-side cost
-    unless the step blocks) and ``checkpoint`` (save calls). *heartbeat*:
+    spans, all at depth 0 and with ``step=`` — per step ``data_wait`` (host
+    blocked on the batch source), ``rng`` (the ``fold_in`` dispatch),
+    ``step`` (dispatch of the jitted step; async, so this measures
+    host-side cost unless the step blocks) and ``hooks`` (heartbeat,
+    preemption check; a second one before ``data_wait`` around the fault
+    and profiler hooks where a run has either); at the log cadence ``log_sync`` (only the fence on
+    the loss) and ``log`` (the metrics line and gauges); ``eval`` and
+    ``checkpoint`` around those calls. *heartbeat*:
     a :class:`telemetry.heartbeat.HeartbeatWriter` beaten every step with
     the current step and the tracer's last-completed span — ``launch
     watch --heartbeat-dir`` turns a stale file into a named stalled rank.
@@ -140,78 +145,99 @@ def fit(
 
     batch_iter = batches(start_step) if callable(batches) else batches
     tr = tracer if tracer is not None else _NULL_TRACER
+    if tr.enabled:
+        # The process's first ``fold_in`` traces and compiles it: paid here,
+        # so that the first step's ``rng`` span times a dispatch like every
+        # other step's (graftscope would name that step a straggler).
+        jax.random.fold_in(rng, start_step)
     n_dev = jax.device_count()
     t_last = time.monotonic()
     step_last = start_step  # steps actually in the current timing window
     step = start_step
     for step in range(start_step, num_steps):
-        if inj is not None:
-            inj.fire("step", step=step)
-        if profiler is not None:
-            profiler.step_hook(step)
-        # Both hot-loop spans carry step= so graftscope (telemetry/
-        # timeline.py) can align ranks on step number instead of wall
-        # clock — per-rank JSONL clocks start at different t0s.
+        if inj is not None or profiler is not None:
+            with tr.span("hooks", step=step):
+                if inj is not None:
+                    inj.fire("step", step=step)
+                if profiler is not None:
+                    profiler.step_hook(step)
+        # Every span is a depth-0 sibling and carries step=: graftscope
+        # (telemetry/timeline.py) aligns ranks on the step number (per-rank
+        # JSONL clocks start at different t0s) and sums a step's depth-0
+        # spans into its components, so no span wraps the iteration.
         with tr.span("data_wait", step=step):
             if inj is not None:
                 inj.fire("data_wait", step=step)
             batch = next(batch_iter)
-        step_rng = jax.random.fold_in(rng, step)
+        with tr.span("rng", step=step):
+            step_rng = jax.random.fold_in(rng, step)
         with tr.span("step", step=step):
             state, loss, aux = step_fn(state, batch, step_rng)
-        if heartbeat is not None and (
-                inj is None or not inj.suppressed("heartbeat", step=step + 1)):
-            heartbeat.beat(step + 1, last_span=tr.last_span)
-
-        if preemption is not None:
+        with tr.span("hooks", step=step):
+            if heartbeat is not None and (
+                    inj is None
+                    or not inj.suppressed("heartbeat", step=step + 1)):
+                heartbeat.beat(step + 1, last_span=tr.last_span)
             # Single process: react immediately on the local flag. Multi-
             # process: ONLY branch on the collective agreement (same step on
             # every process) — a local-flag branch would diverge the SPMD
             # programs and deadlock (see preemption.py).
-            if jax.process_count() == 1:
+            if preemption is None:
+                stop = False
+            elif jax.process_count() == 1:
                 stop = preemption.triggered
             else:
                 stop = ((step + 1) % preemption_sync_every == 0
                         and preemption.agreed())
-            if stop:
-                if checkpointer is not None:
-                    with tr.span("checkpoint", step=step + 1):
-                        checkpointer.save(step + 1, state, force=True)
-                if metrics:
-                    metrics.emit("preempted", step=step + 1,
-                                 checkpointed=checkpointer is not None)
-                if profiler is not None:
-                    profiler.stop()
-                return state
+        if stop:
+            if checkpointer is not None:
+                with tr.span("checkpoint", step=step + 1):
+                    checkpointer.save(step + 1, state, force=True)
+            if metrics:
+                metrics.emit("preempted", step=step + 1,
+                             checkpointed=checkpointer is not None)
+            if profiler is not None:
+                profiler.stop()
+            return state
 
         if metrics and log_every and (step + 1) % log_every == 0:
-            # graftlint: disable=host-sync — the one intentional sync, at
-            # log cadence only: everything between logs stays async.
-            loss_f = float(loss)  # blocks: this is the host sync point
-            now = time.monotonic()
-            window = step + 1 - step_last
-            dt_ms = (now - t_last) * 1e3 / window
-            t_last = now
-            step_last = step + 1
-            eps = (global_batch_size or 0) / (dt_ms / 1e3) if global_batch_size else 0.0
-            extra = {}
-            for k, v in (aux or {}).items():
-                # graftlint: disable=host-sync — rides the log-cadence sync
-                extra[k] = float(v)
-            m = None
-            if flops_per_example and peak_flops:
-                m = mfu(flops_per_example, eps, n_dev, peak_flops)
-            metrics.train_step(step + 1, loss_f, dt_ms, eps,
-                               eps / n_dev if n_dev else 0.0, mfu=m, **extra)
-            if telemetry is not None:
-                telemetry.on_log(steps_in_window=window, loss=loss_f,
-                                 step_time_ms=dt_ms, examples_per_sec=eps,
-                                 mfu=m)
+            # ``log_sync`` holds the fence and nothing else: the time the
+            # host waits for the device to finish the step just dispatched.
+            # When it returns the device's queue is empty, and stays empty
+            # until the next ``step`` dispatch — what follows up to there is
+            # time the device has nothing to do.
+            with tr.span("log_sync", step=step):
+                # graftlint: disable=host-sync — the one intentional sync,
+                # at log cadence only: everything between logs stays async.
+                loss_f = float(loss)  # blocks: this is the host sync point
+                extra = {}
+                for k, v in (aux or {}).items():
+                    # graftlint: disable=host-sync — rides the same sync
+                    extra[k] = float(v)
+            with tr.span("log", step=step):
+                now = time.monotonic()
+                window = step + 1 - step_last
+                dt_ms = (now - t_last) * 1e3 / window
+                t_last = now
+                step_last = step + 1
+                eps = ((global_batch_size or 0) / (dt_ms / 1e3)
+                       if global_batch_size else 0.0)
+                m = None
+                if flops_per_example and peak_flops:
+                    m = mfu(flops_per_example, eps, n_dev, peak_flops)
+                metrics.train_step(step + 1, loss_f, dt_ms, eps,
+                                   eps / n_dev if n_dev else 0.0, mfu=m,
+                                   **extra)
+                if telemetry is not None:
+                    telemetry.on_log(steps_in_window=window, loss=loss_f,
+                                     step_time_ms=dt_ms,
+                                     examples_per_sec=eps, mfu=m)
 
         if eval_fn is not None and eval_every and (step + 1) % eval_every == 0:
-            # graftlint: disable=host-sync — eval results are read at eval
-            # cadence; blocking here is the point.
-            ev = {k: float(v) for k, v in eval_fn(state).items()}
+            with tr.span("eval", step=step):
+                # graftlint: disable=host-sync — eval results are read at
+                # eval cadence; blocking here is the point.
+                ev = {k: float(v) for k, v in eval_fn(state).items()}
             if metrics:
                 metrics.emit("eval", step=step + 1, **ev)
             if (checkpointer is not None
@@ -255,6 +281,8 @@ def fit(
         # host this is where a layout that left a chip empty shows.
         metrics.emit("device_memory", step=max(start_step, num_steps),
                      bytes_in_use=backend.device_bytes_in_use())
+        metrics.emit("compile", step=max(start_step, num_steps),
+                     **backend.compile_log().summary())
     if quant_calib is not None and distributed.is_primary():
         n = dump_quant_calibration(getattr(state, "params", state),
                                    quant_calib)

@@ -11,8 +11,6 @@ _LAZY = {
                       "MetricsLogger"),
     "StepProfiler": ("k8s_distributed_deeplearning_tpu.utils.profiling",
                      "StepProfiler"),
-    "StepTimer": ("k8s_distributed_deeplearning_tpu.utils.profiling",
-                  "StepTimer"),
     "retry_transient": ("k8s_distributed_deeplearning_tpu.utils.retry",
                         "retry_transient"),
 }

@@ -8,11 +8,11 @@ activity. Here profiling is first-class and TPU-native:
 - :func:`trace` / :class:`StepProfiler` wrap ``jax.profiler`` — the traces
   land in a TensorBoard/XProf-readable directory with host + device
   timelines, XLA HLO, and (on TPU) per-op MXU/HBM utilization;
-- :class:`StepTimer` measures honest step wall-times: it blocks on the
-  step's *output value* (TPU dispatch is async; timing the dispatch call
-  alone flatters the number) and reports p50/p95/mean;
-- :func:`annotate` marks host-side spans so data-loading vs dispatch vs
-  blocking time separates cleanly in the trace viewer.
+- while either has a session on, every span of the program's
+  :class:`telemetry.trace.Tracer` is also written into it as a
+  ``program:<name>`` host annotation (:func:`_start` / :func:`_stop` flip
+  the tracer's switch), so data wait, dispatch and blocking time sit
+  beside the device's timeline.
 
 Only the primary process should write traces (rank-0 discipline, parity with
 ``tensorflow_mnist.py:159``); pass ``enabled=is_primary()``.
@@ -20,13 +20,23 @@ Only the primary process should write traces (rank-0 discipline, parity with
 from __future__ import annotations
 
 import contextlib
-import statistics
-import time
-from typing import Any, Iterator
+from typing import Iterator
 
 import jax
 
-__all__ = ["trace", "annotate", "StepProfiler", "StepTimer"]
+from k8s_distributed_deeplearning_tpu.telemetry import trace as _spans
+
+__all__ = ["trace", "StepProfiler"]
+
+
+def _start(log_dir: str) -> None:
+    jax.profiler.start_trace(log_dir)
+    _spans.profiler_session(jax.profiler.TraceAnnotation)
+
+
+def _stop() -> None:
+    _spans.profiler_session(None)
+    jax.profiler.stop_trace()
 
 
 @contextlib.contextmanager
@@ -36,16 +46,11 @@ def trace(log_dir: str, enabled: bool = True) -> Iterator[None]:
     if not enabled:
         yield
         return
-    jax.profiler.start_trace(log_dir)
+    _start(log_dir)
     try:
         yield
     finally:
-        jax.profiler.stop_trace()
-
-
-def annotate(name: str) -> contextlib.AbstractContextManager:
-    """Named host-side span, visible in the trace viewer's host timeline."""
-    return jax.profiler.TraceAnnotation(name)
+        _stop()
 
 
 class StepProfiler:
@@ -73,7 +78,7 @@ class StepProfiler:
         if not self.enabled or self._done:
             return
         if not self._active and step >= self.start_step:
-            jax.profiler.start_trace(self.log_dir)
+            _start(self.log_dir)
             self._active = True
             self._stop_step = step + self.num_steps
         elif self._active and step >= self._stop_step:
@@ -81,45 +86,6 @@ class StepProfiler:
 
     def stop(self) -> None:
         if self._active:
-            jax.profiler.stop_trace()
+            _stop()
             self._active = False
             self._done = True
-
-
-class StepTimer:
-    """Wall-clock step statistics with a true device sync per sample.
-
-    ``observe(value)`` blocks on *value* (e.g. the loss) before reading the
-    clock, so async dispatch can't hide device time. Warmup steps (compile)
-    are excluded from the summary.
-    """
-
-    def __init__(self, warmup: int = 2):
-        self.warmup = warmup
-        self._samples: list[float] = []
-        self._seen = 0
-        self._last = time.perf_counter()
-
-    def observe(self, value: Any = None) -> float:
-        if value is not None:
-            jax.block_until_ready(value)
-        now = time.perf_counter()
-        dt = now - self._last
-        self._last = now
-        self._seen += 1
-        if self._seen > self.warmup:
-            self._samples.append(dt)
-        return dt
-
-    def summary(self) -> dict[str, float]:
-        if not self._samples:
-            return {"steps": 0}
-        s = sorted(self._samples)
-        return {
-            "steps": len(s),
-            "mean_ms": 1e3 * statistics.fmean(s),
-            "p50_ms": 1e3 * s[len(s) // 2],
-            "p95_ms": 1e3 * s[min(len(s) - 1, int(len(s) * 0.95))],
-            "min_ms": 1e3 * s[0],
-            "max_ms": 1e3 * s[-1],
-        }

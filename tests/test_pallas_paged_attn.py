@@ -216,3 +216,20 @@ def test_auto_selection_rule_is_shape_and_platform():
     assert plain.attention_impls() == {
         "decode": "xla", "final_chunk_32": "xla", "final_chunk_64": "xla",
         "final_chunk_128": "xla", "final_chunk_256": "xla"}
+
+
+def test_kernel_carries_the_name_it_was_given():
+    """The kernel's events in a device trace are found by name: the
+    ``pallas_call`` names itself ``paged_attn`` and does not inherit the
+    scope of whichever module calls it."""
+    q, pool_k, pool_v, tables, positions = _case(
+        np.random.default_rng(3), 2, 1, 4, 2, 16, 8, 4)
+
+    def caller(*a):
+        with jax.named_scope("some_module"):
+            return paged_decode_attention(*a, interpret=True)
+    args = [jnp.asarray(x) for x in (q, pool_k, pool_v, tables, positions)]
+    jaxpr = str(jax.make_jaxpr(caller)(*args))
+    assert "name=paged_attn" in jaxpr
+    lowered = jax.jit(caller).lower(*args).as_text(debug_info=True)
+    assert "some_module/paged_attn" in lowered

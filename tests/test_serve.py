@@ -311,3 +311,136 @@ def test_deadline_expired_in_queue_never_prefills(tiny):
     np.testing.assert_array_equal(
         np.asarray(outs[live.request_id].tokens),
         _ref_greedy(model, params, prompts[1], 5))
+
+
+# ------------------------------------------------ the spans of one step()
+
+def _step_spans(tiny, **engine_kw):
+    """A record-only tracer (no logger, a ring) around one engine: returns
+    (engine, take) where take() hands back the spans closed since the last
+    call, oldest first."""
+    from k8s_distributed_deeplearning_tpu.telemetry.trace import Tracer
+    model, params, _ = tiny
+    tr = Tracer(ring_size=4096)
+    eng = ServeEngine(model, params, num_slots=2, eos_id=None, tracer=tr,
+                      **engine_kw)
+    seen = [0]
+
+    def take():
+        spans = tr.recent_spans()[seen[0]:]
+        seen[0] += len(spans)
+        return spans
+    return eng, take
+
+
+def _named(spans, name, **fields):
+    return [s for s in spans if s["name"] == name
+            and all(s.get(k) == v for k, v in fields.items())]
+
+
+def _assert_nested(spans):
+    """Every span with a parent lies inside a span of that name one level
+    up (same thread; the ring is in closing order, so the parent closes
+    later)."""
+    for i, s in enumerate(spans):
+        if s["parent"] is None:
+            assert s["depth"] == 0
+            continue
+        parents = [p for p in spans[i + 1:]
+                   if p["name"] == s["parent"] and p["depth"] == s["depth"] - 1
+                   and p["t0"] <= s["t0"] and s["t1"] <= p["t1"]]
+        assert parents, (s, [p["name"] for p in spans])
+
+
+def test_one_step_names_every_phase_once_and_nests(tiny):
+    """One ``step()`` that admits, prefills (a final chunk) and decodes:
+    exactly one ``engine_step`` (the only depth-0 span), ``sweep``,
+    ``grow``, ``decode``, ``emit`` and ``epilogue``; the fence is a
+    ``device_wait`` child of ``decode`` (kind=decode) and of the final
+    chunk's ``prefill`` (kind=first_token); ``emit`` follows the fence."""
+    _, _, cfg = tiny
+    eng, take = _step_spans(tiny)
+    req = Request(prompt=np.arange(5, dtype=np.int32) % cfg.vocab_size,
+                  max_new_tokens=4)
+    eng.submit(req)
+    eng.step()
+    spans = take()
+    _assert_nested(spans)
+    for name in ("engine_step", "sweep", "admission", "prefill", "grow",
+                 "decode", "emit", "epilogue"):
+        assert len(_named(spans, name)) == 1, (name, spans)
+    step = _named(spans, "engine_step")[0]
+    assert step["depth"] == 0 and step["step"] == 0
+    assert [s["name"] for s in spans if s["depth"] == 0] == ["engine_step"]
+    assert _named(spans, "admission")[0]["request_id"] == req.request_id
+    assert _named(spans, "prefill")[0]["request_id"] == req.request_id
+    waits = _named(spans, "device_wait")
+    assert sorted((w["kind"], w["parent"]) for w in waits) == [
+        ("decode", "decode"), ("first_token", "prefill")]
+    decode, emit = _named(spans, "decode")[0], _named(spans, "emit")[0]
+    assert decode["active"] == 1 and decode["parent"] == "engine_step"
+    assert emit["t0"] >= _named(waits, "device_wait", kind="decode")[0]["t1"]
+    assert emit["t0"] >= decode["t1"]
+    ep = _named(spans, "epilogue")[0]
+    assert ep["t0"] >= emit["t1"] and ep["parent"] == "engine_step"
+    assert (ep["active"], ep["queued"], ep["prefill_tokens"]) == (1, 0, 5)
+    assert 0 < ep["pages_used"] <= ep["pages_total"]
+    # the next step decodes only: same single spans, no admission/prefill
+    eng.step()
+    spans = take()
+    _assert_nested(spans)
+    assert _named(spans, "engine_step")[0]["step"] == 1
+    assert not _named(spans, "prefill") and not _named(spans, "admission")
+    assert [w["kind"] for w in _named(spans, "device_wait")] == ["decode"]
+    assert len(_named(spans, "epilogue")) == 1
+
+
+@pytest.mark.parametrize("path", ["idle", "prefill_only", "spec_k"])
+def test_every_return_path_closes_engine_step_and_epilogue(tiny, path):
+    """The idle (no active slot), prefill-only (disaggregated role) and
+    speculative return paths each close one ``engine_step`` around one
+    ``epilogue``; the speculative fence is ``device_wait(kind=spec)``
+    inside ``decode``, with ``emit`` after it."""
+    model, params, cfg = tiny
+    kw = {}
+    if path == "prefill_only":
+        kw = {"prefill_only": True}
+    elif path == "spec_k":
+        dmodel = llama.LlamaLM(llama.config_tiny(dtype=jnp.float32,
+                                                 max_seq_len=64))
+        dparams = dmodel.init(jax.random.key(7),
+                              jnp.zeros((1, 8), jnp.int32))["params"]
+        kw = {"draft_model": dmodel, "draft_params": dparams, "spec_k": 2}
+    eng, take = _step_spans(tiny, **kw)
+    if path != "idle":
+        eng.submit(Request(prompt=np.arange(6, dtype=np.int32),
+                           max_new_tokens=6))
+    eng.step()
+    spans = take()
+    _assert_nested(spans)
+    assert len(_named(spans, "engine_step")) == 1
+    assert len(_named(spans, "epilogue")) == 1
+    assert len(_named(spans, "sweep")) == 1
+    assert [s["name"] for s in spans if s["depth"] == 0] == ["engine_step"]
+    if path == "idle":
+        assert {s["name"] for s in spans} == {"engine_step", "sweep",
+                                              "epilogue"}
+    elif path == "prefill_only":
+        assert not _named(spans, "decode") and not _named(spans, "emit")
+        assert [w["kind"] for w in _named(spans, "device_wait")] == [
+            "first_token"]
+    else:
+        decode = _named(spans, "decode")[0]
+        assert decode["spec_k"] == 2
+        spec = _named(spans, "device_wait", kind="spec")
+        assert len(spec) == 1 and spec[0]["parent"] == "decode"
+        assert _named(spans, "emit")[0]["t0"] >= decode["t1"]
+
+
+def test_disabled_tracer_costs_the_engine_the_shared_null_span(tiny):
+    """No tracer: every phase gets the one shared no-op span object."""
+    from k8s_distributed_deeplearning_tpu.telemetry import trace as trace_lib
+    model, params, _ = tiny
+    eng = ServeEngine(model, params, num_slots=2, eos_id=None)
+    assert eng.tracer.span("engine_step", step=0) is trace_lib._NULL_SPAN
+    assert eng.tracer.span("emit") is trace_lib._NULL_SPAN

@@ -95,3 +95,21 @@ def test_resolve_rules_filters_absent_axes():
     rules = dict(sharding.resolve_rules(mesh))
     assert rules["mlp"] is None          # no tensor axis in this mesh
     assert rules["batch"] == ("data",)   # fsdp filtered out of the tuple
+
+
+def test_step_carries_stable_scopes_for_the_device_trace():
+    """The lowered step names its two halves: every operation's metadata
+    (the device trace's ``tf_op``) starts under ``forward_backward`` or
+    ``optimizer``, so device time can be split by a name that survives a
+    refactor of either."""
+    trainer, state, step = _make_trainer(mesh_lib.make_mesh({"data": 8}))
+    tokens = jax.random.randint(jax.random.key(42), (8, 17), 0, 256)
+    batch = trainer.shard_batch({"tokens": tokens})
+    text = step.lower(state, batch, jax.random.key(0)).as_text(debug_info=True)
+    assert "jit(step)/forward_backward/" in text
+    assert "jit(step)/optimizer/" in text
+    # the model's matmuls are under the first, Adam's update under the second
+    assert any("forward_backward" in ln and "dot_general" in ln
+               for ln in text.splitlines())
+    assert not any("optimizer" in ln and "dot_general" in ln
+                   for ln in text.splitlines())

@@ -229,7 +229,8 @@ def test_tracing_overhead_under_two_percent():
     out = bench.measure_telemetry_overhead(steps=12, warmup=3,
                                            batch_size=256, repeats=2)
     assert out["step_ms_plain"] > 0 and out["step_ms_traced"] > 0
-    assert out["spans_emitted_last_window"] == 2 * 12   # data_wait + step
+    # data_wait, rng, step, hooks a step (log_every=0: no sync spans)
+    assert out["spans_emitted_last_window"] == out["spans_per_step"] * 12 == 4 * 12
     assert out["telemetry_overhead_pct"] < 2.0, out
 
 
@@ -287,3 +288,165 @@ def test_metrics_logger_emit_failure_never_raises(capsys):
         log.emit("checkpoint", step=i)   # must not raise
     err = capsys.readouterr().err
     assert err.count("metrics emit failed") == 1
+
+
+# ------------------------------------------- the loop's spans, compile log
+
+def test_trace_module_imports_without_jax():
+    """Control-plane processes import the tracer; it must not pull jax in
+    (the profiler switch is flipped by utils.profiling, which has jax)."""
+    import subprocess
+    import sys
+    code = ("import sys\n"
+            "from k8s_distributed_deeplearning_tpu.telemetry import trace\n"
+            "assert trace._annotation is None\n"
+            "assert 'jax' not in sys.modules, 'jax imported'\n")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr
+
+
+def test_ring_records_carry_both_clocks():
+    tr = Tracer(ring_size=8)
+    with tr.span("outer", step=1):
+        with tr.span("inner"):
+            pass
+    inner, outer = tr.recent_spans()
+    assert outer["t0"] <= inner["t0"] <= inner["t1"] <= outer["t1"]
+    assert abs((outer["t1"] - outer["t0"]) * 1e3 - outer["dur_ms"]) < 1e-2
+    assert outer["ts"] > 1e9 and outer["step"] == 1
+
+
+def _fit_spans(num_steps, log_every, **fit_kw):
+    import jax
+
+    from k8s_distributed_deeplearning_tpu import backend
+    from k8s_distributed_deeplearning_tpu.train import loop as train_loop
+    backend.compile_log()      # entry points start it (use_compile_cache)
+    tr = Tracer(ring_size=4096)
+    buf = io.StringIO()
+    log = MetricsLogger(stream=buf, job="t")
+    train_loop.fit(lambda state, batch, rng: (state, 0.5, {"acc": 1.0}),
+                   state=None, batches=iter(range(num_steps)),
+                   num_steps=num_steps, rng=jax.random.key(0), tracer=tr,
+                   metrics=log, log_every=log_every, **fit_kw)
+    return tr.recent_spans(), _events(buf)
+
+
+def test_fit_spans_are_flat_siblings_per_step_and_per_sync():
+    """``fit`` for 2 x log_every steps: ``data_wait``/``rng``/``step``/
+    ``hooks`` once a step in that order, ``log_sync`` then ``log`` once a
+    sync, every one at depth 0 with its step (graftscope sums depth-0
+    spans into a step's components: no span may wrap the iteration)."""
+    log_every = 3
+    spans, events = _fit_spans(2 * log_every, log_every)
+    assert all(s["depth"] == 0 and s["parent"] is None for s in spans)
+    for step in range(2 * log_every):
+        names = [s["name"] for s in spans if s["step"] == step]
+        want = ["data_wait", "rng", "step", "hooks"]
+        if (step + 1) % log_every == 0:
+            want += ["log_sync", "log"]
+        assert names == want, (step, names)
+    sync, logged = [[s for s in spans if s["name"] == n]
+                    for n in ("log_sync", "log")]
+    assert [s["step"] for s in sync] == [2, 5]
+    assert all(a["t1"] <= b["t0"] for a, b in zip(sync, logged))
+    # the run's last events: device_memory, then the compile summary
+    assert [e["event"] for e in events[-2:]] == ["device_memory", "compile"]
+    assert events[-1]["step"] == 2 * log_every
+
+
+def test_fit_eval_and_profiler_hooks_get_spans(tmp_path):
+    """``eval`` wraps the eval call at its cadence; a run with a profiler
+    has a second ``hooks`` span a step (before ``data_wait``), and the
+    steps inside the profiler's window are on its clock as ``program:``
+    annotations."""
+    from benchmarks.harness import trace_reduce
+    from k8s_distributed_deeplearning_tpu.utils.profiling import StepProfiler
+    d = str(tmp_path / "prof")
+    spans, _ = _fit_spans(4, 2, eval_every=2, eval_fn=lambda st: {"m": 1.0},
+                          profiler=StepProfiler(d, start_step=1, num_steps=2))
+    assert [s["step"] for s in spans if s["name"] == "eval"] == [1, 3]
+    assert [s["name"] for s in spans if s["step"] == 0][:2] == [
+        "hooks", "data_wait"]
+    assert sum(s["name"] == "hooks" for s in spans) == 2 * 4
+    ex = trace_reduce.extract(trace_reduce.find_xplane(d))
+    names = [e[0] for p in ex["planes"] for ln in p["lines"]
+             for e in ln["events"] if e[0].startswith("program:")]
+    assert names.count("program:step") == 2          # steps 1 and 2
+    assert names.count("program:log_sync") == 1      # the sync at step 1
+
+
+def test_compile_log_counts_a_fresh_jit_once_and_a_second_call_not_at_all():
+    import jax
+    import jax.numpy as jnp
+
+    from k8s_distributed_deeplearning_tpu import backend
+    log = backend.compile_log()
+    assert backend.compile_log() is log        # one listener per process
+
+    def fresh_fn_for_the_compile_log(x):
+        return x * 3 + 1
+    f = jax.jit(fresh_fn_for_the_compile_log)
+
+    def mine():
+        return [(p, s) for _, p, s, name in log.events()
+                if name and "fresh_fn_for_the_compile_log" in name]
+    x = jnp.ones((5,))
+    t0 = __import__("time").perf_counter()
+    jax.block_until_ready(f(x))
+    once = mine()
+    assert sorted(p for p, _ in once) == ["backend_compile", "lower", "trace"]
+    assert all(s >= 0 for _, s in once)
+    jax.block_until_ready(f(x))
+    assert mine() == once
+    # the window arithmetic: everything of this jit arrived after t0
+    assert log.count("lower", t0) >= 1 and log.seconds("lower", t0) > 0
+    assert log.count("lower", t_hi=t0) == log.count("lower") - log.count("lower", t0)
+    summ = log.summary()
+    assert summ["trace"]["count"] >= 1 and summ["trace"]["seconds"] > 0
+
+
+def test_compile_log_seconds_are_wall_time_not_a_sum_of_nested_events():
+    """JAX reports a jit traced inside another's trace as its own event,
+    inside the outer one's seconds: a phase's seconds are the union of the
+    events' intervals, so nothing is counted twice."""
+    from k8s_distributed_deeplearning_tpu import backend
+    log = backend.CompileLog()
+    log._events = [
+        (10.2, "trace", 0.2, "inner"),         # [10.0, 10.2]
+        (10.3, "trace", 0.3, "outer"),         # [10.0, 10.3] holds inner
+        (10.4, "lower", 0.1, "jit(outer)"),    # [10.3, 10.4]
+        (12.0, "trace", 0.5, "later"),         # [11.5, 12.0]
+        (12.5, "cache_saved", -0.25, None)]
+    assert log.count("trace") == 3
+    assert log.seconds("trace") == pytest.approx(0.8)
+    assert log.seconds("trace", t_hi=11.0) == pytest.approx(0.3)
+    assert log.seconds(("trace", "lower"), t_hi=11.0) == pytest.approx(0.4)
+    assert log.seconds("trace", 11.0, 13.0) == pytest.approx(0.5)
+    assert log.seconds("cache_saved") == -0.25
+    assert log.summary()["trace"] == {"count": 3, "seconds": 0.8}
+    assert "backend_compile" not in log.summary()
+    # and through jax.monitoring itself: the listener keeps what it is sent
+    for name, phase in backend.COMPILE_PHASES.items():
+        log._on_duration(name, 0.5, fun_name="f")
+        assert log.events()[-1][1:] == (phase, 0.5, "f")
+    log._on_duration("/jax/some/other/event", 1.0)
+    assert len(log.events()) == 5 + len(backend.COMPILE_PHASES)
+
+
+def test_compile_collector_exports_seconds_by_phase():
+    import jax
+    import jax.numpy as jnp
+    reg = MetricsRegistry()
+    bridge.compile_collector(reg)
+    jax.block_until_ready(jax.jit(lambda x: x - 7)(jnp.ones((3,))))
+    def rows():
+        samples, types = _parse_exposition(reg.render())
+        assert types["xla_compile_seconds_total"] == "counter"
+        return {dict(labels)["phase"]: v for (name, labels), v in
+                samples.items() if name == "xla_compile_seconds_total"}
+    first = rows()
+    assert first["lower"] > 0 and first["trace"] > 0
+    assert "cache_saved" not in first
+    assert rows() == first                     # a scrape adds only new events
