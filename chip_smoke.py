@@ -80,7 +80,8 @@ PHASE_TIMEOUT_S = 900
 # Kernel cases: (q heads, kv heads, head_dim) of what `small` serves and of
 # config_llama3_8b; the trainer's sequence length; the MoE bench dims.
 HEAD_SHAPES = ((12, 4, 64), (32, 8, 128))
-PAGED_SQ_BATCH = ((1, SERVE_SLOTS), (5, SERVE_SLOTS), (SERVE_CHUNK, 1))
+PAGED_SQ_BATCH = ((1, SERVE_SLOTS), (5, SERVE_SLOTS), (SERVE_CHUNK, 2))
+PAGED_TABLE = (32, 128)          # (page tokens, blocks a row): 4096 positions
 FLASH_BATCH_SEQ = (2, 2048)
 GMM_EXPERTS, GMM_ROWS, GMM_BLOCK_M = 8, 16384, 512
 GMM_DIMS = ((768, 2048), (2048, 768))
@@ -317,7 +318,8 @@ def phase_serve(name: str, n_devices: int,
                 f"{summ.get('kv_pages_used')}, by owner {owners}")
         impls = summ.get("attention_impls", {})
         ph.info["attention_impls"] = impls
-        ph.need(impls and set(impls.values()) == {"paged_flash"},
+        ph.need(impls and {v.split()[0] for v in impls.values()}
+                == {"paged_flash"},
                 f"serving programs did not all resolve to the Pallas "
                 f"kernel: {impls}")
         _shares(ph, summ.get("device_bytes_in_use"), n_devices)
@@ -532,8 +534,11 @@ def child_kernels() -> int:
                 return fn(*args)
         return jax.jit(run)
 
-    # ---- paged decode attention at the engine's page geometry
-    page_tokens, n_blocks = 32, SERVE_MAX_SEQ // 32
+    # ---- paged decode attention over the benchmark cell's table: 128
+    # blocks of 32 tokens, so a row spans several of the kernel's grid cells
+    # (ragged cursors; one row idle, as a free slot is: cursor sq-1, every
+    # table entry the scratch page)
+    page_tokens, n_blocks = PAGED_TABLE
 
     def paged_ref(q, pk, pv, tables, pos, ks, vs):
         b, sq, h, hd = q.shape
@@ -555,6 +560,7 @@ def child_kernels() -> int:
             kf = rng.standard_normal((pages, page_tokens, hkv, hd))
             vf = rng.standard_normal((pages, page_tokens, hkv, hd))
             cursor = rng.integers(sq - 1, n_blocks * page_tokens, size=b)
+            cursor[0] = sq - 1
             pos = (cursor[:, None] - (sq - 1)
                    + np.arange(sq)[None, :]).astype(np.int32)
             tables = rng.permutation(np.arange(1, pages)).reshape(
@@ -563,6 +569,7 @@ def child_kernels() -> int:
             # table entry 0, the never-attended scratch page.
             tables[np.arange(n_blocks)[None, :]
                    > (cursor // page_tokens)[:, None]] = 0
+            tables[0] = 0
             tables, pos = jnp.asarray(tables), jnp.asarray(pos)
             fold = lambda x: x.reshape(pages, page_tokens, hkv * hd)
             for quant in (False, True):

@@ -530,8 +530,9 @@ class Attention(nn.Module):
                 # picks it on TPU for the query widths it serves and
                 # the XLA gather below otherwise. Under kv_quant the
                 # kernel fuses the dequant into its page stream: int8
-                # pages and their scale pages ride the same prefetched
-                # block table, so dequantized K/V never hit HBM either.
+                # pages are copied by the same prefetched block table
+                # (their f32 scales, 1/32 of the bytes, are gathered
+                # beside it), so dequantized K/V never hit HBM either.
                 out = pallas_paged_attn.paged_decode_attention(
                     q, pool_k, pool_v, block_tables, wpos,
                     k_scale=pool_ks if quant else None,

@@ -88,6 +88,7 @@ from jax.sharding import PartitionSpec as P
 
 from k8s_distributed_deeplearning_tpu import faults as _faults
 from k8s_distributed_deeplearning_tpu.models import generate, transformer
+from k8s_distributed_deeplearning_tpu.ops import pallas_paged_attn
 from k8s_distributed_deeplearning_tpu.parallel import mesh as mesh_lib
 from k8s_distributed_deeplearning_tpu.parallel import sharding as sharding_lib
 from k8s_distributed_deeplearning_tpu.serve import quant as quant_lib
@@ -2010,21 +2011,41 @@ class ServeEngine:
         intermediate prefill chunk and every final-chunk bucket this
         engine can compile. Asks the model's own rule
         (``transformer.paged_attention_impl``), so it cannot drift from
-        what the programs trace."""
-        widths = {"decode": 1}
+        what the programs trace. Beside ``paged_flash`` stands the kernel's
+        grid as its own rule (``default_pages_per_cell``) sets it for that
+        program's call: pages a cell attends, and cells a call steps."""
+        slots = self.num_slots
+        programs = {"decode": (1, slots)}        # name -> (sq, batch rows)
         if self.spec_k:
-            widths["spec_verify"] = self.spec_k + 1
+            programs["spec_verify"] = (self.spec_k + 1, slots)
         c = self.prefill_chunk_tokens
         if c:
-            widths[f"chunk_{c}"] = c
+            programs[f"chunk_{c}"] = (c, 1)
         top = self._bucket(c or self.max_seq_len)
         b = self.min_bucket
         while b < top:
-            widths[f"final_chunk_{b}"] = b
+            programs[f"final_chunk_{b}"] = (b, 1)
             b *= 2
-        widths[f"final_chunk_{top}"] = top
-        return {name: transformer.paged_attention_impl(self.model.cfg, sq)
-                for name, sq in widths.items()}
+        programs[f"final_chunk_{top}"] = (top, 1)
+        cfg = self.model.cfg
+        quant = self.kv_quant == "int8"
+        q_itemsize = jnp.dtype(cfg.dtype).itemsize
+        shard = max(self.tp, 1)                  # heads are split over tp
+
+        def report(sq: int, rows: int) -> str:
+            impl = transformer.paged_attention_impl(cfg, sq)
+            if impl != "paged_flash":
+                return impl
+            pages = pallas_paged_attn.default_pages_per_cell(
+                sq=sq, heads=cfg.n_heads // shard,
+                hd=cfg.resolved_head_dim, page_tokens=self.page_tokens,
+                kvhd=cfg.resolved_kv_heads * cfg.resolved_head_dim // shard,
+                kv_itemsize=1 if quant else q_itemsize,
+                q_itemsize=q_itemsize, n_blocks=self.max_blocks,
+                quant=quant)
+            return (f"{impl} pages_per_cell={pages} "
+                    f"cells={rows * -(-self.max_blocks // pages)}")
+        return {name: report(*shape) for name, shape in programs.items()}
 
     def _fits(self, req: Request) -> bool:
         """Admission-time page probe (the scheduler calls this on its

@@ -71,8 +71,10 @@ CHUNKS[fleet]="tests/test_fleet.py"
 CHUNKS[gateway]="tests/test_gateway.py"
 # Speculative decoding bit-parity matrix + the Pallas paged decode-
 # attention kernel (interpret mode on CPU): both compile their own draft/
-# target engines, so they get their own chunk.
-CHUNKS[spec]="tests/test_spec.py tests/test_pallas_paged_attn.py"
+# target engines, so they get their own chunk. test_tpu_compile.py compiles
+# that kernel for a described v5e (Mosaic, no chip): it loads the TPU's
+# library, so it stays the one file of its kind.
+CHUNKS[spec]="tests/test_spec.py tests/test_pallas_paged_attn.py tests/test_tpu_compile.py"
 # graftflight (flight recorder / page ledger / trace stitching): mostly
 # jax-free unit tests plus engine+gateway chaos cases that compile their
 # own tiny models — its own chunk so serve/gateway stay under timeout.

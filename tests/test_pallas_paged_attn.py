@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from k8s_distributed_deeplearning_tpu.models import generate, llama
+from k8s_distributed_deeplearning_tpu.ops import pallas_paged_attn
 from k8s_distributed_deeplearning_tpu.ops.pallas_paged_attn import (
     paged_decode_attention)
 from k8s_distributed_deeplearning_tpu.serve import Request, ServeEngine
@@ -54,19 +55,136 @@ def _case(rng, b, sq, h, hkv, pages, bt, nb):
     return q, pool_k, pool_v, tables, positions
 
 
-@pytest.mark.parametrize("b,sq,h,hkv,pages,bt,nb", [
-    (2, 1, 4, 2, 16, 8, 4),      # classic single-token decode, GQA 2:1
-    (3, 5, 4, 4, 32, 16, 3),     # speculative verify window, MHA
-    (2, 3, 8, 2, 64, 4, 6),      # wide window, GQA 4:1, small pages
+def _cursors(q, pool_k, pool_v, tables, positions, cursors):
+    """The same case with each row's LAST query at the given cursor (the
+    window's earlier queries just before it), and the table entries past
+    the cursor set to the scratch page, as the engine leaves them."""
+    sq = positions.shape[1]
+    bt = pool_k.shape[1]
+    cursors = np.asarray(cursors)
+    positions = (cursors[:, None] - (sq - 1)
+                 + np.arange(sq)[None, :]).astype(np.int32)
+    tables = np.where(np.arange(tables.shape[1])[None, :]
+                      > (cursors // bt)[:, None], 0, tables)
+    return q, pool_k, pool_v, tables.astype(np.int32), positions
+
+
+def _run(case, **kw):
+    return np.asarray(paged_decode_attention(
+        *(jnp.asarray(x) for x in case), interpret=True, **kw))
+
+
+@pytest.mark.parametrize("b,sq,h,hkv,pages,bt,nb,ppc,cursors", [
+    # the rule's own choice (these pools are narrower than a lane tile: one
+    # page a cell, read through the block pipeline)
+    (2, 1, 4, 2, 16, 8, 4, None, None),     # single-token decode, GQA 2:1
+    (3, 5, 4, 4, 32, 16, 3, None, None),    # speculative verify window, MHA
+    (2, 3, 8, 2, 64, 4, 6, None, None),     # wide window, GQA 4:1, small pages
+    # several cells a row; a cell is ppc pages of bt tokens
+    (4, 1, 8, 2, 64, 4, 7, 2, None),        # n_blocks not a multiple of P
+    (4, 1, 8, 2, 64, 4, 7, 3, (2, 11, 12, 27)),   # first page; a cell's last
+    #                                column; the next cell's first; last page
+    (3, 5, 4, 4, 64, 8, 6, 4, (4, 31, 32)),       # window ends in the first
+    #                                page; on a cell's edge; straddles cells
+    (2, 1, 8, 2, 64, 4, 6, 2, (0, 23)),     # a cursor-0 row beside a full row
+    (2, 16, 8, 2, 64, 8, 5, 2, (15, 39)),   # a chunk width; its first chunk
+    (2, 16, 4, 4, 64, 8, 5, 3, (31, 24)),   # chunk, MHA, tail cell of 2 pages
+    (3, 1, 4, 4, 32, 16, 3, 1, None),       # one page a cell, MHA
+    (2, 5, 8, 2, 64, 4, 6, 6, (19, 5)),     # forced P = n_blocks, GQA 4:1
 ])
-def test_kernel_matches_xla_reference(b, sq, h, hkv, pages, bt, nb):
+def test_kernel_matches_xla_reference(b, sq, h, hkv, pages, bt, nb, ppc,
+                                      cursors):
     rng = np.random.default_rng(b * 100 + sq * 10 + h)
-    q, pk, pv, tables, pos = _case(rng, b, sq, h, hkv, pages, bt, nb)
-    out = np.asarray(paged_decode_attention(
-        jnp.asarray(q), jnp.asarray(pk), jnp.asarray(pv),
-        jnp.asarray(tables), jnp.asarray(pos), interpret=True))
-    np.testing.assert_allclose(out, _ref(q, pk, pv, tables, pos),
+    case = _case(rng, b, sq, h, hkv, pages, bt, nb)
+    if cursors is not None:
+        case = _cursors(*case, cursors)
+    np.testing.assert_allclose(_run(case, pages_per_cell=ppc), _ref(*case),
                                atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("sq,h,hkv", [(1, 8, 2), (5, 4, 4), (16, 8, 2)])
+def test_one_page_a_cell_equals_the_rules_choice(sq, h, hkv):
+    """The grid is a schedule, not a result: one page a cell (seven cells a
+    row here — the rule's choice for so narrow a pool, and the same forced)
+    gives what the whole table in one cell gives, to f32 rounding of the
+    extra rescales."""
+    rng = np.random.default_rng(sq)
+    case = _case(rng, 3, sq, h, hkv, 64, 4, 7)
+    ruled = _run(case)
+    np.testing.assert_array_equal(_run(case, pages_per_cell=1), ruled)
+    np.testing.assert_allclose(_run(case, pages_per_cell=7), ruled,
+                               atol=2e-6, rtol=2e-6)
+
+
+def test_pages_per_cell_out_of_range_is_refused():
+    case = _case(np.random.default_rng(0), 2, 1, 4, 2, 16, 8, 4)
+    for bad in (0, 5):
+        with pytest.raises(ValueError, match="pages_per_cell"):
+            _run(case, pages_per_cell=bad)
+
+
+# The benchmark cell's call shapes (mistral-7b widths: 32 q / 8 kv x 128,
+# 32-token pages, a 128-block table) and the smoke model's, fp and int8.
+_RULE_SHAPES = [
+    dict(heads=32, hd=128, kvhd=1024, page_tokens=32, n_blocks=128),
+    dict(heads=12, hd=64, kvhd=256, page_tokens=32, n_blocks=32),
+    dict(heads=8, hd=128, kvhd=256, page_tokens=32, n_blocks=128),   # tp = 4
+    dict(heads=3, hd=64, kvhd=64, page_tokens=32, n_blocks=32),      # tp = 4
+]
+
+
+@pytest.mark.parametrize("shape", _RULE_SHAPES,
+                         ids=["mistral", "small", "mistral-tp4", "small-tp4"])
+@pytest.mark.parametrize("sq", [1, 5, 128])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_pages_per_cell_rule(shape, sq, quant):
+    """The rule's choice, from what a call can see: at least one page, never
+    more than the table holds, than CELL_TOKENS or than SCORE_TILE_ELEMS
+    scores a KV head, and inside the VMEM budget by the module's own
+    accounting — where one page more would not be, unless the table or one
+    of the two targets stopped it first."""
+    kw = dict(shape, sq=sq, quant=quant, q_itemsize=2,
+              kv_itemsize=1 if quant else 2)
+    n_blocks = kw.pop("n_blocks")
+    p = pallas_paged_attn.default_pages_per_cell(n_blocks=n_blocks, **kw)
+    rows = kw["heads"] // (kw["kvhd"] // kw["hd"]) * sq
+    tokens = min(pallas_paged_attn.CELL_TOKENS,
+                 pallas_paged_attn.SCORE_TILE_ELEMS // rows)
+    assert 1 <= p <= n_blocks
+    assert p * kw["page_tokens"] <= tokens
+    budget = pallas_paged_attn.VMEM_BUDGET_BYTES
+    assert pallas_paged_attn.cell_vmem_bytes(p, **kw) <= budget
+    assert budget < pallas_paged_attn.VMEM_LIMIT_BYTES
+    if kw["kvhd"] % 128:
+        assert p == 1
+    elif p < n_blocks and (p + 1) * kw["page_tokens"] <= tokens:
+        assert pallas_paged_attn.cell_vmem_bytes(p + 1, **kw) > budget
+
+
+def test_pages_per_cell_rule_at_the_benchmark_cell():
+    """What the backlog cell's three programs get (PERF.md section 6, PR 26):
+    decode and a verify window 32 pages = 1024 tokens a cell, 4 cells a row;
+    a 128-token chunk 8 pages = 256 tokens, 16 cells."""
+    kw = dict(heads=32, hd=128, kvhd=1024, page_tokens=32, n_blocks=128,
+              kv_itemsize=2, q_itemsize=2)
+    rule = pallas_paged_attn.default_pages_per_cell
+    assert [rule(sq=sq, **kw) for sq in (1, 5, 128)] == [32, 32, 8]
+
+
+def test_pages_per_cell_rule_small_tables_wide_pages_narrow_pools():
+    """Bounded by the table; one page when a page alone is the token target
+    or more; and one page for a pool narrower than a lane tile (every
+    geometry of this file, and one local hd-64 KV head under tp), which the
+    kernel then reads through the block pipeline."""
+    kw = dict(sq=1, heads=4, hd=128, kvhd=128, kv_itemsize=2, q_itemsize=2)
+    rule = pallas_paged_attn.default_pages_per_cell
+    assert rule(page_tokens=8, n_blocks=4, **kw) == 4
+    assert rule(page_tokens=4, n_blocks=6, **kw) == 6
+    assert rule(page_tokens=1024, n_blocks=4, **kw) == 1
+    assert rule(page_tokens=2048, n_blocks=4, **kw) == 1
+    for kvhd, hd in ((64, 64), (192, 64), (16, 8)):
+        assert rule(page_tokens=32, n_blocks=128, sq=1, heads=kvhd // hd * 3,
+                    hd=hd, kvhd=kvhd, kv_itemsize=2, q_itemsize=2) == 1
 
 
 def test_explicit_softmax_scale():
@@ -209,9 +327,15 @@ def test_auto_selection_rule_is_shape_and_platform():
     params = model.init(jax.random.key(0),
                         jnp.zeros((1, 8), jnp.int32))["params"]
     eng = ServeEngine(model, params, num_slots=2, prefill_chunk_tokens=64)
+    # 256 tokens in 32-token pages, a pool 32 lanes wide (2 KV heads x 16):
+    # narrower than a lane tile, so a page a cell — 8 cells a row.
     assert eng.attention_impls() == {
-        "decode": "paged_flash", "chunk_64": "paged_flash",
-        "final_chunk_32": "paged_flash", "final_chunk_64": "paged_flash"}
+        "decode": "paged_flash pages_per_cell=1 cells=16",
+        "chunk_64": "paged_flash pages_per_cell=1 cells=8",
+        "final_chunk_32": "paged_flash pages_per_cell=1 cells=8",
+        "final_chunk_64": "paged_flash pages_per_cell=1 cells=8"}
+    # The same engine at the benchmark cell's widths and table would report
+    # what test_pages_per_cell_rule_at_the_benchmark_cell holds the rule to.
     plain = ServeEngine(llama.LlamaLM(cfg), params, num_slots=2)
     assert plain.attention_impls() == {
         "decode": "xla", "final_chunk_32": "xla", "final_chunk_64": "xla",

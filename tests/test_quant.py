@@ -144,16 +144,25 @@ def _quantize_pool(pool):
     return q.reshape(pool.shape), sc.astype(np.float32)
 
 
-@pytest.mark.parametrize("b,sq,h,hkv,pages,bt,nb", [
-    (2, 1, 4, 2, 16, 8, 4),      # single-token decode, GQA 2:1
-    (3, 5, 4, 4, 32, 16, 3),     # speculative verify window, MHA
+@pytest.mark.parametrize("b,sq,h,hkv,pages,bt,nb,ppc,cursors", [
+    (2, 1, 4, 2, 16, 8, 4, None, None),     # single-token decode, GQA 2:1
+    (3, 5, 4, 4, 32, 16, 3, None, None),    # speculative verify window, MHA
+    # several cells a row (a cell is ppc pages of bt tokens): the scale
+    # blocks are cut to the same cells as the int8 pages
+    (4, 1, 8, 2, 64, 4, 7, 3, (2, 11, 12, 27)),   # tail cell of one page;
+    #          first page, a cell's last column, the next's first, last page
+    (2, 1, 8, 2, 64, 4, 6, 2, (0, 23)),     # a cursor-0 row beside a full row
+    (3, 5, 4, 4, 64, 8, 6, 4, (4, 31, 32)),       # window on a cell's edge
+    (2, 16, 8, 2, 64, 8, 5, 2, (15, 39)),   # a chunk width, GQA 4:1
+    (3, 1, 4, 4, 32, 16, 3, 1, None),       # one page a cell, MHA
 ])
 def test_kernel_dequant_matches_xla_on_dequantized_pool(
-        b, sq, h, hkv, pages, bt, nb):
+        b, sq, h, hkv, pages, bt, nb, ppc, cursors):
     """The kernel's fused dequant IS the reference dequant: running the
     kernel on (int8 pool, scales) must equal running it on the
     explicitly dequantized fp pool — same f32 multiply, fused into the
-    page stream instead of materialized in HBM."""
+    page stream instead of materialized in HBM — and both must equal the
+    plain softmax over the dequantized virtual sequence."""
     rng = np.random.default_rng(b * 10 + sq)
     hd = 8
     q = rng.standard_normal((b, sq, h, hd)).astype(np.float32)
@@ -161,7 +170,10 @@ def test_kernel_dequant_matches_xla_on_dequantized_pool(
     pool_v = rng.standard_normal((pages, bt, hkv * hd)).astype(np.float32)
     perm = rng.permutation(np.arange(1, pages))[:b * nb]
     tables = perm.reshape(b, nb).astype(np.int32)
-    base = rng.integers(sq - 1, nb * bt, size=b)
+    base = (rng.integers(sq - 1, nb * bt, size=b) if cursors is None
+            else np.asarray(cursors))
+    if cursors is not None:     # unallocated blocks: the scratch page
+        tables[np.arange(nb)[None, :] > (base // bt)[:, None]] = 0
     pos = (base[:, None] - (sq - 1) + np.arange(sq)[None, :]).astype(
         np.int32)
     qk, sk = _quantize_pool(pool_k)
@@ -173,11 +185,22 @@ def test_kernel_dequant_matches_xla_on_dequantized_pool(
     out_q = np.asarray(paged_decode_attention(
         jnp.asarray(q), jnp.asarray(qk), jnp.asarray(qv),
         jnp.asarray(tables), jnp.asarray(pos),
-        k_scale=jnp.asarray(sk), v_scale=jnp.asarray(sv), interpret=True))
+        k_scale=jnp.asarray(sk), v_scale=jnp.asarray(sv),
+        pages_per_cell=ppc, interpret=True))
     out_ref = np.asarray(paged_decode_attention(
         jnp.asarray(q), jnp.asarray(dk), jnp.asarray(dv),
-        jnp.asarray(tables), jnp.asarray(pos), interpret=True))
+        jnp.asarray(tables), jnp.asarray(pos), pages_per_cell=ppc,
+        interpret=True))
     np.testing.assert_allclose(out_q, out_ref, atol=1e-6, rtol=1e-6)
+    k = dk[tables].reshape(b, nb * bt, hkv, hd)
+    v = dv[tables].reshape(b, nb * bt, hkv, hd)
+    want = np.zeros_like(q)
+    for bi, i, qi in np.ndindex(b, sq, h):
+        s = (k[bi, :, qi // (h // hkv)] @ q[bi, i, qi]) * hd ** -0.5
+        s = np.where(np.arange(nb * bt) <= pos[bi, i], s, -np.inf)
+        pr = np.exp(s - s.max())
+        want[bi, i, qi] = (pr / pr.sum()) @ v[bi, :, qi // (h // hkv)]
+    np.testing.assert_allclose(out_q, want, atol=2e-5, rtol=2e-5)
 
 
 def test_kernel_scale_validation():

@@ -1,0 +1,75 @@
+"""The serving path's kernel compiled for the chip, without the chip.
+
+The Pallas interpreter (every other kernel test) cannot see what Mosaic
+refuses: a copy too narrow for its tiling, a slice off the tile grid, more
+VMEM than a kernel may hold. The TPU compiler is installed here and compiles
+for a v5e that is described, not attached — about two seconds a kernel — so
+these cases hold the paged kernel to the benchmark cell's call shapes at the
+page count its own rule picks. All of them live in this one file: the worker
+that runs it is the one process that loads the TPU's library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from k8s_distributed_deeplearning_tpu.ops import pallas_paged_attn
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A program compiled for a described chip is written to the persistent
+    # cache but cannot be read back without one: keep these out of it.
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+# mistral-7b widths as the backlog cell serves them: 32 q / 8 kv heads x 128,
+# 32-token pages, a 128-block table over a 2,560-page pool (+ scratch); and
+# what one of four tp shards of the smoke's `small` model holds: 3 q heads on
+# ONE hd-64 KV head, a pool half a lane tile wide.
+MISTRAL = (32, 8, 128)
+SMALL_TP4 = (3, 1, 64)
+PAGE_TOKENS, N_BLOCKS, NUM_PAGES = 32, 128, 2561
+
+
+@pytest.mark.parametrize("heads,sq,b,quant,pages", [
+    (MISTRAL, 1, 32, False, None),      # the decode program
+    (MISTRAL, 5, 32, False, None),      # a speculative verify window
+    (MISTRAL, 128, 1, False, None),     # the prefill chunk programs
+    (MISTRAL, 1, 32, True, None),       # int8 pages + their scale blocks
+    (MISTRAL, 128, 1, True, None),
+    (MISTRAL, 1, 32, False, 3),         # a tile off the lane grid, ragged tail
+    (SMALL_TP4, 1, 8, False, None),     # a page a cell, by the block pipeline
+    (SMALL_TP4, 128, 1, True, None),
+], ids=["decode", "verify5", "chunk128", "decode-int8", "chunk128-int8",
+        "decode-3pages", "narrow-decode", "narrow-chunk128-int8"])
+def test_paged_kernel_compiles_for_v5e(one_chip, heads, sq, b, quant, pages):
+    H, HKV, HD = heads
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    page_dtype = jnp.int8 if quant else jnp.bfloat16
+    pool = sds((NUM_PAGES, PAGE_TOKENS, HKV * HD), page_dtype)
+    scale = sds((NUM_PAGES, PAGE_TOKENS, HKV), jnp.float32) if quant else None
+
+    def call(q, pk, pv, tables, pos, ks, vs):
+        return pallas_paged_attn.paged_decode_attention(
+            q, pk, pv, tables, pos, k_scale=ks, v_scale=vs,
+            pages_per_cell=pages, interpret=False)
+    compiled = jax.jit(call).lower(
+        sds((b, sq, H, HD), jnp.bfloat16), pool, pool,
+        sds((b, N_BLOCKS), jnp.int32), sds((b, sq), jnp.int32),
+        scale, scale).compile()
+    assert "paged_attn" in compiled.as_text()
