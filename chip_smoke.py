@@ -82,6 +82,10 @@ PHASE_TIMEOUT_S = 900
 HEAD_SHAPES = ((12, 4, 64), (32, 8, 128))
 PAGED_SQ_BATCH = ((1, SERVE_SLOTS), (5, SERVE_SLOTS), (SERVE_CHUNK, 2))
 PAGED_TABLE = (32, 128)          # (page tokens, blocks a row): 4096 positions
+# The latent kernel over the docs-backlog cell's table: (heads, latent rank,
+# rope lanes, page tokens, blocks a row, rows); query widths 1 and 4.
+LATENT_TABLE = (64, 512, 64, 64, 272, 8)
+LATENT_SQ = (1, 4)
 FLASH_BATCH_SEQ = (2, 2048)
 GMM_EXPERTS, GMM_ROWS, GMM_BLOCK_M = 8, 16384, 512
 GMM_DIMS = ((768, 2048), (2048, 768))
@@ -333,7 +337,8 @@ def phase_serve(name: str, n_devices: int,
         if got is not None:
             par.run["setup_s"] = got.get("setup_s")
             par.info.update({k: got.get(k) for k in (
-                "tokens_compared", "tokens_equal", "near_tie_divergences")})
+                "tokens_compared", "tokens_equal", "near_tie_divergences",
+                "latent_moe_tokens_equal")})
             par.need(got.get("ok"), f"greedy tokens disagree: {got}")
         out.append((name + "-parity", par.result()))
     return out
@@ -495,6 +500,7 @@ def child_kernels() -> int:
     from k8s_distributed_deeplearning_tpu import backend
     from k8s_distributed_deeplearning_tpu.ops import (attention, pallas_flash,
                                                       pallas_gmm,
+                                                      pallas_latent_attn,
                                                       pallas_paged_attn)
     backend.use_compile_cache()
     if not backend.on_tpu():
@@ -594,6 +600,45 @@ def child_kernels() -> int:
                       f"hd{hd} sq{sq}", timed(kernel, *args),
                       reference(paged_ref)(*args))
 
+    # ---- absorbed latent decode attention over the docs-backlog cell's
+    # table: 272 blocks of 64 tokens, one 640-lane row a token (ragged
+    # cursors; row 0 idle)
+    h, rank, rope, page_tokens_l, n_blocks_l, b = LATENT_TABLE
+    lanes = -(-(rank + rope) // 128) * 128
+
+    def latent_ref(q, pool, tables, pos):
+        s_virt = n_blocks_l * page_tokens_l
+        lat = pool[tables].reshape(b, s_virt, lanes).astype(f32)
+        sc = jnp.einsum("bqhl,bkl->bhqk", q.astype(f32), lat) * 0.135
+        allow = jnp.arange(s_virt)[None, None, :] <= pos[:, :, None]
+        p = jax.nn.softmax(jnp.where(allow[:, None], sc, -1e30), axis=-1)
+        return jnp.einsum("bhqk,bkr->bqhr", p, lat[..., :rank])
+
+    for sq in LATENT_SQ:
+        rng = np.random.default_rng(27 + sq)
+        pages = b * n_blocks_l + 1
+        pad = lambda x: np.concatenate(
+            [x, np.zeros(x.shape[:-1] + (lanes - rank - rope,))], axis=-1)
+        q = jnp.asarray(pad(rng.standard_normal((b, sq, h, rank + rope))), bf16)
+        pool = jnp.asarray(pad(rng.standard_normal(
+            (pages, page_tokens_l, rank + rope))), bf16)
+        cursor = rng.integers(sq - 1, n_blocks_l * page_tokens_l, size=b)
+        cursor[0] = sq - 1
+        pos = (cursor[:, None] - (sq - 1)
+               + np.arange(sq)[None, :]).astype(np.int32)
+        tables = rng.permutation(np.arange(1, pages)).reshape(
+            b, n_blocks_l).astype(np.int32)
+        tables[np.arange(n_blocks_l)[None, :]
+               > (cursor // page_tokens_l)[:, None]] = 0
+        tables[0] = 0
+        kernel = jax.jit(lambda q, pool, t, p:
+                         pallas_latent_attn.latent_decode_attention(
+                             q, pool, t, p, rank=rank, softmax_scale=0.135,
+                             interpret=False))
+        args = (q, pool, jnp.asarray(tables), jnp.asarray(pos))
+        check(f"latent bf16 {h}q rank{rank}+{rope} sq{sq}",
+              timed(kernel, *args), reference(latent_ref)(*args))
+
     # ---- flash attention fwd + bwd at the trainer's sequence length
     # (Arrays go in as arguments: a closed-over array is baked into the
     # program as a constant, and into its cache entry.)
@@ -688,7 +733,7 @@ def child_serve_parity(argv: list[str]) -> int:
     import numpy as np
 
     from k8s_distributed_deeplearning_tpu import backend
-    from k8s_distributed_deeplearning_tpu.models import generate, llama
+    from k8s_distributed_deeplearning_tpu.models import generate, llama, moe
     from k8s_distributed_deeplearning_tpu.serve import Request, ServeEngine
     from k8s_distributed_deeplearning_tpu.serve.cli import preset_config
     backend.use_compile_cache()
@@ -702,7 +747,8 @@ def child_serve_parity(argv: list[str]) -> int:
     prompts = np.random.default_rng(0).integers(
         0, cfg.vocab_size, size=(n_prompts, prompt_len)).astype(np.int32)
 
-    def engine_tokens(tp: int) -> np.ndarray:
+    def engine_tokens(tp: int, model=model, params=params,
+                      prompts=prompts) -> np.ndarray:
         eng = ServeEngine(model, params, num_slots=SERVE_SLOTS,
                           prefill_chunk_tokens=SERVE_CHUNK,
                           prefix_cache_mb=SERVE_TRIE_MB, tp=tp)
@@ -716,13 +762,27 @@ def child_serve_parity(argv: list[str]) -> int:
     routes = {"tp0": engine_tokens(0)}
     if tp:
         routes[f"tp{tp}"] = engine_tokens(tp)
+    # The tiny latent-MoE preset (latent pool, its kernel, expert layers with
+    # a share of the experts' routing) through the same engine, against its
+    # own generate(), to the same near-tie standard (float32 parameters, but
+    # the chip's default matmul precision is bf16's).
+    lm_cfg, lm_latent, lm_moe = moe.config_tiny_latent_moe(
+        max_seq_len=SERVE_MAX_SEQ)
+    lm = moe.LatentMoELM(lm_cfg, lm_latent, lm_moe)
+    lm_params = lm.init(jax.random.PRNGKey(1),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    lm_prompts = prompts % lm_cfg.vocab_size
+    lm_dense = np.asarray(generate.generate(
+        lm, lm_params, jnp.asarray(lm_prompts), max_new_tokens=new))
+    lm_served = engine_tokens(0, lm, lm_params, lm_prompts)
     setup_s = round(time.monotonic() - t0, 1)
 
     tol = 4 * float(jnp.finfo(cfg.dtype).eps)
     compared = equal = 0
     near_ties, wrong = [], []
 
-    def compare(name: str, got: np.ndarray, want: np.ndarray) -> None:
+    def compare(name: str, got: np.ndarray, want: np.ndarray, model=model,
+                params=params, prompts=prompts) -> None:
         nonlocal compared, equal
         for i in range(n_prompts):
             diff = np.nonzero(got[i] != want[i])[0]
@@ -748,9 +808,14 @@ def child_serve_parity(argv: list[str]) -> int:
         compare(f"{name} vs dense", toks, dense)
     if tp:
         compare(f"tp{tp} vs tp0", routes[f"tp{tp}"], routes["tp0"])
+    before = equal
+    compare("latent-moe vs dense", lm_served, lm_dense, lm, lm_params,
+            lm_prompts)
     _emit("serve_parity", **_device_fields(), tp=tp, setup_s=setup_s,
           tokens_compared=compared, tokens_equal=equal,
-          near_tie_divergences=near_ties, wrong=wrong, ok=not wrong)
+          near_tie_divergences=near_ties, wrong=wrong,
+          latent_moe_tokens_equal=[equal - before, lm_dense.size],
+          ok=not wrong)
     return 0
 
 

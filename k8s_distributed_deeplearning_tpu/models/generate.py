@@ -28,6 +28,14 @@ import jax.numpy as jnp
 PyTree = Any
 
 
+def moe_assignments(variables: PyTree) -> jax.Array | None:
+    """What the call's expert layers sowed under ``moe_stats``: the count of
+    picks that landed on each HELD expert, ``[expert layers, held]`` int32 —
+    None for a model with no expert layer."""
+    leaves = jax.tree.leaves(variables.get("moe_stats", {}))
+    return jnp.stack(leaves) if leaves else None
+
+
 def prefill(model, params: PyTree, prompt: jax.Array, *,
             positions: jax.Array | None = None,
             segment_ids: jax.Array | None = None
@@ -57,11 +65,13 @@ def prefill_chunk(model, params: PyTree, cache: PyTree, chunk: jax.Array, *,
                   positions: jax.Array | None = None,
                   segment_ids: jax.Array | None = None,
                   block_tables: jax.Array | None = None
-                  ) -> tuple[jax.Array, PyTree]:
+                  ) -> tuple[jax.Array, PyTree, jax.Array | None]:
     """Resume prefill on an EXISTING cache: run ``chunk`` ([B, C] int32)
     through the shared-cursor decode path starting at cache position
     ``start`` (default: wherever the cache's cursor already is). Returns
-    ``(logits [B, C, V], cache)`` with the cursor advanced by C.
+    ``(logits [B, C, V], cache, counts)`` with the cursor advanced by C;
+    ``counts`` is what the model's expert layers sowed under ``moe_stats``
+    (:func:`moe_assignments`; None for a model without any).
 
     This is what makes chunked prefill possible without touching the model:
     the shared-cursor decode branch (models/transformer.py) already appends
@@ -93,8 +103,9 @@ def prefill_chunk(model, params: PyTree, cache: PyTree, chunk: jax.Array, *,
     if block_tables is not None:
         kw["block_tables"] = block_tables
     logits, vars_ = model.apply({"params": params, "cache": cache}, chunk,
-                                decode=True, mutable=["cache"], **kw)
-    return logits, vars_["cache"]
+                                decode=True, mutable=["cache", "moe_stats"],
+                                **kw)
+    return logits, vars_["cache"], moe_assignments(vars_)
 
 
 def decode_step(model, params: PyTree, cache: PyTree, token: jax.Array, *,
@@ -119,13 +130,14 @@ def decode_step(model, params: PyTree, cache: PyTree, token: jax.Array, *,
 def slot_decode_step(model, params: PyTree, cache: PyTree,
                      tokens: jax.Array, slot_positions: jax.Array,
                      block_tables: jax.Array | None = None
-                     ) -> tuple[jax.Array, PyTree]:
+                     ) -> tuple[jax.Array, PyTree, jax.Array | None]:
     """One SLOT decode step: row i's ``tokens[i]`` is written at that
     row's own cursor ``slot_positions[i]`` ([B] int32) and attends to its
     row prefix ``0..slot_positions[i]`` only (models/transformer.py slot
     branch). Rows live independent lifetimes — the continuous-batching
-    engine's per-iteration program. Returns ``(logits, cache)`` with
-    logits [B, V]. The caller owns cursor arithmetic (pass position =
+    engine's per-iteration program. Returns ``(logits, cache, counts)``
+    with logits [B, V] and :func:`moe_assignments` (None for a model with
+    no expert layer). The caller owns cursor arithmetic (pass position =
     tokens-written-so-far for each row) and must keep ``slot_positions``
     within ``max_seq_len``; stale KV beyond a row's cursor is never
     attended, so freed slots are reusable without clearing.
@@ -139,8 +151,8 @@ def slot_decode_step(model, params: PyTree, cache: PyTree,
     logits, vars_ = model.apply({"params": params, "cache": cache},
                                 tokens[:, None], decode=True,
                                 cache_positions=slot_positions,
-                                mutable=["cache"], **kw)
-    return logits[:, -1, :], vars_["cache"]
+                                mutable=["cache", "moe_stats"], **kw)
+    return logits[:, -1, :], vars_["cache"], moe_assignments(vars_)
 
 
 def slot_verify_step(model, params: PyTree, cache: PyTree,

@@ -39,6 +39,16 @@ aux loss + router z-loss exposed via ``sow("intermediates", ...)``. Both
 dispatch mechanisms implement IDENTICAL routing semantics (same keep set:
 drops only start once an expert is full, after which both drop everything
 later in choice-major order) — asserted by parity tests.
+
+Serving (``decode=True``) is dropless and per-token whatever the config says,
+by ONE rule on the rows a call has (:func:`serving_dispatch`): the grouped
+kernel from ``GROUPED_MIN_ROWS_PER_EXPERT`` rows an expert, every held expert
+on every row below. A layer may be ONE CHIP'S SHARE of an expert-parallel
+deployment (``experts_held`` / ``expert_offset``): it routes over all the
+experts and computes the part its own experts give. The router's conventions
+(softmax, or sigmoid scores with a selection-only bias and scaled gates) and
+a shared expert are config; :class:`LatentMoELM` is the family with latent
+attention and a leading dense layer.
 """
 from __future__ import annotations
 
@@ -53,7 +63,8 @@ import jax.numpy as jnp
 import optax
 
 from k8s_distributed_deeplearning_tpu.models.transformer import (
-    LMHead, Transformer, TransformerConfig, default_init, lm_batch_views)
+    MLP, LatentAttention, LatentAttentionConfig, LayerKind, LMHead,
+    Transformer, TransformerConfig, default_init, lm_batch_views)
 
 Dtype = Any
 
@@ -85,6 +96,23 @@ class MoEConfig:
     routing: str = "topk"            # "topk" | "expert_choice"
     dispatch: str = "index"          # "index" | "einsum" | "ragged"
     ragged_block_m: int = 512        # grouped-GEMM row block (see pallas_gmm)
+    # The router's conventions. "softmax": probabilities over all experts,
+    # the chosen k renormalised. "sigmoid": independent scores; with
+    # ``select_bias`` a learned per-expert bias is added FOR THE CHOICE ONLY
+    # (aux-loss-free balancing) and the gates are the chosen experts' own
+    # scores, renormalised over the chosen k, times ``routed_scale``.
+    score_fn: str = "softmax"        # "softmax" | "sigmoid"
+    select_bias: bool = False
+    routed_scale: float = 1.0
+    shared_experts: int = 0          # always-on experts beside the routed
+    expert_mlp_dim: int | None = None   # routed/shared width (None: cfg's)
+    # One chip's share of an expert-parallel layer: the router keeps all
+    # ``num_experts`` outputs and the top-k over them; this module holds
+    # experts [expert_offset, expert_offset + experts_held) and computes
+    # their part of the result (plus the shared expert, which every share
+    # computes alike). None = all of them.
+    experts_held: int | None = None
+    expert_offset: int = 0
 
     def __post_init__(self):
         if self.routing not in ("topk", "expert_choice"):
@@ -93,12 +121,43 @@ class MoEConfig:
         if self.dispatch not in ("index", "einsum", "ragged"):
             raise ValueError(f"dispatch must be 'index', 'einsum' or "
                              f"'ragged', got {self.dispatch!r}")
+        if self.score_fn not in ("softmax", "sigmoid"):
+            raise ValueError(f"score_fn must be 'softmax' or 'sigmoid', "
+                             f"got {self.score_fn!r}")
+        if not 0 <= self.expert_offset <= self.num_experts - self.held:
+            raise ValueError(
+                f"experts [{self.expert_offset}, {self.expert_offset} + "
+                f"{self.held}) are not among the {self.num_experts} routed")
         if self.dispatch == "ragged" and self.routing == "expert_choice":
             raise ValueError(
                 "dispatch='ragged' targets top-k routing: expert choice "
                 "already runs every expert exactly full (its [E, C, d] "
                 "buffers carry no capacity padding), so the grouped GEMM "
                 "has nothing to reclaim — use dispatch='index'.")
+
+    @property
+    def held(self) -> int:
+        return (self.num_experts if self.experts_held is None
+                else self.experts_held)
+
+
+# Rows a held expert can expect (rows x top_k / num_experts) from which the
+# serving path takes the grouped kernel: below it every held expert runs on
+# every row (no scatter; the weights' read binds either way), above it the
+# all-rows form's E x T products cost more than the sort. 32 is where the
+# old `t >= 128` rule sat for the config it was measured on (8 experts,
+# top-2: grouped 4.2k vs 3.8k tok/s below it, round 5).
+GROUPED_MIN_ROWS_PER_EXPERT = 32
+
+
+def serving_dispatch(rows: int, moe: "MoEConfig") -> str:
+    """The serving (``decode=True``) dispatch of a call with *rows* tokens,
+    from what the call can see: ``"grouped"`` (dropless grouped matmul,
+    :mod:`ops.pallas_gmm` — a ``dispatch="ragged"`` config at enough rows an
+    expert) or ``"dense"`` (every held expert on every row, gated)."""
+    per_expert = rows * moe.top_k / moe.num_experts
+    return ("grouped" if moe.dispatch == "ragged"
+            and per_expert >= GROUPED_MIN_ROWS_PER_EXPERT else "dense")
 
 
 def clamped_capacity(tokens: int, moe: "MoEConfig") -> int:
@@ -112,15 +171,22 @@ def clamped_capacity(tokens: int, moe: "MoEConfig") -> int:
                                   * tokens / moe.num_experts)))
 
 
-def _topk_assignments(logits: jax.Array, k: int):
-    """Greedy top-k expert choices shared by both dispatch mechanisms.
+def _topk_assignments(logits: jax.Array, k: int,
+                      moe: "MoEConfig | None" = None,
+                      bias: jax.Array | None = None):
+    """Greedy top-k expert choices shared by every dispatch mechanism.
 
-    Returns (probs [T, E] f32, idx list of k [T] int32 expert picks,
-    assign list of k one-hot [T, E], gate_stack [k, T] renormalized)."""
+    Returns (scores [T, E] f32, idx list of k [T] int32 expert picks,
+    assign list of k one-hot [T, E], gate_stack [k, T] renormalized).
+    *moe* gives the router's conventions (None: softmax, no bias, scale 1);
+    *bias* [E] moves the CHOICE and never the gate."""
     t, e = logits.shape
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    sigmoid = moe is not None and moe.score_fn == "sigmoid"
+    scores = (jax.nn.sigmoid if sigmoid else
+              functools.partial(jax.nn.softmax, axis=-1))(
+                  logits.astype(jnp.float32))
 
-    remaining = probs
+    remaining = scores if bias is None else scores + bias.astype(jnp.float32)
     idx_list = []   # k [T] argmax picks
     assign = []     # k one-hot [T, E] masks
     gates = []      # k [T] gate values
@@ -129,14 +195,16 @@ def _topk_assignments(logits: jax.Array, k: int):
         one_hot = jax.nn.one_hot(idx, e, dtype=jnp.float32)
         idx_list.append(idx.astype(jnp.int32))
         assign.append(one_hot)
-        gates.append(jnp.sum(probs * one_hot, axis=-1))
-        remaining = remaining * (1.0 - one_hot)
+        gates.append(jnp.sum(scores * one_hot, axis=-1))
+        remaining = jnp.where(one_hot > 0, -jnp.inf, remaining)
 
     # Renormalize the k gates per token.
     gate_stack = jnp.stack(gates, axis=0)                     # [k, T]
     gate_stack = gate_stack / jnp.maximum(
         jnp.sum(gate_stack, axis=0, keepdims=True), 1e-9)
-    return probs, idx_list, assign, gate_stack
+    if moe is not None and moe.routed_scale != 1.0:
+        gate_stack = gate_stack * moe.routed_scale
+    return scores, idx_list, assign, gate_stack
 
 
 def _z_loss(logits: jax.Array) -> jax.Array:
@@ -174,7 +242,9 @@ def _expert_choice_picks(logits: jax.Array, capacity: int):
     return jax.lax.top_k(probs.T, capacity)
 
 
-def top_k_dispatch_indices(logits: jax.Array, k: int, capacity: int):
+def top_k_dispatch_indices(logits: jax.Array, k: int, capacity: int,
+                           moe: "MoEConfig | None" = None,
+                           bias: jax.Array | None = None):
     """Index-based top-k routing: the same keep set as :func:`top_k_routing`
     (identical cumsum capacity accounting — choice 0 takes priority, then
     token order) expressed as direct scatter/gather indices instead of
@@ -186,7 +256,8 @@ def top_k_dispatch_indices(logits: jax.Array, k: int, capacity: int):
     keep [k, T] bool, aux dict). All shapes static.
     """
     t, e = logits.shape
-    probs, idx_list, assign, gate_stack = _topk_assignments(logits, k)
+    probs, idx_list, assign, gate_stack = _topk_assignments(logits, k, moe,
+                                                            bias)
 
     used = jnp.zeros((e,), jnp.float32)       # kept slots from earlier choices
     dests, keeps = [], []
@@ -287,8 +358,8 @@ class MoEMLP(nn.Module):
     def __call__(self, x: jax.Array, decode: bool = False) -> jax.Array:
         cfg, moe = self.cfg, self.moe
         b, s, d = x.shape
-        mlp = cfg.resolved_mlp_dim
-        e = moe.num_experts
+        mlp = moe.expert_mlp_dim or cfg.resolved_mlp_dim
+        e, held = moe.num_experts, moe.held
         tokens = x.reshape(b * s, d)
         t = b * s
         capacity = clamped_capacity(t, moe)
@@ -297,16 +368,29 @@ class MoEMLP(nn.Module):
             "router", nn.with_logical_partitioning(default_init(),
                                                    ("embed", "expert")),
             (d, e), jnp.float32)
-        logits = tokens.astype(jnp.float32) @ router_w
+        # float32 in earnest: on the TPU an f32 product runs in bf16 passes
+        # unless asked otherwise, and a near-tie among the top-k would flip.
+        logits = jnp.dot(tokens.astype(jnp.float32),
+                         router_w.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        bias = None
+        if moe.select_bias:
+            bias = self.param(
+                "router_bias", nn.with_logical_partitioning(
+                    nn.initializers.zeros, ("expert",)), (e,), jnp.float32)
 
         def expert_param(name, shape, axes):
             return self.param(
                 name, nn.with_logical_partitioning(default_init(), axes),
                 shape, jnp.float32).astype(cfg.dtype)
 
-        w_gate = expert_param("w_gate", (e, d, mlp), ("expert", "embed", "mlp"))
-        w_up = expert_param("w_up", (e, d, mlp), ("expert", "embed", "mlp"))
-        w_down = expert_param("w_down", (e, mlp, d), ("expert", "mlp", "embed"))
+        w_gate = expert_param("w_gate", (held, d, mlp), ("expert", "embed", "mlp"))
+        w_up = expert_param("w_up", (held, d, mlp), ("expert", "embed", "mlp"))
+        w_down = expert_param("w_down", (held, mlp, d), ("expert", "mlp", "embed"))
+        shared = 0.0
+        if moe.shared_experts:
+            shared = MLP(dataclasses.replace(
+                cfg, mlp_dim=mlp * moe.shared_experts), name="shared")(x)
 
         def experts_apply(xe):
             """[E, C, d] expert buffers -> [E, C, d] outputs."""
@@ -317,58 +401,70 @@ class MoEMLP(nn.Module):
             ye = jnp.einsum("ecm,emd->ecd", h, w_down)
             return nn.with_logical_constraint(ye, ("expert", None, "embed"))
 
-        if decode:
-            if moe.dispatch == "ragged" and t >= 128:
-                # Ragged serving for WIDE calls (prefill): dropless and
-                # width-independent like the capacity=T path below but
-                # without its [E, T, d] buffers — prefill MLP work stays
-                # at top_k slots/token instead of E× (parity-tested
-                # alongside the index serving path). Narrow calls (the
-                # per-token decode steps, t = B) stay on the index path:
-                # both serve IDENTICAL per-token top-k routing, so
-                # switching by call width changes nothing semantically,
-                # and at t=8 the grouped-GEMM grid overhead measured
-                # slower than the tiny dropless einsums (3.8k vs 4.2k
-                # tok/s end-to-end) while ragged prefill does ~E/k×
-                # less MLP work. Single-shard expert compute, like
-                # ragged training.
-                y, _ = self._ragged_dispatch(tokens, logits,
-                                             w_gate, w_up, w_down,
-                                             decode=True)
-                return y.reshape(b, s, d)
-            # Serving path: DROPLESS top-k via the index dispatch with
-            # capacity = T (no token can overflow a T-deep buffer, so
-            # every token keeps all k choices). The training paths size
-            # capacity from THIS call's token count, so a decode step
-            # (T = B) and a prefill (T = B·S_prompt) would drop different
-            # tokens — routing would depend on call width; with keep
-            # always true each token's output is a function of that token
-            # alone, so incremental decode matches one-shot prefill
-            # exactly (parity-tested). Reuses experts_apply, so the
-            # "expert" logical-axis constraints keep EP sharding at
-            # serving too. routing="topk" is FORCED: expert choice's
-            # whole-batch token selection has no causal decode semantics
-            # (see the MoELM warning), so EC models decode through the
-            # same per-token top-k gates. Cost note: the [E, T, d]
-            # buffers make prefill MLP work scale with E rather than the
-            # training path's capacity_factor·k slots (~E/(k·cf)× FLOPs,
-            # mostly zero rows) — the price of exact width-independent
-            # routing; decode steps (T = B) are unaffected.
-            y, _ = self._index_dispatch(tokens, logits, t, experts_apply,
-                                        routing="topk")
-            return y.reshape(b, s, d)
+        if decode or held != e:
+            # Serving: DROPLESS per-token top-k, so that a token's output is
+            # a function of that token alone and incremental decode matches
+            # one-shot prefill whatever the call's width (parity-tested).
+            # routing="topk" is FORCED: expert choice's whole-batch token
+            # selection has no causal decode semantics (see the MoELM
+            # warning). Which mechanism computes it is serving_dispatch's
+            # stated rule on the rows a call has. One chip's share of the
+            # experts (experts_held) is a serving layout: its plain forward
+            # takes this path too, and sows no auxiliary loss.
+            if serving_dispatch(t, moe) == "grouped":
+                y, _ = self._ragged_dispatch(tokens, logits, w_gate, w_up,
+                                             w_down, decode=True, bias=bias)
+            else:
+                y = self._dense_serving(tokens, logits, bias, experts_apply)
+            return y.reshape(b, s, d) + shared
         if moe.dispatch == "ragged":
             y, aux = self._ragged_dispatch(tokens, logits,
-                                           w_gate, w_up, w_down)
+                                           w_gate, w_up, w_down, bias=bias)
         elif moe.dispatch == "index":
             y, aux = self._index_dispatch(tokens, logits, capacity,
-                                          experts_apply)
+                                          experts_apply, bias=bias)
         else:
+            if bias is not None or moe.score_fn != "softmax":
+                raise NotImplementedError(
+                    "dispatch='einsum' is the softmax reference path")
             y, aux = self._einsum_dispatch(tokens, logits, capacity,
                                            experts_apply)
         for name, val in aux.items():
             self.sow("intermediates", name, val)
-        return y.reshape(b, s, d)
+        return y.reshape(b, s, d) + shared
+
+    def _held_picks(self, idx_list, assign):
+        """The picks that landed on held experts: per choice the local
+        expert index (clipped), whether it is held, and the one-hot over
+        the held experts alone; and their count per held expert, sown as
+        ``moe_stats/assignments`` for the engine's counters."""
+        moe = self.moe
+        lo, held = moe.expert_offset, moe.held
+        local = [jnp.clip(i - lo, 0, held - 1) for i in idx_list]
+        mine = [(i >= lo) & (i < lo + held) for i in idx_list]
+        assign_held = [a[:, lo:lo + held] for a in assign]
+        counts = functools.reduce(
+            lambda a, b: a + b, (jnp.sum(a, axis=0) for a in assign_held))
+        self.sow("moe_stats", "assignments", counts.astype(jnp.int32),
+                 reduce_fn=lambda _, new: new, init_fn=lambda: None)
+        return local, mine, assign_held, counts
+
+    def _dense_serving(self, tokens, logits, bias, experts_apply):
+        """Every held expert on every row, gated: ``y_t = Σ_e w[t, e]
+        E_e(x_t)`` with ``w`` the gate where token t chose held expert e and
+        0 elsewhere. No sort, no scatter — at a few rows an expert the
+        products are bound by reading each expert's weights, which this
+        reads once."""
+        cfg, moe = self.cfg, self.moe
+        _, idx_list, assign, gate_stack = _topk_assignments(
+            logits, moe.top_k, moe, bias)
+        _, _, assign_held, _ = self._held_picks(idx_list, assign)
+        w = sum(a * g[:, None] for a, g in zip(assign_held, gate_stack))
+        tok_c = tokens.astype(cfg.dtype)
+        ye = experts_apply(jnp.broadcast_to(
+            tok_c[None], (moe.held,) + tok_c.shape))            # [E, T, d]
+        return jnp.einsum("etd,te->td", ye.astype(jnp.float32), w
+                          ).astype(cfg.dtype)   # gates and their sum in f32
 
     def _einsum_dispatch(self, tokens, logits, capacity, experts_apply):
         """Dense one-hot dispatch/combine (Switch-style reference path)."""
@@ -387,17 +483,16 @@ class MoEMLP(nn.Module):
         return y, aux
 
     def _index_dispatch(self, tokens, logits, capacity, experts_apply,
-                        routing=None):
+                        bias=None):
         """Index-based scatter/gather dispatch — O(T·k·d) data movement
         instead of the dense path's T·E·C·d dispatch/combine MACs,
-        identical routing semantics (parity-tested). *routing* overrides
-        the config's assignment policy (the decode path forces "topk")."""
+        identical routing semantics (parity-tested)."""
         cfg, moe = self.cfg, self.moe
         t, d = tokens.shape
         e = moe.num_experts
         tok_c = tokens.astype(cfg.dtype)
 
-        if (routing or moe.routing) == "expert_choice":
+        if moe.routing == "expert_choice":
             gates, idx = _expert_choice_picks(logits, capacity)   # [E, C]
             sel = idx.reshape(-1)
             xe = jnp.take(tok_c, sel, axis=0).reshape(e, capacity, d)
@@ -413,7 +508,7 @@ class MoEMLP(nn.Module):
             return y, aux
 
         dest, gate, keep, aux = top_k_dispatch_indices(
-            logits, moe.top_k, capacity)
+            logits, moe.top_k, capacity, moe, bias)
         # Scatter tokens into [E*C, d] buffers, one scatter per choice (the
         # operand is `tokens` in place — no gather needed); dropped slots
         # carry the out-of-range sentinel and fall away via mode="drop".
@@ -432,7 +527,7 @@ class MoEMLP(nn.Module):
         return y, aux
 
     def _ragged_dispatch(self, tokens, logits, w_gate, w_up, w_down,
-                         decode=False):
+                         decode=False, bias=None):
         """Dropless grouped-GEMM dispatch (``ops.pallas_gmm``): tokens
         scatter into one flat [M_pad, d] buffer sorted by expert
         (block-aligned ragged layout — the SAME cumsum position accounting
@@ -470,7 +565,8 @@ class MoEMLP(nn.Module):
                 bspec, rep = P(batch_axes), P()
 
                 def inner(tk, lg, wg, wu, wd):
-                    y, (f, p, z) = self._ragged_core(tk, lg, wg, wu, wd)
+                    y, (f, p, z) = self._ragged_core(tk, lg, wg, wu, wd,
+                                                     bias, decode)
                     # pmean the ROUTING STATISTICS, not per-shard losses:
                     # the load-balance loss is E·Σ_e f̄_e·p̄_e of GLOBAL
                     # means — averaging per-shard Σ f·p would differ
@@ -503,10 +599,11 @@ class MoEMLP(nn.Module):
                     _RAGGED_FALLBACK_WARNED.append(True)
                     warnings.warn(msg, RuntimeWarning, stacklevel=2)
         y, (f, p, z) = self._ragged_core(tokens, logits, w_gate, w_up,
-                                         w_down)
+                                         w_down, bias, decode)
         return y, _ragged_aux(f, p, z)
 
-    def _ragged_core(self, tokens, logits, w_gate, w_up, w_down):
+    def _ragged_core(self, tokens, logits, w_gate, w_up, w_down, bias=None,
+                     decode=False):
         from k8s_distributed_deeplearning_tpu.ops import pallas_gmm
 
         cfg, moe = self.cfg, self.moe
@@ -514,9 +611,12 @@ class MoEMLP(nn.Module):
         k = moe.top_k
         tok_c = tokens.astype(cfg.dtype)
 
-        probs, idx_list, assign, gate_stack = _topk_assignments(logits, k)
-        counts = functools.reduce(
-            lambda a, b: a + b, (jnp.sum(a, axis=0) for a in assign))
+        probs, idx_list, assign, gate_stack = _topk_assignments(
+            logits, k, moe, bias)
+        # Rows exist for picks that landed on HELD experts (all of them
+        # unless this module is one chip's share); the others' destination is
+        # the out-of-range sentinel, dropped by the scatter and gated to 0.
+        local, mine, assign_held, counts = self._held_picks(idx_list, assign)
         # Row block clipped to the call width: at decode steps (t = B)
         # the configured 512 block would pad 16 real rows to 4.6k (one
         # mostly-dead block per expert) and measure 2.2x SLOWER than the
@@ -526,23 +626,39 @@ class MoEMLP(nn.Module):
         layout = pallas_gmm.grouped_layout(
             counts.astype(jnp.int32), t * k, block_m=bm)
 
-        used = jnp.zeros((moe.num_experts,), jnp.float32)
+        used = jnp.zeros((moe.held,), jnp.float32)
         dests = []
         for c in range(k):
-            one_hot = assign[c]                                   # [T, E]
+            one_hot = assign_held[c]                              # [T, held]
             pos = jnp.cumsum(one_hot, axis=0) - one_hot + used
             used = used + jnp.sum(one_hot, axis=0)
             pos_t = jnp.sum(pos * one_hot, axis=-1).astype(jnp.int32)
-            dests.append(layout.row_offset[idx_list[c]] + pos_t)
+            dests.append(jnp.where(mine[c],
+                                   layout.row_offset[local[c]] + pos_t,
+                                   layout.m_pad))
 
         # Destinations are unique across tokens AND choices (one row per
-        # (expert, position)), so add ≡ set — and add's VJP is just a
-        # gather, where set's pays an extra zeroing scatter on the base.
-        # Padding rows stay zero (the gmm contract relies on this).
-        xs = jnp.zeros((layout.m_pad, d), cfg.dtype)
-        for c in range(k):
-            xs = xs.at[dests[c]].add(tok_c, mode="drop",
-                                     unique_indices=True)
+        # (expert, position); a share's picks on experts held elsewhere all
+        # carry the sentinel and are dropped). Padding rows stay zero (the
+        # gmm contract relies on this).
+        if decode:
+            # Serving: the buffer is ONE gather — each row's source token,
+            # scattered as int32 (k·T numbers, not k passes over the
+            # [M_pad, d] buffer: on the chip those k scatter-adds were 10 ms
+            # of a 1,024-token chunk, PR 27), then the rows themselves.
+            src = jnp.full((layout.m_pad,), -1, jnp.int32)
+            for c in range(k):
+                src = src.at[dests[c]].set(jnp.arange(t, dtype=jnp.int32),
+                                           mode="drop")
+            xs = jnp.where((src >= 0)[:, None],
+                           jnp.take(tok_c, jnp.maximum(src, 0), axis=0), 0)
+        else:
+            # Training: add ≡ set on unique rows — and add's VJP is just a
+            # gather, where set's pays an extra zeroing scatter on the base.
+            xs = jnp.zeros((layout.m_pad, d), cfg.dtype)
+            for c in range(k):
+                xs = xs.at[dests[c]].add(tok_c, mode="drop",
+                                         unique_indices=True)
         # checkpoint_name: a Pallas call is not a dot XLA's remat policy
         # can match, so without the tag remat policies that save matmul
         # outputs would recompute all three grouped GEMMs in the backward
@@ -552,10 +668,16 @@ class MoEMLP(nn.Module):
             pallas_gmm.gmm(x, w, layout), "gmm_out")
         h = nn.silu(gmm(xs, w_gate)) * gmm(xs, w_up)
         ys = gmm(h, w_down)
-        y = jnp.zeros((t, d), cfg.dtype)
+        # Serving sums the k gated rows in f32, as the dense serving form
+        # does (the two serving dispatches agree); training sums in the
+        # model's type.
+        acc = jnp.float32 if decode else cfg.dtype
+        y = jnp.zeros((t, d), acc)
         for c in range(k):
-            y = y + (jnp.take(ys, dests[c], axis=0)
-                     * gate_stack[c][:, None].astype(cfg.dtype))
+            y = y + (jnp.take(ys, jnp.minimum(dests[c], layout.m_pad - 1),
+                              axis=0)
+                     * (gate_stack[c] * mine[c])[:, None].astype(acc))
+        y = y.astype(cfg.dtype)
         # Raw routing statistics, not losses: the caller (sharded or not)
         # forms the load-balance loss from (pmean'd) means via
         # _ragged_aux, keeping sharded and unsharded numerics identical.
@@ -568,7 +690,7 @@ class MoELM(nn.Module):
     """Decoder-only MoE language model (every layer MoE, GShard-dense layout).
 
     Rides the shared :class:`~models.transformer.Transformer` core with
-    ``mlp_factory`` swapping the dense MLP for :class:`MoEMLP`, so
+    a uniform ``pattern`` swapping the dense MLP for :class:`MoEMLP`, so
     scan_layers / remat / dropout / packed ``segment_ids`` /
     ``decode`` (KV-cache generation via :func:`models.generate.generate`)
     all work for MoE exactly as for dense models. Decode routes the MoE
@@ -609,7 +731,9 @@ class MoELM(nn.Module):
                 UserWarning, stacklevel=2)
         factory = functools.partial(MoEMLP, moe=self.moe,
                                     shard_mesh=self.shard_mesh)
-        x = Transformer(self.cfg, mlp_factory=factory, name="transformer")(
+        x = Transformer(self.cfg,
+                        pattern=(LayerKind(mlp=factory),) * self.cfg.n_layers,
+                        name="transformer")(
             tokens, positions=positions, segment_ids=segment_ids,
             deterministic=deterministic,
             attention_fn=attention_fn, decode=decode)
@@ -619,6 +743,61 @@ class MoELM(nn.Module):
             # takes the default path so LMHead params get created.
             return x
         return LMHead(self.cfg, name="head")(x)
+
+
+class LatentMoELM(nn.Module):
+    """Decoder-only LM of the latent-attention + sparse-expert family
+    (DeepSeek-V2/V3 layout): every layer attends through
+    :class:`~models.transformer.LatentAttention`; the first ``first_dense``
+    layers have the dense SwiGLU MLP (``cfg.mlp_dim`` wide), the rest
+    :class:`MoEMLP` (``moe.expert_mlp_dim`` wide, with its shared expert).
+    Layers differ, so ``cfg.scan_layers`` must be False. The serving engine
+    calls it like :class:`models.llama.LlamaLM`; a ``moe`` with
+    ``experts_held`` makes it one chip's share of an expert-parallel
+    deployment (serving only)."""
+
+    cfg: TransformerConfig
+    latent: LatentAttentionConfig
+    moe: MoEConfig
+    first_dense: int = 1
+
+    @nn.compact
+    def __call__(self, tokens, *, positions=None, deterministic: bool = True,
+                 decode: bool = False, cache_positions=None,
+                 block_tables=None, return_hidden: bool = False):
+        attn = functools.partial(LatentAttention, latent=self.latent)
+        dense = LayerKind(attention=attn)
+        sparse = LayerKind(attention=attn,
+                           mlp=functools.partial(MoEMLP, moe=self.moe))
+        n = self.cfg.n_layers
+        pattern = ((dense,) * min(self.first_dense, n)
+                   + (sparse,) * max(n - self.first_dense, 0))
+        x = Transformer(self.cfg, pattern=pattern, name="transformer")(
+            tokens, positions=positions, deterministic=deterministic,
+            decode=decode, cache_positions=cache_positions,
+            block_tables=block_tables)
+        if return_hidden:
+            return x
+        return LMHead(self.cfg, name="head")(x)
+
+
+def config_tiny_latent_moe(**overrides):
+    """(cfg, latent, moe) of a tiny float32 latent-MoE with the family's
+    topology — one dense layer, expert layers with a shared expert, sigmoid
+    router with a selection bias, YaRN — for tests and ``chip_smoke``."""
+    base = dict(vocab_size=256, dim=64, n_layers=3, n_heads=4, mlp_dim=128,
+                max_seq_len=128, rope_theta=10000.0, activation="swiglu",
+                norm="rmsnorm", position="rope", causal=True,
+                scan_layers=False, dtype=jnp.float32)
+    base.update(overrides)
+    latent = LatentAttentionConfig(
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, rope_factor=4.0, rope_original_max=32,
+        mscale=1.0, mscale_all_dim=1.0)
+    moe = MoEConfig(num_experts=8, top_k=2, dispatch="ragged",
+                    ragged_block_m=8, score_fn="sigmoid", select_bias=True,
+                    routed_scale=2.5, shared_experts=1, expert_mlp_dim=32)
+    return TransformerConfig(**base), latent, moe
 
 
 def flops_per_token(cfg: TransformerConfig, moe: MoEConfig, *,

@@ -23,6 +23,8 @@ TPU-first throughout:
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 from typing import Any, Callable
 
 import flax.linen as nn
@@ -32,6 +34,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from k8s_distributed_deeplearning_tpu.ops import attention as attention_ops
 from k8s_distributed_deeplearning_tpu.ops import collectives
+from k8s_distributed_deeplearning_tpu.ops import pallas_latent_attn
 from k8s_distributed_deeplearning_tpu.ops import pallas_paged_attn
 
 Dtype = Any
@@ -102,7 +105,9 @@ class TransformerConfig:
                                         # widths it serves
                                         # (paged_attention_impl below).
                                         # "paged_flash" forces that kernel
-                                        # (interpret-mode off-TPU)
+                                        # (interpret-mode off-TPU) — for a
+                                        # latent pool, the latent kernels
+                                        # (latent_attention_impl)
     remat: bool = False                 # checkpoint each block
     remat_policy: str = "dots"          # "dots" (keep matmul outputs —
                                         # measured slightly faster) |
@@ -181,11 +186,12 @@ class RMSNorm(nn.Module):
 
     eps: float = 1e-6
     dtype: Dtype = jnp.bfloat16
+    axis: str | None = "embed"          # logical axis of the normed lanes
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         scale = self.param(
-            "scale", nn.with_logical_partitioning(nn.initializers.ones, ("embed",)),
+            "scale", nn.with_logical_partitioning(nn.initializers.ones, (self.axis,)),
             (x.shape[-1],), jnp.float32)
         var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
         y = x.astype(jnp.float32) * jax.lax.rsqrt(var + self.eps)
@@ -278,6 +284,65 @@ def paged_attention_impl(cfg: TransformerConfig, sq: int) -> str:
     if cfg.attention_impl == "auto":
         return pallas_paged_attn.default_impl(sq)
     return "paged_flash" if cfg.attention_impl == "paged_flash" else "xla"
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttentionConfig:
+    """Multi-head latent attention (DeepSeek-V2 §2.1) layered on a
+    TransformerConfig, which gives ``dim``, ``n_heads`` and ``rope_theta``:
+    keys and values are up-projections of ONE ``kv_lora_rank``-wide latent a
+    token, beside one ``qk_rope_head_dim``-wide rope key shared by all heads —
+    and that pair is all the cache holds. ``rope_factor`` > 1 turns on
+    ``deepseek_yarn`` scaling (:func:`yarn_inv_freq`)."""
+
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    qk_norm: bool = True                # RMSNorm over each query head, pre-RoPE
+    rope_factor: float = 1.0
+    rope_original_max: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @property
+    def cache_lanes(self) -> int:
+        """Lanes of one cached token: latent + rope key, padded to whole
+        128-lane tiles. The pad is free on the chip — an array 576 lanes wide
+        occupies 640 under the (8, 128) tiling — and Mosaic copies whole
+        tiles only (PR 27's compile of the 576-lane pool was refused)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def softmax_scale(self) -> float:
+        m = yarn_mscale(self.rope_factor, self.mscale_all_dim)
+        return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """``0.1·mscale·ln(factor) + 1`` (1 at factor <= 1): YaRN's attention
+    temperature."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1.0 else 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float) -> jax.Array:
+    """``deepseek_yarn`` inverse frequencies ``[dim/2]``: pair ``j`` keeps
+    ``theta^(-2j/dim)`` where it turns more than ``beta_fast`` times over the
+    original context, is divided by ``factor`` where it turns fewer than
+    ``beta_slow`` times, with a linear ramp between the two pair indices."""
+    j = jnp.arange(dim // 2, dtype=jnp.float32)
+    inv = theta ** (-2.0 * j / dim)
+    if factor <= 1.0:
+        return inv
+    turns = lambda beta: (dim * math.log(original_max / (2 * math.pi * beta))
+                          / (2 * math.log(theta)))
+    lo = max(math.floor(turns(beta_fast)), 0)
+    hi = min(math.ceil(turns(beta_slow)), dim - 1)
+    ramp = jnp.clip((j - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    return inv * ((1.0 - ramp) + ramp / factor)
 
 
 class Attention(nn.Module):
@@ -652,6 +717,214 @@ class Attention(nn.Module):
         return nn.with_logical_constraint(out, ("batch", "seq", "act_embed"))
 
 
+def latent_attention_impl(cfg: TransformerConfig) -> str:
+    """Which implementation a LATENT block-table call resolves to:
+    ``"latent_flash"`` (:mod:`ops.pallas_latent_attn`
+    — the absorbed decode kernel up to its ``MAX_QUERY_TOKENS``, the expanded
+    chunk kernel beyond) or ``"xla"`` (either form over the table's pages
+    gathered whole: the CPU tests' path and the kernels' reference — on a
+    TPU ``"auto"`` always resolves to the kernels)."""
+    if cfg.attention_impl == "auto":
+        return pallas_latent_attn.default_impl()
+    return "latent_flash" if cfg.attention_impl == "paged_flash" else "xla"
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention with the three cache modes the serving
+    path uses (same keywords as :class:`Attention`):
+
+    - ``decode=True`` alone: a row cache ``cached_latent``
+      ``[B, max_seq_len, cache_lanes]`` with the shared scalar cursor —
+      ``generate()``'s prefill and steps;
+    - ``block_tables`` + explicit ``positions``: a prefill chunk written
+      through the table into the page POOL ``[pages, page_tokens, lanes]``;
+    - ``block_tables`` + ``cache_positions``: paged slot decode.
+
+    One token's cache row is ``[RMSNorm(c) ; RoPE(k^R) ; 0-pad]`` and nothing
+    else. Two forms of the same function: EXPANDED (``k^N = W_UK c``,
+    ``v = W_UV c`` per head — wherever a call carries more than
+    ``pallas_latent_attn.MAX_QUERY_TOKENS`` queries a row; over the pool the
+    chunk kernel runs it by blocks of KV tokens with an online softmax, so
+    the expanded K and V of a long prefix never exist whole) and ABSORBED (``q̃ = W_UKᵀ q^N`` scores the latent itself and
+    ``W_UV`` is applied to the weighted latent — decode, where the cache is
+    read once and is key and value both).
+    """
+
+    cfg: TransformerConfig
+    latent: LatentAttentionConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array, *,
+                 mask: jax.Array | None = None,
+                 positions: jax.Array | None = None,
+                 segment_ids: jax.Array | None = None,
+                 attention_fn: Callable | None = None,
+                 decode: bool = False,
+                 cache_positions: jax.Array | None = None,
+                 block_tables: jax.Array | None = None) -> jax.Array:
+        cfg, la = self.cfg, self.latent
+        if mask is not None or segment_ids is not None or attention_fn is not None:
+            raise NotImplementedError(
+                "LatentAttention builds its own causal / cache-prefix mask; "
+                "mask, segment_ids and attention_fn are not supported")
+        if cfg.tp_axis is not None or (cfg.kv_quant is not None
+                                       and block_tables is not None):
+            raise NotImplementedError(
+                "LatentAttention has no tp_axis / kv_quant path: the latent "
+                "cache has no head axis to shard and no int8 scales")
+        if cache_positions is not None and block_tables is None:
+            raise NotImplementedError(
+                "latent slot decode needs block_tables (the paged pool)")
+        b, sq, _ = x.shape
+        h = cfg.n_heads
+        r, dn, dr, dv = (la.kv_lora_rank, la.qk_nope_head_dim,
+                         la.qk_rope_head_dim, la.v_head_dim)
+        lanes = la.cache_lanes
+        absorbed = sq <= pallas_latent_attn.MAX_QUERY_TOKENS
+        dense = functools.partial(nn.DenseGeneral, axis=-1, use_bias=False,
+                                  dtype=cfg.dtype, param_dtype=jnp.float32)
+        q = dense((h, dn + dr), kernel_init=nn.with_logical_partitioning(
+            default_init(), ("embed", "heads", "head_dim")), name="q_proj")(x)
+        if la.qk_norm:
+            q = RMSNorm(dtype=cfg.dtype, axis=None, name="q_norm")(q)
+        ckr = dense(r + dr, kernel_init=nn.with_logical_partitioning(
+            default_init(), ("embed", None)), name="kv_down")(x)
+        c = RMSNorm(dtype=cfg.dtype, axis=None, name="kv_norm")(ckr[..., :r])
+        w_up = self.param(
+            "kv_up", nn.with_logical_partitioning(
+                default_init(), (None, "heads", "head_dim")),
+            (r, h, dn + dv), jnp.float32).astype(cfg.dtype)
+        w_uk, w_uv = w_up[..., :dn], w_up[..., dn:]
+
+        cur = None
+        if decode and block_tables is not None:
+            def _pool_missing():
+                raise ValueError(
+                    "paged decode (block_tables) requires an engine-provided "
+                    "page-pool cache; it cannot be initialised from inside "
+                    "the model")
+            cached = self.variable("cache", "cached_latent", _pool_missing)
+            if positions is None:
+                if cache_positions is None:
+                    raise ValueError(
+                        "paged chunk prefill requires explicit positions")
+                positions = (cache_positions[:, None]
+                             + jnp.arange(sq, dtype=jnp.int32)[None, :])
+        elif decode:
+            cached = self.variable("cache", "cached_latent", jnp.zeros,
+                                   (b, cfg.max_seq_len, lanes), cfg.dtype)
+            cache_index = self.variable("cache", "cache_index",
+                                        lambda: jnp.zeros((), jnp.int32))
+            cur = cache_index.value
+            if positions is None:
+                positions = (cur + jnp.arange(sq))[None, :]
+
+        inv = yarn_inv_freq(dr, cfg.rope_theta, la.rope_factor,
+                            la.rope_original_max, la.beta_fast, la.beta_slow)
+        ang = jnp.outer(jnp.arange(cfg.max_seq_len, dtype=jnp.float32), inv)
+        amp = (yarn_mscale(la.rope_factor, la.mscale)
+               / yarn_mscale(la.rope_factor, la.mscale_all_dim))
+        cos, sin = jnp.cos(ang) * amp, jnp.sin(ang) * amp
+        q_n = q[..., :dn]
+        q_r = apply_rope(q[..., dn:], cos, sin, positions)
+        k_r = apply_rope(ckr[..., None, r:], cos, sin, positions)[:, :, 0]
+        row = jnp.concatenate(
+            [c, k_r, jnp.zeros((b, sq, lanes - r - dr), cfg.dtype)], axis=-1)
+        scale = la.softmax_scale
+
+        def expanded(lat, allow):
+            """lat [B, K, lanes], allow [B, sq, K] -> scores [B, H, sq, K]
+            (f32, masked) and v [B, K, H, dv]."""
+            k_n = jnp.einsum("bkr,rhd->bkhd", lat[..., :r], w_uk)
+            v = jnp.einsum("bkr,rhd->bkhd", lat[..., :r], w_uv)
+            s = (jnp.einsum("bqhd,bkhd->bhqk", q_n, k_n,
+                            preferred_element_type=jnp.float32)
+                 + jnp.einsum("bqhd,bkd->bhqk", q_r, lat[..., r:r + dr],
+                              preferred_element_type=jnp.float32)) * scale
+            return jnp.where(allow[:, None], s, pallas_paged_attn.NEG_INF), v
+
+        def attend(lat, allow, absorbed):
+            """Attention of the call's queries over cache rows ``lat``, in
+            the expanded or the absorbed form."""
+            if not absorbed:
+                s, v = expanded(lat, allow)
+                p = jax.nn.softmax(s, axis=-1).astype(cfg.dtype)
+                return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+            qt = jnp.einsum("bqhd,rhd->bqhr", q_n, w_uk)
+            s = (jnp.einsum("bqhr,bkr->bhqk", qt, lat[..., :r],
+                            preferred_element_type=jnp.float32)
+                 + jnp.einsum("bqhd,bkd->bhqk", q_r, lat[..., r:r + dr],
+                              preferred_element_type=jnp.float32)) * scale
+            s = jnp.where(allow[:, None], s, pallas_paged_attn.NEG_INF)
+            p = jax.nn.softmax(s, axis=-1).astype(cfg.dtype)
+            u = jnp.einsum("bhqk,bkr->bqhr", p, lat[..., :r])
+            return jnp.einsum("bqhr,rhd->bqhd", u, w_uv)
+
+        if decode and block_tables is not None:
+            pool = cached.value
+            page_tokens, n_blocks = pool.shape[-2], block_tables.shape[1]
+            wpos = positions.astype(jnp.int32)                    # [B, sq]
+            blk = wpos // page_tokens
+            pg = jnp.take_along_axis(block_tables,
+                                     jnp.minimum(blk, n_blocks - 1), axis=1)
+            pg = jnp.where(blk >= n_blocks, 0, pg)                # scratch
+            pool = pool.at[pg, wpos % page_tokens].set(row.astype(pool.dtype))
+            cached.value = pool
+            if latent_attention_impl(cfg) != "latent_flash":
+                # XLA: every page of the table gathered whole, then the same
+                # two forms as the row cache below — the kernels' reference
+                # and the CPU tests' path. "auto" never takes it on a TPU
+                # (a 17 k-token prefix expanded at once is gigabytes).
+                s_virt = n_blocks * page_tokens
+                lat = pool[block_tables].reshape(b, s_virt, lanes)
+                out = attend(lat, jnp.arange(s_virt)[None, None, :]
+                             <= wpos[:, :, None], absorbed)
+            elif absorbed:
+                qt = jnp.einsum("bqhd,rhd->bqhr", q_n, w_uk)
+                q_abs = jnp.concatenate(
+                    [qt, q_r, jnp.zeros((b, sq, h, lanes - r - dr), cfg.dtype)],
+                    axis=-1)
+                u = pallas_latent_attn.latent_decode_attention(
+                    q_abs, pool, block_tables, wpos, rank=r,
+                    softmax_scale=scale)
+                out = jnp.einsum("bqhr,rhd->bqhd", u, w_uv)
+            else:
+                # A chunk works through the row's pages CHUNK_BLOCK_K tokens
+                # at a time (whole blocks of the table): the pages gathered
+                # in table order (one 640-lane row a token — 22 MB at 17 k),
+                # expanded to keys and values block by block INSIDE the
+                # kernel, so the expanded K and V of a long prefix never
+                # exist whole.
+                ppb = max(1, pallas_latent_attn.CHUNK_BLOCK_K // page_tokens)
+                tbl = jnp.pad(block_tables, ((0, 0), (0, -n_blocks % ppb)))
+                out = pallas_latent_attn.latent_chunk_attention(
+                    q_n, q_r, pool[tbl].reshape(b, -1, lanes), w_uk, w_uv,
+                    wpos, rank=r, softmax_scale=scale,
+                    block_k=ppb * page_tokens)
+        elif decode:
+            lat = jax.lax.dynamic_update_slice(
+                cached.value, row.astype(cached.value.dtype), (0, cur, 0))
+            cached.value = lat
+            cache_index.value = cur + sq
+            col = jnp.arange(cfg.max_seq_len)
+            allow = col[None, None, :] <= (cur + jnp.arange(sq))[None, :, None]
+            out = attend(lat, jnp.broadcast_to(allow, (b, sq, cfg.max_seq_len)),
+                         absorbed)
+        else:
+            allow = (jnp.arange(sq)[None, :] <= jnp.arange(sq)[:, None]
+                     if cfg.causal else jnp.ones((sq, sq), bool))
+            out = checkpoint_name(
+                attend(row, jnp.broadcast_to(allow, (b, sq, sq)), False),
+                "attn_out")
+        out = nn.with_logical_constraint(out, ("batch", "seq", "heads", "head_dim"))
+        out = nn.DenseGeneral(cfg.dim, axis=(-2, -1), use_bias=False,
+                              dtype=cfg.dtype, param_dtype=jnp.float32,
+                              kernel_init=nn.with_logical_partitioning(
+                                  default_init(), ("heads", "head_dim", "embed")),
+                              name="o_proj")(out)
+        return nn.with_logical_constraint(out, ("batch", "seq", "act_embed"))
+
+
 class MLP(nn.Module):
     """Feed-forward: SwiGLU (Llama) or GELU (BERT/ViT). Column-parallel up
     projections ("mlp" logical axis), row-parallel down projection."""
@@ -689,20 +962,30 @@ class MLP(nn.Module):
         return nn.with_logical_constraint(out, ("batch", "seq", "act_embed"))
 
 
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """What one layer of a stack is made of: two factories
+    ``(cfg, name=...) -> module``. ``attention=None`` is :class:`Attention`
+    (any other must take its keywords); ``mlp=None`` is the dense
+    :class:`MLP`, any other (e.g. the expert-parallel
+    :class:`models.moe.MoEMLP`) must accept a ``decode`` keyword — the static
+    mode flag rides to it so that it can switch to its dropless serving
+    dispatch."""
+
+    attention: Callable | None = None
+    mlp: Callable | None = None
+
+
 class Block(nn.Module):
     """Pre-norm transformer block: x + attn(norm(x)); x + mlp(norm(x)).
 
-    ``mlp_factory(cfg, name=...)`` swaps the feed-forward module (e.g. the
-    expert-parallel :class:`models.moe.MoEMLP`) while keeping the block's
-    norm/residual/dropout structure — and therefore scan/remat — shared.
-    Factory-provided modules must accept a ``decode`` keyword (the static
-    mode flag rides to them so e.g. MoE can switch to its dropless
-    serving dispatch); the plain :class:`MLP` is mode-independent and is
-    called without it.
+    ``kind`` swaps the attention and the feed-forward module while keeping
+    the block's norm/residual/dropout structure — and therefore scan/remat —
+    shared.
     """
 
     cfg: TransformerConfig
-    mlp_factory: Callable | None = None
+    kind: LayerKind = LayerKind()
     # attention_fn rides as a module ATTRIBUTE (static), not a call
     # argument: under nn.remat every call argument is traced, and a
     # python callable cannot be turned into a tracer — passing e.g. the
@@ -720,28 +1003,27 @@ class Block(nn.Module):
                  decode: bool = False,
                  cache_positions: jax.Array | None = None,
                  block_tables: jax.Array | None = None) -> jax.Array:
-        cfg = self.cfg
+        cfg, kind = self.cfg, self.kind
         attention_fn = attention_fn or self.attention_fn
         h = make_norm(cfg, "attn_norm")(x)
-        h = Attention(cfg, name="attn")(h, mask=mask, positions=positions,
-                                        segment_ids=segment_ids,
-                                        attention_fn=attention_fn,
-                                        decode=decode,
-                                        cache_positions=cache_positions,
-                                        block_tables=block_tables)
+        attn = (kind.attention or Attention)(cfg, name="attn")
+        h = attn(h, mask=mask, positions=positions,
+                 segment_ids=segment_ids, attention_fn=attention_fn,
+                 decode=decode, cache_positions=cache_positions,
+                 block_tables=block_tables)
         if cfg.dropout_rate:
             h = nn.Dropout(cfg.dropout_rate, deterministic=deterministic)(h)
         x = x + h
         h = make_norm(cfg, "mlp_norm")(x)
-        if self.mlp_factory is not None:
+        if kind.mlp is not None:
             if cfg.tp_axis is not None:
                 # Factory MLPs (MoE) don't know about the serving-TP psum
                 # contract — running one under tp_axis would return partial
                 # sums as if complete.
                 raise NotImplementedError(
                     "tp_axis (serving tensor parallelism) supports only the "
-                    "dense MLP; got a custom mlp_factory")
-            h = self.mlp_factory(cfg, name="mlp")(h, decode=decode)
+                    "dense MLP; got a layer kind with its own mlp")
+            h = kind.mlp(cfg, name="mlp")(h, decode=decode)
         else:
             h = MLP(cfg, name="mlp")(h)
         if cfg.dropout_rate:
@@ -757,10 +1039,16 @@ class Transformer(nn.Module):
     compile time in depth; the layout pipeline parallelism slices); ``remat``
     checkpoints each block for long-context memory. Both are config flags so
     tests can exercise either path.
+
+    ``pattern`` says what each layer is made of (one :class:`LayerKind` a
+    layer; None = every layer the default attention and dense MLP). A
+    uniform pattern keeps the scanned path and its parameter tree; layers
+    that differ (a leading dense layer before expert layers) have no one
+    block body to scan and need ``scan_layers=False``.
     """
 
     cfg: TransformerConfig
-    mlp_factory: Callable | None = None
+    pattern: tuple[LayerKind, ...] | None = None
 
     @nn.compact
     def __call__(self, tokens_or_embeds: jax.Array, *,
@@ -802,6 +1090,13 @@ class Transformer(nn.Module):
                              name="pos_embed")(pos)
         x = nn.with_logical_constraint(x, ("batch", "seq", "act_embed"))
 
+        pattern = self.pattern or (LayerKind(),) * cfg.n_layers
+        if len(pattern) != cfg.n_layers:
+            raise ValueError(f"pattern names {len(pattern)} layers, the "
+                             f"config has {cfg.n_layers}")
+        if cfg.scan_layers and any(k != pattern[0] for k in pattern):
+            raise ValueError("layers of different kinds cannot be scanned: "
+                             "set scan_layers=False")
         block_cls = Block
         if cfg.remat and not decode:
             # remat trades FLOPs for backward-pass memory; decode has no
@@ -828,12 +1123,12 @@ class Transformer(nn.Module):
                 split_rngs={"params": True, "dropout": True},
                 length=cfg.n_layers,
                 metadata_params={nn.PARTITION_NAME: "layers"},
-            )(block_cls(cfg, mlp_factory=self.mlp_factory,
+            )(block_cls(cfg, kind=pattern[0],
                         attention_fn=attention_fn, name="blocks"),
               x, None)
         else:
             for i in range(cfg.n_layers):
-                x = block_cls(cfg, mlp_factory=self.mlp_factory,
+                x = block_cls(cfg, kind=pattern[i],
                               attention_fn=attention_fn,
                               name=f"block_{i}")(
                     x, mask=mask, positions=positions,

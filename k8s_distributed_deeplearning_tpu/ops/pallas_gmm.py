@@ -193,6 +193,8 @@ def _gmm_call(lhs, rhs, layout: GroupedLayout, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((m_pad, n), lhs.dtype),
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
+        # the name the device trace carries for the kernel's events
+        name="moe_gmm",
     )(layout.block_expert, layout.block_live, layout.block_first, lhs, rhs)
 
 

@@ -88,7 +88,7 @@ from jax.sharding import PartitionSpec as P
 
 from k8s_distributed_deeplearning_tpu import faults as _faults
 from k8s_distributed_deeplearning_tpu.models import generate, transformer
-from k8s_distributed_deeplearning_tpu.ops import pallas_paged_attn
+from k8s_distributed_deeplearning_tpu.ops import pallas_latent_attn, pallas_paged_attn
 from k8s_distributed_deeplearning_tpu.parallel import mesh as mesh_lib
 from k8s_distributed_deeplearning_tpu.parallel import sharding as sharding_lib
 from k8s_distributed_deeplearning_tpu.serve import quant as quant_lib
@@ -155,14 +155,25 @@ def _maybe_dequant_params(params: PyTree) -> PyTree:
     return params
 
 
+def _with_counts(tokens: jax.Array, moe: jax.Array | None) -> jax.Array:
+    """What a program hands back for the host to fetch: its sampled tokens
+    and — for a model with expert layers — their ``generate.moe_assignments``
+    behind them in ONE int32 array, so that the counts ride the fetch of the
+    tokens (``ServeEngine._take_counts`` splits them). A model without expert
+    layers hands back its tokens as they are."""
+    if moe is None:
+        return tokens
+    return jnp.concatenate([tokens.reshape(-1), moe.reshape(-1)])
+
+
 def _decode_core(model, params: PyTree, cache: PyTree, tokens: jax.Array,
                  kv_lens: jax.Array, tables: jax.Array, temps: jax.Array,
                  top_ks: jax.Array, top_ps: jax.Array, keys: jax.Array):
     params = _maybe_dequant_params(params)
-    logits, cache = generate.slot_decode_step(model, params, cache, tokens,
-                                              kv_lens, block_tables=tables)
+    logits, cache, moe = generate.slot_decode_step(
+        model, params, cache, tokens, kv_lens, block_tables=tables)
     keys, nxt = _sample_slots(logits, temps, top_ks, top_ps, keys)
-    return nxt, keys, cache
+    return _with_counts(nxt, moe), keys, cache
 
 
 @functools.partial(jax.jit, static_argnames=("model",),
@@ -176,7 +187,8 @@ def _decode_program(model, params: PyTree, cache: PyTree, tokens: jax.Array,
     Compiles once per (model, num_slots, max_blocks). The pool cache AND
     the key register are donated: the step updates both in place — no
     per-iteration arena copy (tests/test_tp_serve.py asserts the aliasing
-    by buffer identity)."""
+    by buffer identity). Behind the tokens: the expert layers' counts, where
+    the model has any (:func:`_with_counts`)."""
     return _decode_core(model, params, cache, tokens, kv_lens, tables,
                         temps, top_ks, top_ps, keys)
 
@@ -188,9 +200,9 @@ def _spec_draft_core(model, params: PyTree, cache: PyTree,
 
     def body(carry, _):
         cache, tok, pos = carry
-        logits, cache = generate.slot_decode_step(model, params, cache,
-                                                  tok, pos,
-                                                  block_tables=tables)
+        logits, cache, _ = generate.slot_decode_step(model, params, cache,
+                                                     tok, pos,
+                                                     block_tables=tables)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return (cache, nxt, pos + 1), tok
 
@@ -275,9 +287,9 @@ def _chunk_core(model, params: PyTree, cache: PyTree, chunk: jax.Array,
                 table: jax.Array, start: jax.Array):
     params = _maybe_dequant_params(params)
     pos = (start + jnp.arange(chunk.shape[1], dtype=jnp.int32))[None, :]
-    _, cache = generate.prefill_chunk(model, params, cache, chunk,
-                                      positions=pos, block_tables=table)
-    return cache
+    _, cache, moe = generate.prefill_chunk(model, params, cache, chunk,
+                                           positions=pos, block_tables=table)
+    return cache, moe
 
 
 @functools.partial(jax.jit, static_argnames=("model",),
@@ -288,7 +300,7 @@ def _chunk_program(model, params: PyTree, cache: PyTree, chunk: jax.Array,
     tokens — never padded) through block table ``table`` ([1, max_blocks])
     at absolute positions ``start + [0, C)``. Logits are discarded, so XLA
     dead-code-eliminates the lm_head matmul for every chunk but the final
-    one. One compile per C."""
+    one. One compile per C. Returns ``(cache, moe counts or None)``."""
     return _chunk_core(model, params, cache, chunk, table, start)
 
 
@@ -299,12 +311,12 @@ def _final_chunk_core(model, params: PyTree, cache: PyTree,
                       top_p: jax.Array, key: jax.Array):
     params = _maybe_dequant_params(params)
     pos = (start + jnp.arange(chunk.shape[1], dtype=jnp.int32))[None, :]
-    logits, cache = generate.prefill_chunk(model, params, cache, chunk,
-                                           positions=pos, block_tables=table)
+    logits, cache, moe = generate.prefill_chunk(
+        model, params, cache, chunk, positions=pos, block_tables=table)
     last = jax.lax.dynamic_slice_in_dim(logits, length - 1, 1, axis=1)[:, 0, :]
     new_key, tok = _sample_slots(last, temp[None], top_k[None], top_p[None],
                                  key[None])
-    return tok[0], new_key[0], cache
+    return _with_counts(tok[0], moe), new_key[0], cache
 
 
 @functools.partial(jax.jit, static_argnames=("model",),
@@ -433,7 +445,8 @@ class _TpPrograms:
 
         def chunk(params, cache, chunk_toks, table, start):
             return smap(functools.partial(_chunk_core, local_model), 3,
-                        cache_specs)(params, cache, chunk_toks, table, start)
+                        (cache_specs, rep))(
+                params, cache, chunk_toks, table, start)
 
         self.chunk = jax.jit(chunk, donate_argnums=(1,))
 
@@ -841,6 +854,9 @@ class ServeEngine:
         self._keys = np.zeros((num_slots, 2), np.uint32)
         self._slots: list[_InFlight | None] = [None] * num_slots
         self._pending: dict[int, _PendingPrefill] = {}
+        # (fields, device counts) of the intermediate chunks dispatched since
+        # the last fence (_take_counts)
+        self._chunk_counts: list[tuple] = []
         # Serving tensor parallelism (graftmesh): a 1-D ("tp",) mesh over
         # the first tp devices. The params are placed column/row-sharded
         # once here, the pool cache below is built sharded-at-birth along
@@ -851,12 +867,6 @@ class ServeEngine:
         self._mesh = None
         self._tp_programs: _TpPrograms | None = None
         self._tp_draft_programs: _TpPrograms | None = None
-        if self.tp:
-            self._mesh = mesh_lib.make_mesh(
-                {sharding_lib.SERVE_TP_AXIS: self.tp},
-                devices=jax.devices()[:self.tp])
-            self.params = jax.device_put(
-                self.params, self._named_shardings(_tp_param_specs(model)))
         # Single-row cache SHAPES (eval_shape: no FLOPs) — the leaf
         # structure the pool is derived from, and the byte source for
         # _block_nbytes.
@@ -865,6 +875,27 @@ class ServeEngine:
             lambda p, t: generate.prefill(self.model,
                                           _maybe_dequant_params(p), t),
             self.params, dummy)
+        other = sorted({_leaf_name(path) for path, leaf in
+                        self._pool_rows(self._row_shapes)}
+                       - {"cached_key", "cached_value"})
+        if other:
+            # A pool of other leaves than K and V per head (a latent row)
+            # has no head axis to shard over tp, no per-head int8 scales,
+            # and no draft model of its family to pair pages with.
+            for what, on in (("kv_quant='int8'", kv_quant is not None),
+                             (f"tp={self.tp}", self.tp > 0),
+                             ("a speculative draft (spec_k)", spec_k > 0)):
+                if on:
+                    raise ValueError(
+                        f"a page pool of {other} leaves cannot take {what} "
+                        "yet: those paths assume cached_key/cached_value "
+                        "pages of kv_heads x head_dim lanes")
+        if self.tp:
+            self._mesh = mesh_lib.make_mesh(
+                {sharding_lib.SERVE_TP_AXIS: self.tp},
+                devices=jax.devices()[:self.tp])
+            self.params = jax.device_put(
+                self.params, self._named_shardings(_tp_param_specs(model)))
         self._cache = self._init_pool_cache(
             self._row_shapes, head_dim=cfg.resolved_head_dim)
         # Speculative decoding: the draft cache is a SECOND paged KV
@@ -890,7 +921,8 @@ class ServeEngine:
                                               _maybe_dequant_params(p), t),
                 self.draft_params, dummy)
             self._draft_cache = self._init_pool_cache(
-                draft_shapes, head_dim=dcfg.resolved_head_dim)
+                draft_shapes, head_dim=dcfg.resolved_head_dim,
+                max_seq_len=dcfg.max_seq_len)
         if self.tp:
             self._tp_programs = _tp_programs_for(
                 _local_tp_model(model, self.tp), self._mesh,
@@ -929,13 +961,27 @@ class ServeEngine:
         return jax.tree.map(lambda s: NamedSharding(self._mesh, s), specs,
                             is_leaf=lambda s: isinstance(s, P))
 
-    def _init_pool_cache(self, row_shapes: PyTree, *,
-                         head_dim: int) -> PyTree:
+    def _pool_rows(self, row_shapes: PyTree,
+                   max_seq_len: int | None = None) -> list[tuple]:
+        """(path, leaf) of the row-cache leaves a pool is made of: those
+        laid out ``[..., 1, max_seq_len, F]`` — one F-lane row a position
+        (``cached_key``/``cached_value`` of kv·head_dim lanes, a latent
+        model's ``cached_latent``). Cursors and segment ids are not rows.
+        *max_seq_len*: the row length of the model the shapes are of (a
+        draft's may be longer than the engine's)."""
+        n = self.max_seq_len if max_seq_len is None else max_seq_len
+        return [(path, leaf) for path, leaf in
+                jax.tree_util.tree_flatten_with_path(row_shapes)[0]
+                if len(leaf.shape) >= 3 and leaf.shape[-3] == 1
+                and leaf.shape[-2] == n]
+
+    def _init_pool_cache(self, row_shapes: PyTree, *, head_dim: int,
+                         max_seq_len: int | None = None) -> PyTree:
         """Zero-filled page pool with the cache-leaf structure a prefill
         produces (``row_shapes``: the target model's single-row
         eval_shape, or the draft model's for its sibling arena), keeping
-        ONLY cached_key/cached_value (the paged decode branch declares
-        nothing else) and reshaping each leaf's [..., 1, max_seq, F] row
+        ONLY the row leaves (:meth:`_pool_rows` — what the model's paged
+        branch declares) and reshaping each leaf's [..., 1, max_seq, F] row
         layout to [..., num_pages, page_tokens, F]. KV content is
         irrelevant — nothing is attended until a table maps a written
         page. Under tp the pool is built SHARDED-AT-BIRTH along each
@@ -952,6 +998,8 @@ class ServeEngine:
         validated tp-divisible) all compose unchanged."""
         bt, pages = self.page_tokens, self.pool.num_pages
         quant = self.kv_quant == "int8"
+        rows = {id(leaf) for _, leaf in
+                self._pool_rows(row_shapes, max_seq_len)}
 
         def build(tree):
             out = {}
@@ -960,7 +1008,7 @@ class ServeEngine:
                     sub = build(v)
                     if sub:
                         out[name] = sub
-                elif name in ("cached_key", "cached_value"):
+                elif id(v) in rows:
                     # [1, S, F] -> [P, bt, F]; scanned [L, 1, S, F] ->
                     # [L, P, bt, F] (batch dim 1 at -3 dropped).
                     shape = v.shape[:-3] + (pages, bt) + v.shape[-1:]
@@ -1001,8 +1049,8 @@ class ServeEngine:
 
     def _block_nbytes(self, block_tokens: int, *,
                       kv_quant: str | None = "unset") -> int:
-        """Bytes of KV one pool page holds (seq dim of every cached_key/
-        cached_value leaf cut to block_tokens) — the trie's exact per-node
+        """Bytes of KV one pool page holds (seq dim of every row leaf,
+        :meth:`_pool_rows`, cut to block_tokens) — the trie's exact per-node
         cost, known without touching device arrays. Under int8 KV a
         position costs 1 byte per lane plus a 4-byte f32 scale per KV
         head instead of ``itemsize`` per lane (``kv_quant`` overrides the
@@ -1010,16 +1058,13 @@ class ServeEngine:
         mode = self.kv_quant if kv_quant == "unset" else kv_quant
         hd = self.model.cfg.resolved_head_dim
         total = 0
-        for path, s in jax.tree_util.tree_flatten_with_path(
-                self._row_shapes)[0]:
-            if _leaf_name(path) in ("cached_key", "cached_value"):
-                lanes = s.shape[-1]
-                lead = int(np.prod(s.shape)) // (s.shape[-2] * lanes)
-                if mode == "int8":
-                    total += lead * block_tokens * (
-                        lanes + (lanes // hd) * 4)
-                else:
-                    total += lead * lanes * block_tokens * s.dtype.itemsize
+        for _, s in self._pool_rows(self._row_shapes):
+            lanes = s.shape[-1]
+            lead = int(np.prod(s.shape)) // (s.shape[-2] * lanes)
+            if mode == "int8":
+                total += lead * block_tokens * (lanes + (lanes // hd) * 4)
+            else:
+                total += lead * lanes * block_tokens * s.dtype.itemsize
         return total
 
     def _need_pages(self, req: Request) -> int:
@@ -1550,12 +1595,16 @@ class ServeEngine:
     def _decode(self, active: int, outputs: list[RequestOutput]) -> None:
         """Advance every occupied slot one token: one dispatch, one fence,
         then the host-side bookkeeping per slot."""
-        with self.tracer.span("decode", active=active):
+        # rows: the live rows; context_tokens: the positions they attend in
+        # all (a row at cursor n attends n + 1; free slots' cursors are 0).
+        with self.tracer.span(
+                "decode", active=active, rows=active,
+                context_tokens=int(self._kv_lens.sum()) + active) as span:
             nxt, keys, self._cache = self._decode_step()
             with self.tracer.span("device_wait", kind="decode"):
                 # graftlint: disable=host-sync — the iteration's one honest
                 # sync: every slot's sampled token in a single device fence.
-                nxt = np.asarray(nxt)
+                nxt = self._take_counts(span, np.asarray(nxt), self.num_slots)
                 # np.array (copy), not np.asarray: the zero-copy view of a
                 # jax CPU buffer is read-only, and admissions write per-slot
                 # keys in place.
@@ -1836,6 +1885,40 @@ class ServeEngine:
         if req.on_finish is not None:
             req.on_finish(reason)
 
+    def _take_counts(self, span, fetched: np.ndarray, n: int) -> np.ndarray:
+        """Inside a ``device_wait``, on what it has just fetched: a program's
+        *n* tokens and, behind them, its expert layers' counts
+        (:func:`_with_counts`; nothing behind them for a model without).
+        Returns the tokens. The counts become the three numbers the OPEN
+        *span* (the ``decode`` step, the final ``prefill`` chunk) and the
+        counters carry: ``moe_assignments`` (picks that landed on held
+        experts, over every row the call computed), ``moe_experts_touched``
+        (held experts with at least one row, summed over layers) and
+        ``moe_max_rows`` (the fullest expert). No device read of their own.
+
+        The intermediate chunks dispatched since the last fence had none:
+        their counts are done by now and are fetched here (one small copy a
+        chunk), each into a ``prefill_counts`` record of its own with the
+        chunk's ``tokens``, ``start`` and ``request_id`` — nothing is
+        written into a span that has closed."""
+        if fetched.size > n:
+            # (a disabled tracer's span keeps no fields: the counters alone)
+            self._record_counts(getattr(span, "fields", {}), fetched[n:])
+        for fields, counts in self._chunk_counts:
+            # graftlint: disable=host-sync — finished before the fence
+            self._record_counts(fields, np.asarray(counts))
+            with self.tracer.span("prefill_counts", **fields):
+                pass
+        self._chunk_counts.clear()
+        return fetched[:n]
+
+    def _record_counts(self, fields: dict, counts: np.ndarray) -> None:
+        a, touched, mx = (int(counts.sum()), int(np.count_nonzero(counts)),
+                          int(counts.max()))
+        self.stats.record_moe(a, touched, mx)
+        fields.update(moe_assignments=a, moe_experts_touched=touched,
+                      moe_max_rows=mx)
+
     def _record_pool_gauges(self, counters: dict | None = None) -> None:
         c = counters if counters is not None else self.pool.counters()
         self.stats.record_kv_pool(c["pages_total"], c["pages_used"],
@@ -2006,7 +2089,8 @@ class ServeEngine:
 
     def attention_impls(self) -> dict[str, str]:
         """Which paged-attention implementation each serving program
-        resolves to (``"paged_flash"`` or ``"xla"``), keyed by program and
+        resolves to (``"paged_flash"``, ``"latent_flash"`` for a latent pool,
+        or ``"xla"``), keyed by program and
         query width: decode, the speculative verify window, the
         intermediate prefill chunk and every final-chunk bucket this
         engine can compile. Asks the model's own rule
@@ -2031,8 +2115,24 @@ class ServeEngine:
         quant = self.kv_quant == "int8"
         q_itemsize = jnp.dtype(cfg.dtype).itemsize
         shard = max(self.tp, 1)                  # heads are split over tp
+        latent = any(_leaf_name(path) == "cached_latent"
+                     for path, _ in self._pool_rows(self._row_shapes))
 
         def report(sq: int, rows: int) -> str:
+            if latent:
+                # a latent pool: the absorbed kernel at decode widths, the
+                # expanded one for a chunk, else XLA
+                impl = transformer.latent_attention_impl(cfg)
+                if impl != "latent_flash":
+                    return impl
+                if sq > pallas_latent_attn.MAX_QUERY_TOKENS:
+                    return (f"{impl} expanded block_k="
+                            f"{pallas_latent_attn.CHUNK_BLOCK_K} heads_per_cell="
+                            f"{pallas_latent_attn.CHUNK_HEADS}")
+                pages = pallas_latent_attn.default_pages_per_cell(
+                    self.page_tokens, self.max_blocks)
+                return (f"{impl} pages_per_cell={pages} "
+                        f"cells={rows * -(-self.max_blocks // pages)}")
             impl = transformer.paged_attention_impl(cfg, sq)
             if impl != "paged_flash":
                 return impl
@@ -2138,13 +2238,19 @@ class ServeEngine:
                         break       # out of budget; resume next iteration
                     chunk = pend.prompt[None, pend.pos:pend.pos + c]
                     with self.tracer.span("prefill", chunk=c, slot=slot,
-                                          request_id=pend.req.request_id):
-                        self._cache = self._chunk_step(
+                                          request_id=pend.req.request_id,
+                                          tokens=c, start=pend.pos) as span:
+                        self._cache, moe = self._chunk_step(
                             np.ascontiguousarray(chunk),
                             np.ascontiguousarray(table),
                             np.int32(pend.pos))
+                        if moe is not None:
+                            # no fence here: read at the step's next one
+                            self._chunk_counts.append((
+                                dict(chunk=c, tokens=c, start=pend.pos,
+                                     request_id=pend.req.request_id), moe))
                         if self.spec_k:
-                            self._draft_cache = self._chunk_step(
+                            self._draft_cache, _ = self._chunk_step(
                                 np.ascontiguousarray(chunk),
                                 np.ascontiguousarray(table),
                                 np.int32(pend.pos), draft=True)
@@ -2192,7 +2298,8 @@ class ServeEngine:
         table = self._tables[slot:slot + 1]
         with self.tracer.span("prefill", bucket=bucket, slot=slot,
                               cached=pend.hit_tokens,
-                              request_id=req.request_id):
+                              request_id=req.request_id,
+                              tokens=rem, start=pend.pos) as span:
             tok, key, self._cache = self._final_chunk_step(
                 chunk, np.ascontiguousarray(table), np.int32(pend.pos),
                 np.int32(rem), np.float32(sp.temperature),
@@ -2203,7 +2310,7 @@ class ServeEngine:
                 # DCE'd): same padded chunk, same table, same positions
                 # — pad writes land beyond the cursor or in scratch,
                 # exactly as on the target path.
-                self._draft_cache = self._chunk_step(
+                self._draft_cache, _ = self._chunk_step(
                     chunk, np.ascontiguousarray(table), np.int32(pend.pos),
                     draft=True)
             if self.prefix_cache is not None:
@@ -2226,7 +2333,9 @@ class ServeEngine:
                 self.prefix_cache.release(pend.nodes)
                 pend.nodes = []
             with self.tracer.span("device_wait", kind="first_token"):
-                first = int(tok)     # the admission's one fence
+                # graftlint: disable=host-sync — the admission's one fence
+                first = int(self._take_counts(
+                    span, np.asarray(tok).reshape(-1), 1)[0])
         del self._pending[slot]
         now = time.perf_counter()
         fl = _InFlight(req, first, now)

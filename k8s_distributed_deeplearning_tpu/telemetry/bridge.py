@@ -208,6 +208,17 @@ def serving_collector(registry: MetricsRegistry,
         "serve_spec_acceptance_rate": registry.gauge(
             "serve_spec_acceptance_rate",
             "fraction of proposed draft tokens accepted and emitted"),
+        "serve_moe_assignments_total": registry.gauge(
+            "serve_moe_assignments_total",
+            "expert-layer picks that landed on experts held here, over "
+            "every row the serving programs computed"),
+        "serve_moe_experts_touched_total": registry.gauge(
+            "serve_moe_experts_touched_total",
+            "held experts with at least one row, summed over layers and "
+            "calls — times an expert's bytes, what the expert products read"),
+        "serve_moe_max_rows": registry.gauge(
+            "serve_moe_max_rows",
+            "rows of the fullest held expert any one call has seen"),
         "serve_kv_quant_bytes_saved": registry.gauge(
             "serve_kv_quant_bytes_saved",
             "HBM bytes the int8 KV pool saves vs its fp equivalent "
@@ -276,6 +287,9 @@ def serving_collector(registry: MetricsRegistry,
                "transport_retries": "serve_transport_retries_total",
                "transport_dedup_hits": "serve_transport_dedup_hits_total",
                "transport_reconnects": "serve_transport_reconnects_total",
+               "moe_assignments": "serve_moe_assignments_total",
+               "moe_experts_touched": "serve_moe_experts_touched_total",
+               "moe_max_rows": "serve_moe_max_rows",
                "kv_quant_bytes_saved": "serve_kv_quant_bytes_saved",
                "weight_quant_bytes_saved": "serve_weight_quant_bytes_saved"}
 

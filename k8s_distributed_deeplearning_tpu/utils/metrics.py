@@ -191,6 +191,12 @@ class ServingStats:
         self.weight_quant: str | None = None
         self.kv_quant_bytes_saved = 0
         self.weight_quant_bytes_saved = 0
+        # Expert layers (models/moe.py), per compiled call summed: picks
+        # that landed on held experts, held experts with at least one row
+        # (summed over layers), and the fullest expert any call has seen.
+        self.moe_assignments = 0
+        self.moe_experts_touched = 0
+        self.moe_max_rows = 0
 
     def _tick(self) -> None:
         now = time.perf_counter()
@@ -377,6 +383,13 @@ class ServingStats:
         self.weight_quant_bytes_saved = int(weight_bytes_saved)
 
     @_locked
+    def record_moe(self, assignments: int, experts_touched: int,
+                   max_rows: int) -> None:
+        self.moe_assignments += int(assignments)
+        self.moe_experts_touched += int(experts_touched)
+        self.moe_max_rows = max(self.moe_max_rows, int(max_rows))
+
+    @_locked
     def record_completion(self, latency_s: float, n_tokens: int,
                           reason: str) -> None:
         self._tick()
@@ -446,6 +459,9 @@ class ServingStats:
             "weight_quant": self.weight_quant,
             "kv_quant_bytes_saved": self.kv_quant_bytes_saved,
             "weight_quant_bytes_saved": self.weight_quant_bytes_saved,
+            "moe_assignments": self.moe_assignments,
+            "moe_experts_touched": self.moe_experts_touched,
+            "moe_max_rows": self.moe_max_rows,
             "spec_steps": self.spec_steps,
             "spec_proposed_tokens": self.spec_proposed_tokens,
             "spec_accepted_tokens": self.spec_accepted_tokens,

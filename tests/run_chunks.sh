@@ -31,7 +31,7 @@ CHUNKS[parallel1]="tests/test_collectives.py tests/test_data_parallel.py tests/t
 CHUNKS[parallel2]="tests/test_context_parallel.py tests/test_pipeline.py tests/test_pipeline_lm.py"
 # MoE grew its own chunk in round 5 (ragged grouped-GEMM dispatch tests):
 # bundled with parallel2 the pair overran the chunk timeout.
-CHUNKS[moe]="tests/test_moe.py"
+CHUNKS[moe]="tests/test_moe.py tests/test_latent_moe.py"
 CHUNKS[train]="tests/test_mnist_convergence.py tests/test_grad_accum.py tests/test_chunked_ce.py tests/test_checkpoint.py tests/test_data.py tests/test_prefetch.py tests/test_metrics.py tests/test_profiling.py tests/test_fusion.py"
 CHUNKS[llama]="tests/test_train_llama.py tests/test_generate.py"
 CHUNKS[deploy]="tests/test_watch.py tests/test_render.py tests/test_deploy_smoke.py tests/test_elastic.py tests/test_preemption.py tests/test_cluster_e2e.py"

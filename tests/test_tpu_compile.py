@@ -4,8 +4,8 @@ The Pallas interpreter (every other kernel test) cannot see what Mosaic
 refuses: a copy too narrow for its tiling, a slice off the tile grid, more
 VMEM than a kernel may hold. The TPU compiler is installed here and compiles
 for a v5e that is described, not attached — about two seconds a kernel — so
-these cases hold the paged kernel to the benchmark cell's call shapes at the
-page count its own rule picks. All of them live in this one file: the worker
+these cases hold the paged and the latent kernel to the benchmark cells' call
+shapes at the page count each one's own rule picks. All of them live in this one file: the worker
 that runs it is the one process that loads the TPU's library.
 """
 import os
@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from k8s_distributed_deeplearning_tpu.ops import pallas_paged_attn
+from k8s_distributed_deeplearning_tpu.ops import pallas_latent_attn, pallas_paged_attn
 
 
 @pytest.fixture(scope="module")
@@ -73,3 +73,46 @@ def test_paged_kernel_compiles_for_v5e(one_chip, heads, sq, b, quant, pages):
         sds((b, N_BLOCKS), jnp.int32), sds((b, sq), jnp.int32),
         scale, scale).compile()
     assert "paged_attn" in compiled.as_text()
+
+
+# sarvam-105b's latent pool as the docs-backlog cell serves it: 64 heads on one
+# 640-lane row a token (512 latent + 64 rope + 64 pad), 64-token pages, a
+# 272-block table over a 7,000-page pool (+ scratch).
+@pytest.mark.parametrize("sq,pages", [(1, None), (4, None), (1, 3)],
+                         ids=["decode", "verify4", "decode-3pages"])
+def test_latent_kernel_compiles_for_v5e(one_chip, sq, pages):
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def call(q, pool, tables, pos):
+        return pallas_latent_attn.latent_decode_attention(
+            q, pool, tables, pos, rank=512, softmax_scale=0.135,
+            pages_per_cell=pages, interpret=False)
+    compiled = jax.jit(call).lower(
+        sds((32, sq, 64, 640), jnp.bfloat16), sds((7001, 64, 640), jnp.bfloat16),
+        sds((32, 272), jnp.int32), sds((32, sq), jnp.int32)).compile()
+    assert "latent_attn" in compiled.as_text()
+
+
+def test_latent_chunk_kernel_compiles_for_v5e(one_chip):
+    """The expanded form of a 1,024-token chunk over the cell's 17,408
+    gathered cache positions: 4 heads a cell, blocks of 512."""
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    call = lambda qn, qr, lat, wuk, wuv, pos: pallas_latent_attn.latent_chunk_attention(
+        qn, qr, lat, wuk, wuv, pos, rank=512, softmax_scale=0.135, interpret=False)
+    compiled = jax.jit(call).lower(
+        sds((1, 1024, 64, 128), jnp.bfloat16), sds((1, 1024, 64, 64), jnp.bfloat16),
+        sds((1, 17408, 640), jnp.bfloat16), sds((512, 64, 128), jnp.bfloat16),
+        sds((512, 64, 128), jnp.bfloat16), sds((1, 1024), jnp.int32)).compile()
+    assert "latent_chunk_attn" in compiled.as_text()
+
+
+def test_a_576_lane_latent_pool_is_refused_by_mosaic(one_chip):
+    """Why a cached token is padded to 640 lanes: the chip lays a 576-lane
+    array out 640 wide anyway, and Mosaic copies whole 128-lane tiles."""
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    call = lambda q, pool, tables, pos: pallas_latent_attn.latent_decode_attention(
+        q, pool, tables, pos, rank=512, softmax_scale=0.135, interpret=False)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        jax.jit(call).lower(
+            sds((32, 1, 64, 576), jnp.bfloat16), sds((7001, 64, 576), jnp.bfloat16),
+            sds((32, 272), jnp.int32), sds((32, 1), jnp.int32)).compile()
