@@ -159,7 +159,7 @@ def _with_counts(tokens: jax.Array, moe: jax.Array | None) -> jax.Array:
     """What a program hands back for the host to fetch: its sampled tokens
     and — for a model with expert layers — their ``generate.moe_assignments``
     behind them in ONE int32 array, so that the counts ride the fetch of the
-    tokens (``ServeEngine._take_counts`` splits them). A model without expert
+    tokens (``ServeEngine._record_counts`` takes them). A model without expert
     layers hands back its tokens as they are."""
     if moe is None:
         return tokens
@@ -561,10 +561,13 @@ class _PendingPrefill:
     prefill cursor — prompt tokens [0, pos) are already in the slot's
     pages (mapped prefix + completed chunks); ``nodes`` pins the trie
     segments backing the mapped region until admission completes;
-    ``grow`` is the slot's reserved decode-growth page count."""
+    ``grow`` is the slot's reserved decode-growth page count; ``first`` is
+    None until the final chunk has been dispatched, then what it owes the
+    host — ``(token, key, dispatch number, the call's fields)``, the first
+    two still on the device (:meth:`ServeEngine._activate` reads them)."""
 
     __slots__ = ("req", "prompt", "n", "pos", "hit_tokens", "nodes",
-                 "t_pop", "chunks", "grow", "table")
+                 "t_pop", "chunks", "grow", "table", "first")
 
     def __init__(self, req: Request, prompt: np.ndarray, pos: int,
                  hit_tokens: int, nodes: list, t_pop: float, grow: int,
@@ -578,6 +581,7 @@ class _PendingPrefill:
         self.t_pop = t_pop
         self.chunks = 0        # compiled prefill program runs so far
         self.grow = grow
+        self.first: tuple | None = None
         self.table = table     # PRIVATE block-table row until admission:
         # the engine-wide table must keep this slot all-scratch while the
         # prefill is pending, because the decode program writes a rider
@@ -785,8 +789,9 @@ class ServeEngine:
         self.stats = stats if stats is not None else ServingStats()
         # Spans: "admission" (queue pop -> pending created, wrapping the
         # prefix lookup + page mapping), "prefill" (one compiled chunk /
-        # final chunk) and "decode" (one pool-wide decode iteration incl.
-        # the host sync).
+        # final chunk: the dispatch) and "decode" (one pool-wide decode
+        # iteration from its dispatch to the host sync, the step's
+        # admission work inside it) — the whole list: :meth:`step`.
         self.tracer = tracer if tracer is not None else _NULL_TRACER
         # End-to-end lifecycle traces (graftscope): each terminal path
         # funnels through _emit_request_trace, which emits one sampled
@@ -854,9 +859,19 @@ class ServeEngine:
         self._keys = np.zeros((num_slots, 2), np.uint32)
         self._slots: list[_InFlight | None] = [None] * num_slots
         self._pending: dict[int, _PendingPrefill] = {}
-        # (fields, device counts) of the intermediate chunks dispatched since
-        # the last fence (_take_counts)
-        self._chunk_counts: list[tuple] = []
+        # Programs dispatched so far. A result the host will wait for keeps
+        # the number its program got: the wait is COVERED when a later
+        # program was dispatched before it began (the device has work queued
+        # behind the awaited one), and everything dispatched before the
+        # awaited program is done when the wait returns.
+        self._dispatches = 0
+        # (dispatch number, fields, device counts) of the intermediate
+        # chunks whose counts have not been fetched yet (_take_chunk_counts)
+        self._chunk_counts: deque[tuple] = deque()
+        # Outputs of requests that finished when a first token was taken
+        # outside step() (cancel, export_request_kv): the next step()
+        # returns them.
+        self._late_outputs: list[RequestOutput] = []
         # Serving tensor parallelism (graftmesh): a 1-D ("tp",) mesh over
         # the first tp devices. The params are placed column/row-sharded
         # once here, the pool cache below is built sharded-at-birth along
@@ -1123,13 +1138,16 @@ class ServeEngine:
 
     def busy(self) -> bool:
         """True while any work remains: queued requests, prefills in
-        progress, or occupied decode slots. THE loop condition for
+        progress (a slot whose first token is still on the device is one of
+        them), occupied decode slots, or an output the next :meth:`step`
+        still has to hand over. THE loop condition for
         callers driving :meth:`step` (in-progress prefills hold no slot
         entry, so checking queue+slots alone would exit early). A
         prefill-only engine also counts staged exports awaiting pickup —
         they hold client requests, so draining before the coordinator
         collects them would lose work."""
         return bool(len(self.queue) or self._pending or self._exports
+                    or self._late_outputs
                     or any(s is not None for s in self._slots))
 
     def occupied_slots(self) -> int:
@@ -1194,7 +1212,12 @@ class ServeEngine:
             return out
         for slot in list(self._pending):
             if self._pending[slot].req.request_id == request_id:
-                return self._cancel_pending(slot, reason)
+                if self._pending[slot].first is None:
+                    return self._cancel_pending(slot, reason)
+                # Its first token is out: it is cancelled as the decoding
+                # request it is (or has just finished — then it is unknown
+                # here, as it would have been a step ago).
+                self._activate(slot, self._late_outputs)
         for slot, fl in enumerate(self._slots):
             if fl is not None and fl.req.request_id == request_id:
                 return self._finish(slot, reason)
@@ -1234,6 +1257,9 @@ class ServeEngine:
                 "arena's KV is not shipped, so the import side could not "
                 "verify drafts — disable spec_k or migrate by token "
                 "resubmission instead")
+        for i, pend in self._owing():
+            if pend.req.request_id == request_id:
+                self._activate(i, self._late_outputs)   # admitted: take it
         slot = next((i for i, fl in enumerate(self._slots)
                      if fl is not None
                      and fl.req.request_id == request_id), None)
@@ -1296,6 +1322,12 @@ class ServeEngine:
             pages=nb, nbytes=sum(v.nbytes for v in staged))
         self._record_pool_gauges()
         return blob
+
+    def _owing(self) -> list[tuple[int, _PendingPrefill]]:
+        """(slot, pending record) of every slot whose final chunk has been
+        dispatched and whose first token is still on the device."""
+        return [(slot, pend) for slot, pend in self._pending.items()
+                if pend.first is not None]
 
     def _free_slot(self) -> int | None:
         for slot in range(self.num_slots):
@@ -1481,12 +1513,30 @@ class ServeEngine:
         return slot
 
     def step(self) -> list[RequestOutput]:
-        """One serving iteration: admit queued requests into free slots
-        (page-budget permitting), run at most ``prefill_chunk_tokens``
-        real tokens of prefill work (unlimited when chunking is off),
-        then advance every occupied slot one token. Returns the requests
-        that finished during this iteration (possibly at admission, when
-        the first token is already EOS or ``max_new_tokens == 1``).
+        """One serving iteration: advance every occupied slot one token
+        and, under that decode, admit queued requests into free slots
+        (page-budget permitting) and run at most ``prefill_chunk_tokens``
+        real tokens of prefill work (unlimited when chunking is off).
+        Returns the requests that finished during this iteration (possibly
+        when their first token is taken: it is already EOS, or
+        ``max_new_tokens == 1``).
+
+        **The host never waits for a result with an empty device queue
+        behind it, where it can help it.** The order of a step: deadline
+        sweep → page growth → DISPATCH the decode of the occupied slots →
+        take the first tokens that earlier final chunks owe (they lie
+        before this decode in the device's queue) → admission and this
+        step's prefill chunks (dispatched behind the decode) → only now
+        block on the decode's tokens → emit → epilogue. A final chunk's
+        first token is not waited for in the step that dispatches it: the
+        slot stays a pending prefill (reserved, not decoded for) until the
+        next step has its decode in the queue, and joins the decode after
+        that. Where no decode will be dispatched behind it — no slot is
+        occupied (the first request on an idle engine), the
+        ``prefill_only`` role, :meth:`shutdown` — the token is read at
+        once. An idle engine's first request therefore takes one call more
+        than one decode per token; streams, finish reasons and sampling
+        keys are what they were.
 
         Deadline enforcement happens here, at the decode boundary: an
         occupied or mid-prefill slot whose request's ``deadline_s`` has
@@ -1498,20 +1548,28 @@ class ServeEngine:
         its own budget, and never stalls the other slots.
 
         Every phase is a span of ``self.tracer``, nested under
-        ``engine_step``: ``sweep`` (the deadline sweeps), ``admission``
-        and ``prefill`` per request, ``grow`` (decode-growth pages),
-        ``decode`` (dispatch + fence), ``device_wait`` (ONLY the blocking
-        read of the device's result, inside ``decode`` and the final-chunk
-        ``prefill``), ``emit`` (per-slot bookkeeping and ``on_token``
+        ``engine_step``: ``sweep`` (the deadline sweeps), ``grow``
+        (decode-growth pages), ``decode`` (from the decode's dispatch to
+        its tokens on the host — so the ``admission`` and ``prefill``
+        spans of the step lie inside it when a decode runs),
+        ``device_wait`` (ONLY the blocking reads of the device's results:
+        ``kind`` ``decode`` / ``spec`` / ``first_token``; ``covered`` 1
+        when a program was dispatched behind the awaited one before the
+        wait began), ``emit`` (per-slot bookkeeping and ``on_token``
         after the fence) and ``epilogue``."""
         with self.tracer.span("engine_step", step=self.stats.steps):
             return self._step()
 
     # graftlint: hot-path
     def _step(self) -> list[RequestOutput]:
-        outputs: list[RequestOutput] = []
+        outputs, self._late_outputs = self._late_outputs, []
         with self.tracer.span("sweep"):
             now = time.perf_counter()
+            # A slot that owes its first token is swept as the decoding
+            # request it is: take the token, then let the deadline end it.
+            for slot, pend in self._owing():
+                if self._expired(pend.req, now):
+                    self._activate(slot, outputs)
             for slot, fl in enumerate(self._slots):
                 if fl is not None and self._expired(fl.req, now):
                     outputs.append(self._finish(slot, "timeout"))
@@ -1525,6 +1583,69 @@ class ServeEngine:
                 outputs.append(self._timeout_unadmitted(req))
         self.last_step_prefill_tokens = 0
         self._step_prefill_budget = self.prefill_chunk_tokens
+        # The rows this step decodes for: the slots occupied NOW. A slot
+        # activated further down joins the next step's.
+        rows = ([] if self.prefill_only else
+                [slot for slot, fl in enumerate(self._slots)
+                 if fl is not None])
+        if not rows:
+            self._admissions(outputs)
+            if self.prefill_only:
+                # Disaggregated prefill role: every slot that completed
+                # admission this step is exported instead of decoded.
+                # Requests that finished AT admission (EOS first token /
+                # 1-token budget) are already terminal in ``outputs`` and
+                # never ship.
+                for slot, fl in enumerate(self._slots):
+                    if fl is not None:
+                        self._exports.append(
+                            self.export_request_kv(fl.req.request_id))
+            self._step_epilogue()
+            return outputs
+        # Decode-growth pages: a slot whose next write positions cross
+        # into unmapped blocks claims from ITS reserved pages —
+        # infallible by construction (reserved at admission), so growth
+        # can never be starved by other admissions. A speculative step
+        # writes up to spec_k positions past the cursor, but never past
+        # the request's own budget (position n + max_new - 2 is the last
+        # one any emitted token can occupy) — writes beyond that land in
+        # the scratch page and the garbage selections they feed are
+        # provably never emitted.
+        with self.tracer.span("grow"):
+            for slot in rows:
+                fl = self._slots[slot]
+                last = int(self._kv_lens[slot])
+                if self.spec_k:
+                    limit = len(fl.req.prompt) + fl.req.max_new_tokens - 2
+                    last = min(last + self.spec_k, limit)
+                for blk in range(int(self._kv_lens[slot]) // self.page_tokens,
+                                 last // self.page_tokens + 1):
+                    if self._tables[slot, blk] == 0:
+                        self._tables[slot, blk] = (
+                            self.pool.alloc_reserved(1)[0])
+                        fl.grow_left -= 1
+        inj = _faults.active()
+        if inj is not None:
+            inj.fire("serve_decode")
+        flight_on = self.flight is not None and self.flight.enabled
+        t_dec = time.perf_counter() if flight_on else 0.0
+        if self.spec_k:
+            self._spec_decode(rows, outputs)
+        else:
+            self._decode(rows, outputs)
+        if flight_on:
+            self._last_decode_ms = round(
+                (time.perf_counter() - t_dec) * 1e3, 3)
+        self._step_epilogue()
+        return outputs
+
+    # graftlint: hot-path
+    def _admissions(self, outputs: list[RequestOutput]) -> None:
+        """The step's admission work — under the decode, where one runs:
+        take the first tokens earlier final chunks owe, then admit and
+        prefill within the step's token budget."""
+        for slot, _ in self._owing():
+            self._activate(slot, outputs)
         flight_on = self.flight is not None and self.flight.enabled
         t_pf = time.perf_counter() if flight_on else 0.0
         # Admission and prefill alternate until neither makes progress:
@@ -1539,82 +1660,46 @@ class ServeEngine:
         if flight_on and self.last_step_prefill_tokens:
             self._last_prefill_ms = round(
                 (time.perf_counter() - t_pf) * 1e3, 3)
-        if self.prefill_only:
-            # Disaggregated prefill role: every slot that completed
-            # admission this step is exported instead of decoded. Requests
-            # that finished AT admission (EOS first token / 1-token
-            # budget) are already terminal in ``outputs`` and never ship.
-            for slot, fl in enumerate(self._slots):
-                if fl is not None:
-                    self._exports.append(
-                        self.export_request_kv(fl.req.request_id))
-            self._step_epilogue()
-            return outputs
-        active = sum(s is not None for s in self._slots)
-        if active == 0:
-            self._step_epilogue()
-            return outputs
-        # Decode-growth pages: a slot whose next write positions cross
-        # into unmapped blocks claims from ITS reserved pages —
-        # infallible by construction (reserved at admission), so growth
-        # can never be starved by other admissions. A speculative step
-        # writes up to spec_k positions past the cursor, but never past
-        # the request's own budget (position n + max_new - 2 is the last
-        # one any emitted token can occupy) — writes beyond that land in
-        # the scratch page and the garbage selections they feed are
-        # provably never emitted.
-        with self.tracer.span("grow"):
-            for slot, fl in enumerate(self._slots):
-                if fl is None:
-                    continue
-                last = int(self._kv_lens[slot])
-                if self.spec_k:
-                    limit = len(fl.req.prompt) + fl.req.max_new_tokens - 2
-                    last = min(last + self.spec_k, limit)
-                for blk in range(int(self._kv_lens[slot]) // self.page_tokens,
-                                 last // self.page_tokens + 1):
-                    if self._tables[slot, blk] == 0:
-                        self._tables[slot, blk] = (
-                            self.pool.alloc_reserved(1)[0])
-                        fl.grow_left -= 1
-        inj = _faults.active()
-        if inj is not None:
-            inj.fire("serve_decode")
-        t_dec = time.perf_counter() if flight_on else 0.0
-        if self.spec_k:
-            self._spec_decode(active, outputs)
-        else:
-            self._decode(active, outputs)
-        if flight_on:
-            self._last_decode_ms = round(
-                (time.perf_counter() - t_dec) * 1e3, 3)
-        self._step_epilogue()
-        return outputs
+
+    def _wait(self, kind: str, seq: int):
+        """The ``device_wait`` span around the blocking read of what program
+        number *seq* hands back; counts whether the wait is covered."""
+        covered = int(self._dispatches > seq)
+        self.stats.record_fence(covered)
+        return self.tracer.span("device_wait", kind=kind, covered=covered)
 
     # graftlint: hot-path
-    def _decode(self, active: int, outputs: list[RequestOutput]) -> None:
-        """Advance every occupied slot one token: one dispatch, one fence,
-        then the host-side bookkeeping per slot."""
+    def _decode(self, rows: list[int], outputs: list[RequestOutput]) -> None:
+        """Advance the occupied slots *rows* one token: one dispatch, the
+        step's admission work behind it, one fence, then the host-side
+        bookkeeping per slot."""
+        active = len(rows)
         # rows: the live rows; context_tokens: the positions they attend in
-        # all (a row at cursor n attends n + 1; free slots' cursors are 0).
+        # all (a row at cursor n attends n + 1; other slots' cursors are 0).
         with self.tracer.span(
                 "decode", active=active, rows=active,
                 context_tokens=int(self._kv_lens.sum()) + active) as span:
             nxt, keys, self._cache = self._decode_step()
-            with self.tracer.span("device_wait", kind="decode"):
+            seq = self._dispatches
+            self._admissions(outputs)
+            with self._wait("decode", seq):
                 # graftlint: disable=host-sync — the iteration's one honest
                 # sync: every slot's sampled token in a single device fence.
-                nxt = self._take_counts(span, np.asarray(nxt), self.num_slots)
-                # np.array (copy), not np.asarray: the zero-copy view of a
-                # jax CPU buffer is read-only, and admissions write per-slot
-                # keys in place.
+                nxt = np.asarray(nxt)
+                if nxt.size > self.num_slots:
+                    # (a disabled tracer's span keeps no fields: the
+                    # counters alone)
+                    self._record_counts(getattr(span, "fields", {}),
+                                        nxt[self.num_slots:])
+                self._take_chunk_counts(seq)
+                # Only the decoded rows' keys: a slot activated under this
+                # decode has had its own written since the dispatch.
                 # graftlint: disable=host-sync — rides the same fence as nxt
-                self._keys = np.array(keys)
+                self._keys[rows] = np.asarray(keys)[rows]
         with self.tracer.span("emit"):
             self.stats.record_step(active, self.num_slots)
-            for slot, fl in enumerate(self._slots):
-                if fl is None:
-                    continue
+            for slot in rows:
+                fl = self._slots[slot]
                 tok = int(nxt[slot])
                 # The PREVIOUS token was just written at kv_lens; the
                 # freshly sampled one becomes the next step's input.
@@ -1629,12 +1714,13 @@ class ServeEngine:
                     outputs.append(self._finish(slot, "length"))
 
     # graftlint: hot-path
-    def _spec_decode(self, active: int,
+    def _spec_decode(self, rows: list[int],
                      outputs: list[RequestOutput]) -> None:
         """One speculative serving iteration: ``spec_k`` greedy draft
         proposals per slot (scanned into one dispatch over the draft
         model's sibling paged cache), ONE multi-token verify pass through
-        the target model, then host-side accept bookkeeping. Each slot
+        the target model, the step's admission work behind them, then
+        host-side accept bookkeeping. Each slot
         emits the longest prefix of drafts matching the target's own
         selections plus the target's correction/bonus token (1 to
         spec_k + 1 tokens) — bit-identical to non-speculative decoding
@@ -1644,11 +1730,15 @@ class ServeEngine:
         truncation: rejected drafts stay in pages beyond the advanced
         cursor, never attended, overwritten in place by the next window
         before anything reads them."""
-        with self.tracer.span("decode", active=active, spec_k=self.spec_k):
-            window, self._draft_cache = self._spec_draft_step()
+        with self.tracer.span("decode", active=len(rows),
+                              spec_k=self.spec_k):
+            regs = self._registers()
+            window, self._draft_cache = self._spec_draft_step(regs)
             sel, key_states, acc, self._cache = self._spec_verify_step(
-                window)
-            with self.tracer.span("device_wait", kind="spec"):
+                window, regs)
+            seq = self._dispatches
+            self._admissions(outputs)
+            with self._wait("spec", seq):
                 # graftlint: disable=host-sync — the iteration's one honest
                 # sync: every slot's window/selections in a single fence.
                 window = np.asarray(window)
@@ -1660,18 +1750,18 @@ class ServeEngine:
                 # admissions, and only the emitted-count column survives.
                 # graftlint: disable=host-sync — rides the same fence
                 key_states = np.array(key_states)
+                self._take_chunk_counts(seq)
         with self.tracer.span("emit"):
-            self._spec_emit(active, outputs, window, sel, acc, key_states)
+            self._spec_emit(rows, outputs, window, sel, acc, key_states)
 
-    def _spec_emit(self, active: int, outputs: list[RequestOutput],
+    def _spec_emit(self, rows: list[int], outputs: list[RequestOutput],
                    window, sel, acc, key_states) -> None:
         """The host-side accept bookkeeping of one speculative step."""
         emitted_total = 0
         proposed = 0
         accepted_counts: list[int] = []
-        for slot, fl in enumerate(self._slots):
-            if fl is None:
-                continue
+        for slot in rows:
+            fl = self._slots[slot]
             a = int(acc[slot])
             # Candidates in emission order: the accepted drafts, then the
             # target's correction (a < k) or bonus (a == k) token.
@@ -1706,7 +1796,7 @@ class ServeEngine:
             self._keys[slot] = key_states[slot, m - 1]
             if finished is not None:
                 outputs.append(self._finish(slot, finished))
-        self.stats.record_step(active, self.num_slots,
+        self.stats.record_step(len(rows), self.num_slots,
                                tokens=emitted_total)
         self.stats.record_spec_step(proposed, accepted_counts)
 
@@ -1748,7 +1838,9 @@ class ServeEngine:
         requests (pinned trie segments released, pages freed) and
         in-flight requests (partial tokens) all complete with
         finish_reason "aborted". The engine is reusable afterwards."""
-        outs: list[RequestOutput] = []
+        outs, self._late_outputs = self._late_outputs, []
+        for slot, _ in self._owing():
+            self._activate(slot, outs)
         now = time.perf_counter()
         for req in self.queue.drain():
             t0 = req._t_submit if req._t_submit is not None else now
@@ -1778,42 +1870,52 @@ class ServeEngine:
     # sides — everything above this seam (admission, trie, chunked
     # prefill, growth, migration, spec bookkeeping) is mode-blind.
 
+    # Every dispatch counts itself (``_dispatches``): the number a program
+    # got tells a later wait what lies behind it in the device's queue.
+
+    def _registers(self) -> tuple:
+        """COPIES of the per-slot register file (tokens, cursors, tables,
+        temperatures, top-k, top-p, keys) for a program that runs while the
+        host goes on writing the registers: jit may alias a numpy operand
+        (the CPU backend does, zero-copy, when the buffer is aligned) or
+        copy it to the device after the call returned, so what a dispatched
+        program reads must not change under it. A few tens of KB a step."""
+        return tuple(r.copy() for r in (
+            self._tokens, self._kv_lens, self._tables, self._temps,
+            self._top_ks, self._top_ps, self._keys))
+
     # graftlint: hot-path
     def _decode_step(self):
+        self._dispatches += 1
         if self.tp:
             return self._tp_programs.decode(
-                self.params, self._cache, self._tokens, self._kv_lens,
-                self._tables, self._temps, self._top_ks, self._top_ps,
-                self._keys)
+                self.params, self._cache, *self._registers())
         return _decode_program(
-            self.model, self.params, self._cache, self._tokens,
-            self._kv_lens, self._tables, self._temps, self._top_ks,
-            self._top_ps, self._keys)
+            self.model, self.params, self._cache, *self._registers())
 
     # graftlint: hot-path
-    def _spec_draft_step(self):
+    def _spec_draft_step(self, regs: tuple):
+        self._dispatches += 1
+        tokens, kv_lens, tables = regs[:3]
         if self.tp:
             return self._tp_draft_programs.spec_draft(
-                self.draft_params, self._draft_cache, self._tokens,
-                self._kv_lens, self._tables)
+                self.draft_params, self._draft_cache, tokens, kv_lens,
+                tables)
         return _spec_draft_program(
             self.draft_model, self.draft_params, self._draft_cache,
-            self._tokens, self._kv_lens, self._tables,
-            steps=self.spec_k + 1)
+            tokens, kv_lens, tables, steps=self.spec_k + 1)
 
     # graftlint: hot-path
-    def _spec_verify_step(self, window):
+    def _spec_verify_step(self, window, regs: tuple):
+        self._dispatches += 1
         if self.tp:
             return self._tp_programs.spec_verify(
-                self.params, self._cache, window, self._kv_lens,
-                self._tables, self._temps, self._top_ks, self._top_ps,
-                self._keys)
+                self.params, self._cache, window, *regs[1:])
         return _spec_verify_program(
-            self.model, self.params, self._cache, window, self._kv_lens,
-            self._tables, self._temps, self._top_ks, self._top_ps,
-            self._keys)
+            self.model, self.params, self._cache, window, *regs[1:])
 
     def _chunk_step(self, chunk, table, start, *, draft: bool = False):
+        self._dispatches += 1
         if draft:
             if self.tp:
                 return self._tp_draft_programs.chunk(
@@ -1829,6 +1931,7 @@ class ServeEngine:
 
     def _final_chunk_step(self, chunk, table, start, length, temp, top_k,
                           top_p, key):
+        self._dispatches += 1
         if self.tp:
             return self._tp_programs.final_chunk(
                 self.params, self._cache, chunk, table, start, length,
@@ -1885,34 +1988,38 @@ class ServeEngine:
         if req.on_finish is not None:
             req.on_finish(reason)
 
-    def _take_counts(self, span, fetched: np.ndarray, n: int) -> np.ndarray:
-        """Inside a ``device_wait``, on what it has just fetched: a program's
-        *n* tokens and, behind them, its expert layers' counts
-        (:func:`_with_counts`; nothing behind them for a model without).
-        Returns the tokens. The counts become the three numbers the OPEN
-        *span* (the ``decode`` step, the final ``prefill`` chunk) and the
+    def _take_chunk_counts(self, seq: int) -> None:
+        """Inside a ``device_wait`` that has just returned what program
+        number *seq* handed back: the intermediate chunks DISPATCHED BEFORE
+        that program had no fence of their own, and are done now. Their
+        counts are fetched here (one small copy a chunk). A chunk dispatched
+        BEHIND the awaited program (this step's, behind this step's decode)
+        is still running or queued: reading its counts here would wait for
+        it with nothing queued behind it. It is read at the next step's
+        fence."""
+        while self._chunk_counts and self._chunk_counts[0][0] < seq:
+            _, fields, counts = self._chunk_counts.popleft()
+            # graftlint: disable=host-sync — ran before the awaited program
+            self._chunk_counts_record(fields, np.asarray(counts))
+
+    def _chunk_counts_record(self, fields: dict, counts: np.ndarray) -> None:
+        """A chunk's counts, known only after its ``prefill`` span closed:
+        a ``prefill_counts`` record of their own beside the call's *fields*
+        (``tokens``, ``start``, ``request_id``; ``chunk`` or, for a final
+        one, ``bucket``) — nothing is written into a span that has
+        closed."""
+        self._record_counts(fields, counts)
+        with self.tracer.span("prefill_counts", **fields):
+            pass
+
+    def _record_counts(self, fields: dict, counts: np.ndarray) -> None:
+        """What a program's expert layers counted (:func:`_with_counts`: the
+        decode and final-chunk programs hand it back BEHIND their tokens, so
+        it rides their fetch), as the three numbers *fields* and the
         counters carry: ``moe_assignments`` (picks that landed on held
         experts, over every row the call computed), ``moe_experts_touched``
         (held experts with at least one row, summed over layers) and
-        ``moe_max_rows`` (the fullest expert). No device read of their own.
-
-        The intermediate chunks dispatched since the last fence had none:
-        their counts are done by now and are fetched here (one small copy a
-        chunk), each into a ``prefill_counts`` record of its own with the
-        chunk's ``tokens``, ``start`` and ``request_id`` — nothing is
-        written into a span that has closed."""
-        if fetched.size > n:
-            # (a disabled tracer's span keeps no fields: the counters alone)
-            self._record_counts(getattr(span, "fields", {}), fetched[n:])
-        for fields, counts in self._chunk_counts:
-            # graftlint: disable=host-sync — finished before the fence
-            self._record_counts(fields, np.asarray(counts))
-            with self.tracer.span("prefill_counts", **fields):
-                pass
-        self._chunk_counts.clear()
-        return fetched[:n]
-
-    def _record_counts(self, fields: dict, counts: np.ndarray) -> None:
+        ``moe_max_rows`` (the fullest expert)."""
         a, touched, mx = (int(counts.sum()), int(np.count_nonzero(counts)),
                           int(counts.max()))
         self.stats.record_moe(a, touched, mx)
@@ -2186,7 +2293,7 @@ class ServeEngine:
         node's page is ref'd and written into the row), allocate private
         pages for the uncached prompt tail, reserve worst-case decode
         growth, and park it as a pending prefill for :meth:`_run_prefills`.
-        The row is installed engine-wide only at :meth:`_finish_admission`
+        The row is installed engine-wide only at :meth:`_activate`
         — until then the slot stays all-scratch in ``self._tables`` so the
         decode program's rider write for this (stale-cursor) slot lands in
         the scratch page, not in the half-prefilled prompt.
@@ -2223,11 +2330,16 @@ class ServeEngine:
         Intermediate chunks are exact C-token slices; the final chunk
         (bucketed) completes the admission. All chunks write straight into
         the slot's pool pages through its block table — there is no
-        intermediate row cache and no splice. Returns True when a request
-        finished AT admission and freed its slot."""
+        intermediate row cache and no splice. A final chunk's first token
+        is taken here only where no decode will be dispatched behind it (no
+        occupied slot, the ``prefill_only`` role); else the slot owes it
+        until the next step's decode is in the queue. Returns True when a
+        request finished AT admission and freed its slot."""
         freed = False
         for slot in list(self._pending):
             pend = self._pending.get(slot)
+            if pend.first is not None:
+                continue            # dispatched to its end: owes its token
             c = self.prefill_chunk_tokens
             table = pend.table[None, :]
             while pend is not None:
@@ -2245,8 +2357,9 @@ class ServeEngine:
                             np.ascontiguousarray(table),
                             np.int32(pend.pos))
                         if moe is not None:
-                            # no fence here: read at the step's next one
+                            # no fence here: read at the next one behind it
                             self._chunk_counts.append((
+                                self._dispatches,
                                 dict(chunk=c, tokens=c, start=pend.pos,
                                      request_id=pend.req.request_id), moe))
                         if self.spec_k:
@@ -2260,11 +2373,11 @@ class ServeEngine:
                     continue
                 if budget is not None and rem > budget:
                     break
-                out = self._finish_admission(slot, pend)
+                self._dispatch_final_chunk(slot, pend)
                 self._charge_prefill(rem)
-                if out is not None:
-                    outputs.append(out)
-                    freed = True
+                if self.prefill_only or not any(
+                        fl is not None for fl in self._slots):
+                    freed |= self._activate(slot, outputs)
                 pend = None
         return freed
 
@@ -2274,52 +2387,49 @@ class ServeEngine:
             self._step_prefill_budget = max(
                 0, self._step_prefill_budget - int(tokens))
 
-    def _finish_admission(self, slot: int,
-                          pend: _PendingPrefill) -> RequestOutput | None:
-        """Run the final (sampling) chunk, adopt the prompt's pages into
-        the trie, and activate the slot. The chunk resumes at the prefill
-        cursor RIGHT-PADDED to the bucket — the paged scatter writes each
-        token at its absolute position, so the pad tail lands beyond the
-        cursor (never attended) or in the scratch page (beyond the
-        table), and positions before the cursor — including trie-shared
-        pages — are never touched. Returns a RequestOutput when the
-        request finished at admission (first token was EOS, or the length
-        budget is a single token) — the slot stays free in that case."""
+    def _dispatch_final_chunk(self, slot: int,
+                              pend: _PendingPrefill) -> None:
+        """Dispatch the final (sampling) chunk and adopt the prompt's pages
+        into the trie; the first token stays on the device
+        (``pend.first``) until :meth:`_activate` takes it. The chunk
+        resumes at the prefill cursor RIGHT-PADDED to the bucket — the
+        paged scatter writes each token at its absolute position, so the
+        pad tail lands beyond the cursor (never attended) or in the
+        scratch page (beyond the table), and positions before the cursor —
+        including trie-shared pages — are never touched. The slot stays a
+        pending prefill: its row is still private, so the decode that runs
+        before the activation writes this slot's rider row to scratch."""
         req, n = pend.req, pend.n
         rem = n - pend.pos
         bucket = self._bucket(rem)
         sp = req.sampling
         chunk = np.full((1, bucket), self.pad_id, np.int32)
         chunk[0, :rem] = pend.prompt[pend.pos:]
-        # Admission completes this step: install the pending row engine-
-        # wide. The slot's cursor is set to n below, BEFORE the next
-        # decode, so the rider write lands past the prompt from now on.
-        self._tables[slot, :] = pend.table
-        table = self._tables[slot:slot + 1]
-        with self.tracer.span("prefill", bucket=bucket, slot=slot,
-                              cached=pend.hit_tokens,
-                              request_id=req.request_id,
-                              tokens=rem, start=pend.pos) as span:
+        table = np.ascontiguousarray(pend.table[None, :])
+        fields = dict(bucket=bucket, tokens=rem, start=pend.pos,
+                      request_id=req.request_id)
+        with self.tracer.span("prefill", slot=slot, cached=pend.hit_tokens,
+                              **fields):
             tok, key, self._cache = self._final_chunk_step(
-                chunk, np.ascontiguousarray(table), np.int32(pend.pos),
+                chunk, table, np.int32(pend.pos),
                 np.int32(rem), np.float32(sp.temperature),
                 np.int32(sp.top_k), np.float32(sp.top_p),
                 np.asarray(jax.random.PRNGKey(req.seed), np.uint32))
+            pend.first = (tok, key, self._dispatches, fields)
             if self.spec_k:
                 # Mirror the final chunk into the draft arena (logits
                 # DCE'd): same padded chunk, same table, same positions
                 # — pad writes land beyond the cursor or in scratch,
                 # exactly as on the target path.
                 self._draft_cache, _ = self._chunk_step(
-                    chunk, np.ascontiguousarray(table), np.int32(pend.pos),
-                    draft=True)
+                    chunk, table, np.int32(pend.pos), draft=True)
             if self.prefix_cache is not None:
                 # Adopt whole prompt blocks into the trie by REFERENCE:
                 # the trie takes its own refcount on the slot's page, so
                 # the KV survives the slot and later requests map it with
                 # zero copies. Runs only for blocks the trie doesn't hold.
                 def page_for_block(i: int) -> int:
-                    page = int(self._tables[slot, i])
+                    page = int(pend.table[i])
                     self.pool.ref(page)
                     # Ledger: the trie's reference outlives the slot, so
                     # the attribution moves with the longer lifetime.
@@ -2332,11 +2442,31 @@ class ServeEngine:
                     self.stats.record_prefix_evictions(evicted)
                 self.prefix_cache.release(pend.nodes)
                 pend.nodes = []
-            with self.tracer.span("device_wait", kind="first_token"):
-                # graftlint: disable=host-sync — the admission's one fence
-                first = int(self._take_counts(
-                    span, np.asarray(tok).reshape(-1), 1)[0])
-        del self._pending[slot]
+
+    def _activate(self, slot: int, outputs: list[RequestOutput]) -> bool:
+        """Take the first token *slot*'s final chunk owes and make the slot
+        a decoding one: install its row engine-wide with the cursor at the
+        prompt's length (BEFORE the next decode is dispatched, so the rider
+        write lands past the prompt from now on), the sampling registers
+        and the chained key. The final chunk's expert counts ride the
+        token's fetch and become ONE ``prefill_counts`` record (its
+        ``prefill`` span may have closed a step ago). Returns True when the
+        request finished right here (first token was EOS, or the length
+        budget is a single token): its output is appended to *outputs* and
+        the slot is free again."""
+        pend = self._pending.pop(slot)
+        tok, key, seq, fields = pend.first
+        req, n, sp = pend.req, pend.n, pend.req.sampling
+        with self._wait("first_token", seq):
+            # graftlint: disable=host-sync — the admission's one fence
+            tok = np.asarray(tok).reshape(-1)
+            # graftlint: disable=host-sync — rides the same fence
+            key = np.asarray(key)
+            self._take_chunk_counts(seq)
+            if tok.size > 1:
+                self._chunk_counts_record(fields, tok[1:])
+        first = int(tok[0])
+        self._tables[slot, :] = pend.table
         now = time.perf_counter()
         fl = _InFlight(req, first, now)
         fl.t_admit = pend.t_pop
@@ -2349,15 +2479,17 @@ class ServeEngine:
         self._temps[slot] = sp.temperature
         self._top_ks[slot] = sp.top_k
         self._top_ps[slot] = sp.top_p
-        self._keys[slot] = np.asarray(key)
+        self._keys[slot] = key
         self.stats.record_first_token(ttft_s=now - fl.t_submit)
         if req.on_token is not None:
             req.on_token(first)
         if self.eos_id is not None and first == self.eos_id:
-            return self._finish(slot, "eos")
-        if req.max_new_tokens == 1:
-            return self._finish(slot, "length")
-        return None
+            outputs.append(self._finish(slot, "eos"))
+        elif req.max_new_tokens == 1:
+            outputs.append(self._finish(slot, "length"))
+        else:
+            return False
+        return True
 
     def _release_slot_pages(self, slot: int, grow_left: int,
                             row: np.ndarray | None = None) -> None:

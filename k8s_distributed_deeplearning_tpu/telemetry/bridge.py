@@ -219,6 +219,11 @@ def serving_collector(registry: MetricsRegistry,
         "serve_moe_max_rows": registry.gauge(
             "serve_moe_max_rows",
             "rows of the fullest held expert any one call has seen"),
+        "serve_fence_covered_share": registry.gauge(
+            "serve_fence_covered_share",
+            "share of the engine's blocking reads of device results made "
+            "with a later program already dispatched behind the awaited "
+            "one (the device had work queued while the host waited)"),
         "serve_kv_quant_bytes_saved": registry.gauge(
             "serve_kv_quant_bytes_saved",
             "HBM bytes the int8 KV pool saves vs its fp equivalent "
@@ -290,6 +295,7 @@ def serving_collector(registry: MetricsRegistry,
                "moe_assignments": "serve_moe_assignments_total",
                "moe_experts_touched": "serve_moe_experts_touched_total",
                "moe_max_rows": "serve_moe_max_rows",
+               "fence_covered_share": "serve_fence_covered_share",
                "kv_quant_bytes_saved": "serve_kv_quant_bytes_saved",
                "weight_quant_bytes_saved": "serve_weight_quant_bytes_saved"}
 

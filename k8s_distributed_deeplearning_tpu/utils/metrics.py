@@ -197,6 +197,12 @@ class ServingStats:
         self.moe_assignments = 0
         self.moe_experts_touched = 0
         self.moe_max_rows = 0
+        # The engine's blocking reads of device results (its ``device_wait``
+        # spans), and those of them made with a later program already
+        # dispatched behind the awaited one: the device had work queued
+        # while the host waited.
+        self.fences = 0
+        self.fences_covered = 0
 
     def _tick(self) -> None:
         now = time.perf_counter()
@@ -390,6 +396,11 @@ class ServingStats:
         self.moe_max_rows = max(self.moe_max_rows, int(max_rows))
 
     @_locked
+    def record_fence(self, covered: int) -> None:
+        self.fences += 1
+        self.fences_covered += int(covered)
+
+    @_locked
     def record_completion(self, latency_s: float, n_tokens: int,
                           reason: str) -> None:
         self._tick()
@@ -462,6 +473,13 @@ class ServingStats:
             "moe_assignments": self.moe_assignments,
             "moe_experts_touched": self.moe_experts_touched,
             "moe_max_rows": self.moe_max_rows,
+            "fences": self.fences,
+            "fences_covered": self.fences_covered,
+            # Share of the blocking reads that had a program queued behind
+            # the awaited one (None until the first read).
+            "fence_covered_share": (
+                round(self.fences_covered / self.fences, 4)
+                if self.fences else None),
             "spec_steps": self.spec_steps,
             "spec_proposed_tokens": self.spec_proposed_tokens,
             "spec_accepted_tokens": self.spec_accepted_tokens,

@@ -129,36 +129,66 @@ def test_engine_greedy_equals_generate_with_the_pool_compiled_once(tiny):
 
 
 def test_every_calls_expert_counts_are_in_a_record_written_after_they_are_known(tiny):
-    """A decode step's and a final chunk's counts are fields of their own open
-    span; an intermediate chunk's span has closed before its counts exist, so
-    they come in a ``prefill_counts`` record at the step's next fence — what a
-    sink wrote at each close (the ring keeps just that) carries them all."""
+    """A decode step's counts are fields of its own open span. A chunk's span
+    has closed before its counts are on the host, so they come in a
+    ``prefill_counts`` record: an intermediate chunk's at a fence that lies
+    BEHIND it in the device's queue — a later step's, never the fence of the
+    decode it was dispatched behind — and a final chunk's as ONE record (with
+    ``bucket``, ``tokens``, ``start``, ``request_id``) where its first token
+    is taken. What a sink wrote at each close (the ring keeps just that)
+    carries them all, and every chunk call's counts are what the same request
+    served alone gives (the decode calls count the free slots' rows too, and
+    an idle engine's first request waits a call more: their sum is not
+    comparable)."""
     from k8s_distributed_deeplearning_tpu.telemetry.trace import Tracer
     cfg, model, params = tiny
-    tracer = Tracer(ring_size=4096)
-    eng = ServeEngine(model, params, num_slots=4, min_bucket=16, prefill_chunk_tokens=16,
-                      prefix_block_tokens=8, kv_pool_pages=48, prefix_cache_mb=1,
-                      tracer=tracer)
+    mk = lambda tracer: ServeEngine(
+        model, params, num_slots=4, min_bucket=16, prefill_chunk_tokens=16,
+        prefix_block_tokens=8, kv_pool_pages=48, prefix_cache_mb=1, tracer=tracer)
     rng = np.random.default_rng(3)
-    eng.run([Request(prompt=rng.integers(0, 256, size=n).tolist(), max_new_tokens=4,
-                     request_id=f"q{i}") for i, n in enumerate((50, 9, 37))])
+    reqs = [Request(prompt=rng.integers(0, 256, size=n).tolist(), max_new_tokens=4,
+                    request_id=f"q{i}") for i, n in enumerate((50, 9, 37))]
+    call = lambda s: (s["request_id"], s["start"], s["tokens"], s.get("bucket"),
+                      s["moe_assignments"], s["moe_experts_touched"], s["moe_max_rows"])
+    alone = []
+    for r in reqs:
+        tr = Tracer(ring_size=4096)
+        mk(tr).run([Request(prompt=r.prompt, max_new_tokens=4, request_id=r.request_id)])
+        alone += [call(s) for s in tr.recent_spans() if s["name"] == "prefill_counts"]
+
+    tracer = Tracer(ring_size=4096)
+    eng = mk(tracer)
+    eng.run(reqs)
     spans = tracer.recent_spans()
     counted = [s for s in spans if "moe_assignments" in s]
     by_name = lambda n: [s for s in spans if s["name"] == n]
+    steps = by_name("engine_step")
+    step_of = lambda s: next(i for i, e in enumerate(steps) if e["t0"] <= s["t0"] <= e["t1"])
     chunks = [s for s in by_name("prefill") if "chunk" in s]
     finals = [s for s in by_name("prefill") if "bucket" in s]
     assert len(chunks) == 3 + 0 + 2 and len(finals) == 3
-    assert not any("moe_assignments" in s for s in chunks)
-    assert all("moe_assignments" in s for s in finals + by_name("decode"))
+    assert not any("moe_assignments" in s for s in chunks + finals)
+    assert all("moe_assignments" in s for s in by_name("decode"))
     late = by_name("prefill_counts")
-    assert (sorted((s["request_id"], s["start"], s["tokens"]) for s in late)
-            == sorted((s["request_id"], s["start"], s["tokens"]) for s in chunks))
-    for s in late:          # written inside the fence that followed its chunk
+    key = lambda s: (s["request_id"], s["start"], s["tokens"], s.get("bucket"))
+    assert sorted(map(key, late)) == sorted(map(key, chunks + finals))   # ONE record a call
+    deferred = []
+    for s in late:          # written inside a fence of a LATER step than its chunk's
         assert s["parent"] == "device_wait" and s["moe_max_rows"] >= 1
-        assert s["t0"] > next(c["t1"] for c in chunks if (c["request_id"], c["start"])
-                              == (s["request_id"], s["start"]))
-    assert {s["name"] for s in counted} == {"decode", "prefill", "prefill_counts"}
+        mine = next(c for c in chunks + finals if key(c) == key(s))
+        assert s["t0"] > mine["t1"]
+        if "bucket" not in s:
+            assert step_of(s) > step_of(mine), (s, mine)
+        else:       # at once where no slot was occupied, else a step later
+            wait = next(w for w in by_name("device_wait") if w["t0"] <= s["t0"] <= w["t1"])
+            assert wait["kind"] == "first_token"
+            assert step_of(s) - step_of(mine) in (0, 1)
+            deferred.append((step_of(s) - step_of(mine), wait["covered"]))
+    assert (0, 0) in deferred and (1, 1) in deferred and (0, 1) not in deferred
+    assert {s["name"] for s in counted} == {"decode", "prefill_counts"}
+    assert sorted(map(call, late)) == sorted(alone)
     assert sum(s["moe_assignments"] for s in counted) == eng.stats.summary()["moe_assignments"]
+    assert not eng._chunk_counts
 
 
 def test_latent_pages_export_and_import_by_value(tiny):

@@ -127,8 +127,10 @@ def test_chunked_prefill_parity_and_per_step_budget(med):
         if not eng._pending:
             break
     # 103 tokens at C=32: three intermediate chunks + the 7-token final
-    # chunk, each on its own iteration (the budget admits one per step).
-    assert pending_steps == 4
+    # chunk, each on its own iteration (the budget admits one per step);
+    # the slot stays pending one step more, until its first token is taken
+    # behind that step's decode.
+    assert pending_steps == 5
     outs = {o.request_id: o for o in eng.run()}
     np.testing.assert_array_equal(
         np.asarray(victim_toks), _ref_greedy(model, params, victim_p, 24))

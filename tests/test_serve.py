@@ -353,11 +353,14 @@ def _assert_nested(spans):
 
 
 def test_one_step_names_every_phase_once_and_nests(tiny):
-    """One ``step()`` that admits, prefills (a final chunk) and decodes:
-    exactly one ``engine_step`` (the only depth-0 span), ``sweep``,
+    """The first request on an idle engine: its ``step()`` admits and
+    prefills (a final chunk) and, with no slot occupied, takes the first
+    token at once (``device_wait`` kind=first_token, not covered, directly
+    under ``engine_step``); nothing decodes yet. The next ``step()`` decodes
+    only: exactly one ``engine_step`` (the only depth-0 span), ``sweep``,
     ``grow``, ``decode``, ``emit`` and ``epilogue``; the fence is a
-    ``device_wait`` child of ``decode`` (kind=decode) and of the final
-    chunk's ``prefill`` (kind=first_token); ``emit`` follows the fence."""
+    ``device_wait`` child of ``decode`` (kind=decode); ``emit`` follows the
+    fence."""
     _, _, cfg = tiny
     eng, take = _step_spans(tiny)
     req = Request(prompt=np.arange(5, dtype=np.int32) % cfg.vocab_size,
@@ -366,41 +369,51 @@ def test_one_step_names_every_phase_once_and_nests(tiny):
     eng.step()
     spans = take()
     _assert_nested(spans)
-    for name in ("engine_step", "sweep", "admission", "prefill", "grow",
-                 "decode", "emit", "epilogue"):
+    for name in ("engine_step", "sweep", "admission", "prefill", "epilogue"):
         assert len(_named(spans, name)) == 1, (name, spans)
+    for name in ("grow", "decode", "emit"):
+        assert not _named(spans, name), (name, spans)
     step = _named(spans, "engine_step")[0]
     assert step["depth"] == 0 and step["step"] == 0
     assert [s["name"] for s in spans if s["depth"] == 0] == ["engine_step"]
     assert _named(spans, "admission")[0]["request_id"] == req.request_id
     assert _named(spans, "prefill")[0]["request_id"] == req.request_id
     waits = _named(spans, "device_wait")
-    assert sorted((w["kind"], w["parent"]) for w in waits) == [
-        ("decode", "decode"), ("first_token", "prefill")]
-    decode, emit = _named(spans, "decode")[0], _named(spans, "emit")[0]
-    assert decode["active"] == 1 and decode["parent"] == "engine_step"
-    assert emit["t0"] >= _named(waits, "device_wait", kind="decode")[0]["t1"]
-    assert emit["t0"] >= decode["t1"]
+    assert [(w["kind"], w["parent"], w["covered"]) for w in waits] == [
+        ("first_token", "engine_step", 0)]
+    assert waits[0]["t0"] >= _named(spans, "prefill")[0]["t1"]
     ep = _named(spans, "epilogue")[0]
-    assert ep["t0"] >= emit["t1"] and ep["parent"] == "engine_step"
+    assert ep["parent"] == "engine_step"
     assert (ep["active"], ep["queued"], ep["prefill_tokens"]) == (1, 0, 5)
     assert 0 < ep["pages_used"] <= ep["pages_total"]
-    # the next step decodes only: same single spans, no admission/prefill
+    # the next step decodes only: single spans, no admission/prefill
     eng.step()
     spans = take()
     _assert_nested(spans)
-    assert _named(spans, "engine_step")[0]["step"] == 1
+    for name in ("engine_step", "sweep", "grow", "decode", "emit",
+                 "epilogue"):
+        assert len(_named(spans, name)) == 1, (name, spans)
+    assert _named(spans, "engine_step")[0]["step"] == 0   # no decode yet ran
     assert not _named(spans, "prefill") and not _named(spans, "admission")
-    assert [w["kind"] for w in _named(spans, "device_wait")] == ["decode"]
-    assert len(_named(spans, "epilogue")) == 1
+    waits = _named(spans, "device_wait")
+    assert [(w["kind"], w["parent"], w["covered"]) for w in waits] == [
+        ("decode", "decode", 0)]
+    decode, emit = _named(spans, "decode")[0], _named(spans, "emit")[0]
+    assert decode["active"] == 1 and decode["parent"] == "engine_step"
+    assert emit["t0"] >= waits[0]["t1"] and emit["t0"] >= decode["t1"]
+    ep = _named(spans, "epilogue")[0]
+    assert ep["t0"] >= emit["t1"] and ep["parent"] == "engine_step"
+    eng.step()
+    assert _named(take(), "engine_step")[0]["step"] == 1
 
 
 @pytest.mark.parametrize("path", ["idle", "prefill_only", "spec_k"])
 def test_every_return_path_closes_engine_step_and_epilogue(tiny, path):
-    """The idle (no active slot), prefill-only (disaggregated role) and
-    speculative return paths each close one ``engine_step`` around one
-    ``epilogue``; the speculative fence is ``device_wait(kind=spec)``
-    inside ``decode``, with ``emit`` after it."""
+    """The idle (no active slot), prefill-only (disaggregated role: the
+    first token is read at once, not covered) and speculative return paths
+    each close one ``engine_step`` around one ``epilogue``; the speculative
+    fence is ``device_wait(kind=spec)`` inside ``decode``, with ``emit``
+    after it."""
     model, params, cfg = tiny
     kw = {}
     if path == "prefill_only":
@@ -416,6 +429,9 @@ def test_every_return_path_closes_engine_step_and_epilogue(tiny, path):
         eng.submit(Request(prompt=np.arange(6, dtype=np.int32),
                            max_new_tokens=6))
     eng.step()
+    if path == "spec_k":
+        take()          # the idle engine's first step admits; the next decodes
+        eng.step()
     spans = take()
     _assert_nested(spans)
     assert len(_named(spans, "engine_step")) == 1
@@ -427,8 +443,8 @@ def test_every_return_path_closes_engine_step_and_epilogue(tiny, path):
                                               "epilogue"}
     elif path == "prefill_only":
         assert not _named(spans, "decode") and not _named(spans, "emit")
-        assert [w["kind"] for w in _named(spans, "device_wait")] == [
-            "first_token"]
+        assert [(w["kind"], w["covered"])
+                for w in _named(spans, "device_wait")] == [("first_token", 0)]
     else:
         decode = _named(spans, "decode")[0]
         assert decode["spec_k"] == 2
@@ -444,3 +460,276 @@ def test_disabled_tracer_costs_the_engine_the_shared_null_span(tiny):
     eng = ServeEngine(model, params, num_slots=2, eos_id=None)
     assert eng.tracer.span("engine_step", step=0) is trace_lib._NULL_SPAN
     assert eng.tracer.span("emit") is trace_lib._NULL_SPAN
+
+
+# --------------------- no wait with an empty device queue behind it (PR 28)
+
+def _busy_then_one_more(tiny, *, first_budget=24, **engine_kw):
+    """An engine with request A decoding in a slot and request B just
+    submitted: the next ``step()`` dispatches A's decode, then B's (final)
+    prefill chunk behind it. Returns (engine, take, A, B)."""
+    _, _, cfg = tiny
+    eng, take = _step_spans(tiny, **engine_kw)
+    a = Request(prompt=np.arange(5, dtype=np.int32) % cfg.vocab_size,
+                max_new_tokens=first_budget, request_id="A")
+    eng.submit(a)
+    eng.step()                                  # idle engine: A's token at once
+    assert eng.occupied_slots() == 1
+    b = Request(prompt=(np.arange(7, dtype=np.int32) * 3) % cfg.vocab_size,
+                max_new_tokens=6, request_id="B")
+    eng.submit(b)
+    take()
+    return eng, take, a, b
+
+
+def _owing(eng):
+    """Slots whose final chunk has been dispatched and whose first token is
+    still on the device."""
+    return [slot for slot, p in eng._pending.items() if p.first is not None]
+
+
+def test_decode_is_dispatched_before_the_steps_prefill_and_awaited_after(tiny):
+    """A step with an occupied slot and a pending prefill opens its spans in
+    the order ``decode`` (the dispatch) → ``admission`` → ``prefill`` →
+    ``device_wait(kind=decode)``: the chunk lies behind the decode in the
+    device's queue when the host blocks, so the wait is covered. The final
+    chunk's first token is NOT waited for in that step: the slot owes it
+    (``busy()`` stays true), and the next step takes it right after its own
+    decode's dispatch — ``device_wait(kind=first_token, covered=1)`` — before
+    that decode is awaited. The slot joins the decode after that."""
+    eng, take, a, b = _busy_then_one_more(tiny)
+    got_b = []
+    b.on_token = got_b.append
+    eng.step()
+    spans = sorted(take(), key=lambda s: s["t0"])
+    _assert_nested(sorted(spans, key=lambda s: s["t1"]))
+    order = [(s["name"], s.get("kind")) for s in spans
+             if s["name"] in ("decode", "admission", "prefill", "device_wait")]
+    assert order == [("decode", None), ("admission", None), ("prefill", None),
+                     ("device_wait", "decode")]
+    wait = _named(spans, "device_wait")[0]
+    assert wait["covered"] == 1 and wait["parent"] == "decode"
+    assert _named(spans, "prefill")[0]["parent"] == "decode"
+    assert _named(spans, "decode")[0]["rows"] == 1
+    assert _owing(eng) and eng.busy() and eng.occupied_slots() == 1
+    assert got_b == [] and eng.stats.summary()["requests_admitted"] == 2
+    eng.step()
+    spans = sorted(take(), key=lambda s: s["t0"])
+    order = [(s["name"], s.get("kind"), s.get("covered")) for s in spans
+             if s["name"] in ("decode", "prefill", "device_wait")]
+    assert order == [("decode", None, None), ("device_wait", "first_token", 1),
+                     ("device_wait", "decode", 0)]
+    assert _named(spans, "decode")[0]["rows"] == 1        # B is not a row yet
+    assert len(got_b) == 1 and not _owing(eng) and eng.occupied_slots() == 2
+    eng.step()
+    assert _named(take(), "decode")[0]["rows"] == 2 and len(got_b) == 2
+
+
+def test_busy_while_a_first_token_is_owed_and_nothing_else_is_left(tiny):
+    """A's last token and B's final chunk fall into one step: afterwards no
+    slot is occupied and the queue is empty, and only the owed first token
+    keeps ``busy()`` true. The next step has no decode to dispatch: the token
+    is read with nothing behind it (``covered=0``)."""
+    eng, take, a, b = _busy_then_one_more(tiny, first_budget=2)
+    outs = eng.step()
+    assert [o.request_id for o in outs] == ["A"]
+    assert eng.occupied_slots() == 0 and len(eng.queue) == 0
+    assert _owing(eng) and eng.busy() and eng.load() == 1
+    take()
+    eng.step()
+    spans = take()
+    assert not _named(spans, "decode")
+    assert [(w["kind"], w["covered"], w["parent"])
+            for w in _named(spans, "device_wait")] == [
+                ("first_token", 0, "engine_step")]
+    assert eng.occupied_slots() == 1 and not _owing(eng)
+    assert [o.request_id for o in eng.run()] == ["B"] and not eng.busy()
+
+
+def test_fence_covered_share_counts_every_wait(tiny):
+    """``serve_fence_covered_share`` = covered ÷ all blocking reads, counted
+    where the ``device_wait`` spans are opened."""
+    from k8s_distributed_deeplearning_tpu.telemetry import bridge
+    from k8s_distributed_deeplearning_tpu.telemetry.registry import (
+        MetricsRegistry)
+    model, params, cfg = tiny
+    eng, take = _step_spans(tiny, min_bucket=8, prefill_chunk_tokens=8)
+    assert eng.stats.summary()["fence_covered_share"] is None
+    reg = MetricsRegistry()
+    bridge.serving_collector(reg, eng.stats)
+    prompts, max_news = _workload(cfg, 7, seed=21)
+    eng.run([Request(prompt=p, max_new_tokens=m)
+             for p, m in zip(prompts, max_news)])
+    waits = _named(take(), "device_wait")
+    covered = sum(w["covered"] for w in waits)
+    s = eng.stats.summary()
+    assert (s["fences"], s["fences_covered"]) == (len(waits), covered)
+    assert 0 < covered < len(waits)
+    assert s["fence_covered_share"] == round(covered / len(waits), 4)
+    assert (f"serve_fence_covered_share {s['fence_covered_share']}"
+            in reg.render())
+
+
+# One engine, requests arriving while others decode and prefill in chunks,
+# against the same requests served one at a time (where every first token is
+# read at once, as the engine did before it deferred them).
+
+_FIELDS = ("request_id", "prompt_len", "tokens", "finish_reason",
+           "cached_prompt_tokens", "prefill_chunks", "spec_proposed",
+           "spec_accepted")
+
+
+def _fields(out, skip=()):
+    return {k: getattr(out, k) for k in _FIELDS if k not in skip}
+
+
+def _draft(cfg):
+    dmodel = llama.LlamaLM(llama.config_tiny(dtype=jnp.float32,
+                                             max_seq_len=cfg.max_seq_len))
+    return dmodel, dmodel.init(jax.random.key(7),
+                               jnp.zeros((1, 8), jnp.int32))["params"]
+
+
+def _engine(tiny, **kw):
+    model, params, _ = tiny
+    kw.setdefault("eos_id", None)
+    return ServeEngine(model, params, num_slots=3, min_bucket=8,
+                       prefill_chunk_tokens=8, **kw)
+
+
+def _mixed_run(eng, reqs):
+    """Two requests first, the rest while those decode: most final chunks
+    are dispatched behind a decode and owe their first token for a step."""
+    streams = {r.request_id: [] for r in reqs}
+    for r in reqs:
+        r.on_token = streams[r.request_id].append
+    owed = 0
+    outs = []
+    for r in reqs[:2]:
+        eng.submit(r)
+    outs += eng.step() + eng.step()
+    for r in reqs[2:]:
+        eng.submit(r)
+    while eng.busy():
+        outs += eng.step()
+        owed += bool(_owing(eng))
+    return {o.request_id: o for o in outs}, streams, owed
+
+
+@pytest.mark.parametrize("case", [
+    "greedy", "sampled", "spec_k", "prefix_hits", "eos_first_token",
+    "one_token_budgets", "tp1"])
+def test_streams_equal_one_at_a_time_serving(tiny, case):
+    """Token streams, finish reasons and the other fields of every
+    ``RequestOutput`` of a mixed batch are those of the same requests served
+    one at a time, and no page is leaked."""
+    model, params, cfg = tiny
+    n = 7
+    prompts, max_news = _workload(cfg, n, seed=31, p_lo=3, p_hi=25)
+    sampling = [SamplingParams()] * n
+    kw, skip = {}, ()
+    if case == "sampled":
+        sampling = [SamplingParams(temperature=0.8 + 0.1 * (i % 3),
+                                   top_k=(0, 12, 40)[i % 3],
+                                   top_p=(1.0, 0.9, 0.7)[i % 3])
+                    for i in range(n)]
+    elif case == "spec_k":
+        dmodel, dparams = _draft(cfg)
+        kw = {"draft_model": dmodel, "draft_params": dparams, "spec_k": 2}
+        sampling = [SamplingParams(), SamplingParams(temperature=0.9,
+                                                     top_k=20)] * 4
+    elif case == "prefix_hits":
+        shared = prompts[0][:16] if len(prompts[0]) >= 16 else np.resize(
+            prompts[0], 16)
+        prompts = [np.concatenate([shared, p[:9]]) for p in prompts]
+        kw = {"prefix_cache_mb": 4, "prefix_block_tokens": 8}
+        skip = ("cached_prompt_tokens", "prefill_chunks")   # who hits differs
+    elif case == "eos_first_token":
+        # the first token request 3 samples ends it, where its final chunk
+        # was dispatched behind a decode
+        kw = {"eos_id": int(_ref_greedy(model, params, prompts[3], 1)[0])}
+    elif case == "one_token_budgets":
+        max_news = [1 if i % 2 else m for i, m in enumerate(max_news)]
+    elif case == "tp1":
+        kw = {"tp": 1}
+    mk = lambda: [Request(prompt=p, max_new_tokens=m, sampling=sp, seed=40 + i,
+                          request_id=f"{case}-{i}")
+                  for i, (p, m, sp) in enumerate(zip(prompts, max_news, sampling))]
+    alone_eng = _engine(tiny, **kw)
+    alone = {}
+    for r in mk():
+        (out,) = alone_eng.run([r])
+        alone[out.request_id] = out
+    eng = _engine(tiny, **kw)
+    mixed, streams, owed = _mixed_run(eng, mk())
+    assert owed >= 3                       # the deferred path really ran
+    assert sorted(mixed) == sorted(alone)
+    for rid, want in alone.items():
+        assert _fields(mixed[rid], skip) == _fields(want, skip), rid
+        assert streams[rid] == want.tokens
+    if case == "prefix_hits":
+        assert eng.stats.summary()["prefix_cache_hits"] >= 1
+    if case == "eos_first_token":
+        assert mixed[f"{case}-3"].finish_reason == "eos"
+        assert len(mixed[f"{case}-3"].tokens) == 1
+    assert eng._check_page_leaks("test") is None
+    assert alone_eng._check_page_leaks("test") is None
+
+
+@pytest.mark.parametrize("what", ["cancel", "cancel_as_it_finishes", "export",
+                                  "timeout", "drain", "shutdown"])
+def test_a_slot_owing_its_first_token_is_seen_by(tiny, what):
+    """``cancel``, ``export_request_kv``, the deadline sweep, ``drain`` and
+    ``shutdown`` meet a slot whose first token is still on the device: they
+    take the token first (a plain read), so each sees the decoding request
+    the engine would have had a step earlier — its first token emitted, then
+    cancelled / shipped / timed out / finished / aborted."""
+    model, params, cfg = tiny
+    budget = 1 if what == "cancel_as_it_finishes" else 6
+    eng, take, a, b = _busy_then_one_more(tiny)
+    b.max_new_tokens = budget
+    b.deadline_s = 5.0 if what == "timeout" else None
+    got, reasons = [], []
+    b.on_token, b.on_finish = got.append, reasons.append
+    ref = _ref_greedy(model, params, b.prompt, 6).tolist()
+    outs = eng.step()
+    assert outs == [] and _owing(eng) and got == []
+    if what == "cancel":
+        out = eng.cancel("B", "migrated")
+        assert (out.finish_reason, out.tokens, got) == ("migrated", ref[:1],
+                                                        ref[:1])
+        assert out.ttft_s is not None and out.prefill_chunks == 1
+    elif what == "cancel_as_it_finishes":
+        assert eng.cancel("B") is None          # it had finished: unknown here
+        assert (got, reasons) == (ref[:1], ["length"]) and eng.busy()
+        outs = eng.step()                       # … and its output is not lost
+        assert [(o.request_id, o.finish_reason, o.tokens) for o in outs] == [
+            ("B", "length", ref[:1])]
+    elif what == "export":
+        blob = eng.export_request_kv("B")
+        assert (blob["emitted"], got) == (ref[:1], ref[:1])
+        dst = ServeEngine(model, params, num_slots=2, eos_id=None)
+        dst.import_request_kv(blob)
+        (out,) = dst.run()
+        assert out.tokens == ref and dst._check_page_leaks("test") is None
+    elif what == "timeout":
+        b._t_submit -= 10.0                     # its deadline has passed
+        outs = eng.step()
+        (out,) = [o for o in outs if o.request_id == "B"]
+        assert (out.finish_reason, out.tokens) == ("timeout", ref[:1])
+        assert reasons == ["timeout"]
+    elif what == "drain":
+        assert eng.drain() == [] and not eng.drained
+        outs = []
+        while not eng.drained:
+            outs += eng.step()
+        assert {o.request_id: o.tokens for o in outs}["B"] == ref
+    elif what == "shutdown":
+        outs = {o.request_id: o for o in eng.shutdown()}
+        assert (outs["B"].finish_reason, outs["B"].tokens) == ("aborted",
+                                                               ref[:1])
+        assert outs["A"].finish_reason == "aborted" and len(outs["A"].tokens) == 2
+    assert not _owing(eng)
+    if what != "shutdown":
+        eng.run()
+    assert not eng.busy() and eng._check_page_leaks("test") is None
