@@ -176,8 +176,7 @@ def run_accuracy_gate(data_dir: str, checkpoint_dir: str,
     reference's deployed config (batch 100, Adam 1e-3 x world, default
     20000 // world steps — ``tensorflow_mnist.py:33-34,123,146``) on real
     MNIST through the DP engine, evaluate the FULL 10k test split, and
-    assert >= 0.99. Called by both ``bench.py --suite mnist|all`` and
-    ``tests/test_mnist_convergence.py`` so the two can never drift apart.
+    assert >= 0.99. Called by ``tests/test_mnist_convergence.py``.
     *checkpoint_dir* must be fresh — a stale dir would restore a finished
     run and certify params the current code never trained. Returns the
     measured accuracy."""

@@ -4,8 +4,7 @@ Activation is process-global and resolved ONCE: :func:`active` reads
 ``$TPUJOB_FAULT_PLAN`` (inline JSON, or ``@/path`` to a JSON file) the
 first time any hook asks, and caches the result — including the common
 "no plan" case, so the steady-state cost of an un-faulted run is one
-``is not None`` check per hook site (the <2% telemetry-overhead gate in
-``bench.py`` also covers these hooks riding in ``train/loop.py``).
+``is not None`` check per hook site.
 
 Identity comes from the gang env contract: the firing rank is
 ``$TPUJOB_PROCESS_ID`` and the restart incarnation is ``$TPUJOB_ATTEMPT``

@@ -129,54 +129,47 @@ def decode_step(model, params: PyTree, cache: PyTree, token: jax.Array, *,
 
 def slot_decode_step(model, params: PyTree, cache: PyTree,
                      tokens: jax.Array, slot_positions: jax.Array,
-                     block_tables: jax.Array | None = None
+                     block_tables: jax.Array
                      ) -> tuple[jax.Array, PyTree, jax.Array | None]:
-    """One SLOT decode step: row i's ``tokens[i]`` is written at that
-    row's own cursor ``slot_positions[i]`` ([B] int32) and attends to its
-    row prefix ``0..slot_positions[i]`` only (models/transformer.py slot
-    branch). Rows live independent lifetimes — the continuous-batching
-    engine's per-iteration program. Returns ``(logits, cache, counts)``
-    with logits [B, V] and :func:`moe_assignments` (None for a model with
-    no expert layer). The caller owns cursor arithmetic (pass position =
-    tokens-written-so-far for each row) and must keep ``slot_positions``
-    within ``max_seq_len``; stale KV beyond a row's cursor is never
-    attended, so freed slots are reusable without clearing.
-
-    ``block_tables`` ([B, n_blocks] int32) switches the cache to the paged
-    pool layout: row i writes at page ``block_tables[i, pos // bt]``,
-    offset ``pos % bt``, and attends its table-gathered prefix."""
-    kw: dict = {}
-    if block_tables is not None:
-        kw["block_tables"] = block_tables
+    """One SLOT decode step over the paged pool: row i's ``tokens[i]`` is
+    written at that row's own cursor ``slot_positions[i]`` ([B] int32) —
+    page ``block_tables[i, pos // bt]`` ([B, n_blocks] int32), offset
+    ``pos % bt`` — and attends its table-gathered prefix
+    ``0..slot_positions[i]`` only. Rows live independent lifetimes — the
+    continuous-batching engine's per-iteration program. Returns
+    ``(logits, cache, counts)`` with logits [B, V] and
+    :func:`moe_assignments` (None for a model with no expert layer). The
+    caller owns cursor arithmetic (pass position = tokens-written-so-far
+    for each row) and must keep ``slot_positions`` within ``max_seq_len``;
+    stale KV beyond a row's cursor is never attended, so freed slots are
+    reusable without clearing."""
     logits, vars_ = model.apply({"params": params, "cache": cache},
                                 tokens[:, None], decode=True,
                                 cache_positions=slot_positions,
-                                mutable=["cache", "moe_stats"], **kw)
+                                block_tables=block_tables,
+                                mutable=["cache", "moe_stats"])
     return logits[:, -1, :], vars_["cache"], moe_assignments(vars_)
 
 
 def slot_verify_step(model, params: PyTree, cache: PyTree,
                      tokens: jax.Array, slot_positions: jax.Array,
-                     block_tables: jax.Array | None = None
-                     ) -> tuple[jax.Array, PyTree]:
+                     block_tables: jax.Array) -> tuple[jax.Array, PyTree]:
     """One speculative VERIFY window: row i's ``tokens[i]`` ([B, W] int32)
-    is written at consecutive per-row positions
+    is written through the row's table at consecutive per-row positions
     ``slot_positions[i] + [0, W)`` and each window token attends its own
-    causal prefix (models/transformer.py slot branch, multi-token form —
-    writes land before the gather, so window tokens see each other).
+    causal prefix (writes land before the gather, so window tokens see
+    each other).
     Returns ``(logits [B, W, V], cache)``: position ``i`` of the window
     scores the continuation AFTER ``tokens[:, :i+1]``, which is exactly
     what the draft-and-verify accept rule compares against. The caller
     owns the accepted-length cursor arithmetic; rejected window tokens
     stay in the cache beyond the truncated cursor and are never attended
     (rollback = cursor truncation, no KV copies)."""
-    kw: dict = {}
-    if block_tables is not None:
-        kw["block_tables"] = block_tables
     logits, vars_ = model.apply({"params": params, "cache": cache},
                                 tokens, decode=True,
                                 cache_positions=slot_positions,
-                                mutable=["cache"], **kw)
+                                block_tables=block_tables,
+                                mutable=["cache"])
     return logits, vars_["cache"]
 
 

@@ -345,6 +345,10 @@ def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
     return inv * ((1.0 - ramp) + ramp / factor)
 
 
+_SLOT_NEEDS_TABLES = ("cache_positions (slot decode) requires block_tables: "
+                      "per-row cursors exist only over the paged pool")
+
+
 class Attention(nn.Module):
     """Multi-head / grouped-query attention with optional RoPE.
 
@@ -359,23 +363,6 @@ class Attention(nn.Module):
     "cache" collection; each call appends the current chunk and attends the
     chunk's queries against the cache prefix.
 
-    ``cache_positions`` ([B] int32) selects SLOT decode mode (the
-    continuous-batching serving engine, :mod:`serve.engine`): each batch
-    row is an independent request slot with its OWN cursor — token ``i``
-    of the chunk writes at per-row column ``cache_positions[b] + i``
-    (a row-indexed scatter instead of the shared-cursor
-    ``dynamic_update_slice``) and attends columns
-    ``<= cache_positions[b] + i``. A [B, 1] chunk is classic one-token
-    decode; a [B, W] chunk is a speculative VERIFY window — W draft
-    tokens written at consecutive per-row positions, each attending its
-    own causal prefix, so one pass scores every draft (serve/engine.py
-    truncates the cursor to the accepted length; stale KV beyond it is
-    never attended, which is what makes rollback free). Columns beyond a
-    slot's cursor are never read, so a freed slot can be re-filled by a
-    new request's prefill without clearing the stale K/V the previous
-    occupant left behind. The shared scalar ``cache_index`` is untouched:
-    per-slot lengths are the caller's registers.
-
     ``block_tables`` ([B, n_blocks] int32) selects PAGED decode mode: the
     cache leaves are one POOL of fixed-size KV pages
     (``[num_pages, page_tokens, kv·hd]``) shared by every row, and each
@@ -386,10 +373,25 @@ class Attention(nn.Module):
     row's pages back for attention. Page 0 is the caller's reserved
     SCRATCH page: table entries default to it and out-of-table writes are
     redirected there, so right-pad garbage never lands where a live row
-    attends. Composes with ``cache_positions`` (paged slot decode) or with
-    explicit ``positions`` (paged chunk prefill at any start — the
-    token-granular scatter has no ``dynamic_update_slice`` clamping
-    hazard, so a right-padded tail chunk is safe at any cursor).
+    attends. The write positions come from explicit ``positions`` (a
+    prefill chunk at any start — the token-granular scatter has no
+    ``dynamic_update_slice`` clamping hazard, so a right-padded tail chunk
+    is safe at any cursor) or from ``cache_positions``.
+
+    ``cache_positions`` ([B] int32) is SLOT decode over the paged pool (the
+    continuous-batching serving engine, :mod:`serve.engine`) and requires
+    ``block_tables``: each batch row is an independent request slot with
+    its OWN cursor — token ``i`` of the chunk writes through the row's
+    table at position ``cache_positions[b] + i`` and attends positions
+    ``<= cache_positions[b] + i``. A [B, 1] chunk is classic one-token
+    decode; a [B, W] chunk is a speculative VERIFY window — W draft
+    tokens written at consecutive per-row positions, each attending its
+    own causal prefix, so one pass scores every draft (serve/engine.py
+    truncates the cursor to the accepted length; stale KV beyond it is
+    never attended, which is what makes rollback free). Positions beyond
+    a slot's cursor are never read, so a freed slot can be re-filled by a
+    new request's prefill without clearing the stale K/V the previous
+    occupant left behind. Per-slot lengths are the caller's registers.
     """
 
     cfg: TransformerConfig
@@ -423,6 +425,8 @@ class Attention(nn.Module):
         cur = None
         if cache_positions is not None and not decode:
             raise ValueError("cache_positions requires decode=True")
+        if cache_positions is not None and block_tables is None:
+            raise ValueError(_SLOT_NEEDS_TABLES)
         if decode:
             if mask is not None or attention_fn is not None:
                 raise NotImplementedError(
@@ -431,12 +435,6 @@ class Attention(nn.Module):
                     "silently wrong")
             b, sq = x.shape[0], x.shape[1]
             kv = cfg.resolved_kv_heads
-            if cache_positions is not None:
-                if segment_ids is not None:
-                    raise NotImplementedError(
-                        "slot decode isolates rows by construction (each "
-                        "slot is one request); segment_ids have no meaning "
-                        "here")
             if block_tables is not None:
                 # Paged mode: the "cache" collection holds ONE pool of
                 # fixed-size pages [num_pages, page_tokens, kv·hd] shared
@@ -509,26 +507,17 @@ class Attention(nn.Module):
                                            (b, cfg.max_seq_len), jnp.int32)
                 cache_index = self.variable("cache", "cache_index",
                                             lambda: jnp.zeros((), jnp.int32))
-                if cache_positions is not None:
-                    # Slot mode: per-row cursors own positions; the shared
-                    # scalar cursor and the seg-validity machinery stay
-                    # idle.
-                    if positions is None:
-                        positions = (cache_positions[:, None]
-                                     + jnp.arange(sq,
-                                                  dtype=jnp.int32)[None, :])
-                else:
-                    cur = cache_index.value
-                    if use_seg:
-                        seg_now = segment_ids.astype(jnp.int32)
-                        cached_seg.value = jax.lax.dynamic_update_slice(
-                            cached_seg.value, seg_now, (0, cur))
-                    segment_ids = None  # consumed into the cache mask below
-                    if positions is None:
-                        # Absolute positions for RoPE: the cache cursor
-                        # onward. (Left-padded callers pass explicit
-                        # per-row positions.)
-                        positions = (cur + jnp.arange(sq))[None, :]
+                cur = cache_index.value
+                if use_seg:
+                    seg_now = segment_ids.astype(jnp.int32)
+                    cached_seg.value = jax.lax.dynamic_update_slice(
+                        cached_seg.value, seg_now, (0, cur))
+                segment_ids = None  # consumed into the cache mask below
+                if positions is None:
+                    # Absolute positions for RoPE: the cache cursor
+                    # onward. (Left-padded callers pass explicit
+                    # per-row positions.)
+                    positions = (cur + jnp.arange(sq))[None, :]
 
         if cfg.position == "rope":
             cos, sin = rope_frequencies(hd, cfg.max_seq_len, cfg.rope_theta)
@@ -620,28 +609,6 @@ class Attention(nn.Module):
                 dmask = (col[None, None, :] <= wpos[:, :, None])[:, None]
                 out = attention_ops.multi_head_attention(
                     q, k_all, v_all, causal=False, mask=dmask, impl="xla")
-        elif decode and cache_positions is not None:
-            # Slot decode: token i of the [B, sq] chunk scatters into
-            # per-row column cursor+i and attends its prefix
-            # col <= cursor+i — including the just-written token, so even
-            # a cursor-0 idle slot has one finite score (no NaN softmax).
-            # sq == 1 is classic decode; sq > 1 is a speculative verify
-            # window (writes happen before the gather, so window tokens
-            # see each other causally within one pass).
-            b, sq = x.shape[0], x.shape[1]
-            kv = cfg.resolved_kv_heads
-            wpos = positions.astype(jnp.int32)                    # [B, sq]
-            k_all = cached_k.value.at[jnp.arange(b)[:, None], wpos].set(
-                k.reshape(b, sq, kv * hd).astype(cached_k.value.dtype))
-            v_all = cached_v.value.at[jnp.arange(b)[:, None], wpos].set(
-                v.reshape(b, sq, kv * hd).astype(cached_v.value.dtype))
-            cached_k.value, cached_v.value = k_all, v_all
-            k_all = k_all.reshape(b, cfg.max_seq_len, kv, hd)
-            v_all = v_all.reshape(b, cfg.max_seq_len, kv, hd)
-            col = jnp.arange(cfg.max_seq_len)
-            dmask = (col[None, None, :] <= wpos[:, :, None])[:, None]
-            out = attention_ops.multi_head_attention(
-                q, k_all, v_all, causal=False, mask=dmask, impl="xla")
         elif decode:
             # Append this chunk at the cursor (static-shape cache update) and
             # attend the chunk's queries against the cache prefix: query at
@@ -773,8 +740,7 @@ class LatentAttention(nn.Module):
                 "LatentAttention has no tp_axis / kv_quant path: the latent "
                 "cache has no head axis to shard and no int8 scales")
         if cache_positions is not None and block_tables is None:
-            raise NotImplementedError(
-                "latent slot decode needs block_tables (the paged pool)")
+            raise ValueError(_SLOT_NEEDS_TABLES)
         b, sq, _ = x.shape
         h = cfg.n_heads
         r, dn, dr, dv = (la.kv_lora_rank, la.qk_nope_head_dim,

@@ -29,16 +29,14 @@ def default_impl(seq_len: int, kv_seq_len: int | None = None,
                  platform: str | None = None) -> str:
     """Data-driven attention-impl selection (the ``impl="auto"`` rule).
 
-    Measured on a TPU v5e in round 4 (bench.py --suite attention, on the
-    installation of that round; not re-measured on the current one): the
-    Pallas flash kernel beat XLA einsum attention at every tested length —
-    S=1024 (1.3x fwd / 1.9x fwd+bwd), S=2048 (1.4x / 2.1x), S=4096
-    (2.1x / 2.2x) — so TPU picks flash whenever BOTH sequence lengths tile
-    well (>= 1024, 128-aligned). The measurements are self-attention
-    (sq == sk); a cross-attention caller with an awkward KV length would
-    get degenerate fine blocks (``_pick_block`` can fall to 1), so any
-    badly-tiled side falls back to xla. Off-TPU (CPU CI) flash runs in the
-    Pallas interpreter, orders of magnitude slower than XLA: always xla.
+    TPU picks the Pallas flash kernel whenever BOTH sequence lengths tile
+    well (>= 1024, 128-aligned). The threshold is inherited from an
+    earlier installation and has NOT been measured on this one (ROADMAP,
+    speed item 2: no cell trains at S >= 1024 yet). A cross-attention
+    caller with an awkward KV length would get degenerate fine blocks
+    (``_pick_block`` can fall to 1), so any badly-tiled side falls back
+    to xla. Off-TPU (CPU CI) flash runs in the Pallas interpreter, orders
+    of magnitude slower than XLA: always xla.
     """
     tpu = backend.on_tpu() if platform is None else platform == "tpu"
     kv = seq_len if kv_seq_len is None else kv_seq_len

@@ -24,14 +24,13 @@ from k8s_distributed_deeplearning_tpu.serve.request import (
     EngineDraining, QueueFull, Request, RequestOutput, SamplingParams)
 from k8s_distributed_deeplearning_tpu.serve.sched import (
     DEFAULT_TENANT, TenantConfig, TenantScheduler, load_tenants)
-from k8s_distributed_deeplearning_tpu.serve.scheduler import RequestQueue
 from k8s_distributed_deeplearning_tpu.serve.storm import (
     InvariantMonitor, StormConfig, StormReport, run_storm)
 from k8s_distributed_deeplearning_tpu.serve.transport import (
     ReplicaClient, ReplicaServer, discover_replica_clients)
 
 __all__ = ["ServeEngine", "ServeGateway", "Request", "RequestOutput",
-           "SamplingParams", "RequestQueue", "QueueFull", "EngineDraining",
+           "SamplingParams", "QueueFull", "EngineDraining",
            "PagePool", "PrefixCache", "TenantConfig", "TenantScheduler",
            "DEFAULT_TENANT", "load_tenants", "ReplicaServer",
            "ReplicaClient", "discover_replica_clients",

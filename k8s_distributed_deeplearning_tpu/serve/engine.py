@@ -489,9 +489,8 @@ def _tp_programs_for(local_model, mesh, param_specs, cache_specs, *,
     mesh, and pool layout share one set of jitted wrappers. Without this,
     every ServeEngine ctor would mint fresh ``jax.jit`` objects and pay
     full recompiles — the tp=0 path never does (its programs are
-    module-level jits), and the bench's < 2% tp=1 overhead gate holds the
-    tp path to the same standard. param_specs is derived from the model,
-    so it needs no key of its own."""
+    module-level jits). param_specs is derived from the model, so it
+    needs no key of its own."""
     spec_leaves, spec_treedef = jax.tree.flatten(
         cache_specs, is_leaf=lambda s: isinstance(s, P))
     key = (local_model, mesh, spec_steps, spec_treedef, tuple(spec_leaves))
@@ -648,8 +647,8 @@ class ServeEngine:
     prefill, page growth, migration and speculative decoding work
     unchanged on top of sharded storage. Head/mlp divisibility and mesh
     size are validated here (and offline in launch/validate.py), never
-    at first trace. ``tp=1`` is the shard_map path on one device —
-    the overhead-measurement variant (bench.py --suite tp).
+    at first trace. ``tp=1`` is the shard_map path on one device
+    (tests/test_tp_serve.py holds it to the plain engine's tokens).
 
     ``tenants`` (optional) configures the SLO-aware multi-tenant
     scheduler (serve/sched): per-tenant EDF queues drained by

@@ -2,10 +2,9 @@
 round-robin under strict priority classes, with token-bucket rate limits
 and slot quotas enforced at pop time.
 
-Drop-in for the FCFS :class:`serve.scheduler.RequestQueue` surface
-(``submit``/``pop``/``drain``/``__len__`` plus the scheduler-aware calls
-the engine makes: ``sweep_expired`` and ``release``), so the engine's
-admission loop stays policy-agnostic:
+The engine's admission loop sees a queue (``submit``/``pop``/``drain``/
+``__len__``, plus ``sweep_expired`` and ``release``) and stays
+policy-agnostic:
 
 - **Within a tenant — EDF.** Each tenant's queue is a heap keyed by
   absolute deadline (``_t_submit + deadline_s``; no deadline sorts last,
@@ -118,9 +117,8 @@ class TenantScheduler:
 
     ``tenants=None`` registers the single :data:`DEFAULT_TENANT` with no
     limits — behaviorally FCFS (every deadline-less request sorts equal,
-    FIFO tiebreak), which is what keeps the single-tenant overhead gate
-    in ``bench.py --suite sched`` honest. ``default_max_queue`` bounds
-    any tenant that does not set its own ``max_queue``.
+    FIFO tiebreak). ``default_max_queue`` bounds any tenant that does not
+    set its own ``max_queue``.
 
     ``clock`` is injectable for deterministic token-bucket tests; it must
     be the same clock that stamps ``Request._t_submit``

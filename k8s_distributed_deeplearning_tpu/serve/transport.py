@@ -23,8 +23,8 @@ network) between them without changing the gateway at all:
   of the gateway's ``step()`` and is scored like any other dispatch
   failure.
 
-Robustness contract (what the chaos matrix in ``bench.py --suite
-transport`` proves):
+Robustness contract (tests/test_transport.py holds each clause under a
+replica kill and under dropped, stalled and partitioned calls):
 
 - **Idempotent submit.** Every dispatch gets a client-minted key
   ``request_id@seq``. A retry after an AMBIGUOUS failure (the request

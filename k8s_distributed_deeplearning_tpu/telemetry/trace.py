@@ -16,10 +16,10 @@ stdout→Promtail→Loki pipeline as every other metric — Grafana selects
 Design constraints, in order:
 
 - **Cheap on the hot path.** A closed span costs two ``perf_counter``
-  calls, one dict build, one ``json.dumps`` and one stream write —
-  ``bench.py --suite telemetry`` holds the total under 2% of a CPU train
-  step. A disabled tracer (``enabled=False``) costs one attribute check:
-  ``span()`` hands back a shared no-op singleton.
+  calls, one dict build, one ``json.dumps`` and one stream write (what
+  that is on the chip: PERF.md §5, "Tracing ON"). A disabled tracer
+  (``enabled=False``) costs one attribute check: ``span()`` hands back a
+  shared no-op singleton.
 - **Thread-safe.** The span stack is ``threading.local`` (the serving
   engine and prefetch threads trace concurrently with the main loop);
   emission goes through ``MetricsLogger`` whose line-buffered writes are

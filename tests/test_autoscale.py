@@ -293,6 +293,7 @@ def test_scale_up_on_slo_fast_burn():
     clk.advance(0.1)
     d = ctl.control_round(clk.t)
     assert d["decision"] == "up" and d["fast_burn"] > 14.4
+    assert any(a.window == "fast" for a in slo.active_alerts())
     assert len(gw.replica_ids()) == 2
     # Burn decays out of the fast window -> calm -> eventual scale-down.
     clk.advance(30.0)
@@ -301,6 +302,7 @@ def test_scale_up_on_slo_fast_burn():
     for _ in range(4):
         clk.advance(1.1)
         d = ctl.control_round(clk.t)
+    assert not any(a.window == "fast" for a in slo.active_alerts())
     assert d["decision"] in ("down", "hold")
     assert ctl.snapshot()["actual_replicas"] >= 1
 

@@ -524,8 +524,7 @@ def test_watch_crash_loop_detection(tmp_path):
 
 def test_hooks_are_noop_without_plan():
     """The steady-state contract: with no plan configured, every hook site
-    resolves to a single cached None check (the <2% telemetry-overhead
-    gate in bench.py rides on this)."""
+    resolves to a single cached None check."""
     assert faults.active() is None
     assert faults.active() is None   # cached, not re-read
     state = _tiny_fit(num_steps=3)

@@ -21,7 +21,6 @@ from k8s_distributed_deeplearning_tpu import faults
 from k8s_distributed_deeplearning_tpu.faults.plan import Fault, FaultPlan
 from k8s_distributed_deeplearning_tpu.models import generate, llama
 from k8s_distributed_deeplearning_tpu.serve import (QueueFull, Request,
-                                                    RequestQueue,
                                                     ServeEngine,
                                                     ServeGateway,
                                                     TenantConfig,
@@ -582,13 +581,6 @@ def test_tenant_remove_and_fifo_requeue():
     assert ts.remove(a.request_id) is a
     assert ts.remove("nope") is None
     assert ts.pop() is b and len(ts) == 0
-    # The legacy FCFS queue honors the same requeue/remove contract.
-    rq = RequestQueue(max_size=1)
-    rq.submit(a)
-    rq.requeue(b)                            # head entry, bound bypassed
-    assert rq.pop() is b and rq.pop() is a
-    rq.submit(a)
-    assert rq.remove(a.request_id) is a and rq.remove(a.request_id) is None
 
 
 def test_gateway_dispatch_fault_site_plan_validation():

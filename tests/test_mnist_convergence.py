@@ -154,8 +154,7 @@ def test_real_mnist_converges_to_99(tmp_path):
     """The north-star gate: reference deployed config (batch 100, Adam
     1e-3 x world, steps 20000 // world — ``tensorflow_mnist.py:33-34,123,146``)
     through the real DP engine on real data must reach >= 99.0% accuracy on
-    the full held-out test split. Shares its entire definition with
-    ``bench.py --suite mnist`` via ``train_mnist.run_accuracy_gate``."""
+    the full held-out test split (``train_mnist.run_accuracy_gate``)."""
     from examples import train_mnist
 
     real = _real_dir_or_skip()

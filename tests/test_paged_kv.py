@@ -112,21 +112,44 @@ def test_pool_validation():
 # ----------------------------------------------- engine: back-pressure
 
 
-def test_pool_exhaustion_backpressure_defers_admission(tiny):
-    """A pool sized for ~2 concurrent requests under a 6-request load:
-    admission back-pressure (the scheduler's fits probe) caps residency at
-    the true capacity, nothing crashes, every request completes with full
-    greedy parity, and the pool drains back to zero used pages."""
+@pytest.mark.parametrize("case", ["pages_cap_residency",
+                                  "dense_arena_budget", "int8_same_bytes"])
+def test_pool_exhaustion_backpressure_defers_admission(tiny, case):
+    """Residency follows the pool's pages, not the slot count: admission
+    back-pressure (the scheduler's fits probe) admits what fits, nothing
+    crashes, every request completes (with full greedy parity where the
+    pool is not quantized), and the pool drains back to zero used pages.
+
+    - ``pages_cap_residency``: a pool sized for 2 concurrent requests
+      under a 6-request load holds at most 2.
+    - ``dense_arena_budget``: the pages a dense arena would spend on TWO
+      slots of ``max_seq_len`` hold, at requests a quarter that long, at
+      least twice as many residents.
+    - ``int8_same_bytes``: the first case's byte budget spent on int8
+      pages holds at least 1.8x its residents."""
     model, params, cfg = tiny
     rng = np.random.default_rng(0)
-    # 8 tokens/page; each request needs ceil((6 + 12 - 1)/8) = 3 pages —
-    # growth crosses two page boundaries mid-decode. 6 usable pages => at
-    # most 2 requests resident at once.
+    # 8 tokens/page; a request of 6 + 12 tokens needs ceil((6 + 12 - 1)/8)
+    # = 3 pages — growth crosses two page boundaries mid-decode.
+    bt, slots, max_new, kw = 8, 4, 12, {}
+    if case == "pages_cap_residency":
+        pages, lo, hi = 6, 1, 2     # 6 usable pages => at most 2 resident
+    elif case == "dense_arena_budget":
+        # two dense slots = 2 * (96 / 8) pages; 6 + 18 tokens = max_seq / 4
+        pages, slots, max_new = 2 * (cfg.max_seq_len // bt), 8, 18
+        lo, hi = 4, 8
+    else:
+        probe = ServeEngine(model, params, num_slots=2, kv_quant="int8",
+                            prefix_block_tokens=bt)
+        pages = (6 * probe._block_nbytes(bt, kv_quant=None)
+                 // probe._block_nbytes(bt))
+        slots, kw = 8, {"kv_quant": "int8"}
+        lo, hi = 4, pages // 3      # >= 1.8 x the fp pool's 2
     prompts = [rng.integers(0, cfg.vocab_size, size=6).astype(np.int32)
-               for _ in range(6)]
-    eng = ServeEngine(model, params, num_slots=4, eos_id=None,
-                      prefix_block_tokens=8, kv_pool_pages=6)
-    reqs = [Request(prompt=p, max_new_tokens=12) for p in prompts]
+               for _ in range(3 * slots // 2)]
+    eng = ServeEngine(model, params, num_slots=slots, eos_id=None,
+                      prefix_block_tokens=bt, kv_pool_pages=int(pages), **kw)
+    reqs = [Request(prompt=p, max_new_tokens=max_new) for p in prompts]
     for r in reqs:
         eng.submit(r)
     outs, peak = [], 0
@@ -135,14 +158,15 @@ def test_pool_exhaustion_backpressure_defers_admission(tiny):
         resident = (sum(s is not None for s in eng._slots)
                     + len(eng._pending))
         peak = max(peak, resident)
-    assert 1 <= peak <= 2          # capped by pages, not by the 4 slots
+    assert lo <= peak <= hi         # capped by pages, not by the slots
     outs = {o.request_id: o for o in outs}
-    assert len(outs) == 6
+    assert len(outs) == len(reqs)
     for r, p in zip(reqs, prompts):
         assert outs[r.request_id].finish_reason == "length"
-        np.testing.assert_array_equal(
-            np.asarray(outs[r.request_id].tokens),
-            _ref_greedy(model, params, p, 12))
+        if not kw:
+            np.testing.assert_array_equal(
+                np.asarray(outs[r.request_id].tokens),
+                _ref_greedy(model, params, p, max_new))
     c = eng.pool.counters()
     assert c["pages_used"] == 0 and c["pages_reserved"] == 0
 

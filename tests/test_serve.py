@@ -676,6 +676,101 @@ def test_streams_equal_one_at_a_time_serving(tiny, case):
     assert alone_eng._check_page_leaks("test") is None
 
 
+def _program_counts(eng):
+    return (eng.decode_cache_size(), ServeEngine.prefill_cache_size(),
+            ServeEngine.chunk_cache_size())
+
+
+@pytest.mark.parametrize("case", [
+    "prefix_cache_below_one_block", "request_trace_sample_1", "flight_ring",
+    "explicit_default_tenant", "jsonl_tracer", "fleet_scrape",
+    "one_replica_gateway", "idle_autoscaler"])
+def test_feature_idle_adds_no_device_work(tiny, case):
+    """A feature that is attached and has nothing to do is host bookkeeping:
+    the same seeded requests through a plain engine and through one with the
+    feature on give the same ``RequestOutput`` fields (token streams, finish
+    reasons, chunk counts), dispatch the same number of programs, compile
+    none, and leak no page."""
+    import io
+
+    from k8s_distributed_deeplearning_tpu.serve import (
+        DEFAULT_TENANT, EngineFactoryBackend, FleetController, ServeGateway,
+        TenantConfig)
+    from k8s_distributed_deeplearning_tpu.telemetry import (
+        MetricsExporter, MetricsRegistry, Tracer, bridge, fleet)
+    from k8s_distributed_deeplearning_tpu.telemetry.flight import (
+        FlightRecorder)
+    from k8s_distributed_deeplearning_tpu.utils.metrics import MetricsLogger
+
+    _, _, cfg = tiny
+    n = 7
+    prompts, max_news = _workload(cfg, n, seed=47, p_lo=3, p_hi=25)
+    mk = lambda: [Request(prompt=p, max_new_tokens=m, request_id=f"{case}-{i}")
+                  for i, (p, m) in enumerate(zip(prompts, max_news))]
+    # the gateway's own RequestOutput carries no per-engine chunk count
+    skip = (("prefill_chunks",) if case in ("one_replica_gateway",
+                                            "idle_autoscaler") else ())
+    plain = _engine(tiny)
+    want = {o.request_id: _fields(o, skip) for o in plain.run(mk())}
+    assert len(want) == n
+    programs = _program_counts(plain)
+
+    sink = MetricsLogger(stream=io.StringIO(), job="test")
+    kw = {
+        "prefix_cache_below_one_block": {"prefix_cache_mb": 1 / 1024},
+        "request_trace_sample_1": {"request_trace_sample": 1.0,
+                                   "request_log": sink},
+        "flight_ring": {"flight": FlightRecorder(256)},
+        "explicit_default_tenant": {"tenants": [TenantConfig(DEFAULT_TENANT)]},
+        "jsonl_tracer": {"tracer": Tracer(sink)},
+    }.get(case, {})
+    eng = _engine(tiny, **kw)
+    if case == "fleet_scrape":
+        registry = MetricsRegistry()
+        bridge.serving_collector(registry, eng.stats)
+        exporter = MetricsExporter(registry, host="127.0.0.1", port=0).start()
+        scraper = fleet.FleetScraper([f"127.0.0.1:{exporter.port}"])
+        try:
+            scraper.poll()
+            outs = eng.run(mk())
+            (state,) = scraper.poll().values()
+        finally:
+            exporter.stop()
+        assert state.consecutive_failures == 0 and state.families
+    elif case == "one_replica_gateway":
+        outs = ServeGateway([eng]).run(mk())
+    elif case == "idle_autoscaler":
+        def never():
+            raise AssertionError("an idle controller started a replica")
+        gw = ServeGateway([eng])
+        # thresholds out of reach: every round senses, decides, holds
+        ctl = FleetController(gw, EngineFactoryBackend(never), min_replicas=1,
+                              max_replicas=1, interval_s=0.0, load_high=1e9,
+                              load_low=0.0)
+        for r in mk():
+            gw.submit(r)
+        outs = []
+        while len(outs) < n:
+            outs += gw.step()
+            assert ctl.control_round()["decision"] == "hold"
+    else:
+        outs = eng.run(mk())
+    assert {o.request_id: _fields(o, skip) for o in outs} == want
+    assert eng._dispatches == plain._dispatches
+    assert _program_counts(eng) == programs
+    assert eng._check_page_leaks("test") is None
+    if case == "prefix_cache_below_one_block":
+        assert len(eng.prefix_cache) == 0
+        assert eng.prefix_cache.inserted_blocks == 0
+        assert eng.stats.prefix_misses == n
+    elif case == "request_trace_sample_1":
+        assert eng.stats.request_traces == n
+    elif case == "flight_ring":
+        assert len(kw["flight"].ring) >= eng.stats.steps > 0
+    elif case == "jsonl_tracer":
+        assert kw["tracer"].spans_emitted > eng.stats.steps
+
+
 @pytest.mark.parametrize("what", ["cancel", "cancel_as_it_finishes", "export",
                                   "timeout", "drain", "shutdown"])
 def test_a_slot_owing_its_first_token_is_seen_by(tiny, what):

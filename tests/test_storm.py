@@ -275,8 +275,9 @@ def test_storm_autoscale_topology_conserves_under_fire():
     rep = run_storm(cfg, make_engine=_ScriptEngine)
     assert rep.violations == []
     assert rep.submitted == rep.finished > 0
-    assert "serve_decode" in rep.distinct_sites
-    assert rep.peak_load_frac > 0.0
+    # under real pressure: three fault sites fired, at half load or more
+    assert len(rep.distinct_sites) >= 3 and "serve_decode" in rep.distinct_sites
+    assert rep.peak_load_frac >= 0.5
 
 
 # --------------------------------- the monitor catches what it claims

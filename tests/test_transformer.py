@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from k8s_distributed_deeplearning_tpu.models import llama
+from k8s_distributed_deeplearning_tpu.models import llama, moe
 from k8s_distributed_deeplearning_tpu.models.transformer import (
     RMSNorm, TransformerConfig, apply_rope, rope_frequencies)
 from k8s_distributed_deeplearning_tpu.ops import attention as attn_ops
@@ -213,3 +213,24 @@ def test_remat_policy_variants():
             a, b, rtol=2e-5, atol=2e-6), grads, ref_grads)
     with pytest.raises(ValueError, match="remat_policy"):
         dataclasses.replace(base, remat_policy="bogus")
+
+
+@pytest.mark.parametrize("attention", ["dense", "latent"])
+def test_slot_decode_without_block_tables_is_refused(attention):
+    """Per-row cursors exist only over the paged pool: ``cache_positions``
+    without ``block_tables`` is refused by both attention classes (at trace,
+    before any cache is made), and so is ``slot_decode_step`` without its
+    tables."""
+    from k8s_distributed_deeplearning_tpu.models import generate
+    if attention == "dense":
+        model = llama.LlamaLM(llama.config_tiny(dtype=jnp.float32))
+    else:
+        model = moe.LatentMoELM(*moe.config_tiny_latent_moe())
+    toks = jnp.zeros((2, 1), jnp.int32)
+    with pytest.raises(ValueError, match="requires block_tables"):
+        jax.eval_shape(lambda: model.init(
+            jax.random.key(0), toks, decode=True,
+            cache_positions=jnp.zeros((2,), jnp.int32)))
+    with pytest.raises(TypeError, match="block_tables"):
+        generate.slot_decode_step(model, None, None, toks[:, 0],
+                                  jnp.zeros((2,), jnp.int32))

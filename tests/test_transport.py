@@ -601,21 +601,52 @@ def _tracked_requests(cfg, n, seed, p_lo=4, p_hi=12, m_lo=6, m_hi=12):
     return reqs, streams, finishes
 
 
-def test_remote_gateway_bit_parity_and_wire_drain(tiny):
+_NETWORK_FAULTS = {
+    "drop": Fault(site="transport_send", action="drop", count=3),
+    "latency": Fault(site="transport_send", action="stall", seconds=0.25,
+                     count=3),
+    "partition": Fault(site="transport_send", action="partition",
+                       seconds=0.5),
+}
+
+
+@pytest.mark.parametrize("fault", ["none", *_NETWORK_FAULTS])
+def test_remote_gateway_bit_parity_and_wire_drain(tiny, fault):
     """The tentpole end-to-end: a gateway over two replica-server
     processes-worth of HTTP (in-process servers, real sockets) serves
     every stream bit-identically to the oracle with exactly-once
-    on_finish; then a wire drain empties r0 and routing excludes it."""
+    on_finish — also with calls dropped, stalled or cut off for a window
+    at 50 % fleet load: the client's retries and the server's dispatch
+    ledger absorb all three, nothing is lost; then a wire drain empties r0
+    and routing excludes it."""
     from k8s_distributed_deeplearning_tpu.serve import ServeGateway
     model, params, cfg = tiny
     engines, servers, clients, stats = _remote_fleet(tiny)
+    plan = None
+    if fault != "none":
+        plan = FaultPlan((_NETWORK_FAULTS[fault],))
+        for c in clients:           # the retries must outlast the window
+            c.retries, c.backoff_s = 6, 0.15
     try:
         gw = ServeGateway(clients, stats=stats)
         reqs, streams, finishes = _tracked_requests(cfg, 4, seed=3)
-        for r in reqs:
-            gw.submit(r)
-        outs = []
-        _drive_remote(gw, outs)
+        # drop and latency are armed before admission (a submit whose
+        # answer is lost must not admit twice); the partition opens once
+        # polling is under way, or admission would sit the window out
+        try:
+            if plan is not None and fault != "partition":
+                inj = faults.activate(plan)
+            for r in reqs:
+                gw.submit(r)
+            if fault == "partition":
+                inj = faults.activate(plan)
+            outs = []
+            _drive_remote(gw, outs)
+        finally:
+            faults.deactivate()
+        if plan is not None:
+            assert inj.fired and stats.gateway_breaker_trips == 0
+            assert fault == "latency" or stats.transport_retries >= 1
         assert {o.request_id for o in outs} == {r.request_id for r in reqs}
         for r in reqs:
             assert finishes[r.request_id] == ["length"]
@@ -660,21 +691,29 @@ def test_remote_replica_kill_migrates_bit_identically(tiny):
         reqs, streams, finishes = _tracked_requests(cfg, 4, seed=5,
                                                     p_lo=4, p_hi=8,
                                                     m_lo=40, m_hi=50)
-        for r in reqs:
-            gw.submit(r)
-        assert clients[0].busy() and clients[1].busy()
-        outs = []
-        t0 = time.time()
-        while True:
-            outs.extend(gw.step())
-            live0 = {st.req.request_id
-                     for st in clients[0]._streams.values()}
-            if live0 and any(streams.get(rid) for rid in live0):
-                break                             # r0 provably mid-stream
-            assert clients[0]._streams, "r0 finished before the kill"
-            assert time.time() - t0 < 300.0, "no tokens before kill"
-            time.sleep(0.005)
-        servers[0].close()                        # kill the replica process
+        # Until the kill every decode iteration stalls: a replica steps on
+        # its own thread and, on a loaded machine, could otherwise finish
+        # its streams between two polls of this loop.
+        faults.activate(FaultPlan((Fault(site="serve_decode", action="stall",
+                                         seconds=0.05, count=10_000),)))
+        try:
+            for r in reqs:
+                gw.submit(r)
+            assert clients[0].busy() and clients[1].busy()
+            outs = []
+            t0 = time.time()
+            while True:
+                outs.extend(gw.step())
+                live0 = {st.req.request_id
+                         for st in clients[0]._streams.values()}
+                if live0 and any(streams.get(rid) for rid in live0):
+                    break                         # r0 provably mid-stream
+                assert clients[0]._streams, "r0 finished before the kill"
+                assert time.time() - t0 < 300.0, "no tokens before kill"
+                time.sleep(0.005)
+            servers[0].close()                    # kill the replica process
+        finally:
+            faults.deactivate()
         _drive_remote(gw, outs)
         assert stats.gateway_breaker_trips >= 1
         assert stats.gateway_migrations >= 1
