@@ -86,7 +86,11 @@ PAGED_TABLE = (32, 128)          # (page tokens, blocks a row): 4096 positions
 # rope lanes, page tokens, blocks a row, rows); query widths 1 and 4.
 LATENT_TABLE = (64, 512, 64, 64, 272, 8)
 LATENT_SQ = (1, 4)
-FLASH_BATCH_SEQ = (2, 2048)
+# Flash forward + backward: (batch, seq, head shape, causal) — the trainer's
+# causal S = 2,048 at both head shapes, and the BERT cells' step
+# (bert-base.mlm-s512: pairs of 64-lane heads, the whole sequence resident).
+FLASH_CASES = tuple((2, 2048, hs, True) for hs in HEAD_SHAPES) + (
+    (16, 512, (12, 12, 64), False),)
 GMM_EXPERTS, GMM_ROWS, GMM_BLOCK_M = 8, 16384, 512
 GMM_DIMS = ((768, 2048), (2048, 768))
 
@@ -642,22 +646,21 @@ def child_kernels() -> int:
     # ---- flash attention fwd + bwd at the trainer's sequence length
     # (Arrays go in as arguments: a closed-over array is baked into the
     # program as a constant, and into its cache entry.)
-    for h, hkv, hd in HEAD_SHAPES:
+    for b, s, (h, hkv, hd), causal in FLASH_CASES:
         rng = np.random.default_rng(h)
-        b, s = FLASH_BATCH_SEQ
         q = jnp.asarray(rng.standard_normal((b, s, h, hd)), bf16)
         k = jnp.asarray(rng.standard_normal((b, s, hkv, hd)), bf16)
         v = jnp.asarray(rng.standard_normal((b, s, hkv, hd)), bf16)
         w = jnp.asarray(rng.standard_normal((b, s, h, hd)), f32)
 
         def flash_loss(q, k, v, w):
-            o = pallas_flash.flash_attention(q, k, v, causal=True,
+            o = pallas_flash.flash_attention(q, k, v, causal=causal,
                                              interpret=False)
             return jnp.sum(o.astype(f32) * w), o
 
         def ref_loss(q, k, v, w):
             o = attention.dot_product_attention(
-                q.astype(f32), k.astype(f32), v.astype(f32), causal=True)
+                q.astype(f32), k.astype(f32), v.astype(f32), causal=causal)
             return jnp.sum(o * w), o
 
         grad = lambda f: jax.jit(jax.grad(f, argnums=(0, 1, 2), has_aux=True))

@@ -3,8 +3,10 @@
 The hot op of every transformer config in BASELINE.json. Design follows the
 flash-attention recurrence (online softmax), mapped to TPU:
 
-- grid (batch·KV-heads, S_q/block_q, S_k/superblock): K/V arrive in
-  VMEM-resident SUPERBLOCKS (4096 positions) streamed through the innermost
+- grid (cells, S_q/block_q, S_k/superblock), a cell being the KV heads of
+  one batch row that a grid cell owns (``_pack``: one, or a PAIR where the
+  head size is 64 and K/V have as many heads as Q — BERT, ViT): K/V arrive
+  in VMEM-resident SUPERBLOCKS (4096 positions) streamed through the innermost
   ("arbitrary") grid dim, and the kernel fori_loops over fine blocks inside
   each with the online-softmax carries in registers. Short sequences
   (S ≤ superblock) take exactly one grid step — a fully VMEM-resident fast
@@ -12,11 +14,16 @@ flash-attention recurrence (online softmax), mapped to TPU:
   VMEM scratch across superblocks, so VMEM use is O(superblock) and
   sequence length is bounded by HBM only (64k+ measured on one chip). The
   S×S score matrix never exists in HBM either way;
+- the op works on rows of heads, [B, S, H·d] — the projections' own
+  layout, and what the custom VJP keeps for the backward pass. A pair of
+  64-lane heads is one 128-lane column block of such a row, so that shape
+  class reads and writes q, k, v, o and every gradient where they lie: no
+  transpose in HBM, no half-empty VMEM tile;
 - GQA is NATIVE: one grid cell owns one KV head and serves its whole
   query-head group from the single resident K/V superblock. Q rides as
-  [B·Hkv, S, group·d] — a free reinterpretation of the projection's
-  [B, S, H, d] layout (adjacent query heads of a group are adjacent in
-  memory) plus the same batch×head transpose the MHA path pays — and the
+  [B·Hkv, S, group·d] — a free reinterpretation of a row of heads
+  (adjacent query heads of a group are adjacent in memory) plus the same
+  batch×head transpose the MHA path pays — and the
   kernels unroll the group with per-head online-softmax carries. K/V are
   never repeated to query-head count (the round-3 kernel materialized the
   repeat in HBM: 3× K/V footprint, residual traffic, and per-head re-reads
@@ -28,7 +35,13 @@ flash-attention recurrence (online softmax), mapped to TPU:
   matching the mask's sparsity;
 - the backward pass recomputes P from (Q, K, lse) per block — the standard
   flash trade: O(S) extra FLOPs for never storing P — with separate dQ and
-  dK/dV kernels so each accumulates over its own grid without races;
+  dK/dV kernels so each accumulates over its own grid without races, and
+  ONE kernel where one query block and one key block hold the sequence
+  (``_resident``: nothing accumulates across cells, so one recomputation
+  feeds dQ, dK and dV);
+- the kernels are named ``flash_attn_fwd``, ``flash_attn_bwd`` (resident)
+  and ``flash_attn_dq`` / ``flash_attn_dkv``: a device trace and the
+  benchmark's ``flash_attn_roofline`` find them as ``jit_step/.*flash_attn``;
 - off-TPU (CPU CI) the same kernels run with ``interpret=True``, so tests
   exercise the identical code path the TPU compiles.
 
@@ -60,12 +73,17 @@ def _pick_block(s: int, target: int) -> int:
 
 def _block_sizes(sq: int, sk: int) -> tuple[int, int]:
     """Largest power-of-two block sizes ≤ the swept targets dividing the seq
-    lengths. 512/512 won the v5e sweep at S=2048-8192 (round 4, "flash
-    block sweep"); the knobs exist so future sweeps don't edit the kernel."""
-    return _pick_block(sq, _BLOCK_Q), _pick_block(sk, _BLOCK_K)
+    lengths. 512/512 won the v5e sweep at S=2048-8192 and head size 128
+    (round 4, "flash block sweep"), and again at head size 64 in pairs (PR
+    30, forward + backward, ms at blocks of 512 / 256 / 128: S = 512
+    0.71 / 1.86 / 3.41, S = 1,024 3.91 / 6.49 / 12.9): the head size does
+    not enter. Fine blocks tile WITHIN a superblock."""
+    return (min(_pick_block(sq, _BLOCK_Q), _superblock(sq)),
+            min(_pick_block(sk, _BLOCK_K), _superblock(sk)))
 
 
-# Fine-block size targets (power-of-two caps; clipped to divide S).
+# Fine-block size targets (power-of-two caps; clipped to divide S). What a
+# sweep, or a test that wants a streamed sequence at a small size, changes.
 _BLOCK_Q = 512
 _BLOCK_K = 512
 
@@ -116,39 +134,72 @@ def _stream_split(causal: bool, off: int, segments: bool,
     return causal and off == 0 and not segments and block_q == block_k
 
 
-def _fold_q(x: jax.Array, hkv: int) -> jax.Array:
-    """[B, S, H, D] -> [B*hkv, S, group*D].
-
-    Adjacent query heads of one KV group are adjacent in the last two dims
-    of the projection layout, so regrouping H into (hkv, group*D) is a free
-    reinterpretation; the only data movement is the same batch×head
-    transpose the plain MHA fold pays (with group× longer contiguous runs).
-    Head t of a group lives in feature columns [t*D, (t+1)*D) — the kernels
-    slice it statically."""
-    b, s, h, d = x.shape
-    group = h // hkv
-    return x.reshape(b, s, hkv, group * d).transpose(0, 2, 1, 3).reshape(
-        b * hkv, s, group * d)
+def _pack(h: int, hkv: int, d: int) -> int:
+    """KV heads one grid cell owns: 2 at head size 64 when K/V have as many
+    heads as Q (BERT, ViT), else 1. A PAIR of 64-lane heads is one 128-lane
+    column block of the projection's own [B, S, H*D] rows, so the kernels
+    take q, k, v, o and every gradient as they lie in HBM — no transpose on
+    the way in or out — and no VMEM tile is half empty. Head size 128 and
+    the GQA shapes (a KV head's block would be 64 lanes of a wider row:
+    not a legal block) go through :func:`_cells`'s folded view."""
+    return 2 if d == 64 and h == hkv and h % 2 == 0 else 1
 
 
-def _unfold_q(x: jax.Array, b: int, hkv: int, s: int) -> jax.Array:
-    """Inverse of :func:`_fold_q` (back to [B, S, H, D] given head_dim from
-    the caller's reshape)."""
-    gd = x.shape[-1]
-    return x.reshape(b, hkv, s, gd).transpose(0, 2, 1, 3)
+def _cells(x: jax.Array, hkv: int, pack: int) -> jax.Array:
+    """The kernels' view of a [B, S, H*D] operand: [rows, S, width], a grid
+    cell reading one (1, block, pack*group*D) column block of a row.
+
+    ``pack > 1``: *x* itself; a row holds ``hkv // pack`` cells side by
+    side (:func:`_cell_index`).
+    ``pack == 1``: [B*hkv, S, group*D], one cell a row. The query heads of
+    one KV group are adjacent in a row of *x*, so regrouping it into (hkv,
+    group*D) is a free reinterpretation; the data movement is the batch x
+    head transpose. Head t of a cell lives in feature columns
+    [t*D, (t+1)*D) either way — the kernels slice it statically."""
+    if pack > 1:
+        return x
+    b, s, f = x.shape
+    return x.reshape(b, s, hkv, f // hkv).transpose(0, 2, 1, 3).reshape(
+        b * hkv, s, f // hkv)
+
+
+def _uncells(x: jax.Array, b: int, hkv: int, pack: int) -> jax.Array:
+    """Inverse of :func:`_cells`: back to [B, S, H*D]."""
+    if pack > 1:
+        return x
+    _, s, w = x.shape
+    return x.reshape(b, hkv, s, w).transpose(0, 2, 1, 3).reshape(
+        b, s, hkv * w)
+
+
+def _cell_index(hkv: int, pack: int):
+    """(cell, block) -> block index into a :func:`_cells` view."""
+    if pack > 1:
+        per_row = hkv // pack
+        return lambda g, blk: (g // per_row, blk, g % per_row)
+    return lambda g, blk: (g, blk, 0)
+
+
+def _kv_heads(k_ref, v_ref, rows, pack: int, d: int) -> list:
+    """[(k, v)] per KV head of the cell: *rows* of its static d columns."""
+    return [(k_ref[0, rows, u * d:(u + 1) * d],
+             v_ref[0, rows, u * d:(u + 1) * d]) for u in range(pack)]
 
 
 # ---------------------------------------------------------------- forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
                 scale: float, causal: bool, block_k: int, sb: int,
-                n_sb: int, off: int, segments: bool, group: int, d: int):
-    """One (batch·KV-head, q-block, K/V-superblock) grid cell. The
-    superblock (sb positions of K and V) is VMEM-resident and serves the
-    WHOLE query-head group: q_ref is [1, block_q, group*d] and the kernel
-    unrolls the group, each head slicing its static feature columns and
-    carrying its own online-softmax (m, l, acc) — so under GQA each K/V
-    byte fetched from HBM feeds ``group`` heads of work. Masks are built
+                n_sb: int, off: int, segments: bool, group: int, pack: int,
+                d: int):
+    """One (cell, q-block, K/V-superblock) grid cell; a cell is ``pack`` KV
+    heads of one batch row (:func:`_pack`). The superblock (sb positions of
+    K and V) is VMEM-resident and serves the WHOLE query-head group of each
+    of its KV heads: q_ref is [1, block_q, pack*group*d] and the kernel
+    unrolls the heads, each slicing its static feature columns (query head
+    t reads KV head t // group) and carrying its own online-softmax
+    (m, l, acc) — so under GQA each K/V byte fetched from HBM feeds
+    ``group`` heads of work. Masks are built
     once per fine block and shared across the group (positions are
     head-independent). Short sequences (Sk <= superblock) take exactly one
     grid step — the fast resident path; longer sequences stream superblocks
@@ -169,7 +220,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
     # path; f32 inputs would run the systolic array below peak) with f32
     # accumulation via preferred_element_type; the softmax scale applies to
     # the f32 scores.
-    qh = [q_ref[0, :, t * d:(t + 1) * d] for t in range(group)]
+    heads = pack * group
+    qh = [q_ref[0, :, t * d:(t + 1) * d] for t in range(heads)]
 
     def n_inner():
         if causal:
@@ -185,9 +237,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
 
     def make_body(general_mask: bool, bias):
         def body(j, carry):
-            k = k_ref[0, pl.ds(j * block_k, block_k), :]
-            v = v_ref[0, pl.ds(j * block_k, block_k), :]
-            mask = None                  # shared by the whole head group
+            kv = _kv_heads(k_ref, v_ref, pl.ds(j * block_k, block_k), pack, d)
+            mask = None                  # shared by every head of the cell
             if general_mask:
                 row = qi * block_q + jax.lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 0)
@@ -200,8 +251,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
                 seg_ok = sq_ids[:, None] == sk_ids[None, :]
                 mask = seg_ok if mask is None else mask & seg_ok
             out = []
-            for t in range(group):
+            for t in range(heads):
                 m, l, acc = carry[t]
+                k, v = kv[t // group]
                 s = jax.lax.dot_general(
                     qh[t], k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale
@@ -232,7 +284,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
         return body
 
     def emit(carry):
-        for t in range(group):
+        for t in range(heads):
             m, l, acc = carry[t]
             norm = jnp.maximum(l, 1e-30)
             o_ref[0, :, t * d:(t + 1) * d] = (
@@ -246,7 +298,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
         init = tuple((jnp.full((block_q,), NEG_INF, jnp.float32),
                       jnp.zeros((block_q,), jnp.float32),
                       jnp.zeros((block_q, d), jnp.float32))
-                     for _ in range(group))
+                     for _ in range(heads))
         if diag_split:
             tri = _causal_tri(block_q, block_k)
             carry = jax.lax.fori_loop(0, qi, make_body(False, None), init)
@@ -267,10 +319,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
     stream_split = _stream_split(causal, off, segments, block_q, block_k)
 
     def read_carry():
-        return tuple((m_s[t], l_s[t], acc_s[t]) for t in range(group))
+        return tuple((m_s[t], l_s[t], acc_s[t]) for t in range(heads))
 
     def write_carry(carry):
-        for t in range(group):
+        for t in range(heads):
             m_s[t], l_s[t], acc_s[t] = carry[t]
 
     @pl.when(run)
@@ -297,10 +349,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
         emit(read_carry())
 
 
-def _seg_specs(hkv: int, block_q: int, sb_k: int):
-    """BlockSpecs for segment-id arrays on the (b*hkv, q-blocks,
+def _seg_specs(per_row: int, block_q: int, sb_k: int):
+    """BlockSpecs for segment-id arrays on the (cells, q-blocks,
     k-superblocks) grid: q ids per q block, k ids per K superblock (ids are
-    per-batch — every head of the group shares them).
+    per-batch — the ``per_row`` cells of a batch row share them).
 
     Segments ride as [B, 1, S]: TPU block rules constrain the LAST TWO dims
     (8/128-divisible or full), so a [B, S] layout would make the B dim a
@@ -308,16 +360,16 @@ def _seg_specs(hkv: int, block_q: int, sb_k: int):
     length-1 middle dim absorbs that constraint (same trick as lse).
     """
     return [
-        pl.BlockSpec((1, 1, block_q), lambda g, i, j: (g // hkv, 0, i)),
-        pl.BlockSpec((1, 1, sb_k), lambda g, i, j: (g // hkv, 0, j)),
+        pl.BlockSpec((1, 1, block_q), lambda g, i, j: (g // per_row, 0, i)),
+        pl.BlockSpec((1, 1, sb_k), lambda g, i, j: (g // per_row, 0, j)),
     ]
 
 
 def _compiler_params(interpret):
-    # batch×heads is embarrassingly parallel; the q/k block dims carry
+    # Cells are embarrassingly parallel; the q/k block dims carry
     # scratch state across iterations, so they stay sequential. The scoped
-    # VMEM limit is raised above the 16 MiB default: the GQA group-unrolled
-    # blocks (per-head f32 score/prob tiles plus double-buffered
+    # VMEM limit is raised above the 16 MiB default: the unrolled heads of
+    # a cell (per-head f32 score/prob tiles plus double-buffered
     # superblocks) legitimately peak past 16 MiB on the 12/4 flagship,
     # well within the chip's physical VMEM.
     if interpret:
@@ -327,54 +379,51 @@ def _compiler_params(interpret):
         vmem_limit_bytes=64 * 1024 * 1024)
 
 
-def _fwd(q, k, v, segq, segk, *, causal, scale, interpret):
-    b, sq, h, d = q.shape
-    sk, hkv = k.shape[1], k.shape[2]
+def _fwd(q, k, v, segs, d, causal, scale, interpret):
+    """q [B, Sq, H*d], k and v [B, Sk, Hkv*d], *segs* the segment-id pair
+    or () -> (o [B, Sq, H*d], lse [B, H, Sq])."""
+    b, sq, f = q.shape
+    sk, h, hkv = k.shape[1], f // d, k.shape[2] // d
     group = h // hkv                 # query heads sharing one KV head
+    pack = _pack(h, hkv, d)
+    heads, n_cells = pack * group, b * hkv // pack
     block_q, block_k = _block_sizes(sq, sk)
     sb = _superblock(sk)
-    block_k = min(block_k, sb)      # fine blocks tile WITHIN the superblock
     n_sb = sk // sb
-    # Kernel layout: Q folds its KV group into the feature dim (_fold_q —
-    # same transpose cost as the plain MHA fold); K/V fold batch×KV-heads
-    # and are NEVER repeated to query-head count.
-    qt = _fold_q(q, hkv)                              # [b*hkv, sq, group*d]
-    kt = k.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d)
-    segments = segq is not None
+    # K/V are NEVER repeated to query-head count in either view.
+    qt, kt, vt = (_cells(x, hkv, pack) for x in (q, k, v))
+    at = _cell_index(hkv, pack)
 
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_k=block_k, sb=sb, n_sb=n_sb,
-                               off=sk - sq, segments=segments, group=group,
-                               d=d)
-    in_specs = [
-        pl.BlockSpec((1, block_q, group * d), lambda g, i, j: (g, i, 0)),
-        pl.BlockSpec((1, sb, d), lambda g, i, j: (g, j, 0)),
-        pl.BlockSpec((1, sb, d), lambda g, i, j: (g, j, 0)),
-    ]
+                               off=sk - sq, segments=bool(segs), group=group,
+                               pack=pack, d=d)
+    q_spec = pl.BlockSpec((1, block_q, heads * d), lambda g, i, j: at(g, i))
+    kv_spec = pl.BlockSpec((1, sb, pack * d), lambda g, i, j: at(g, j))
+    in_specs = [q_spec, kv_spec, kv_spec]
     operands = [qt, kt, vt]
-    if segments:
-        in_specs += _seg_specs(hkv, block_q, sb)
-        operands += [segq[:, None, :], segk[:, None, :]]   # [B,1,S] layout
+    if segs:
+        in_specs += _seg_specs(hkv // pack, block_q, sb)
+        operands += [x[:, None, :] for x in segs]          # [B,1,S] layout
     o, lse = pl.pallas_call(
         kernel,
-        grid=(b * hkv, sq // block_q, n_sb),
+        grid=(n_cells, sq // block_q, n_sb),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, group * d), lambda g, i, j: (g, i, 0)),
-            # lse rides as [b*hkv, group, sq] with a (1, group, block_q)
+            q_spec,
+            # lse rides as [cells, heads, sq] with a (1, heads, block_q)
             # block: the last two dims are (full, 128-multiple) — legal —
             # and head t writes row t.
-            pl.BlockSpec((1, group, block_q), lambda g, i, j: (g, 0, i)),
+            pl.BlockSpec((1, heads, block_q), lambda g, i, j: (g, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * hkv, sq, group * d), q.dtype),
-            jax.ShapeDtypeStruct((b * hkv, group, sq), jnp.float32),
+            jax.ShapeDtypeStruct(qt.shape, q.dtype),
+            jax.ShapeDtypeStruct((n_cells, heads, sq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((group, block_q), jnp.float32),     # running max m
-            pltpu.VMEM((group, block_q), jnp.float32),     # running sum l
-            pltpu.VMEM((group, block_q, d), jnp.float32),  # unnormalized acc
+            pltpu.VMEM((heads, block_q), jnp.float32),     # running max m
+            pltpu.VMEM((heads, block_q), jnp.float32),     # running sum l
+            pltpu.VMEM((heads, block_q, d), jnp.float32),  # unnormalized acc
         ],
         compiler_params=_compiler_params(interpret),
         cost_estimate=pl.CostEstimate(
@@ -382,19 +431,22 @@ def _fwd(q, k, v, segq, segk, *, causal, scale, interpret):
             bytes_accessed=(qt.size + kt.size + vt.size) * qt.dtype.itemsize,
             transcendentals=b * h * sq * sk),
         interpret=interpret,
+        name="flash_attn_fwd",
     )(*operands)
-    return _unfold_q(o, b, hkv, sq).reshape(b, sq, h, d), lse
+    return _uncells(o, b, hkv, pack), lse.reshape(b, h, sq)
 
 
 # ---------------------------------------------------------------- backward
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                    scale: float, causal: bool, block_k: int, sb: int,
-                   n_sb: int, off: int, segments: bool, group: int, d: int):
-    """dQ on the (b*h_kv, q-blocks, K/V-superblocks) grid: one grid cell
-    serves the whole query-head group from the resident K/V superblock —
-    q/do are [1, block_q, group*d] with static per-head feature slices,
-    lse/delta are [1, group, block_q] rows; the per-head dq accumulators
+                   n_sb: int, off: int, segments: bool, group: int,
+                   pack: int, d: int):
+    """dQ on the (cells, q-blocks, K/V-superblocks) grid: one grid cell
+    serves every query head of its KV heads from the resident K/V
+    superblock — q/do are [1, block_q, pack*group*d] with static per-head
+    feature slices, lse/delta are [1, pack*group, block_q] rows; the
+    per-head dq accumulators
     carry across superblocks in VMEM scratch; fine k blocks loop inside
     the resident superblock (registers)."""
     if segments:
@@ -409,10 +461,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     last_row = qi * block_q + block_q - 1 + off
     # bf16 matmul inputs / f32 accumulation (see _fwd_kernel); the softmax
     # scale folds into ds once instead of pre-scaling q and post-scaling dq.
-    qh = [q_ref[0, :, t * d:(t + 1) * d] for t in range(group)]
-    doh = [do_ref[0, :, t * d:(t + 1) * d] for t in range(group)]
-    lse = [lse_ref[0, t] for t in range(group)]
-    delta = [delta_ref[0, t] for t in range(group)]
+    heads = pack * group
+    qh = [q_ref[0, :, t * d:(t + 1) * d] for t in range(heads)]
+    doh = [do_ref[0, :, t * d:(t + 1) * d] for t in range(heads)]
+    lse = [lse_ref[0, t] for t in range(heads)]
+    delta = [delta_ref[0, t] for t in range(heads)]
 
     def n_inner():
         if causal:
@@ -425,8 +478,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
     def make_body(general_mask: bool, bias):
         def body(j, dq):
-            k = k_ref[0, pl.ds(j * block_k, block_k), :]
-            v = v_ref[0, pl.ds(j * block_k, block_k), :]
+            kv = _kv_heads(k_ref, v_ref, pl.ds(j * block_k, block_k), pack, d)
             mask = None
             if general_mask:
                 row = qi * block_q + jax.lax.broadcasted_iota(
@@ -440,7 +492,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                 seg_ok = sq_ids[:, None] == sk_ids[None, :]
                 mask = seg_ok if mask is None else mask & seg_ok
             out = []
-            for t in range(group):
+            for t in range(heads):
+                k, v = kv[t // group]
                 s = jax.lax.dot_general(
                     qh[t], k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale
@@ -464,12 +517,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         return body
 
     def emit(dq):
-        for t in range(group):
+        for t in range(heads):
             dq_ref[0, :, t * d:(t + 1) * d] = dq[t].astype(dq_ref.dtype)
 
     if resident:
         init = tuple(jnp.zeros((block_q, d), jnp.float32)
-                     for _ in range(group))
+                     for _ in range(heads))
         if diag_split:
             tri = _causal_tri(block_q, block_k)
             dq = jax.lax.fori_loop(0, qi, make_body(False, None), init)
@@ -488,7 +541,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
     @pl.when(run)
     def _superblock_body():
-        carry = tuple(dq_s[t] for t in range(group))
+        carry = tuple(dq_s[t] for t in range(heads))
         # Streaming diagonal-split mirrors _fwd_kernel's.
         if _stream_split(causal, off, segments, block_q, block_k):
             has_diag = jnp.logical_and(base <= qi * block_q,
@@ -504,22 +557,24 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         else:
             carry = jax.lax.fori_loop(0, n_inner(),
                                       make_body(causal, None), carry)
-        for t in range(group):
+        for t in range(heads):
             dq_s[t] = carry[t]
 
     @pl.when(kb == n_sb - 1)
     def _emit():
-        emit(tuple(dq_s[t] for t in range(group)))
+        emit(tuple(dq_s[t] for t in range(heads)))
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                     scale: float, causal: bool, block_q: int, sb: int,
-                    n_sb: int, off: int, segments: bool, group: int, d: int):
-    """dK/dV on the (b*h_kv, k-blocks, Q-superblocks) grid: each grid cell
-    owns one KV head's k block; the streamed Q/dO superblocks carry the
-    WHOLE query-head group in the feature dim ([1, sb, group*d], static
-    per-head slices), so dk/dv accumulate the full GQA head-group sum in
-    one pass — written once at KV-head count with no post-hoc reduction.
+                    n_sb: int, off: int, segments: bool, group: int,
+                    pack: int, d: int):
+    """dK/dV on the (cells, k-blocks, Q-superblocks) grid: each grid cell
+    owns its KV heads' k block; the streamed Q/dO superblocks carry the
+    WHOLE query-head group of each in the feature dim ([1, sb,
+    pack*group*d], static per-head slices), so dk/dv accumulate the full
+    GQA head-group sum in one pass — written once at KV-head count with no
+    post-hoc reduction.
     Fine q blocks loop inside the resident superblock; dk/dv accumulate in
     VMEM scratch across superblocks. Masks are built once per fine block
     and shared across the group."""
@@ -535,8 +590,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     first_col = ki * block_k
     # bf16 matmul inputs / f32 accumulation; scale folds into ds (see
     # _bwd_dq_kernel).
-    k = k_ref[0]
-    v = v_ref[0]
+    kv = _kv_heads(k_ref, v_ref, slice(None), pack, d)
 
     def first_inner():
         if causal:
@@ -551,7 +605,6 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
     def make_body(general_mask: bool, bias):
         def body(i, carry):
-            dk, dv = carry
             mask = None
             if general_mask:
                 row = base + i * block_q + jax.lax.broadcasted_iota(
@@ -564,7 +617,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                 sk_ids = segk_ref[0, 0]
                 seg_ok = sq_ids[:, None] == sk_ids[None, :]
                 mask = seg_ok if mask is None else mask & seg_ok
-            for t in range(group):
+            carry = list(carry)
+            for t in range(pack * group):
+                (k, v), (dk, dv) = kv[t // group], carry[t // group]
                 q = q_ref[0, pl.ds(i * block_q, block_q), t * d:(t + 1) * d]
                 do = do_ref[0, pl.ds(i * block_q, block_q), t * d:(t + 1) * d]
                 lse = lse_ref[0, t, pl.ds(i * block_q, block_q)]
@@ -591,23 +646,28 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                 dk = dk + jax.lax.dot_general(
                     ds, q, (((0,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
-            return dk, dv
+                carry[t // group] = (dk, dv)
+            return tuple(carry)
         return body
 
+    def emit(carry):
+        for u, (dk, dv) in enumerate(carry):
+            dk_ref[0, :, u * d:(u + 1) * d] = dk.astype(dk_ref.dtype)
+            dv_ref[0, :, u * d:(u + 1) * d] = dv.astype(dv_ref.dtype)
+
     if resident:
-        zero = lambda a: jnp.zeros(a.shape, jnp.float32)
-        init = (zero(k), zero(v))
+        zero = jnp.zeros((block_k, d), jnp.float32)
+        init = tuple((zero, zero) for _ in range(pack))
         if diag_split:
             # Diagonal q block i == ki (triangular bias), full blocks after.
             tri = _causal_tri(block_q, block_k)
-            dk, dv = make_body(False, tri)(ki, init)
-            dk, dv = jax.lax.fori_loop(ki + 1, sb // block_q,
-                                       make_body(False, None), (dk, dv))
+            carry = make_body(False, tri)(ki, init)
+            carry = jax.lax.fori_loop(ki + 1, sb // block_q,
+                                      make_body(False, None), carry)
         else:
-            dk, dv = jax.lax.fori_loop(first_inner(), sb // block_q,
-                                       make_body(causal, None), init)
-        dk_ref[0] = dk.astype(dk_ref.dtype)
-        dv_ref[0] = dv.astype(dv_ref.dtype)
+            carry = jax.lax.fori_loop(first_inner(), sb // block_q,
+                                      make_body(causal, None), init)
+        emit(carry)
         return
 
     @pl.when(qb == 0)
@@ -621,7 +681,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
     @pl.when(run)
     def _superblock_body():
-        carry = (dk_s[...], dv_s[...])
+        carry = tuple((dk_s[u], dv_s[u]) for u in range(pack))
         # Streaming diagonal-split: the diagonal q block (when this Q
         # superblock holds it) is exactly first_inner(); later blocks see
         # this k block in full.
@@ -639,131 +699,204 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         else:
             carry = jax.lax.fori_loop(first_inner(), sb // block_q,
                                       make_body(causal, None), carry)
-        dk_s[...], dv_s[...] = carry
+        for u in range(pack):
+            dk_s[u], dv_s[u] = carry[u]
 
     @pl.when(qb == n_sb - 1)
     def _emit():
-        dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+        emit(tuple((dk_s[u], dv_s[u]) for u in range(pack)))
 
 
-def _bwd(causal, scale, interpret, res, g):
-    q, k, v, segq, segk, o, lse = res
-    b, sq, h, d = q.shape
-    sk, hkv = k.shape[1], k.shape[2]
+def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, *rest,
+                      scale: float, causal: bool, off: int, segments: bool,
+                      group: int, pack: int, d: int):
+    """dQ, dK and dV of one cell whose WHOLE sequence is resident (one query
+    block, one key block): nothing accumulates across grid cells, so the
+    split into a dQ and a dK/dV kernel — which exists to avoid races across
+    the grid — would only recompute the scores and dP a second time. One
+    recomputation of the probabilities per head feeds all three gradients
+    (five products of S x S x d a head where the two kernels make seven).
+    With a whole row of P and dP in hand, the softmax Jacobian's row term is
+    rowsum(P * dP) — what the einsum path's backward computes — so neither
+    the output nor a delta array is read."""
+    if segments:
+        segq_ref, segk_ref, dq_ref, dk_ref, dv_ref = rest
+    else:
+        dq_ref, dk_ref, dv_ref = rest
+    sq, sk = q_ref.shape[1], k_ref.shape[1]
+    mask = None                          # shared by every head of the cell
+    if causal:
+        mask = (jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0) + off
+                >= jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1))
+    if segments:
+        seg_ok = segq_ref[0, 0][:, None] == segk_ref[0, 0][None, :]
+        mask = seg_ok if mask is None else mask & seg_ok
+    for u, (k, v) in enumerate(_kv_heads(k_ref, v_ref, slice(None), pack, d)):
+        dk = jnp.zeros((sk, d), jnp.float32)
+        dv = jnp.zeros((sk, d), jnp.float32)
+        for t in range(u * group, (u + 1) * group):
+            q = q_ref[0, :, t * d:(t + 1) * d]
+            do = do_ref[0, :, t * d:(t + 1) * d]
+            # bf16 matmul inputs / f32 accumulation; scale folds into ds
+            # (see _bwd_dq_kernel).
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            if mask is not None:
+                s = jnp.where(mask, s, NEG_INF)
+            p = jnp.exp(s - lse_ref[0, t][:, None])
+            if segments or off < 0:
+                # Fully-masked rows have a degenerate lse (see _fwd_kernel).
+                p = jnp.where(s <= NEG_INF / 2, 0.0, p)
+            dv = dv + jax.lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(
+                do, v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            delta = jnp.sum(p * dp, axis=-1, keepdims=True)
+            ds = (p * (dp - delta) * scale).astype(q.dtype)
+            dq_ref[0, :, t * d:(t + 1) * d] = jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(dq_ref.dtype)
+            dk = dk + jax.lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        dk_ref[0, :, u * d:(u + 1) * d] = dk.astype(dk_ref.dtype)
+        dv_ref[0, :, u * d:(u + 1) * d] = dv.astype(dv_ref.dtype)
+
+
+def _resident(sq: int, sk: int) -> bool:
+    """One query block and one key block hold the sequence: the backward
+    pass is ONE kernel and the forward's output is not among its residuals.
+    """
+    return (sq, sk) == _block_sizes(sq, sk)
+
+
+def _bwd(q, k, v, segs, g, lse, o, d, causal, scale, interpret):
+    """-> (dq, dk, dv) in the operands' [B, S, heads*d] layout. *o*, the
+    forward's output, is read only where the sequence is not
+    :func:`_resident` (None there)."""
+    b, sq, f = q.shape
+    sk, h, hkv = k.shape[1], f // d, k.shape[2] // d
     group = h // hkv
+    pack = _pack(h, hkv, d)
+    heads, n_cells = pack * group, b * hkv // pack
     block_q, block_k = _block_sizes(sq, sk)
     sb_k, sb_q = _superblock(sk), _superblock(sq)
-    block_k = min(block_k, sb_k)    # fine blocks tile WITHIN the superblock
-    block_q = min(block_q, sb_q)
-    segments = segq is not None
+    segments = bool(segs)
+    per_row = hkv // pack
 
-    kvfold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * hkv, x.shape[1], d)
-    qt, dot = _fold_q(q, hkv), _fold_q(g, hkv)
-    kt, vt = kvfold(k), kvfold(v)
+    qt, kt, vt, dot = (_cells(x, hkv, pack) for x in (q, k, v, g))
+    at = _cell_index(hkv, pack)
+    lse = lse.reshape(n_cells, heads, sq)
+    seg_operands = [x[:, None, :] for x in segs]
+    statics = dict(scale=scale, causal=causal, off=sk - sq,
+                   segments=segments, group=group, pack=pack, d=d)
+    q_shape = jax.ShapeDtypeStruct(qt.shape, q.dtype)
+    kv_shapes = [jax.ShapeDtypeStruct(kt.shape, k.dtype),
+                 jax.ShapeDtypeStruct(vt.shape, v.dtype)]
+
+    if _resident(sq, sk):
+        q_spec = pl.BlockSpec((1, sq, heads * d), lambda g_, i, j: at(g_, 0))
+        kv_spec = pl.BlockSpec((1, sk, pack * d), lambda g_, i, j: at(g_, 0))
+        row_spec = pl.BlockSpec((1, heads, sq), lambda g_, i, j: (g_, 0, 0))
+        specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec]
+        if segments:
+            specs += _seg_specs(per_row, sq, sk)
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(_bwd_fused_kernel, **statics),
+            grid=(n_cells, 1, 1),
+            in_specs=specs,
+            out_specs=[q_spec, kv_spec, kv_spec],
+            out_shape=[q_shape] + kv_shapes,
+            compiler_params=_compiler_params(interpret),
+            interpret=interpret,
+            name="flash_attn_bwd",
+        )(qt, kt, vt, dot, lse, *seg_operands)
+        return tuple(_uncells(x, b, hkv, pack) for x in (dq, dk, dv))
+
     # delta_i = rowsum(dO_i * O_i) — the softmax-jacobian diagonal term,
-    # per head: [b*hkv, group, sq] rows match the lse layout.
+    # per head: [cells, heads, sq] rows match the lse layout.
     delta = jnp.sum(
-        dot.astype(jnp.float32).reshape(b * hkv, sq, group, d)
-        * _fold_q(o, hkv).astype(jnp.float32).reshape(b * hkv, sq, group, d),
-        axis=-1).transpose(0, 2, 1)
+        (g.astype(jnp.float32) * o.astype(jnp.float32)).reshape(b, sq, h, d),
+        axis=-1).transpose(0, 2, 1).reshape(n_cells, heads, sq)
+    operands = [qt, kt, vt, dot, lse, delta] + seg_operands
 
-    # One dq grid cell per (batch, KV head): q/do carry the whole query-head
-    # group in the feature dim, K/V load once per group.
-    dq_specs = [
-        pl.BlockSpec((1, block_q, group * d), lambda g_, i, j: (g_, i, 0)),
-        pl.BlockSpec((1, sb_k, d), lambda g_, i, j: (g_, j, 0)),
-        pl.BlockSpec((1, sb_k, d), lambda g_, i, j: (g_, j, 0)),
-        pl.BlockSpec((1, block_q, group * d), lambda g_, i, j: (g_, i, 0)),
-        pl.BlockSpec((1, group, block_q), lambda g_, i, j: (g_, 0, i)),
-        pl.BlockSpec((1, group, block_q), lambda g_, i, j: (g_, 0, i)),
-    ]
-    dq_operands = [qt, kt, vt, dot, lse, delta]
+    # One dq grid cell per (batch, KV heads of a cell): q/do carry every
+    # query head in the feature dim, K/V load once per group.
+    q_spec = pl.BlockSpec((1, block_q, heads * d), lambda g_, i, j: at(g_, i))
+    kv_spec = pl.BlockSpec((1, sb_k, pack * d), lambda g_, i, j: at(g_, j))
+    row_spec = pl.BlockSpec((1, heads, block_q), lambda g_, i, j: (g_, 0, i))
+    dq_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
     if segments:
-        dq_specs += _seg_specs(hkv, block_q, sb_k)
-        dq_operands += [segq[:, None, :], segk[:, None, :]]
+        dq_specs += _seg_specs(per_row, block_q, sb_k)
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_k=block_k, sb=sb_k, n_sb=sk // sb_k,
-                          off=sk - sq, segments=segments, group=group, d=d),
-        grid=(b * hkv, sq // block_q, sk // sb_k),
+        functools.partial(_bwd_dq_kernel, block_k=block_k, sb=sb_k,
+                          n_sb=sk // sb_k, **statics),
+        grid=(n_cells, sq // block_q, sk // sb_k),
         in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, block_q, group * d),
-                               lambda g_, i, j: (g_, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * hkv, sq, group * d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((group, block_q, d), jnp.float32)],
+        out_specs=q_spec,
+        out_shape=q_shape,
+        scratch_shapes=[pltpu.VMEM((heads, block_q, d), jnp.float32)],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
-    )(*dq_operands)
+        name="flash_attn_dq",
+    )(*operands)
 
-    # dK/dV: grid dim 0 owns one KV head; k blocks in the middle dim; Q/dO
-    # superblocks stream innermost carrying the whole query-head group in
-    # the feature dim, so dk/dv accumulate the GQA sum in scratch and are
+    # dK/dV: grid dim 0 owns a cell's KV heads; k blocks in the middle dim;
+    # Q/dO superblocks stream innermost carrying every query head in the
+    # feature dim, so dk/dv accumulate the GQA sum in scratch and are
     # written once at KV-head count.
-    dkv_specs = [
-        pl.BlockSpec((1, sb_q, group * d), lambda g_, j, i: (g_, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda g_, j, i: (g_, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda g_, j, i: (g_, j, 0)),
-        pl.BlockSpec((1, sb_q, group * d), lambda g_, j, i: (g_, i, 0)),
-        pl.BlockSpec((1, group, sb_q), lambda g_, j, i: (g_, 0, i)),
-        pl.BlockSpec((1, group, sb_q), lambda g_, j, i: (g_, 0, i)),
-    ]
-    dkv_operands = [qt, kt, vt, dot, lse, delta]
+    q_spec = pl.BlockSpec((1, sb_q, heads * d), lambda g_, j, i: at(g_, i))
+    kv_spec = pl.BlockSpec((1, block_k, pack * d), lambda g_, j, i: at(g_, j))
+    row_spec = pl.BlockSpec((1, heads, sb_q), lambda g_, j, i: (g_, 0, i))
+    dkv_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
     if segments:
         dkv_specs += [
-            pl.BlockSpec((1, 1, sb_q), lambda g_, j, i: (g_ // hkv, 0, i)),
-            pl.BlockSpec((1, 1, block_k), lambda g_, j, i: (g_ // hkv, 0, j)),
+            pl.BlockSpec((1, 1, sb_q), lambda g_, j, i: (g_ // per_row, 0, i)),
+            pl.BlockSpec((1, 1, block_k),
+                         lambda g_, j, i: (g_ // per_row, 0, j)),
         ]
-        dkv_operands += [segq[:, None, :], segk[:, None, :]]
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, sb=sb_q, n_sb=sq // sb_q,
-                          off=sk - sq, segments=segments, group=group, d=d),
-        grid=(b * hkv, sk // block_k, sq // sb_q),
+        functools.partial(_bwd_dkv_kernel, block_q=block_q, sb=sb_q,
+                          n_sb=sq // sb_q, **statics),
+        grid=(n_cells, sk // block_k, sq // sb_q),
         in_specs=dkv_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda g_, j, i: (g_, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda g_, j, i: (g_, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * hkv, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * hkv, sk, d), v.dtype),
-        ],
+        out_specs=[kv_spec, kv_spec],
+        out_shape=kv_shapes,
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((pack, block_k, d), jnp.float32),
+            pltpu.VMEM((pack, block_k, d), jnp.float32),
         ],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
-    )(*dkv_operands)
-
-    kvunfold = lambda x: x.reshape(b, hkv, sk, d).transpose(0, 2, 1, 3)
-    none_seg = None if segq is None else np.zeros(segq.shape,
-                                                  jax.dtypes.float0)
-    none_segk = None if segk is None else np.zeros(segk.shape,
-                                                   jax.dtypes.float0)
-    return (_unfold_q(dq, b, hkv, sq).reshape(b, sq, h, d),
-            kvunfold(dk), kvunfold(dv), none_seg, none_segk)
+        name="flash_attn_dkv",
+    )(*operands)
+    return tuple(_uncells(x, b, hkv, pack) for x in (dq, dk, dv))
 
 
 # ---------------------------------------------------------------- public API
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _flash(q, k, v, segq, segk, causal, scale, interpret):
-    o, _ = _fwd(q, k, v, segq, segk, causal=causal, scale=scale,
-                interpret=interpret)
-    return o
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash(q, k, v, segs, d, causal, scale, interpret):
+    return _fwd(q, k, v, segs, d, causal, scale, interpret)[0]
 
 
-def _flash_fwd(q, k, v, segq, segk, causal, scale, interpret):
-    o, lse = _fwd(q, k, v, segq, segk, causal=causal, scale=scale,
-                  interpret=interpret)
-    return o, (q, k, v, segq, segk, o, lse)
+def _flash_fwd(q, k, v, segs, d, causal, scale, interpret):
+    o, lse = _fwd(q, k, v, segs, d, causal, scale, interpret)
+    keep_o = not _resident(q.shape[1], k.shape[1])
+    return o, (q, k, v, segs, o if keep_o else None, lse)
 
 
-_flash.defvjp(_flash_fwd,
-              lambda causal, scale, interpret, res, g:
-              _bwd(causal, scale, interpret, res, g))
+def _flash_bwd(d, causal, scale, interpret, res, g):
+    q, k, v, segs, o, lse = res
+    dq, dk, dv = _bwd(q, k, v, segs, g, lse, o, d, causal, scale, interpret)
+    return dq, dk, dv, tuple(np.zeros(x.shape, jax.dtypes.float0)
+                             for x in segs)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -811,5 +944,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     scale = softmax_scale if softmax_scale is not None else q.shape[-1] ** -0.5
     if interpret is None:
         interpret = not on_tpu()
-    return _flash(q, k, v, q_segment_ids, kv_segment_ids, causal, scale,
-                  interpret)
+    segs = () if q_segment_ids is None else (q_segment_ids, kv_segment_ids)
+    # The op works on rows of heads, [B, S, H*D] — the projections' own
+    # layout, and what the custom VJP keeps for the backward pass.
+    (b, sq, _, d), sk = q.shape, k.shape[1]
+    o = _flash(q.reshape(b, sq, hq * d), k.reshape(b, sk, hkv * d),
+               v.reshape(b, sk, hkv * d), segs, d, causal, scale, interpret)
+    return o.reshape(q.shape)

@@ -30,6 +30,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from k8s_distributed_deeplearning_tpu.ops import attention as attention_ops
 from k8s_distributed_deeplearning_tpu.parallel.data_parallel import TrainState
 
 PyTree = Any
@@ -258,7 +259,10 @@ class ShardedTrainer:
             # The two scopes are stable names in the device trace (every
             # operation's ``tf_op`` starts with one of them): device time
             # splits into the model's passes and the optimizer's update.
-            with nn.logical_axis_rules(rules):  # trace-time rule context
+            # Trace-time context: the rules, and the mesh announced to the
+            # one op with no partition rule of its own (the flash kernel).
+            with nn.logical_axis_rules(rules), \
+                    attention_ops.program_mesh(mesh):
                 with jax.named_scope("forward_backward"):
                     (loss, aux), grads = accumulate_gradients(
                         loss_fn, state.params, batch, rng, microbatches,

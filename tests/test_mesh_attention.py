@@ -164,3 +164,100 @@ def test_mesh_attention_broadcast_batch_mask(mesh3):
     # The sharded path actually ran: output lands batch-over-data x fsdp,
     # heads-over-tensor, not the fallback's unsharded layout.
     assert out.sharding.spec == P(("data", "fsdp"), None, "tensor")
+
+
+def _tiny_bert_step(axes, impl, n_devices=4):
+    """One SGD step of a tiny BERT through ShardedTrainer, built the way the
+    benchmark builds it (no ``attention_fn``): -> (loss, new parameters,
+    the step's jaxpr, its compiled text)."""
+    import optax
+
+    from k8s_distributed_deeplearning_tpu.models import bert
+
+    mesh = mesh_lib.make_mesh(axes, devices=jax.devices()[:n_devices])
+    cfg = bert.config_tiny(dim=256, n_heads=4, n_layers=2, mlp_dim=256,
+                           max_seq_len=128, attention_impl=impl,
+                           dtype=jnp.float32)
+    model = bert.BertMLM(cfg)
+
+    def loss(p, batch, r):
+        inputs, targets, w = bert.mask_tokens(
+            batch["tokens"], r, vocab_size=cfg.vocab_size, mask_id=3,
+            mask_prob=0.15)
+        return bert.loss_fn(model, p, {"inputs": inputs, "targets": targets,
+                                       "weights": w})
+    trainer = sharding.ShardedTrainer(loss, optax.sgd(0.1), mesh)
+    state = trainer.init(
+        lambda r: model.init(r, jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.key(0))
+    step = trainer.make_step(donate=False)
+    tokens = jax.random.randint(jax.random.key(1), (8, 128), 4, cfg.vocab_size)
+    args = (state, trainer.shard_batch({"tokens": tokens}), jax.random.key(2))
+    new, value, _ = step(*args)
+    return (float(value), jax.tree.leaves(sharding.unbox(new.params)),
+            jax.make_jaxpr(step)(*args).jaxpr,
+            step.lower(*args).compile().as_text())
+
+
+def _kernel_operands(jaxpr, inside_shard_map=False) -> list:
+    """(name, q operand's shape, traced under a shard_map) per kernel call."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append((eqn.params["name"], eqn.invars[0].aval.shape,
+                          inside_shard_map))
+        inner = inside_shard_map or eqn.primitive.name == "shard_map"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _kernel_operands(sub, inner)
+    return found
+
+
+@pytest.mark.parametrize("axes,rows,lanes", [
+    ({"data": 4}, 2, 256), ({"data": 2, "tensor": 2}, 4, 128)],
+    ids=["data4", "data2-tensor2"])
+def test_sharded_trainer_splits_the_flash_kernel(axes, rows, lanes):
+    """``ShardedTrainer`` announces its mesh to attention at trace time
+    (``ops.attention.program_mesh``), so a step built with NO
+    ``attention_fn`` runs the flash kernels on each device's own rows (and
+    heads): same loss and parameters as the einsum path on the same mesh and
+    as one device, every kernel call under a shard_map with a per-device
+    operand, and nothing gathered for it."""
+    one_loss, one_params, _, _ = _tiny_bert_step({"data": 1}, "xla", 1)
+    ref_loss, ref_params, _, ref_text = _tiny_bert_step(axes, "xla")
+    loss, params, jaxpr, text = _tiny_bert_step(axes, "flash")
+    assert loss == pytest.approx(ref_loss, rel=1e-6)
+    assert loss == pytest.approx(one_loss, rel=1e-6)
+    for got, want, single in zip(params, ref_params, one_params):
+        np.testing.assert_allclose(got, want, atol=2e-6)
+        np.testing.assert_allclose(got, single, atol=2e-6)
+    kernels = _kernel_operands(jaxpr)
+    assert {name for name, _, _ in kernels} == {"flash_attn_fwd",
+                                                "flash_attn_bwd"}
+    # 8 rows of 4 heads x 64 lanes globally
+    assert all(shape == (rows, 128, lanes) and wrapped
+               for _, shape, wrapped in kernels), kernels
+    assert text.count("all-gather") <= ref_text.count("all-gather")
+    if "tensor" not in axes:
+        assert "all-gather" not in text
+
+
+def test_flash_that_does_not_divide_the_mesh_takes_the_einsum_path():
+    """3 rows on a 4-way batch axis: no kernel (it would be replicated), the
+    einsum path, which GSPMD partitions as it can. A trivial mesh: the
+    kernel, unwrapped."""
+    mesh = mesh_lib.make_mesh({"data": 4}, devices=jax.devices()[:4])
+    q, k, v = _qkv(b=3, h=4, hkv=4, d=64)
+
+    def names(mesh):
+        with att.program_mesh(mesh):
+            jaxpr = jax.make_jaxpr(lambda q, k, v: att.multi_head_attention(
+                q, k, v, impl="flash"))(q, k, v).jaxpr
+        return [name for name, _, _ in _kernel_operands(jaxpr)]
+    assert names(mesh) == []
+    assert names(mesh_lib.make_mesh({"data": 1}, devices=jax.devices()[:1])) == [
+        "flash_attn_fwd"]
+    with att.program_mesh(mesh):
+        out = att.multi_head_attention(q, k, v, impl="flash")
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(att.dot_product_attention(q, k, v)),
+        rtol=2e-5, atol=1e-5)

@@ -15,9 +15,27 @@ def _qkv(b=2, sq=64, sk=64, h=2, hkv=None, d=16, seed=0):
             jax.random.normal(ks[2], (b, sk, hkv or h, d)))
 
 
+# The shape classes the kernels adapt to: a head of 16 lanes a grid cell (the
+# folded view), and PAIRS of 64-lane heads (h == hkv) — whole sequence in one
+# block, and streamed in blocks of 128 (_BLOCK_Q/_BLOCK_K narrowed).
+SHAPE_CLASSES = [
+    pytest.param(dict(), None, id="folded"),
+    pytest.param(dict(sq=128, sk=128, h=4, d=64), None, id="pairs-resident"),
+    pytest.param(dict(sq=256, sk=256, h=2, d=64), 128, id="pairs-streamed"),
+]
+
+
+def _narrow_blocks(monkeypatch, block):
+    if block:
+        monkeypatch.setattr(pallas_flash, "_BLOCK_Q", block)
+        monkeypatch.setattr(pallas_flash, "_BLOCK_K", block)
+
+
+@pytest.mark.parametrize("shape,block", SHAPE_CLASSES)
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_matches_reference(causal):
-    q, k, v = _qkv()
+def test_flash_matches_reference(causal, shape, block, monkeypatch):
+    _narrow_blocks(monkeypatch, block)
+    q, k, v = _qkv(**shape)
     ref = attn_ops.dot_product_attention(q, k, v, causal=causal)
     out = pallas_flash.flash_attention(q, k, v, causal=causal, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
@@ -109,9 +127,11 @@ def test_flash_cross_attention_lengths():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
+@pytest.mark.parametrize("shape,block", SHAPE_CLASSES)
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_grads_match_reference(causal):
-    q, k, v = _qkv(sq=32, sk=32)
+def test_flash_grads_match_reference(causal, shape, block, monkeypatch):
+    _narrow_blocks(monkeypatch, block)
+    q, k, v = _qkv(**(shape or dict(sq=32, sk=32)))
 
     def loss_ref(q, k, v):
         o = attn_ops.dot_product_attention(q, k, v, causal=causal)
@@ -127,6 +147,54 @@ def test_flash_grads_match_reference(causal):
     for name, a, b in zip("qkv", g_ref, g_fl):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=1e-4,
                                    err_msg=f"d{name} mismatch")
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, inner jaxprs included, in order."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _pallas_calls(jaxpr) -> list:
+    return [e.params["name"] for e in _eqns(jaxpr)
+            if e.primitive.name == "pallas_call"]
+
+
+def _big_transposes(jaxpr, size) -> list:
+    return [e for e in _eqns(jaxpr) if e.primitive.name == "transpose"
+            and e.invars[0].aval.size >= size]
+
+
+def _grad_jaxpr(q, k, v, **kw):
+    return jax.make_jaxpr(jax.grad(lambda q, k, v: (pallas_flash.flash_attention(
+        q, k, v, interpret=True, **kw) ** 2).sum(), argnums=(0, 1, 2)))(q, k, v).jaxpr
+
+
+def test_pairs_of_heads_take_rows_of_heads_without_a_transpose():
+    """Head size 64 with as many KV heads as query heads: a pair of heads
+    is one 128-lane column block of [B, S, H*D], so no operand, output or
+    gradient is transposed on its way to or from the kernels. The folded
+    view (GQA, other head sizes) does transpose."""
+    q, k, v = _qkv(sq=128, sk=128, h=4, d=64)
+    assert not _big_transposes(_grad_jaxpr(q, k, v), q.size)
+    q, k, v = _qkv(sq=128, sk=128, h=4, hkv=2, d=64)
+    assert _big_transposes(_grad_jaxpr(q, k, v), k.size)
+
+
+def test_resident_sequence_lowers_one_backward_kernel(monkeypatch):
+    """One query block and one key block hold the sequence: dq, dk and dv
+    come from ONE recomputation of the probabilities. A streamed sequence
+    keeps the dq and the dk/dv kernel (each accumulates over its own grid)."""
+    q, k, v = _qkv(sq=256, sk=256, h=2, d=64)
+    assert _pallas_calls(_grad_jaxpr(q, k, v)) == [
+        "flash_attn_fwd", "flash_attn_bwd"]
+    assert _pallas_calls(_grad_jaxpr(q, k, v, causal=True)) == [
+        "flash_attn_fwd", "flash_attn_bwd"]
+    _narrow_blocks(monkeypatch, 128)
+    assert _pallas_calls(_grad_jaxpr(q, k, v)) == [
+        "flash_attn_fwd", "flash_attn_dq", "flash_attn_dkv"]
 
 
 def test_flash_bf16_close_to_f32_reference():
@@ -246,20 +314,30 @@ def test_flash_segment_ids_validation():
 
 
 def test_default_impl_rule():
-    """The impl="auto" crossover rule (measured on a v5e in round 4):
-    flash on TPU at S>=1024 (128-aligned), XLA otherwise and always on CPU."""
+    """The impl="auto" rule as measured on a TPU v5e (PERF.md §6, PR 30):
+    flash on a TPU when both lengths are whole multiples of the kernel's
+    512-token block and the head size is one it tiles; XLA otherwise, and
+    always on a CPU."""
     from k8s_distributed_deeplearning_tpu.ops.attention import default_impl
-    assert default_impl(2048, platform="tpu") == "flash"
-    assert default_impl(1024, platform="tpu") == "flash"
-    assert default_impl(512, platform="tpu") == "xla"       # short seq
-    assert default_impl(1100, platform="tpu") == "xla"      # not 128-aligned
-    assert default_impl(4096, platform="cpu") == "xla"      # interpret mode
-    assert default_impl(4096) == "xla"                      # CI runs on CPU
+    assert default_impl(512, 512, 64, platform="tpu") == "flash"    # BERT's
+    assert default_impl(512, None, 128, platform="tpu") == "flash"
+    assert default_impl(1024, None, 64, platform="tpu") == "flash"
+    assert default_impl(2048, None, 128, platform="tpu") == "flash"
+    assert default_impl(384, None, 64, platform="tpu") == "xla"     # blocks of 128
+    assert default_impl(256, None, 64, platform="tpu") == "xla"     # a tie: stays
+    assert default_impl(640, None, 64, platform="tpu") == "xla"
+    assert default_impl(1100, None, 64, platform="tpu") == "xla"    # unaligned
+    assert default_impl(197, None, 64, platform="tpu") == "xla"     # ViT
+    assert default_impl(512, None, 80, platform="tpu") == "xla"     # odd head size
+    assert default_impl(512, None, 16, platform="tpu") == "xla"
+    assert default_impl(4096, None, 128, platform="cpu") == "xla"   # interpret mode
+    assert default_impl(4096, None, 128) == "xla"                   # CI runs on CPU
     # Cross-attention: BOTH lengths must tile well (ADVICE r2 item 4).
-    assert default_impl(2048, 2048, platform="tpu") == "flash"
-    assert default_impl(2048, 1100, platform="tpu") == "xla"
-    assert default_impl(2048, 512, platform="tpu") == "xla"
-    assert default_impl(512, 4096, platform="tpu") == "xla"
+    assert default_impl(2048, 2048, 128, platform="tpu") == "flash"
+    assert default_impl(2048, 512, 128, platform="tpu") == "flash"
+    assert default_impl(2048, 1100, 128, platform="tpu") == "xla"
+    assert default_impl(2048, 256, 128, platform="tpu") == "xla"
+    assert default_impl(256, 4096, 128, platform="tpu") == "xla"
 
 
 def test_auto_impl_dispatches_and_matches():

@@ -1,11 +1,13 @@
-"""The serving path's kernel compiled for the chip, without the chip.
+"""The kernels of the main paths compiled for the chip, without the chip.
 
 The Pallas interpreter (every other kernel test) cannot see what Mosaic
 refuses: a copy too narrow for its tiling, a slice off the tile grid, more
 VMEM than a kernel may hold. The TPU compiler is installed here and compiles
 for a v5e that is described, not attached — about two seconds a kernel — so
 these cases hold the paged and the latent kernel to the benchmark cells' call
-shapes at the page count each one's own rule picks. All of them live in this one file: the worker
+shapes at the page count each one's own rule picks, and the flash kernels to the
+training cells' — alone on one chip, and inside a ``ShardedTrainer`` step over
+four. All of them live in this one file: the worker
 that runs it is the one process that loads the TPU's library.
 """
 import os
@@ -15,11 +17,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from k8s_distributed_deeplearning_tpu.ops import pallas_latent_attn, pallas_paged_attn
+from k8s_distributed_deeplearning_tpu.ops import (pallas_flash, pallas_latent_attn,
+                                                  pallas_paged_attn)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def chips():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -32,9 +35,14 @@ def one_chip():
     # cache but cannot be read back without one: keep these out of it.
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield list(topo.devices)
     jax.config.update("jax_enable_compilation_cache", True)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(chips):
+    return SingleDeviceSharding(chips[0])
 
 
 # mistral-7b widths as the backlog cell serves them: 32 q / 8 kv heads x 128,
@@ -116,3 +124,75 @@ def test_a_576_lane_latent_pool_is_refused_by_mosaic(one_chip):
         jax.jit(call).lower(
             sds((32, 1, 64, 576), jnp.bfloat16), sds((7001, 64, 576), jnp.bfloat16),
             sds((32, 272), jnp.int32), sds((32, 1), jnp.int32)).compile()
+
+
+@pytest.mark.parametrize("b,seq,heads,causal,kernels", [
+    # bert-base.mlm-s512: pairs of 64-lane heads, the sequence resident
+    (16, 512, (12, 12, 64), False, ["flash_attn_fwd", "flash_attn_bwd"]),
+    (16, 512, (12, 12, 64), True, ["flash_attn_fwd", "flash_attn_bwd"]),
+    # pairs, streamed in blocks; the smoke's two shapes (folded view)
+    (4, 2048, (12, 12, 64), False, ["flash_attn_fwd", "flash_attn_dq", "flash_attn_dkv"]),
+    (2, 2048, (12, 4, 64), True, ["flash_attn_fwd", "flash_attn_dq", "flash_attn_dkv"]),
+    (2, 2048, MISTRAL, True, ["flash_attn_fwd", "flash_attn_dq", "flash_attn_dkv"]),
+], ids=["bert-s512", "bert-s512-causal", "pairs-s2048", "gqa-hd64-s2048", "gqa-hd128-s2048"])
+def test_flash_kernels_compile_for_v5e(one_chip, b, seq, heads, causal, kernels):
+    H, HKV, HD = heads
+    sds = lambda h: jax.ShapeDtypeStruct((b, seq, h, HD), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        o = pallas_flash.flash_attention(q, k, v, causal=causal, interpret=False)
+        return jnp.sum(o.astype(jnp.float32))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        sds(H), sds(HKV), sds(HKV)).compile().as_text()
+    assert [k for k in ("flash_attn_fwd", "flash_attn_bwd", "flash_attn_dq", "flash_attn_dkv")
+            if k in text] == kernels
+
+
+def test_sharded_step_runs_the_flash_kernel_on_each_chips_own_rows(chips, monkeypatch):
+    """A Pallas call has no partition rule: unwrapped, every chip of a
+    data-parallel mesh would all-gather the batch and attend all of it. Built
+    by ``ShardedTrainer`` with no ``attention_fn`` (as the benchmark builds
+    it), the step's kernels take 2 of the 8 rows each and nothing is
+    gathered."""
+    import re
+
+    import flax.linen as nn
+    import optax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    import numpy as np
+
+    from k8s_distributed_deeplearning_tpu.models import bert
+    from k8s_distributed_deeplearning_tpu.parallel import sharding
+    monkeypatch.setattr(pallas_flash, "on_tpu", lambda: True)     # compile, not interpret
+    mesh = Mesh(np.array(chips), ("data",))
+    cfg = bert.config_tiny(dim=128, n_heads=2, n_layers=2, mlp_dim=256, max_seq_len=128,
+                           attention_impl="flash", dtype=jnp.bfloat16)
+    model = bert.BertMLM(cfg)
+
+    def loss(p, batch, r):
+        inputs, targets, w = bert.mask_tokens(batch["tokens"], r, vocab_size=cfg.vocab_size,
+                                              mask_id=3, mask_prob=0.15)
+        return bert.loss_fn(model, p, {"inputs": inputs, "targets": targets, "weights": w})
+    trainer = sharding.ShardedTrainer(loss, optax.sgd(0.1), mesh)
+
+    def make_state(r):
+        params = model.init(r, jnp.zeros((1, 8), jnp.int32))["params"]
+        return sharding.TrainState(params=params, opt_state=trainer.optimizer.init(params),
+                                   step=jnp.zeros((), jnp.int32))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    with mesh, nn.logical_axis_rules(trainer.rules):
+        abstract = jax.eval_shape(make_state, key)
+    placed = sharding.state_shardings(abstract, mesh, trainer.rules)
+    trainer._state_sh = placed
+    state = jax.tree.map(lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+                         abstract, placed)
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (8, 128), jnp.int32, sharding=sharding.batch_sharding(mesh, trainer.rules))}
+    key = jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=NamedSharding(mesh, P()))
+    text = trainer.make_step(donate=False).lower(state, batch, key).compile().as_text()
+    calls = [l for l in text.splitlines() if 'custom_call_target="tpu_custom_call"' in l]
+    assert len(calls) == 2 and "flash_attn_fwd" in text and "flash_attn_bwd" in text
+    for line in calls:
+        operands = re.findall(r"bf16\[(\d+),128,128\]", line)
+        assert operands and set(operands) == {"2"}, line
+    assert "all-gather" not in text
