@@ -64,7 +64,8 @@ def prefill_chunk(model, params: PyTree, cache: PyTree, chunk: jax.Array, *,
                   start: jax.Array | int | None = None,
                   positions: jax.Array | None = None,
                   segment_ids: jax.Array | None = None,
-                  block_tables: jax.Array | None = None
+                  block_tables: jax.Array | None = None,
+                  lengths: jax.Array | None = None
                   ) -> tuple[jax.Array, PyTree, jax.Array | None]:
     """Resume prefill on an EXISTING cache: run ``chunk`` ([B, C] int32)
     through the shared-cursor decode path starting at cache position
@@ -88,6 +89,15 @@ def prefill_chunk(model, params: PyTree, cache: PyTree, chunk: jax.Array, *,
     leaves — ``start`` is then a no-op) and the caller MUST pass explicit
     ``positions``: the paged scatter derives each token's (page, offset)
     from its absolute position, not from any cursor.
+
+    ``lengths`` ([B] int32; only for a model with a mixer that carries state
+    from token to token, :class:`models.transformer.ShortConv`): how many of
+    a RIGHT-PADDED chunk's tokens are real. Attention needs no such thing (pad
+    K/V lie beyond the cursor, never attended); a state must be left as the
+    last real token left it, not as the pad did. The state itself is a leaf of
+    ``cache`` (``conv_state``, one row per row of the call), read before the
+    chunk and written after it: a caller resumes a prompt by handing back the
+    cache the previous chunk returned.
     """
     if start is not None:
         def set_cursor(path, x):
@@ -102,6 +112,8 @@ def prefill_chunk(model, params: PyTree, cache: PyTree, chunk: jax.Array, *,
         kw["segment_ids"] = segment_ids
     if block_tables is not None:
         kw["block_tables"] = block_tables
+    if lengths is not None:
+        kw["lengths"] = lengths
     logits, vars_ = model.apply({"params": params, "cache": cache}, chunk,
                                 decode=True, mutable=["cache", "moe_stats"],
                                 **kw)
@@ -142,7 +154,10 @@ def slot_decode_step(model, params: PyTree, cache: PyTree,
     caller owns cursor arithmetic (pass position = tokens-written-so-far
     for each row) and must keep ``slot_positions`` within ``max_seq_len``;
     stale KV beyond a row's cursor is never attended, so freed slots are
-    reusable without clearing."""
+    reusable without clearing. A model with state beside its pages
+    (``conv_state`` leaves, ``[B, ...]``: row i is slot i's) advances every
+    row's in place, a free slot's too — its next request starts from zeros
+    (the engine's chunk programs see to that)."""
     logits, vars_ = model.apply({"params": params, "cache": cache},
                                 tokens[:, None], decode=True,
                                 cache_positions=slot_positions,

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import optax
 
 from k8s_distributed_deeplearning_tpu.models.transformer import (
-    LMHead, Transformer, TransformerConfig, lm_batch_views)
+    TransformerConfig, lm_batch_views, lm_forward)
 
 import flax.linen as nn
 
@@ -39,25 +39,15 @@ class LlamaLM(nn.Module):
                  cache_positions: jax.Array | None = None,
                  block_tables: jax.Array | None = None,
                  return_hidden: bool = False) -> jax.Array:
-        x = Transformer(self.cfg, name="transformer")(
-            tokens, positions=positions, segment_ids=segment_ids,
-            deterministic=deterministic,
-            attention_fn=attention_fn, decode=decode,
-            cache_positions=cache_positions,
+        # return_hidden: final hidden states for a chunked LM-head loss
+        # (ops/chunked_ce.py). Only valid at apply time: init must take the
+        # default path so LMHead params get created.
+        return lm_forward(
+            self, self.cfg, None, tokens, return_hidden=return_hidden,
+            positions=positions, segment_ids=segment_ids,
+            deterministic=deterministic, attention_fn=attention_fn,
+            decode=decode, cache_positions=cache_positions,
             block_tables=block_tables)
-        if return_hidden:
-            # Final hidden states for a chunked LM-head loss
-            # (ops/chunked_ce.py). Only valid at apply time: init must take
-            # the default path so LMHead params get created.
-            return x
-        embedding = None
-        if self.cfg.tie_embeddings:
-            embedding = self.variables["params"]["transformer"]["tok_embed"]["embedding"]
-            if hasattr(embedding, "unbox"):
-                # Raw self.variables access bypasses flax's transparent
-                # unboxing of nn.Partitioned/LogicallyPartitioned leaves.
-                embedding = embedding.unbox()
-        return LMHead(self.cfg, name="head")(x, embedding)
 
 
 def config_llama3_8b(**overrides) -> TransformerConfig:

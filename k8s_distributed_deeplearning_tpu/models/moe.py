@@ -63,8 +63,8 @@ import jax.numpy as jnp
 import optax
 
 from k8s_distributed_deeplearning_tpu.models.transformer import (
-    MLP, LatentAttention, LatentAttentionConfig, LayerKind, LMHead,
-    Transformer, TransformerConfig, default_init, lm_batch_views)
+    MLP, LatentAttention, LatentAttentionConfig, LayerKind, LMHead, ShortConv,
+    Transformer, TransformerConfig, default_init, lm_batch_views, lm_forward)
 
 Dtype = Any
 
@@ -146,7 +146,13 @@ class MoEConfig:
 # every row (no scatter; the weights' read binds either way), above it the
 # all-rows form's E x T products cost more than the sort. 32 is where the
 # old `t >= 128` rule sat for the config it was measured on (8 experts,
-# top-2: grouped 4.2k vs 3.8k tok/s below it, round 5).
+# top-2: grouped 4.2k vs 3.8k tok/s below it, round 5). Measured on a v5e
+# since: at ~2 rows an expert (32 held experts of 128, top-8, 32 rows: a
+# decode step) dense = grouped; at ~64 (a 1,024-token chunk of the same)
+# grouped (PR 27); at 16 rows an expert (32 experts, top-4, 128 rows: a decode
+# step at 2048 x 1792) dense 29.6 ms a step against grouped 31.5 (PR 31: the
+# kernel's row block is 128, so 16 rows are padded to the same products the
+# dense form does, plus the sort and the gather). The constant stays.
 GROUPED_MIN_ROWS_PER_EXPERT = 32
 
 
@@ -772,13 +778,10 @@ class LatentMoELM(nn.Module):
         n = self.cfg.n_layers
         pattern = ((dense,) * min(self.first_dense, n)
                    + (sparse,) * max(n - self.first_dense, 0))
-        x = Transformer(self.cfg, pattern=pattern, name="transformer")(
-            tokens, positions=positions, deterministic=deterministic,
-            decode=decode, cache_positions=cache_positions,
-            block_tables=block_tables)
-        if return_hidden:
-            return x
-        return LMHead(self.cfg, name="head")(x)
+        return lm_forward(
+            self, self.cfg, pattern, tokens, return_hidden=return_hidden,
+            positions=positions, deterministic=deterministic, decode=decode,
+            cache_positions=cache_positions, block_tables=block_tables)
 
 
 def config_tiny_latent_moe(**overrides):
@@ -798,6 +801,44 @@ def config_tiny_latent_moe(**overrides):
                     ragged_block_m=8, score_fn="sigmoid", select_bias=True,
                     routed_scale=2.5, shared_experts=1, expert_mlp_dim=32)
     return TransformerConfig(**base), latent, moe
+
+
+@functools.lru_cache(maxsize=None)
+def conv_moe_pattern(layer_types: tuple[str, ...], moe: MoEConfig,
+                     num_dense: int, conv_width: int = 3
+                     ) -> tuple[LayerKind, ...]:
+    """The layer pattern of the gated-short-convolution + sparse-expert
+    family (LFM2-MoE layout), for :class:`~models.transformer.PatternLM`:
+    layer ``i``'s mixer is :class:`~models.transformer.ShortConv` where
+    ``layer_types[i]`` is ``"conv"`` and :class:`~models.transformer.Attention`
+    where it is ``"full_attention"``; its feed-forward is the dense SwiGLU MLP
+    in the first ``num_dense`` layers and :class:`MoEMLP` after them. One
+    :class:`LayerKind` object a kind (and one tuple per argument set, cached),
+    so that equal layers — and models built twice from the same arguments —
+    compare equal: a jitted program compiled for one serves the other."""
+    unknown = set(layer_types) - {"conv", "full_attention"}
+    if unknown:
+        raise ValueError(f"layer_types names {sorted(unknown)}; known: "
+                         "'conv', 'full_attention'")
+    conv = functools.partial(ShortConv, width=conv_width)
+    experts = functools.partial(MoEMLP, moe=moe)
+    kinds = {(t, sparse): LayerKind(attention=conv if t == "conv" else None,
+                                    mlp=experts if sparse else None)
+             for t in ("conv", "full_attention") for sparse in (False, True)}
+    return tuple(kinds[t, i >= num_dense] for i, t in enumerate(layer_types))
+
+
+def moe_config_of(model) -> MoEConfig | None:
+    """The expert layers' config of *model*: its ``moe`` field
+    (:class:`MoELM`, :class:`LatentMoELM`) or what the :class:`MoEMLP`
+    factories of its ``pattern`` were made with — None for a model with no
+    expert layer. What :meth:`serve.engine.ServeEngine.attention_impls` asks
+    :func:`serving_dispatch` with."""
+    moe = getattr(model, "moe", None)
+    if moe is None:
+        for kind in getattr(model, "pattern", None) or ():
+            moe = getattr(kind.mlp, "keywords", {}).get("moe", moe)
+    return moe
 
 
 def flops_per_token(cfg: TransformerConfig, moe: MoEConfig, *,

@@ -94,6 +94,11 @@ class TransformerConfig:
     position: str = "rope"              # "rope" | "learned" | "none"
     rope_theta: float = 500000.0        # Llama-3 default
     tie_embeddings: bool = False
+    norm_eps: float = 1e-6              # every norm's epsilon (RMSNorm and
+                                        # LayerNorm; the per-head q/k norms)
+    qk_norm: bool = False               # Attention: an RMSNorm with a learned
+                                        # gain over each query head's and each
+                                        # key head's lanes, before RoPE
     dtype: Dtype = jnp.bfloat16         # compute dtype; params stay f32
     attention_impl: str = "auto"        # "auto" | "xla" | "flash" (pallas)
                                         # | "paged_flash"; auto = measured
@@ -200,9 +205,10 @@ class RMSNorm(nn.Module):
 
 def make_norm(cfg: TransformerConfig, name: str):
     if cfg.norm == "rmsnorm":
-        return RMSNorm(dtype=cfg.dtype, name=name)
+        return RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, name=name)
     return nn.LayerNorm(
-        dtype=cfg.dtype, param_dtype=jnp.float32, name=name,
+        epsilon=cfg.norm_eps, dtype=cfg.dtype, param_dtype=jnp.float32,
+        name=name,
         scale_init=nn.with_logical_partitioning(nn.initializers.ones, ("embed",)),
         bias_init=nn.with_logical_partitioning(nn.initializers.zeros, ("embed",)))
 
@@ -404,7 +410,11 @@ class Attention(nn.Module):
                  attention_fn: Callable | None = None,
                  decode: bool = False,
                  cache_positions: jax.Array | None = None,
-                 block_tables: jax.Array | None = None) -> jax.Array:
+                 block_tables: jax.Array | None = None,
+                 lengths: jax.Array | None = None) -> jax.Array:
+        # lengths (real tokens of a right-padded chunk, for a mixer that
+        # carries state: ShortConv) means nothing here — pad K/V land beyond
+        # the cursor or in the scratch page and are never attended.
         cfg = self.cfg
         hd = cfg.resolved_head_dim
         q = nn.DenseGeneral((cfg.n_heads, hd), axis=-1, use_bias=False,
@@ -422,6 +432,11 @@ class Attention(nn.Module):
                             kernel_init=nn.with_logical_partitioning(
                                 default_init(), ("embed", "kv", "head_dim")),
                             name="v_proj")(x)
+        if cfg.qk_norm:
+            q = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, axis=None,
+                        name="q_norm")(q)
+            k = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, axis=None,
+                        name="k_norm")(k)
         cur = None
         if cache_positions is not None and not decode:
             raise ValueError("cache_positions requires decode=True")
@@ -752,10 +767,12 @@ class LatentAttention(nn.Module):
         q = dense((h, dn + dr), kernel_init=nn.with_logical_partitioning(
             default_init(), ("embed", "heads", "head_dim")), name="q_proj")(x)
         if la.qk_norm:
-            q = RMSNorm(dtype=cfg.dtype, axis=None, name="q_norm")(q)
+            q = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, axis=None,
+                        name="q_norm")(q)
         ckr = dense(r + dr, kernel_init=nn.with_logical_partitioning(
             default_init(), ("embed", None)), name="kv_down")(x)
-        c = RMSNorm(dtype=cfg.dtype, axis=None, name="kv_norm")(ckr[..., :r])
+        c = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, axis=None,
+                    name="kv_norm")(ckr[..., :r])
         w_up = self.param(
             "kv_up", nn.with_logical_partitioning(
                 default_init(), (None, "heads", "head_dim")),
@@ -891,6 +908,82 @@ class LatentAttention(nn.Module):
         return nn.with_logical_constraint(out, ("batch", "seq", "act_embed"))
 
 
+class ShortConv(nn.Module):
+    """Gated short convolution — the ``conv`` mixer of the LFM2 family, a
+    :class:`LayerKind` ``attention`` factory beside :class:`Attention` (same
+    keywords; tables and positions mean nothing to it):
+
+        [B | C | u] = W_in x          (dim -> 3 dim, three equal parts, no bias)
+        z = B * u
+        v[t] = sum_j w[j] * z[t - (width - 1) + j]     (depthwise, causal,
+                                       zeros before the sequence, no bias)
+        out = W_out (C * v)
+
+    Its whole memory is the last ``width - 1`` columns of ``z``. With
+    ``decode=True`` that tail is the cache leaf ``conv_state``
+    ``[B, width - 1, dim]``: read before the call's tokens, written after
+    them. ``generate()``'s row cache makes it itself (zeros: before the
+    sequence). Under ``block_tables`` it is the serving engine's per-slot
+    STATE ARENA (serve/engine.py): the decode program hands every slot's row
+    (the call's batch is the slots), a chunk program its one slot's — and
+    ``lengths`` ([B] int32) says how many of a right-padded final chunk's
+    tokens are real, so that the state left is the last REAL token's."""
+
+    cfg: TransformerConfig
+    width: int = 3
+
+    @nn.compact
+    def __call__(self, x: jax.Array, *,
+                 mask: jax.Array | None = None,
+                 positions: jax.Array | None = None,
+                 segment_ids: jax.Array | None = None,
+                 attention_fn: Callable | None = None,
+                 decode: bool = False,
+                 cache_positions: jax.Array | None = None,
+                 block_tables: jax.Array | None = None,
+                 lengths: jax.Array | None = None) -> jax.Array:
+        cfg, tail = self.cfg, self.width - 1
+        if mask is not None or segment_ids is not None or attention_fn is not None:
+            raise NotImplementedError(
+                "ShortConv is causal over the whole row: mask, segment_ids "
+                "and attention_fn are not supported")
+        if cfg.tp_axis is not None:
+            raise NotImplementedError("ShortConv has no tp_axis path")
+        b, sq, d = x.shape
+        bcu = param_dense(3 * d, ("embed", "mlp"), "in_proj", cfg.dtype)(x)
+        gate_b, gate_c, u = bcu[..., :d], bcu[..., d:2 * d], bcu[..., 2 * d:]
+        z = gate_b * u
+        w = self.param("conv", nn.with_logical_partitioning(
+            default_init(), (None, "mlp")), (self.width, d), jnp.float32)
+        state = None
+        if decode:
+            def _arena_missing():
+                raise ValueError(
+                    "paged decode (block_tables) requires an engine-provided "
+                    "state arena (conv_state); it cannot be initialised from "
+                    "inside the model")
+            state = (self.variable("cache", "conv_state", _arena_missing)
+                     if block_tables is not None else
+                     self.variable("cache", "conv_state", jnp.zeros,
+                                   (b, tail, d), cfg.dtype))
+            before = state.value.astype(z.dtype)
+        else:
+            before = jnp.zeros((b, tail, d), z.dtype)
+        zfull = jnp.concatenate([before, z], axis=1)        # [B, tail + sq, d]
+        if state is not None:
+            # the tail after n real tokens: rows n .. n + tail - 1 of zfull
+            # (all sq of them real unless the caller says otherwise)
+            after = zfull[:, sq:] if lengths is None else jax.vmap(
+                lambda row, n: jax.lax.dynamic_slice_in_dim(row, n, tail, 0)
+            )(zfull, lengths.astype(jnp.int32))
+            state.value = after.astype(state.value.dtype)
+        zf = zfull.astype(jnp.float32)
+        v = sum(w[j] * zf[:, j:j + sq] for j in range(self.width))
+        out = param_dense(d, ("mlp", "embed"), "out_proj", cfg.dtype)(
+            gate_c * v.astype(cfg.dtype))
+        return nn.with_logical_constraint(out, ("batch", "seq", "act_embed"))
+
+
 class MLP(nn.Module):
     """Feed-forward: SwiGLU (Llama) or GELU (BERT/ViT). Column-parallel up
     projections ("mlp" logical axis), row-parallel down projection."""
@@ -932,7 +1025,8 @@ class MLP(nn.Module):
 class LayerKind:
     """What one layer of a stack is made of: two factories
     ``(cfg, name=...) -> module``. ``attention=None`` is :class:`Attention`
-    (any other must take its keywords); ``mlp=None`` is the dense
+    (any other — :class:`LatentAttention`, :class:`ShortConv` — must take its
+    keywords); ``mlp=None`` is the dense
     :class:`MLP`, any other (e.g. the expert-parallel
     :class:`models.moe.MoEMLP`) must accept a ``decode`` keyword — the static
     mode flag rides to it so that it can switch to its dropless serving
@@ -968,15 +1062,19 @@ class Block(nn.Module):
                  attention_fn: Callable | None = None,
                  decode: bool = False,
                  cache_positions: jax.Array | None = None,
-                 block_tables: jax.Array | None = None) -> jax.Array:
+                 block_tables: jax.Array | None = None,
+                 lengths: jax.Array | None = None) -> jax.Array:
         cfg, kind = self.cfg, self.kind
         attention_fn = attention_fn or self.attention_fn
         h = make_norm(cfg, "attn_norm")(x)
         attn = (kind.attention or Attention)(cfg, name="attn")
+        # lengths rides only where a caller gives it (a stack with a mixer
+        # that carries state): the other mixers' signatures stay theirs.
         h = attn(h, mask=mask, positions=positions,
                  segment_ids=segment_ids, attention_fn=attention_fn,
                  decode=decode, cache_positions=cache_positions,
-                 block_tables=block_tables)
+                 block_tables=block_tables,
+                 **({} if lengths is None else {"lengths": lengths}))
         if cfg.dropout_rate:
             h = nn.Dropout(cfg.dropout_rate, deterministic=deterministic)(h)
         x = x + h
@@ -1025,7 +1123,8 @@ class Transformer(nn.Module):
                  attention_fn: Callable | None = None,
                  decode: bool = False,
                  cache_positions: jax.Array | None = None,
-                 block_tables: jax.Array | None = None) -> jax.Array:
+                 block_tables: jax.Array | None = None,
+                 lengths: jax.Array | None = None) -> jax.Array:
         cfg = self.cfg
         if tokens_or_embeds.dtype in (jnp.int32, jnp.int64):
             x = nn.Embed(cfg.vocab_size, cfg.dim, dtype=cfg.dtype,
@@ -1079,6 +1178,8 @@ class Transformer(nn.Module):
             dkw["cache_positions"] = cache_positions
         if block_tables is not None:
             dkw["block_tables"] = block_tables
+        if lengths is not None:
+            dkw["lengths"] = lengths
         if cfg.scan_layers:
             x, _ = nn.scan(
                 lambda mdl, carry, _: (
@@ -1145,3 +1246,47 @@ class LMHead(nn.Module):
                                  "lm_head", cfg.dtype)(x)
         # f32 logits for a numerically stable softmax-CE.
         return logits.astype(jnp.float32)
+
+
+def lm_forward(module: nn.Module, cfg: TransformerConfig,
+               pattern: tuple[LayerKind, ...] | None, tokens: jax.Array, *,
+               return_hidden: bool = False, **kw) -> jax.Array:
+    """The body of a decoder-only LM made of a layer ``pattern``: the stack
+    (``transformer``), then the head (``head``; tied to the embedding where
+    the config says so) — built in *module*'s own scope, so that every model
+    that is this body with a pattern of its own (:class:`PatternLM`,
+    :class:`models.moe.LatentMoELM`) has the same parameter tree."""
+    x = Transformer(cfg, pattern=pattern, name="transformer")(tokens, **kw)
+    if return_hidden:
+        return x
+    embedding = None
+    if cfg.tie_embeddings:
+        embedding = module.variables["params"]["transformer"]["tok_embed"][
+            "embedding"]
+        if hasattr(embedding, "unbox"):     # raw access skips flax's unboxing
+            embedding = embedding.unbox()
+    return LMHead(cfg, name="head")(x, embedding)
+
+
+class PatternLM(nn.Module):
+    """Decoder-only LM whose layers are what ``pattern`` says, one
+    :class:`LayerKind` a layer: any mix of mixers (:class:`Attention`,
+    :class:`LatentAttention`, :class:`ShortConv`) and feed-forwards (the dense
+    :class:`MLP`, :class:`models.moe.MoEMLP`). Layers that differ are unrolled
+    (``cfg.scan_layers=False``). The serving engine calls it like
+    :class:`models.llama.LlamaLM`; ``lengths`` reaches the mixers that carry
+    state (:class:`ShortConv`)."""
+
+    cfg: TransformerConfig
+    pattern: tuple[LayerKind, ...] | None = None
+
+    @nn.compact
+    def __call__(self, tokens, *, positions=None, deterministic: bool = True,
+                 decode: bool = False, cache_positions=None,
+                 block_tables=None, lengths=None,
+                 return_hidden: bool = False):
+        return lm_forward(
+            self, self.cfg, self.pattern, tokens, return_hidden=return_hidden,
+            positions=positions, deterministic=deterministic, decode=decode,
+            cache_positions=cache_positions, block_tables=block_tables,
+            lengths=lengths)

@@ -88,6 +88,7 @@ from jax.sharding import PartitionSpec as P
 
 from k8s_distributed_deeplearning_tpu import faults as _faults
 from k8s_distributed_deeplearning_tpu.models import generate, transformer
+from k8s_distributed_deeplearning_tpu.models import moe as moe_lib
 from k8s_distributed_deeplearning_tpu.ops import pallas_latent_attn, pallas_paged_attn
 from k8s_distributed_deeplearning_tpu.parallel import mesh as mesh_lib
 from k8s_distributed_deeplearning_tpu.parallel import sharding as sharding_lib
@@ -170,10 +171,10 @@ def _decode_core(model, params: PyTree, cache: PyTree, tokens: jax.Array,
                  kv_lens: jax.Array, tables: jax.Array, temps: jax.Array,
                  top_ks: jax.Array, top_ps: jax.Array, keys: jax.Array):
     params = _maybe_dequant_params(params)
-    logits, cache, moe = generate.slot_decode_step(
+    logits, new, moe = generate.slot_decode_step(
         model, params, cache, tokens, kv_lens, block_tables=tables)
     keys, nxt = _sample_slots(logits, temps, top_ks, top_ps, keys)
-    return _with_counts(nxt, moe), keys, cache
+    return _with_counts(nxt, moe), keys, _keep_idle_state(cache, new, kv_lens)
 
 
 @functools.partial(jax.jit, static_argnames=("model",),
@@ -188,7 +189,10 @@ def _decode_program(model, params: PyTree, cache: PyTree, tokens: jax.Array,
     the key register are donated: the step updates both in place — no
     per-iteration arena copy (tests/test_tp_serve.py asserts the aliasing
     by buffer identity). Behind the tokens: the expert layers' counts, where
-    the model has any (:func:`_with_counts`)."""
+    the model has any (:func:`_with_counts`). A state arena
+    (:data:`_STATE_LEAVES`) is advanced in place, row i being slot i's; the
+    rows of slots at cursor 0 (free, or mid-prefill) keep what they held
+    (:func:`_keep_idle_state`)."""
     return _decode_core(model, params, cache, tokens, kv_lens, tables,
                         temps, top_ks, top_ps, keys)
 
@@ -283,40 +287,108 @@ def _leaf_name(path) -> str | None:
     return getattr(path[-1], "key", None)
 
 
+# Cache leaves that are not pages but STATE: one row a SLOT (the batch axis,
+# third from the end, is the slots'), read before a call's tokens and written
+# after them — a ShortConv's tail (models/transformer.py). They live in the
+# same tree as the page pool and are donated with it.
+_STATE_LEAVES = ("conv_state",)
+
+
+def _is_state(path) -> bool:
+    return _leaf_name(path) in _STATE_LEAVES
+
+
+def _slot_state(cache: PyTree, slot: jax.Array | None,
+                start: jax.Array) -> PyTree:
+    """*cache* as a one-row chunk call sees it: every state leaf cut to
+    *slot*'s row — zeros where the chunk is the request's first (``start ==
+    0``: whatever the slot's last occupant left is never read, so a reused
+    slot needs no clearing). ``slot`` None (a model of pages only): as is."""
+    if slot is None:
+        return cache
+
+    def one(path, leaf):
+        if not _is_state(path):
+            return leaf
+        row = jax.lax.dynamic_slice_in_dim(leaf, slot, 1, axis=leaf.ndim - 3)
+        return jnp.where(start == 0, jnp.zeros_like(row), row)
+    return jax.tree_util.tree_map_with_path(one, cache)
+
+
+def _put_slot_state(arena: PyTree, cache: PyTree,
+                    slot: jax.Array | None) -> PyTree:
+    """The cache a chunk call returned, its state leaves' one row written
+    back into *arena*'s at *slot* (in place: the arena is donated)."""
+    if slot is None:
+        return cache
+
+    def one(path, old, new):
+        if not _is_state(path):
+            return new
+        return jax.lax.dynamic_update_slice_in_dim(old, new, slot,
+                                                   axis=old.ndim - 3)
+    return jax.tree_util.tree_map_with_path(one, arena, cache)
+
+
+def _keep_idle_state(cache: PyTree, new: PyTree, kv_lens: jax.Array) -> PyTree:
+    """The cache a decode step returned, with the state rows of the slots it
+    did not decode for (cursor 0: free, or mid-prefill — their rider row's
+    K/V went to the scratch page) left as they were: a prompt's state carried
+    from chunk to chunk must not be advanced by the rider's pad token. A
+    tree of pages only comes back as it is."""
+    def one(path, old, now):
+        if not _is_state(path):
+            return now
+        live = (kv_lens > 0).reshape((-1,) + (1,) * 2)
+        return jnp.where(live, now, old)
+    return jax.tree_util.tree_map_with_path(one, cache, new)
+
+
 def _chunk_core(model, params: PyTree, cache: PyTree, chunk: jax.Array,
-                table: jax.Array, start: jax.Array):
+                table: jax.Array, start: jax.Array,
+                slot: jax.Array | None = None):
     params = _maybe_dequant_params(params)
     pos = (start + jnp.arange(chunk.shape[1], dtype=jnp.int32))[None, :]
-    _, cache, moe = generate.prefill_chunk(model, params, cache, chunk,
-                                           positions=pos, block_tables=table)
-    return cache, moe
+    _, new, moe = generate.prefill_chunk(
+        model, params, _slot_state(cache, slot, start), chunk, positions=pos,
+        block_tables=table)
+    return _put_slot_state(cache, new, slot), moe
 
 
 @functools.partial(jax.jit, static_argnames=("model",),
                    donate_argnames=("cache",))
 def _chunk_program(model, params: PyTree, cache: PyTree, chunk: jax.Array,
-                   table: jax.Array, start: jax.Array):
+                   table: jax.Array, start: jax.Array,
+                   slot: jax.Array | None = None):
     """One INTERMEDIATE prefill chunk: write ``chunk`` ([1, C], all real
     tokens — never padded) through block table ``table`` ([1, max_blocks])
     at absolute positions ``start + [0, C)``. Logits are discarded, so XLA
     dead-code-eliminates the lm_head matmul for every chunk but the final
-    one. One compile per C. Returns ``(cache, moe counts or None)``."""
-    return _chunk_core(model, params, cache, chunk, table, start)
+    one. One compile per C. Returns ``(cache, moe counts or None)``.
+    ``slot`` (a model with per-slot state; None otherwise): the row of the
+    state arena the chunk reads (zeros at ``start == 0``) and leaves its
+    state in."""
+    return _chunk_core(model, params, cache, chunk, table, start, slot)
 
 
 def _final_chunk_core(model, params: PyTree, cache: PyTree,
                       chunk: jax.Array, table: jax.Array,
                       start: jax.Array, length: jax.Array,
                       temp: jax.Array, top_k: jax.Array,
-                      top_p: jax.Array, key: jax.Array):
+                      top_p: jax.Array, key: jax.Array,
+                      slot: jax.Array | None = None):
     params = _maybe_dequant_params(params)
     pos = (start + jnp.arange(chunk.shape[1], dtype=jnp.int32))[None, :]
-    logits, cache, moe = generate.prefill_chunk(
-        model, params, cache, chunk, positions=pos, block_tables=table)
+    # A state is left as the last REAL token left it (lengths), not the pad.
+    logits, new, moe = generate.prefill_chunk(
+        model, params, _slot_state(cache, slot, start), chunk, positions=pos,
+        block_tables=table,
+        lengths=None if slot is None else jnp.reshape(length, (1,)))
     last = jax.lax.dynamic_slice_in_dim(logits, length - 1, 1, axis=1)[:, 0, :]
     new_key, tok = _sample_slots(last, temp[None], top_k[None], top_p[None],
                                  key[None])
-    return _with_counts(tok[0], moe), new_key[0], cache
+    return (_with_counts(tok[0], moe), new_key[0],
+            _put_slot_state(cache, new, slot))
 
 
 @functools.partial(jax.jit, static_argnames=("model",),
@@ -325,16 +397,18 @@ def _final_chunk_program(model, params: PyTree, cache: PyTree,
                          chunk: jax.Array, table: jax.Array,
                          start: jax.Array, length: jax.Array,
                          temp: jax.Array, top_k: jax.Array,
-                         top_p: jax.Array, key: jax.Array):
+                         top_p: jax.Array, key: jax.Array,
+                         slot: jax.Array | None = None):
     """Finish a prefill: write ``chunk`` ([1, bucket], right-padded past
     ``length`` real tokens) at absolute positions ``start + [0, bucket)``
     through ``table`` and sample the first token from the last real column
     ``length - 1`` (all traced operands — one compile per bucket, not per
     prompt length). Pad positions past the table's last block land in the
     pool's scratch page; pad garbage inside the last prompt page sits
-    beyond the cursor and is never attended."""
+    beyond the cursor and is never attended. ``slot``: as
+    :func:`_chunk_program`; the state left is that of token ``length - 1``."""
     return _final_chunk_core(model, params, cache, chunk, table, start,
-                             length, temp, top_k, top_p, key)
+                             length, temp, top_k, top_p, key, slot)
 
 
 # ------------------------------------------------- serving TP (graftmesh)
@@ -904,6 +978,32 @@ class ServeEngine:
                         f"a page pool of {other} leaves cannot take {what} "
                         "yet: those paths assume cached_key/cached_value "
                         "pages of kv_heads x head_dim lanes")
+        # State beside the pages (:data:`_STATE_LEAVES`): the leaves, and
+        # the bytes one slot's rows hold.
+        self._state_rows = self._state_leaves(self._row_shapes)
+        self._slot_state_nbytes = sum(
+            int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+            for _, leaf in self._state_rows)
+        self._state_names = sorted(
+            {_leaf_name(path) for path, _ in self._state_rows})
+        if self._state_rows:
+            # Every path that knows pages only is refused by name until it
+            # carries the state: what follows a mapped prefix's, a rejected
+            # draft's or a shipped request's last token would be lost.
+            for what, on in (
+                    ("prefix_cache_mb > 0 (the trie maps pages, not the "
+                     "state after a prefix's last token)",
+                     bool(prefix_cache_mb)),
+                    ("a speculative draft (spec_k: rollback truncates a "
+                     "cursor, a state has none)", spec_k > 0),
+                    (f"tp={self.tp}", self.tp > 0),
+                    ("kv_quant='int8'", kv_quant is not None),
+                    ("prefill_only (export_request_kv ships pages only)",
+                     self.prefill_only)):
+                if on:
+                    raise ValueError(
+                        f"a model with per-slot state ({self._state_names} "
+                        f"leaves) cannot take {what} yet")
         if self.tp:
             self._mesh = mesh_lib.make_mesh(
                 {sharding_lib.SERVE_TP_AXIS: self.tp},
@@ -934,6 +1034,11 @@ class ServeEngine:
                 lambda p, t: generate.prefill(self.draft_model,
                                               _maybe_dequant_params(p), t),
                 self.draft_params, dummy)
+            if self._state_leaves(draft_shapes):
+                raise ValueError(
+                    "a draft model with per-slot state cannot be rolled "
+                    "back by cursor truncation: spec_k needs a pages-only "
+                    "draft")
             self._draft_cache = self._init_pool_cache(
                 draft_shapes, head_dim=dcfg.resolved_head_dim,
                 max_seq_len=dcfg.max_seq_len)
@@ -987,7 +1092,16 @@ class ServeEngine:
         return [(path, leaf) for path, leaf in
                 jax.tree_util.tree_flatten_with_path(row_shapes)[0]
                 if len(leaf.shape) >= 3 and leaf.shape[-3] == 1
-                and leaf.shape[-2] == n]
+                and leaf.shape[-2] == n and not _is_state(path)]
+
+    @staticmethod
+    def _state_leaves(row_shapes: PyTree) -> list[tuple]:
+        """(path, leaf) of the single-row cache's STATE leaves
+        (:data:`_STATE_LEAVES`, ``[..., 1, rows, F]``): found by name, never
+        by shape — a state is not a row a position."""
+        return [(path, leaf) for path, leaf in
+                jax.tree_util.tree_flatten_with_path(row_shapes)[0]
+                if _is_state(path)]
 
     def _init_pool_cache(self, row_shapes: PyTree, *, head_dim: int,
                          max_seq_len: int | None = None) -> PyTree:
@@ -996,7 +1110,9 @@ class ServeEngine:
         eval_shape, or the draft model's for its sibling arena), keeping
         ONLY the row leaves (:meth:`_pool_rows` — what the model's paged
         branch declares) and reshaping each leaf's [..., 1, max_seq, F] row
-        layout to [..., num_pages, page_tokens, F]. KV content is
+        layout to [..., num_pages, page_tokens, F] — and beside them the
+        STATE ARENA: each state leaf (:meth:`_state_leaves`) with a row a
+        slot, [..., 1, rows, F] -> [..., num_slots, rows, F]. KV content is
         irrelevant — nothing is attended until a table maps a written
         page. Under tp the pool is built SHARDED-AT-BIRTH along each
         leaf's folded kv·head_dim lane dim (jit + out_shardings): every
@@ -1022,6 +1138,10 @@ class ServeEngine:
                     sub = build(v)
                     if sub:
                         out[name] = sub
+                elif name in _STATE_LEAVES:
+                    out[name] = jnp.zeros(
+                        v.shape[:-3] + (self.num_slots,) + v.shape[-2:],
+                        v.dtype)
                 elif id(v) in rows:
                     # [1, S, F] -> [P, bt, F]; scanned [L, 1, S, F] ->
                     # [L, P, bt, F] (batch dim 1 at -3 dropped).
@@ -1256,6 +1376,7 @@ class ServeEngine:
                 "arena's KV is not shipped, so the import side could not "
                 "verify drafts — disable spec_k or migrate by token "
                 "resubmission instead")
+        self._refuse_state("export_request_kv")
         for i, pend in self._owing():
             if pend.req.request_id == request_id:
                 self._activate(i, self._late_outputs)   # admitted: take it
@@ -1322,6 +1443,15 @@ class ServeEngine:
         self._record_pool_gauges()
         return blob
 
+    def _refuse_state(self, what: str) -> None:
+        """KV shipping moves pages; a model's per-slot state would stay
+        behind. Refused by name until the blob carries it."""
+        if self._state_rows:
+            raise ValueError(
+                f"{what}: a model with per-slot state ({self._state_names} "
+                "leaves) cannot ship a request yet — the blob carries pages "
+                "only; migrate by token resubmission instead")
+
     def _owing(self) -> list[tuple[int, _PendingPrefill]]:
         """(slot, pending record) of every slot whose final chunk has been
         dispatched and whose first token is still on the device."""
@@ -1349,7 +1479,7 @@ class ServeEngine:
         not draining, page geometry matches, a free slot exists, and the
         pool covers the shipped pages plus remaining decode growth
         (evicting unpinned trie pages if that closes the gap)."""
-        if (self._draining or self.spec_k
+        if (self._draining or self.spec_k or self._state_rows
                 or int(blob["page_tokens"]) != self.page_tokens
                 or blob.get("kv_quant") != self.kv_quant):
             return False
@@ -1387,6 +1517,7 @@ class ServeEngine:
             raise ValueError(
                 "import_request_kv on a speculative engine: the blob "
                 "carries no draft-arena KV to verify drafts against")
+        self._refuse_state("import_request_kv")
         if int(blob["page_tokens"]) != self.page_tokens:
             raise ValueError(
                 f"page geometry mismatch: blob pages hold "
@@ -1913,7 +2044,13 @@ class ServeEngine:
         return _spec_verify_program(
             self.model, self.params, self._cache, window, *regs[1:])
 
-    def _chunk_step(self, chunk, table, start, *, draft: bool = False):
+    def _state_slot(self, slot: int):
+        """The chunk programs' ``slot`` operand: the arena row of *slot* for
+        a model with per-slot state, None (no operand at all) otherwise."""
+        return np.int32(slot) if self._state_rows else None
+
+    def _chunk_step(self, chunk, table, start, *, slot=None,
+                    draft: bool = False):
         self._dispatches += 1
         if draft:
             if self.tp:
@@ -1926,10 +2063,10 @@ class ServeEngine:
             return self._tp_programs.chunk(
                 self.params, self._cache, chunk, table, start)
         return _chunk_program(self.model, self.params, self._cache, chunk,
-                              table, start)
+                              table, start, slot)
 
     def _final_chunk_step(self, chunk, table, start, length, temp, top_k,
-                          top_p, key):
+                          top_p, key, slot=None):
         self._dispatches += 1
         if self.tp:
             return self._tp_programs.final_chunk(
@@ -1937,7 +2074,7 @@ class ServeEngine:
                 temp, top_k, top_p, key)
         return _final_chunk_program(
             self.model, self.params, self._cache, chunk, table, start,
-            length, temp, top_k, top_p, key)
+            length, temp, top_k, top_p, key, slot)
 
     def decode_cache_size(self) -> int:
         """Compiled-program count of the decode step (jit cache entries —
@@ -2036,14 +2173,24 @@ class ServeEngine:
         gauges, append this step's flight-recorder snapshot, and — once a
         draining engine runs out of work — run the one-shot drain
         finalization (page-leak check + flight dump). The ``epilogue``
-        span's fields are the step's gauges (the pool's fill among them),
+        span's fields are the step's gauges (the pool's fill among them; for
+        a model with per-slot state also ``state_slots`` and ``state_bytes``),
         read before it opens: nothing writes to a span after the fact."""
         c = self.pool.counters()
+        active = self.occupied_slots()
+        state = {}
+        if self._state_rows:
+            # slots a request holds (decoding or mid-prefill) x bytes a slot
+            held = active + len(self._pending)
+            state = dict(state_slots=held,
+                         state_bytes=held * self._slot_state_nbytes)
+            self.stats.record_state(**state)
         with self.tracer.span(
-                "epilogue", active=self.occupied_slots(),
+                "epilogue", active=active,
                 queued=len(self.queue),
                 prefill_tokens=self.last_step_prefill_tokens,
-                pages_used=c["pages_used"], pages_total=c["pages_total"]):
+                pages_used=c["pages_used"], pages_total=c["pages_total"],
+                **state):
             self._epilogue(c)
 
     def _epilogue(self, counters: dict) -> None:
@@ -2203,7 +2350,10 @@ class ServeEngine:
         (``transformer.paged_attention_impl``), so it cannot drift from
         what the programs trace. Beside ``paged_flash`` stands the kernel's
         grid as its own rule (``default_pages_per_cell``) sets it for that
-        program's call: pages a cell attends, and cells a call steps."""
+        program's call: pages a cell attends, and cells a call steps. For a
+        model with expert layers each entry ends in the dispatch that
+        program's rows take (``experts=grouped`` / ``experts=dense``:
+        :func:`models.moe.serving_dispatch`)."""
         slots = self.num_slots
         programs = {"decode": (1, slots)}        # name -> (sq, batch rows)
         if self.spec_k:
@@ -2251,7 +2401,13 @@ class ServeEngine:
                 quant=quant)
             return (f"{impl} pages_per_cell={pages} "
                     f"cells={rows * -(-self.max_blocks // pages)}")
-        return {name: report(*shape) for name, shape in programs.items()}
+        moe = moe_lib.moe_config_of(self.model)
+
+        def experts(tokens: int) -> str:
+            return ("" if moe is None else
+                    f" experts={moe_lib.serving_dispatch(tokens, moe)}")
+        return {name: report(sq, rows) + experts(sq * rows)
+                for name, (sq, rows) in programs.items()}
 
     def _fits(self, req: Request) -> bool:
         """Admission-time page probe (the scheduler calls this on its
@@ -2348,19 +2504,18 @@ class ServeEngine:
                     if budget is not None and budget < c:
                         break       # out of budget; resume next iteration
                     chunk = pend.prompt[None, pend.pos:pend.pos + c]
-                    with self.tracer.span("prefill", chunk=c, slot=slot,
-                                          request_id=pend.req.request_id,
-                                          tokens=c, start=pend.pos) as span:
+                    fields = dict(chunk=c, tokens=c, start=pend.pos,
+                                  request_id=pend.req.request_id,
+                                  **self._state_from(pend))
+                    with self.tracer.span("prefill", slot=slot, **fields):
                         self._cache, moe = self._chunk_step(
                             np.ascontiguousarray(chunk),
                             np.ascontiguousarray(table),
-                            np.int32(pend.pos))
+                            np.int32(pend.pos), slot=self._state_slot(slot))
                         if moe is not None:
                             # no fence here: read at the next one behind it
                             self._chunk_counts.append((
-                                self._dispatches,
-                                dict(chunk=c, tokens=c, start=pend.pos,
-                                     request_id=pend.req.request_id), moe))
+                                self._dispatches, fields, moe))
                         if self.spec_k:
                             self._draft_cache, _ = self._chunk_step(
                                 np.ascontiguousarray(chunk),
@@ -2379,6 +2534,15 @@ class ServeEngine:
                     freed |= self._activate(slot, outputs)
                 pend = None
         return freed
+
+    def _state_from(self, pend: _PendingPrefill) -> dict:
+        """The ``state_from`` field of a chunk's ``prefill`` span and
+        ``prefill_counts`` record, for a model with per-slot state: ``zero``
+        (the request's first chunk starts from zeros) or ``carried`` (from
+        the chunk before it). No field for a model of pages only."""
+        if not self._state_rows:
+            return {}
+        return {"state_from": "zero" if pend.pos == 0 else "carried"}
 
     def _charge_prefill(self, tokens: int) -> None:
         self.last_step_prefill_tokens += int(tokens)
@@ -2406,14 +2570,15 @@ class ServeEngine:
         chunk[0, :rem] = pend.prompt[pend.pos:]
         table = np.ascontiguousarray(pend.table[None, :])
         fields = dict(bucket=bucket, tokens=rem, start=pend.pos,
-                      request_id=req.request_id)
+                      request_id=req.request_id, **self._state_from(pend))
         with self.tracer.span("prefill", slot=slot, cached=pend.hit_tokens,
                               **fields):
             tok, key, self._cache = self._final_chunk_step(
                 chunk, table, np.int32(pend.pos),
                 np.int32(rem), np.float32(sp.temperature),
                 np.int32(sp.top_k), np.float32(sp.top_p),
-                np.asarray(jax.random.PRNGKey(req.seed), np.uint32))
+                np.asarray(jax.random.PRNGKey(req.seed), np.uint32),
+                slot=self._state_slot(slot))
             pend.first = (tok, key, self._dispatches, fields)
             if self.spec_k:
                 # Mirror the final chunk into the draft arena (logits

@@ -219,6 +219,13 @@ def serving_collector(registry: MetricsRegistry,
         "serve_moe_max_rows": registry.gauge(
             "serve_moe_max_rows",
             "rows of the fullest held expert any one call has seen"),
+        "serve_state_slots": registry.gauge(
+            "serve_state_slots",
+            "slots holding per-slot model state (a short convolution's "
+            "tail) beside the page pool: decoding or mid-prefill"),
+        "serve_state_bytes": registry.gauge(
+            "serve_state_bytes",
+            "bytes of the state arena those slots' rows hold"),
         "serve_fence_covered_share": registry.gauge(
             "serve_fence_covered_share",
             "share of the engine's blocking reads of device results made "
@@ -295,6 +302,8 @@ def serving_collector(registry: MetricsRegistry,
                "moe_assignments": "serve_moe_assignments_total",
                "moe_experts_touched": "serve_moe_experts_touched_total",
                "moe_max_rows": "serve_moe_max_rows",
+               "state_slots": "serve_state_slots",
+               "state_bytes": "serve_state_bytes",
                "fence_covered_share": "serve_fence_covered_share",
                "kv_quant_bytes_saved": "serve_kv_quant_bytes_saved",
                "weight_quant_bytes_saved": "serve_weight_quant_bytes_saved"}

@@ -197,6 +197,11 @@ class ServingStats:
         self.moe_assignments = 0
         self.moe_experts_touched = 0
         self.moe_max_rows = 0
+        # Per-slot state beside the page pool (a ShortConv's tail): slots a
+        # request holds now, and their bytes. Gauges; 0 for a model of
+        # pages only.
+        self.state_slots = 0
+        self.state_bytes = 0
         # The engine's blocking reads of device results (its ``device_wait``
         # spans), and those of them made with a later program already
         # dispatched behind the awaited one: the device had work queued
@@ -396,6 +401,12 @@ class ServingStats:
         self.moe_max_rows = max(self.moe_max_rows, int(max_rows))
 
     @_locked
+    def record_state(self, state_slots: int, state_bytes: int) -> None:
+        """Latest state-arena occupancy. A gauge: no ``_tick()``."""
+        self.state_slots = int(state_slots)
+        self.state_bytes = int(state_bytes)
+
+    @_locked
     def record_fence(self, covered: int) -> None:
         self.fences += 1
         self.fences_covered += int(covered)
@@ -473,6 +484,8 @@ class ServingStats:
             "moe_assignments": self.moe_assignments,
             "moe_experts_touched": self.moe_experts_touched,
             "moe_max_rows": self.moe_max_rows,
+            "state_slots": self.state_slots,
+            "state_bytes": self.state_bytes,
             "fences": self.fences,
             "fences_covered": self.fences_covered,
             # Share of the blocking reads that had a program queued behind

@@ -330,7 +330,11 @@ def test_grouped_and_dense_serving_dispatch_agree_for_a_share():
 @pytest.mark.parametrize("rows, top_k, experts, dispatch, want", [
     (128, 2, 8, "ragged", "grouped"), (127, 2, 8, "ragged", "dense"),
     (1024, 8, 128, "ragged", "grouped"), (32, 8, 128, "ragged", "dense"),
-    (4096, 2, 8, "index", "dense")])
+    (4096, 2, 8, "index", "dense"),
+    # 32 experts top-4 (the conv-moe cell): a 128-slot decode has 16 rows an expert — timed on
+    # the chip in both forms, dense won (PR 31) — a 256-token chunk exactly 32
+    (128, 4, 32, "ragged", "dense"), (256, 4, 32, "ragged", "grouped"),
+    (512, 4, 32, "ragged", "grouped")])
 def test_serving_dispatch_is_one_stated_rule_on_rows_an_expert(rows, top_k, experts, dispatch, want):
     mo = moe.MoEConfig(num_experts=experts, top_k=top_k, dispatch=dispatch)
     assert moe.serving_dispatch(rows, mo) == want

@@ -51,6 +51,10 @@ def one_chip(chips):
 # ONE hd-64 KV head, a pool half a lane tile wide.
 MISTRAL = (32, 8, 128)
 SMALL_TP4 = (3, 1, 64)
+# lfm2-8b-a1b's attention layers as the chat-backlog-wide cell serves them:
+# 32 q heads in groups of 4 on 8 hd-64 KV heads (a pool 512 lanes wide, each
+# head a 64-lane slice of a tile), 128 slots a decode call.
+LFM2 = (32, 8, 64)
 PAGE_TOKENS, N_BLOCKS, NUM_PAGES = 32, 128, 2561
 
 
@@ -63,8 +67,11 @@ PAGE_TOKENS, N_BLOCKS, NUM_PAGES = 32, 128, 2561
     (MISTRAL, 1, 32, False, 3),         # a tile off the lane grid, ragged tail
     (SMALL_TP4, 1, 8, False, None),     # a page a cell, by the block pipeline
     (SMALL_TP4, 128, 1, True, None),
+    (LFM2, 1, 128, False, None),        # head size 64 at 128 rows
+    (LFM2, 128, 1, False, None),
 ], ids=["decode", "verify5", "chunk128", "decode-int8", "chunk128-int8",
-        "decode-3pages", "narrow-decode", "narrow-chunk128-int8"])
+        "decode-3pages", "narrow-decode", "narrow-chunk128-int8",
+        "hd64-decode-128rows", "hd64-chunk128"])
 def test_paged_kernel_compiles_for_v5e(one_chip, heads, sq, b, quant, pages):
     H, HKV, HD = heads
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -196,3 +203,47 @@ def test_sharded_step_runs_the_flash_kernel_on_each_chips_own_rows(chips, monkey
         operands = re.findall(r"bf16\[(\d+),128,128\]", line)
         assert operands and set(operands) == {"2"}, line
     assert "all-gather" not in text
+
+
+def test_the_conv_moe_cells_decode_program_compiles_and_fits_one_chip(one_chip, monkeypatch):
+    """`lfm2-8b-a1b-d14.chat-backlog-wide`'s decode program whole, at the
+    published widths and the cell's engine options, from shapes alone: 128
+    slots through 14 unrolled layers — the paged kernel at head size 64 in the
+    3 attention layers, the state arena advanced in place, every expert in the
+    dense form — and what it holds (9.33 GB of weights, 3.22 GB of pages, the
+    arena) beside its temporaries inside one v5e's 16 GB."""
+    import flax.linen as nn
+
+    from benchmarks.harness import family_conv_moe as fam
+    from benchmarks.harness import manifest as M
+    from k8s_distributed_deeplearning_tpu.models.transformer import PatternLM
+    from k8s_distributed_deeplearning_tpu.ops import pallas_gmm
+    from k8s_distributed_deeplearning_tpu.serve import engine as E
+    for mod in (pallas_paged_attn, pallas_gmm):
+        monkeypatch.setattr(mod, "on_tpu", lambda: True)         # compile, not interpret
+    cell = M.Cell(M.load_manifest(), "lfm2-8b-a1b-d14.chat-backlog-wide")
+    cfg, eng = cell.config, cell.options["engine"]
+    slots, pt, pages = eng["num_slots"], eng["page_tokens"], eng["kv_pool_pages"] + 1
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    model = PatternLM(*fam.program_config(cfg, eng["max_seq_len"]))
+    params = jax.tree.map(
+        lambda a: sds(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: nn.meta.unbox(model.init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])))
+    lanes = cfg["num_key_value_heads"] * cfg["head_dim"]
+    cache = {"transformer": {f"block_{i}": {"attn": (
+        {"conv_state": sds((slots, cfg["conv_L_cache"] - 1, cfg["hidden_size"]), jnp.bfloat16)}
+        if kind == "conv" else
+        {"cached_key": sds((pages, pt, lanes), jnp.bfloat16),
+         "cached_value": sds((pages, pt, lanes), jnp.bfloat16)})}
+        for i, kind in enumerate(cfg["layer_types"][:cfg["num_hidden_layers"]])}}
+    i32, f32 = (lambda *s: sds(s, jnp.int32)), (lambda *s: sds(s, jnp.float32))
+    compiled = E._decode_program.lower(
+        model, params, cache, i32(slots), i32(slots),
+        i32(slots, eng["max_seq_len"] // pt), f32(slots), i32(slots), f32(slots),
+        sds((slots, 2), jnp.uint32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert text.count("paged_attn") >= 3 and "moe_gmm" not in text
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 12.5e9 < mem.argument_size_in_bytes < 12.7e9 and held < 14e9
+    assert mem.alias_size_in_bytes > 3.2e9                      # pool and arena in place
