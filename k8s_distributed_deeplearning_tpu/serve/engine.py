@@ -61,7 +61,11 @@ tokens):
   right-pad garbage is never attended.
 - Per-slot sampling params are traced array operands (``temperature <= 0``
   => greedy; ``top_k == 0`` / ``top_p == 1.0`` => off), so heterogeneous
-  sampling across slots never recompiles.
+  sampling across slots never recompiles. The same operand decides on the
+  device what a step pays: with no temperature above 0 in the register file
+  (a freed slot's is reset) the sampler takes the argmax and splits the keys,
+  and skips the sort of the vocabulary, the softmax, the cumsum and the draw
+  (``_sample_slots``; counted as ``serve_sampler_sort_share``).
 
 Greedy decoding through this engine is token-identical to one-shot
 ``generate()`` for the same prompt — on the cold path, the prefix-hit path
@@ -118,29 +122,37 @@ def _sample_slots(logits: jax.Array, temps: jax.Array, top_ks: jax.Array,
     Same k-then-p semantics as :func:`models.generate.filter_logits`, but
     with k and p as array operands (one descending sort serves both); rows
     with ``temperature <= 0`` take the argmax instead.
+
+    Everything only a sampling row needs — the sort, the thresholds, the
+    softmax, the cumsum and the draw — runs under ONE ``lax.cond`` on
+    ``any(temps > 0)``: a step whose rows are all greedy pays the argmax and
+    the key split. The keys are split outside the branch, so the register
+    comes back the same whichever side ran. (Never ``vmap`` over this
+    function: the ``cond`` would become a ``select`` that runs both sides.)
     """
     v = logits.shape[-1]
-    greedy_tok = jnp.argmax(logits, axis=-1)
-    scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-    sorted_desc = -jnp.sort(-scaled, axis=-1)
-    k_eff = jnp.where(top_ks <= 0, v, jnp.clip(top_ks, 1, v))
-    kth = jnp.take_along_axis(sorted_desc, (k_eff - 1)[:, None], axis=-1)
-    filt = jnp.where(scaled < kth, -jnp.inf, scaled)
-    sorted_k = jnp.where(jnp.arange(v)[None, :] < k_eff[:, None],
-                         sorted_desc, -jnp.inf)
-    probs = jax.nn.softmax(sorted_k, axis=-1)
-    exclusive = jnp.cumsum(probs, axis=-1) - probs
-    n_keep = jnp.maximum(
-        jnp.sum(exclusive < top_ps[:, None], axis=-1, keepdims=True), 1)
-    thresh = jnp.take_along_axis(sorted_k, n_keep - 1, axis=-1)
-    filt = jnp.where(filt < thresh, -jnp.inf, filt)
+    greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    new_keys, subs = jnp.moveaxis(jax.vmap(jax.random.split)(keys), 1, 0)
 
-    def one(key, row):
-        new, sub = jax.random.split(key)
-        return new, jax.random.categorical(sub, row)
+    def sampled_branch():
+        scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+        sorted_desc = -jnp.sort(-scaled, axis=-1)
+        k_eff = jnp.where(top_ks <= 0, v, jnp.clip(top_ks, 1, v))
+        kth = jnp.take_along_axis(sorted_desc, (k_eff - 1)[:, None], axis=-1)
+        filt = jnp.where(scaled < kth, -jnp.inf, scaled)
+        sorted_k = jnp.where(jnp.arange(v)[None, :] < k_eff[:, None],
+                             sorted_desc, -jnp.inf)
+        probs = jax.nn.softmax(sorted_k, axis=-1)
+        exclusive = jnp.cumsum(probs, axis=-1) - probs
+        n_keep = jnp.maximum(
+            jnp.sum(exclusive < top_ps[:, None], axis=-1, keepdims=True), 1)
+        thresh = jnp.take_along_axis(sorted_k, n_keep - 1, axis=-1)
+        filt = jnp.where(filt < thresh, -jnp.inf, filt)
+        sampled = jax.vmap(jax.random.categorical)(subs, filt)
+        return jnp.where(temps <= 0.0, greedy_tok, sampled).astype(jnp.int32)
 
-    new_keys, sampled = jax.vmap(one)(keys, filt)
-    toks = jnp.where(temps <= 0.0, greedy_tok, sampled).astype(jnp.int32)
+    toks = jax.lax.cond(jnp.any(temps > 0.0), sampled_branch,
+                        lambda: greedy_tok)
     return new_keys, toks
 
 
@@ -1798,6 +1810,16 @@ class ServeEngine:
         self.stats.record_fence(covered)
         return self.tracer.span("device_wait", kind=kind, covered=covered)
 
+    def _count_sampled_rows(self) -> int:
+        """Rows of the register file that sample (``_temps > 0``: a freed
+        slot's is reset, a request's is set when it is activated), counted
+        where a decode or spec-verify program is about to be dispatched. The
+        program sees the same operand: with none it skips the sampler's sort
+        (:func:`_sample_slots`)."""
+        n = int(np.count_nonzero(self._temps > 0.0))
+        self.stats.record_sampler_step(n)
+        return n
+
     # graftlint: hot-path
     def _decode(self, rows: list[int], outputs: list[RequestOutput]) -> None:
         """Advance the occupied slots *rows* one token: one dispatch, the
@@ -1805,10 +1827,12 @@ class ServeEngine:
         bookkeeping per slot."""
         active = len(rows)
         # rows: the live rows; context_tokens: the positions they attend in
-        # all (a row at cursor n attends n + 1; other slots' cursors are 0).
+        # all (a row at cursor n attends n + 1; other slots' cursors are 0);
+        # sampled_rows: the rows the program's sampler sorts for, if any.
         with self.tracer.span(
                 "decode", active=active, rows=active,
-                context_tokens=int(self._kv_lens.sum()) + active) as span:
+                context_tokens=int(self._kv_lens.sum()) + active,
+                sampled_rows=self._count_sampled_rows()) as span:
             nxt, keys, self._cache = self._decode_step()
             seq = self._dispatches
             self._admissions(outputs)
@@ -1861,7 +1885,8 @@ class ServeEngine:
         cursor, never attended, overwritten in place by the next window
         before anything reads them."""
         with self.tracer.span("decode", active=len(rows),
-                              spec_k=self.spec_k):
+                              spec_k=self.spec_k,
+                              sampled_rows=self._count_sampled_rows()):
             regs = self._registers()
             window, self._draft_cache = self._spec_draft_step(regs)
             sel, key_states, acc, self._cache = self._spec_verify_step(

@@ -231,6 +231,11 @@ def serving_collector(registry: MetricsRegistry,
             "share of the engine's blocking reads of device results made "
             "with a later program already dispatched behind the awaited "
             "one (the device had work queued while the host waited)"),
+        "serve_sampler_sort_share": registry.gauge(
+            "serve_sampler_sort_share",
+            "share of the decode and spec-verify dispatches with a sampling "
+            "row (temperature > 0) resident: the steps whose sampler sorts "
+            "the vocabulary; an all-greedy step takes the argmax alone"),
         "serve_kv_quant_bytes_saved": registry.gauge(
             "serve_kv_quant_bytes_saved",
             "HBM bytes the int8 KV pool saves vs its fp equivalent "
@@ -305,6 +310,7 @@ def serving_collector(registry: MetricsRegistry,
                "state_slots": "serve_state_slots",
                "state_bytes": "serve_state_bytes",
                "fence_covered_share": "serve_fence_covered_share",
+               "sampler_sort_share": "serve_sampler_sort_share",
                "kv_quant_bytes_saved": "serve_kv_quant_bytes_saved",
                "weight_quant_bytes_saved": "serve_weight_quant_bytes_saved"}
 

@@ -208,6 +208,11 @@ class ServingStats:
         # while the host waited.
         self.fences = 0
         self.fences_covered = 0
+        # Decode and spec-verify dispatches, and those of them with a
+        # sampling row (temperature > 0) in the register file: the steps
+        # whose sampler sorts the vocabulary.
+        self.sampler_steps = 0
+        self.sampler_sort_steps = 0
 
     def _tick(self) -> None:
         now = time.perf_counter()
@@ -412,6 +417,11 @@ class ServingStats:
         self.fences_covered += int(covered)
 
     @_locked
+    def record_sampler_step(self, sampled_rows: int) -> None:
+        self.sampler_steps += 1
+        self.sampler_sort_steps += int(sampled_rows > 0)
+
+    @_locked
     def record_completion(self, latency_s: float, n_tokens: int,
                           reason: str) -> None:
         self._tick()
@@ -493,6 +503,13 @@ class ServingStats:
             "fence_covered_share": (
                 round(self.fences_covered / self.fences, 4)
                 if self.fences else None),
+            "sampler_steps": self.sampler_steps,
+            "sampler_sort_steps": self.sampler_sort_steps,
+            # Share of the decode / spec-verify dispatches whose sampler
+            # sorted (None until the first one).
+            "sampler_sort_share": (
+                round(self.sampler_sort_steps / self.sampler_steps, 4)
+                if self.sampler_steps else None),
             "spec_steps": self.spec_steps,
             "spec_proposed_tokens": self.spec_proposed_tokens,
             "spec_accepted_tokens": self.spec_accepted_tokens,

@@ -570,6 +570,47 @@ def test_fence_covered_share_counts_every_wait(tiny):
             in reg.render())
 
 
+@pytest.mark.parametrize("sampling_budget", [0, 5])
+def test_sampler_sort_share_counts_the_steps_with_a_sampling_row(
+        tiny, sampling_budget):
+    """``sampled_rows`` on a ``decode`` span = rows with a temperature > 0
+    in the register file at that dispatch (what the program's own predicate
+    sees); ``serve_sampler_sort_share`` = the share of such dispatches. An
+    all-greedy run has none. A sampling request of n tokens beside a longer
+    greedy one is live for n - 1 decode steps in a row (its first token
+    comes from its final chunk), and for no step after its slot is freed."""
+    from k8s_distributed_deeplearning_tpu.telemetry import bridge
+    from k8s_distributed_deeplearning_tpu.telemetry.registry import (
+        MetricsRegistry)
+    _, _, cfg = tiny
+    eng, take = _step_spans(tiny)
+    assert eng.stats.summary()["sampler_sort_share"] is None
+    reg = MetricsRegistry()
+    bridge.serving_collector(reg, eng.stats)
+    prompts, _ = _workload(cfg, 3, seed=5)
+    reqs = [Request(prompt=prompts[0], max_new_tokens=14),
+            Request(prompt=prompts[1], max_new_tokens=3)]
+    if sampling_budget:
+        reqs.insert(1, Request(
+            prompt=prompts[2], max_new_tokens=sampling_budget,
+            sampling=SamplingParams(temperature=0.8, top_k=12), seed=3))
+    outs = eng.run(reqs)
+    assert sorted(len(o.tokens) for o in outs) == sorted(
+        r.max_new_tokens for r in reqs)
+    rows = [d["sampled_rows"] for d in _named(take(), "decode")]
+    live = max(sampling_budget - 1, 0)
+    assert sum(rows) == live and set(rows) <= {0, 1}
+    first = rows.index(1) if live else 0
+    assert rows[first:first + live] == [1] * live
+    s = eng.stats.summary()
+    assert (s["sampler_steps"], s["sampler_sort_steps"]) == (len(rows), live)
+    assert s["sampler_sort_share"] == round(live / len(rows), 4)
+    (line,) = [ln for ln in reg.render().splitlines()
+               if ln.startswith("serve_sampler_sort_share ")]
+    assert float(line.split()[1]) == s["sampler_sort_share"]
+    assert not (eng._temps > 0).any()
+
+
 # One engine, requests arriving while others decode and prefill in chunks,
 # against the same requests served one at a time (where every first token is
 # read at once, as the engine did before it deferred them).

@@ -7,10 +7,12 @@ for a v5e that is described, not attached — about two seconds a kernel — so
 these cases hold the paged and the latent kernel to the benchmark cells' call
 shapes at the page count each one's own rule picks, and the flash kernels to the
 training cells' — alone on one chip, and inside a ``ShardedTrainer`` step over
-four. All of them live in this one file: the worker
+four — and the serving sampler to a ``conditional`` around its sort. All of
+them live in this one file: the worker
 that runs it is the one process that loads the TPU's library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -161,8 +163,6 @@ def test_sharded_step_runs_the_flash_kernel_on_each_chips_own_rows(chips, monkey
     by ``ShardedTrainer`` with no ``attention_fn`` (as the benchmark builds
     it), the step's kernels take 2 of the 8 rows each and nothing is
     gathered."""
-    import re
-
     import flax.linen as nn
     import optax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -205,6 +205,51 @@ def test_sharded_step_runs_the_flash_kernel_on_each_chips_own_rows(chips, monkey
     assert "all-gather" not in text
 
 
+def _reached_outside_conditionals(text, instruction):
+    """Names of the computations of compiled module *text* that hold an
+    *instruction* (``"sort"``) AND are reached from ENTRY by a path with no
+    ``conditional`` on it: what the program runs whatever its operands are."""
+    blocks, entry, name = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$", line)
+        if head:
+            name = head.group(2)
+            blocks[name] = []
+            entry = name if head.group(1) else entry
+        elif name is not None:
+            blocks[name].append(line)
+    assert entry is not None
+    seen, todo = set(), [entry]
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in blocks[name]:
+            if re.search(r"\bconditional\(", line):
+                continue
+            todo.extend(n for n in re.findall(r"%([\w.\-]+)", line) if n in blocks)
+    return sorted(n for n in seen if any(
+        re.search(rf"\b{instruction}\(", line) for line in blocks[n]))
+
+
+def test_the_sampler_sorts_only_inside_a_conditional(one_chip):
+    """``_sample_slots`` alone at the Mistral cell's decode shape (the `lfm2`
+    cell's is held inside its whole decode program, below): the TPU compiler
+    keeps the branch a ``conditional`` — no ``select`` over both sides — and
+    no computation on the unconditional path sorts."""
+    from k8s_distributed_deeplearning_tpu.serve import engine as E
+    rows, vocab = 32, 32768
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    text = jax.jit(E._sample_slots).lower(
+        sds((rows, vocab), jnp.float32), sds((rows,), jnp.float32),
+        sds((rows,), jnp.int32), sds((rows,), jnp.float32),
+        sds((rows, 2), jnp.uint32)).compile().as_text()
+    assert " sort(" in text and " conditional(" in text
+    assert _reached_outside_conditionals(text, "sort") == []
+    assert _reached_outside_conditionals(text, "conditional")     # the walk sees ENTRY
+
+
 def test_the_conv_moe_cells_decode_program_compiles_and_fits_one_chip(one_chip, monkeypatch):
     """`lfm2-8b-a1b-d14.chat-backlog-wide`'s decode program whole, at the
     published widths and the cell's engine options, from shapes alone: 128
@@ -244,6 +289,9 @@ def test_the_conv_moe_cells_decode_program_compiles_and_fits_one_chip(one_chip, 
         sds((slots, 2), jnp.uint32)).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert text.count("paged_attn") >= 3 and "moe_gmm" not in text
+    # The sampler's sort over [128, 65536] is there, and only a step with a
+    # sampling row runs it.
+    assert " sort(" in text and _reached_outside_conditionals(text, "sort") == []
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 12.5e9 < mem.argument_size_in_bytes < 12.7e9 and held < 14e9
     assert mem.alias_size_in_bytes > 3.2e9                      # pool and arena in place
