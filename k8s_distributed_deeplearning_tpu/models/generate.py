@@ -91,11 +91,12 @@ def prefill_chunk(model, params: PyTree, cache: PyTree, chunk: jax.Array, *,
     from its absolute position, not from any cursor.
 
     ``lengths`` ([B] int32; only for a model with a mixer that carries state
-    from token to token, :class:`models.transformer.ShortConv`): how many of
+    from token to token, :class:`models.transformer.ShortConv` or
+    :class:`models.transformer.Mamba2`): how many of
     a RIGHT-PADDED chunk's tokens are real. Attention needs no such thing (pad
     K/V lie beyond the cursor, never attended); a state must be left as the
     last real token left it, not as the pad did. The state itself is a leaf of
-    ``cache`` (``conv_state``, one row per row of the call), read before the
+    ``cache`` (``conv_state``, ``ssm_state``; one row per row of the call), read before the
     chunk and written after it: a caller resumes a prompt by handing back the
     cache the previous chunk returned.
     """
@@ -157,7 +158,8 @@ def slot_decode_step(model, params: PyTree, cache: PyTree,
     reusable without clearing. A model with state beside its pages
     (``conv_state`` leaves, ``[B, ...]``: row i is slot i's) advances every
     row's in place, a free slot's too — its next request starts from zeros
-    (the engine's chunk programs see to that)."""
+    (the engine's chunk programs see to that); a ``Mamba2``'s ``ssm_state``
+    is advanced for the rows with a cursor (``slot_positions > 0``) alone."""
     logits, vars_ = model.apply({"params": params, "cache": cache},
                                 tokens[:, None], decode=True,
                                 cache_positions=slot_positions,

@@ -42,8 +42,9 @@ later in choice-major order) — asserted by parity tests.
 
 Serving (``decode=True``) is dropless and per-token whatever the config says,
 by ONE rule on the rows a call has (:func:`serving_dispatch`): the grouped
-kernel from ``GROUPED_MIN_ROWS_PER_EXPERT`` rows an expert, every held expert
-on every row below. A layer may be ONE CHIP'S SHARE of an expert-parallel
+kernel from ``GROUPED_MIN_ROWS_PER_EXPERT`` rows an expert or
+``GROUPED_MIN_ROW_BLOCKS`` row blocks' worth of rows, every held expert on every
+row below. A layer may be ONE CHIP'S SHARE of an expert-parallel
 deployment (``experts_held`` / ``expert_offset``): it routes over all the
 experts and computes the part its own experts give. The router's conventions
 (softmax, or sigmoid scores with a selection-only bias and scaled gates) and
@@ -63,8 +64,9 @@ import jax.numpy as jnp
 import optax
 
 from k8s_distributed_deeplearning_tpu.models.transformer import (
-    MLP, LatentAttention, LatentAttentionConfig, LayerKind, LMHead, ShortConv,
-    Transformer, TransformerConfig, default_init, lm_batch_views, lm_forward)
+    MLP, LatentAttention, LatentAttentionConfig, LayerKind, LMHead, Mamba2,
+    Mamba2Config, ShortConv, Transformer, TransformerConfig, default_init,
+    lm_batch_views, lm_forward, param_dense)
 
 Dtype = Any
 
@@ -106,6 +108,18 @@ class MoEConfig:
     routed_scale: float = 1.0
     shared_experts: int = 0          # always-on experts beside the routed
     expert_mlp_dim: int | None = None   # routed/shared width (None: cfg's)
+    # The experts' own form. "swiglu": three matrices, silu(W_g x) * W_u x.
+    # "relu2": TWO matrices, W_d relu(W_u x)^2, no gate (the Nemotron-H
+    # family); the shared expert takes the same activation.
+    expert_act: str = "swiglu"       # "swiglu" | "relu2"
+    # Latent experts: the routed experts see ``latent_dim`` lanes — one
+    # projection down before them (``fc1_latent``) and one up after their
+    # gated sum (``fc2_latent``); the router and the shared expert see the
+    # whole hidden state. None: the experts see the hidden state itself.
+    latent_dim: int | None = None
+    # The shared expert's width where it is not ``expert_mlp_dim x
+    # shared_experts`` (one shared expert of a width of its own).
+    shared_mlp_dim: int | None = None
     # One chip's share of an expert-parallel layer: the router keeps all
     # ``num_experts`` outputs and the top-k over them; this module holds
     # experts [expert_offset, expert_offset + experts_held) and computes
@@ -124,6 +138,9 @@ class MoEConfig:
         if self.score_fn not in ("softmax", "sigmoid"):
             raise ValueError(f"score_fn must be 'softmax' or 'sigmoid', "
                              f"got {self.score_fn!r}")
+        if self.expert_act not in ("swiglu", "relu2"):
+            raise ValueError(f"expert_act must be 'swiglu' or 'relu2', "
+                             f"got {self.expert_act!r}")
         if not 0 <= self.expert_offset <= self.num_experts - self.held:
             raise ValueError(
                 f"experts [{self.expert_offset}, {self.expert_offset} + "
@@ -154,16 +171,30 @@ class MoEConfig:
 # kernel's row block is 128, so 16 rows are padded to the same products the
 # dense form does, plus the sort and the gather). The constant stays.
 GROUPED_MIN_ROWS_PER_EXPERT = 32
+# What the rows an expert alone cannot see: the all-rows form computes every
+# ROW of the call in every held expert, the grouped form one row block of the
+# kernel (``ragged_block_m``) an expert at the least. With many experts and a
+# wide call the two part ways below 32 rows an expert: 512 rows, top-22 of 512
+# (22 rows an expert, 128 held: PR 33) is 128 x 512 row-products a layer in the
+# all-rows form — 3.6 TFLOP a chunk, 23.4 ms a chunk program at 95 % of the
+# MXU's peak — against 128 blocks of 128 rows; the grouped form served 2,243
+# tok/s against 2,193 there. At ONE block's worth of rows (128 rows, block 128:
+# PR 31's decode step above) the all-rows form wins. So, below the constant
+# above, the grouped kernel from this many row blocks' worth of rows.
+GROUPED_MIN_ROW_BLOCKS = 4
 
 
 def serving_dispatch(rows: int, moe: "MoEConfig") -> str:
     """The serving (``decode=True``) dispatch of a call with *rows* tokens,
     from what the call can see: ``"grouped"`` (dropless grouped matmul,
     :mod:`ops.pallas_gmm` — a ``dispatch="ragged"`` config at enough rows an
-    expert) or ``"dense"`` (every held expert on every row, gated)."""
+    expert, or at enough rows against the kernel's row block) or ``"dense"``
+    (every held expert on every row, gated)."""
+    if moe.dispatch != "ragged":
+        return "dense"
     per_expert = rows * moe.top_k / moe.num_experts
-    return ("grouped" if moe.dispatch == "ragged"
-            and per_expert >= GROUPED_MIN_ROWS_PER_EXPERT else "dense")
+    return ("grouped" if per_expert >= GROUPED_MIN_ROWS_PER_EXPERT
+            or rows >= GROUPED_MIN_ROW_BLOCKS * moe.ragged_block_m else "dense")
 
 
 def clamped_capacity(tokens: int, moe: "MoEConfig") -> int:
@@ -211,6 +242,51 @@ def _topk_assignments(logits: jax.Array, k: int,
     if moe is not None and moe.routed_scale != 1.0:
         gate_stack = gate_stack * moe.routed_scale
     return scores, idx_list, assign, gate_stack
+
+
+# Above this many choices the serving dispatches take the router's choice in
+# ONE pass (:func:`_topk_one_pass`) and keep it as ``[T, k]`` arrays: the
+# k-fold loop of :func:`_topk_assignments` and the k-fold loops behind it
+# (one-hots, cumsums, scatters, gathers) are ~15 small device operations a
+# choice and layer — written for k <= 8, and 22 choices of 512 experts make
+# them thousands of operations a program. The k <= 8 branch is untouched.
+ONE_PASS_TOPK_ABOVE = 8
+
+
+def _topk_one_pass(logits: jax.Array, k: int, moe: "MoEConfig",
+                   bias: jax.Array | None = None):
+    """The choices of :func:`_topk_assignments` — the same experts in the same
+    order (``lax.top_k`` breaks a tie for the lower index, as the loop's
+    argmax does) and the same renormalised, scaled gates — from one sort:
+    (scores [T, E] f32, idx [T, k] int32, gates [T, k] f32)."""
+    sigmoid = moe.score_fn == "sigmoid"
+    scores = (jax.nn.sigmoid if sigmoid else
+              functools.partial(jax.nn.softmax, axis=-1))(
+                  logits.astype(jnp.float32))
+    select = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, idx = jax.lax.top_k(select, k)
+    picked = jnp.take_along_axis(scores, idx, axis=1)
+    gates = picked / jnp.maximum(jnp.sum(picked, axis=1, keepdims=True), 1e-9)
+    if moe.routed_scale != 1.0:
+        gates = gates * moe.routed_scale
+    return scores, idx.astype(jnp.int32), gates
+
+
+def _row_block(t: int, k: int, moe: "MoEConfig") -> int:
+    """The grouped layout's row block for a call of *t* tokens: the
+    configured one, clipped to the call's width — at decode steps (t = B) the
+    configured 512 block would pad 16 real rows to 4.6k (one mostly-dead block
+    per expert) and measure 2.2x SLOWER than the capacity path; a t*k-sized
+    block keeps m_pad ~ (E+1)*t*k."""
+    return min(moe.ragged_block_m, max(8, 1 << (t * k - 1).bit_length()))
+
+
+def _experts_hidden(gmm, xs, w_gate, w_up):
+    """The grouped experts' hidden activation: gated (``silu(W_g x) * W_u x``)
+    or, with no gate matrix, squared ReLU."""
+    if w_gate is None:
+        return jnp.square(nn.relu(gmm(xs, w_up)))
+    return nn.silu(gmm(xs, w_gate)) * gmm(xs, w_up)
 
 
 def _z_loss(logits: jax.Array) -> jax.Array:
@@ -390,19 +466,37 @@ class MoEMLP(nn.Module):
                 name, nn.with_logical_partitioning(default_init(), axes),
                 shape, jnp.float32).astype(cfg.dtype)
 
-        w_gate = expert_param("w_gate", (held, d, mlp), ("expert", "embed", "mlp"))
-        w_up = expert_param("w_up", (held, d, mlp), ("expert", "embed", "mlp"))
-        w_down = expert_param("w_down", (held, mlp, d), ("expert", "mlp", "embed"))
+        gated = moe.expert_act == "swiglu"
+        # what the routed experts see: the hidden state, or its latent
+        lat = moe.latent_dim or d
+        if moe.latent_dim is not None:
+            if not (decode or held != e):
+                raise NotImplementedError(
+                    "latent experts (latent_dim) are a serving layout: the "
+                    "capacity dispatches are not written for them")
+            tokens_e = param_dense(lat, ("embed", None), "fc1_latent",
+                                   cfg.dtype)(x).reshape(t, lat)
+        else:
+            tokens_e = tokens
+        w_gate = (expert_param("w_gate", (held, lat, mlp),
+                               ("expert", "embed", "mlp")) if gated else None)
+        w_up = expert_param("w_up", (held, lat, mlp), ("expert", "embed", "mlp"))
+        w_down = expert_param("w_down", (held, mlp, lat), ("expert", "mlp", "embed"))
         shared = 0.0
         if moe.shared_experts:
             shared = MLP(dataclasses.replace(
-                cfg, mlp_dim=mlp * moe.shared_experts), name="shared")(x)
+                cfg, mlp_dim=moe.shared_mlp_dim or mlp * moe.shared_experts,
+                **({} if gated else {"activation": moe.expert_act})),
+                name="shared")(x)
 
         def experts_apply(xe):
             """[E, C, d] expert buffers -> [E, C, d] outputs."""
             xe = nn.with_logical_constraint(xe, ("expert", None, "embed"))
-            h = jnp.einsum("ecd,edm->ecm", xe, w_gate)
-            h = nn.silu(h) * jnp.einsum("ecd,edm->ecm", xe, w_up)
+            if gated:
+                h = jnp.einsum("ecd,edm->ecm", xe, w_gate)
+                h = nn.silu(h) * jnp.einsum("ecd,edm->ecm", xe, w_up)
+            else:
+                h = jnp.square(nn.relu(jnp.einsum("ecd,edm->ecm", xe, w_up)))
             h = nn.with_logical_constraint(h, ("expert", None, "mlp"))
             ye = jnp.einsum("ecm,emd->ecd", h, w_down)
             return nn.with_logical_constraint(ye, ("expert", None, "embed"))
@@ -418,11 +512,14 @@ class MoEMLP(nn.Module):
             # experts (experts_held) is a serving layout: its plain forward
             # takes this path too, and sows no auxiliary loss.
             if serving_dispatch(t, moe) == "grouped":
-                y, _ = self._ragged_dispatch(tokens, logits, w_gate, w_up,
+                y, _ = self._ragged_dispatch(tokens_e, logits, w_gate, w_up,
                                              w_down, decode=True, bias=bias)
             else:
-                y = self._dense_serving(tokens, logits, bias, experts_apply)
-            return y.reshape(b, s, d) + shared
+                y = self._dense_serving(tokens_e, logits, bias, experts_apply)
+            y = y.reshape(b, s, lat)
+            if moe.latent_dim is not None:
+                y = param_dense(d, (None, "embed"), "fc2_latent", cfg.dtype)(y)
+            return y + shared
         if moe.dispatch == "ragged":
             y, aux = self._ragged_dispatch(tokens, logits,
                                            w_gate, w_up, w_down, bias=bias)
@@ -455,6 +552,23 @@ class MoEMLP(nn.Module):
                  reduce_fn=lambda _, new: new, init_fn=lambda: None)
         return local, mine, assign_held, counts
 
+    def _held_picks_one_pass(self, idx):
+        """:meth:`_held_picks` for choices kept as one array ``idx`` [..., k]
+        (:func:`_topk_one_pass`): (one-hot over the HELD experts
+        [..., k, held] — all zero where the pick is held elsewhere —, whether
+        it is held, the local index clipped, the count per held expert, sown
+        as ``moe_stats/assignments``)."""
+        moe = self.moe
+        lo, held = moe.expert_offset, moe.held
+        mine = (idx >= lo) & (idx < lo + held)
+        local = jnp.clip(idx - lo, 0, held - 1)
+        one_hot = jax.nn.one_hot(jnp.where(mine, local, held), held,
+                                 dtype=jnp.float32)
+        counts = jnp.sum(one_hot.reshape(-1, held), axis=0)
+        self.sow("moe_stats", "assignments", counts.astype(jnp.int32),
+                 reduce_fn=lambda _, new: new, init_fn=lambda: None)
+        return one_hot, mine, local, counts
+
     def _dense_serving(self, tokens, logits, bias, experts_apply):
         """Every held expert on every row, gated: ``y_t = Σ_e w[t, e]
         E_e(x_t)`` with ``w`` the gate where token t chose held expert e and
@@ -462,10 +576,15 @@ class MoEMLP(nn.Module):
         products are bound by reading each expert's weights, which this
         reads once."""
         cfg, moe = self.cfg, self.moe
-        _, idx_list, assign, gate_stack = _topk_assignments(
-            logits, moe.top_k, moe, bias)
-        _, _, assign_held, _ = self._held_picks(idx_list, assign)
-        w = sum(a * g[:, None] for a, g in zip(assign_held, gate_stack))
+        if moe.top_k > ONE_PASS_TOPK_ABOVE:
+            _, idx, gates = _topk_one_pass(logits, moe.top_k, moe, bias)
+            one_hot, _, _, _ = self._held_picks_one_pass(idx)
+            w = jnp.einsum("tk,tke->te", gates, one_hot)
+        else:
+            _, idx_list, assign, gate_stack = _topk_assignments(
+                logits, moe.top_k, moe, bias)
+            _, _, assign_held, _ = self._held_picks(idx_list, assign)
+            w = sum(a * g[:, None] for a, g in zip(assign_held, gate_stack))
         tok_c = tokens.astype(cfg.dtype)
         ye = experts_apply(jnp.broadcast_to(
             tok_c[None], (moe.held,) + tok_c.shape))            # [E, T, d]
@@ -553,6 +672,9 @@ class MoEMLP(nn.Module):
         global batch mean). Expert weights stay replicated inside the
         wrap — the expert axis remains the index path's domain."""
         mesh = self.shard_mesh
+        if mesh is not None and w_gate is None:
+            raise NotImplementedError(
+                "shard_mesh wraps the gated (swiglu) experts' three operands")
         if mesh is not None:
             sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
             # "sequence" belongs in the row partition too: the flattened
@@ -616,6 +738,9 @@ class MoEMLP(nn.Module):
         t, d = tokens.shape
         k = moe.top_k
         tok_c = tokens.astype(cfg.dtype)
+        if decode and k > ONE_PASS_TOPK_ABOVE:
+            return self._ragged_serving_one_pass(tok_c, logits, w_gate, w_up,
+                                                 w_down, bias)
 
         probs, idx_list, assign, gate_stack = _topk_assignments(
             logits, k, moe, bias)
@@ -623,14 +748,8 @@ class MoEMLP(nn.Module):
         # unless this module is one chip's share); the others' destination is
         # the out-of-range sentinel, dropped by the scatter and gated to 0.
         local, mine, assign_held, counts = self._held_picks(idx_list, assign)
-        # Row block clipped to the call width: at decode steps (t = B)
-        # the configured 512 block would pad 16 real rows to 4.6k (one
-        # mostly-dead block per expert) and measure 2.2x SLOWER than the
-        # capacity path; a t*k-sized block keeps m_pad ~ (E+1)*t*k.
-        bm = min(moe.ragged_block_m,
-                 max(8, 1 << (t * k - 1).bit_length()))
         layout = pallas_gmm.grouped_layout(
-            counts.astype(jnp.int32), t * k, block_m=bm)
+            counts.astype(jnp.int32), t * k, block_m=_row_block(t, k, moe))
 
         used = jnp.zeros((moe.held,), jnp.float32)
         dests = []
@@ -672,8 +791,7 @@ class MoEMLP(nn.Module):
         from jax.ad_checkpoint import checkpoint_name
         gmm = lambda x, w: checkpoint_name(
             pallas_gmm.gmm(x, w, layout), "gmm_out")
-        h = nn.silu(gmm(xs, w_gate)) * gmm(xs, w_up)
-        ys = gmm(h, w_down)
+        ys = gmm(_experts_hidden(gmm, xs, w_gate, w_up), w_down)
         # Serving sums the k gated rows in f32, as the dense serving form
         # does (the two serving dispatches agree); training sums in the
         # model's type.
@@ -690,6 +808,41 @@ class MoEMLP(nn.Module):
         f = jnp.mean(assign[0], axis=0)
         p = jnp.mean(probs, axis=0)
         return y, (f, p, _z_loss(logits))
+
+
+    def _ragged_serving_one_pass(self, tok_c, logits, w_gate, w_up, w_down,
+                                 bias):
+        """:meth:`_ragged_core`'s serving form for many choices a token
+        (``top_k > ONE_PASS_TOPK_ABOVE``): the same rows in the same layout —
+        a held expert's rows in choice-major, then token order — from ONE
+        cumsum over the ``k T`` picks, one scatter of their sources, one
+        gather and one gated sum, where the loop form does each k times."""
+        from k8s_distributed_deeplearning_tpu.ops import pallas_gmm
+
+        cfg, moe = self.cfg, self.moe
+        t, k = tok_c.shape[0], moe.top_k
+        probs, idx, gates = _topk_one_pass(logits, k, moe, bias)
+        # choice-major: pick (c, t) at c * T + t
+        one_hot, mine, local, counts = self._held_picks_one_pass(idx.T)
+        one_hot, mine, local = (one_hot.reshape(k * t, -1), mine.reshape(-1),
+                                local.reshape(-1))
+        layout = pallas_gmm.grouped_layout(counts.astype(jnp.int32), t * k,
+                                           block_m=_row_block(t, k, moe))
+        pos = jnp.sum((jnp.cumsum(one_hot, axis=0) - one_hot) * one_hot,
+                      axis=-1).astype(jnp.int32)
+        dest = jnp.where(mine, layout.row_offset[local] + pos, layout.m_pad)
+        src = jnp.full((layout.m_pad,), -1, jnp.int32).at[dest].set(
+            jnp.tile(jnp.arange(t, dtype=jnp.int32), k), mode="drop")
+        xs = jnp.where((src >= 0)[:, None],
+                       jnp.take(tok_c, jnp.maximum(src, 0), axis=0), 0)
+        gmm = lambda x, w: pallas_gmm.gmm_forward(x, w, layout)
+        ys = gmm(_experts_hidden(gmm, xs, w_gate, w_up), w_down)
+        gate = (gates.T.reshape(-1) * mine)[:, None]               # f32
+        rows = jnp.take(ys, jnp.minimum(dest, layout.m_pad - 1), axis=0)
+        y = jnp.sum((rows * gate).reshape(k, t, -1), axis=0).astype(cfg.dtype)
+        first = jax.nn.one_hot(idx[:, 0], moe.num_experts, dtype=jnp.float32)
+        return y, (jnp.mean(first, axis=0), jnp.mean(probs, axis=0),
+                   _z_loss(logits))
 
 
 class MoELM(nn.Module):
@@ -826,6 +979,30 @@ def conv_moe_pattern(layer_types: tuple[str, ...], moe: MoEConfig,
                                     mlp=experts if sparse else None)
              for t in ("conv", "full_attention") for sparse in (False, True)}
     return tuple(kinds[t, i >= num_dense] for i, t in enumerate(layer_types))
+
+
+@functools.lru_cache(maxsize=None)
+def hybrid_pattern(layers: str, moe: MoEConfig | None,
+                   mamba: Mamba2Config) -> tuple[LayerKind, ...]:
+    """The layer pattern of the Mamba-2 + attention + sparse-expert family
+    (Nemotron-H layout) from its ``hybrid_override_pattern`` string, for
+    :class:`~models.transformer.PatternLM`. Every layer is ONE sub-layer
+    (``LayerKind.solo``): ``M`` a :class:`~models.transformer.Mamba2` mixer,
+    ``*`` attention, ``E`` a :class:`MoEMLP`, ``-`` the dense MLP. One
+    :class:`LayerKind` object a letter and one tuple per argument set
+    (cached), as :func:`conv_moe_pattern`."""
+    kinds = {"M": LayerKind(attention=functools.partial(Mamba2, mamba=mamba),
+                            solo="mixer"),
+             "*": LayerKind(solo="mixer"),
+             "-": LayerKind(solo="mlp")}
+    if moe is not None:
+        kinds["E"] = LayerKind(mlp=functools.partial(MoEMLP, moe=moe),
+                               solo="mlp")
+    unknown = set(layers) - set(kinds)
+    if unknown:
+        raise ValueError(f"the pattern names {sorted(unknown)}; known: "
+                         f"{sorted(kinds)}")
+    return tuple(kinds[c] for c in layers)
 
 
 def moe_config_of(model) -> MoEConfig | None:

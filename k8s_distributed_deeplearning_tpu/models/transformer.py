@@ -36,6 +36,8 @@ from k8s_distributed_deeplearning_tpu.ops import attention as attention_ops
 from k8s_distributed_deeplearning_tpu.ops import collectives
 from k8s_distributed_deeplearning_tpu.ops import pallas_latent_attn
 from k8s_distributed_deeplearning_tpu.ops import pallas_paged_attn
+from k8s_distributed_deeplearning_tpu.ops import pallas_ssm
+from k8s_distributed_deeplearning_tpu.backend import on_tpu
 
 Dtype = Any
 default_init = nn.initializers.xavier_uniform
@@ -89,7 +91,7 @@ class TransformerConfig:
     mlp_dim: int | None = None          # default 4*dim (gelu) / per-family
     max_seq_len: int = 2048
     causal: bool = True
-    activation: str = "swiglu"          # "swiglu" | "gelu"
+    activation: str = "swiglu"          # "swiglu" | "gelu" | "relu2"
     norm: str = "rmsnorm"               # "rmsnorm" | "layernorm"
     position: str = "rope"              # "rope" | "learned" | "none"
     rope_theta: float = 500000.0        # Llama-3 default
@@ -984,9 +986,276 @@ class ShortConv(nn.Module):
         return nn.with_logical_constraint(out, ("batch", "seq", "act_embed"))
 
 
+@dataclasses.dataclass(frozen=True)
+class Mamba2Config:
+    """A Mamba-2 mixer's sizes (the Nemotron-H family's config keys in
+    brackets): ``num_heads`` heads [mamba_num_heads] of ``head_dim`` lanes
+    [mamba_head_dim], ``n_groups`` groups of B and C [n_groups] (head h uses
+    group ``h // (num_heads / n_groups)``), a state of ``state_size`` columns a
+    lane [ssm_state_size], a causal depthwise convolution of ``conv_kernel``
+    taps [conv_kernel] with a bias, and the chunk of the full-sequence form
+    [chunk_size]. ``time_step_*`` are the published initialisation of
+    ``dt_bias``. ``state_dtype``: what the carried state is kept in (float32:
+    the published serving recipe's; the convolution's tail is the model's
+    type). ``update_impl``: the one-token update — ``"kernel"``
+    (:mod:`ops.pallas_ssm`), ``"xla"``, or ``"auto"`` (:func:`ssm_update_impl`)."""
+
+    num_heads: int = 128
+    head_dim: int = 64
+    n_groups: int = 8
+    state_size: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    state_dtype: Dtype = jnp.float32
+    update_impl: str = "auto"
+
+    @property
+    def inner(self) -> int:
+        return self.num_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.inner + 2 * self.n_groups * self.state_size
+
+    @property
+    def state_rows_lanes(self) -> tuple[int, int]:
+        """One row's ``ssm_state`` (the update kernel's layout)."""
+        return pallas_ssm.state_shape(self.num_heads, self.head_dim,
+                                      self.state_size, self.n_groups)
+
+
+def ssm_update_impl(mamba: Mamba2Config) -> str:
+    """Which implementation a one-token state update resolves to:
+    ``"kernel"`` (:func:`ops.pallas_ssm.ssm_update`: in place over the live
+    rows) or ``"xla"``. ``"auto"``: the kernel on a TPU for a float32 state."""
+    if mamba.update_impl != "auto":
+        return mamba.update_impl
+    return ("kernel" if on_tpu() and mamba.state_dtype == jnp.float32
+            else "xla")
+
+
+def mamba_config_of(model) -> Mamba2Config | None:
+    """The :class:`Mamba2Config` the :class:`Mamba2` factories of *model*'s
+    ``pattern`` were made with; None for a model with no such layer."""
+    for kind in getattr(model, "pattern", None) or ():
+        mamba = getattr(kind.attention, "keywords", {}).get("mamba")
+        if mamba is not None:
+            return mamba
+    return None
+
+
+def ssd_chunked(xs, dt, a, bmat, cmat, h0, chunk: int, dtype):
+    """The state-space recurrence over a whole sequence in the chunked
+    (state-space-duality) form: within a chunk of ``chunk`` tokens the output
+    is a masked matrix product, between chunks a state is carried — so a
+    512-token prefill chunk is matrix products and 4 steps, not 512.
+
+    xs ``[B, S, H, P]``, dt ``[B, S, H]`` f32 (after the softplus; 0 where a
+    token must not move the state), a ``[H]`` f32 (negative), bmat / cmat
+    ``[B, S, G, N]``, h0 ``[B, H, P, N]`` f32. -> (y ``[B, S, H, P]`` f32
+    without the ``D x`` term, the state after the last token, f32). Decays
+    and sums are float32; the products' operands are *dtype*."""
+    b, s, h, p = xs.shape
+    g, n = bmat.shape[2:]
+    r = h // g
+    pad = -s % chunk
+    if pad:
+        xs, dt, bmat, cmat = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
+                              for v in (xs, dt, bmat, cmat))
+    nc = (s + pad) // chunk
+    f32 = jnp.float32
+    xs = xs.reshape(b, nc, chunk, g, r, p).astype(dtype)
+    dt = dt.reshape(b, nc, chunk, g, r)
+    bmat = bmat.reshape(b, nc, chunk, g, n).astype(dtype)
+    cmat = cmat.reshape(b, nc, chunk, g, n).astype(dtype)
+    cs = jnp.cumsum(dt * a.reshape(g, r), axis=2)           # [B, nc, L, G, R]
+    # inside a chunk: y_l = sum_{s <= l} (C_l . B_s) exp(cs_l - cs_s) dt_s x_s
+    cb = jnp.einsum("bclgn,bcsgn->bcgls", cmat, bmat, preferred_element_type=f32)
+    seg = cs[:, :, :, None] - cs[:, :, None, :]             # [B, nc, L, S, G, R]
+    tri = (jnp.arange(chunk)[:, None] >= jnp.arange(chunk)[None, :])
+    w = jnp.where(tri[None, None, :, :, None, None], jnp.exp(seg), 0.0)
+    w = w * dt[:, :, None] * jnp.moveaxis(cb, 2, 4)[..., None]
+    y = jnp.einsum("bclsgr,bcsgrp->bclgrp", w.astype(dtype), xs,
+                   preferred_element_type=f32)
+    # what a chunk adds to the state by its end, and the state carried in
+    to_end = jnp.exp(cs[:, :, -1:] - cs) * dt               # [B, nc, L, G, R]
+    added = jnp.einsum("bclgrp,bclgn->bcgrpn",
+                       (xs.astype(f32) * to_end[..., None]).astype(dtype), bmat,
+                       preferred_element_type=f32)
+    whole = jnp.exp(cs[:, :, -1])                           # [B, nc, G, R]
+
+    def carry(hc, step):
+        dec, add = step
+        return dec[..., None, None] * hc + add, hc
+    h_end, h_in = jax.lax.scan(
+        carry, h0.astype(f32).reshape(b, g, r, p, n),
+        (jnp.moveaxis(whole, 1, 0), jnp.moveaxis(added, 1, 0)))
+    h_in = jnp.moveaxis(h_in, 0, 1)                         # [B, nc, G, R, P, N]
+    y = y + (jnp.einsum("bclgn,bcgrpn->bclgrp", cmat, h_in.astype(dtype),
+                        preferred_element_type=f32)
+             * jnp.exp(cs)[..., None])
+    return (y.reshape(b, nc * chunk, h, p)[:, :s], h_end.reshape(b, h, p, n))
+
+
+class GroupRMSNorm(nn.Module):
+    """RMSNorm over each of ``groups`` equal runs of the lanes, with one
+    learned gain over all of them (a Mamba-2 mixer's output norm)."""
+
+    groups: int
+    eps: float = 1e-6
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        scale = self.param(
+            "scale", nn.with_logical_partitioning(nn.initializers.ones, ("mlp",)),
+            (x.shape[-1],), jnp.float32)
+        xg = x.astype(jnp.float32).reshape(x.shape[:-1] + (self.groups, -1))
+        xg = xg * jax.lax.rsqrt(
+            jnp.mean(jnp.square(xg), axis=-1, keepdims=True) + self.eps)
+        return (xg.reshape(x.shape) * scale).astype(self.dtype)
+
+
+class Mamba2(nn.Module):
+    """Mamba-2 mixer — the ``M`` layers of the Nemotron-H family, a
+    :class:`LayerKind` ``attention`` factory beside :class:`ShortConv` (same
+    keywords; tables and positions mean nothing to it):
+
+        [z | xBC | dt] = W_in x            (no bias)
+        xBC = silu(conv(xBC))              (depthwise, causal, WITH bias)
+        x [H, P], B [G, N], C [G, N] = split(xBC);   D = softplus(dt + dt_bias)
+        h_t = exp(D_t A) h_(t-1) + D_t x_t (x) B_t;   y_t = h_t C_t + D x_t
+        out = W_out RMSNorm_g(y * silu(z))     (the gate BEFORE the norm)
+
+    Its memory is two cache leaves: ``conv_state`` ``[B, conv_kernel - 1,
+    conv_dim]`` (the pre-convolution ``xBC`` tail, the model's type) and
+    ``ssm_state`` ``[B, rows, lanes]`` (every head's ``h``, ``state_dtype``,
+    in :mod:`ops.pallas_ssm`'s layout). ``generate()``'s row cache makes both
+    itself (zeros). Under ``block_tables`` they are the serving engine's
+    per-slot STATE ARENA: a chunk call gets one slot's rows and runs the
+    chunked form from the state it is handed (``lengths``: the state left is
+    the last REAL token's); the decode call gets every slot's, and advances
+    ``ssm_state`` IN PLACE for the rows with a cursor (``cache_positions >
+    0``) only — masked inside the update, never by a select over the arena
+    (serve/engine.py keeps the small ``conv_state`` of the other rows)."""
+
+    cfg: TransformerConfig
+    mamba: Mamba2Config = Mamba2Config()
+
+    @nn.compact
+    def __call__(self, x: jax.Array, *,
+                 mask: jax.Array | None = None,
+                 positions: jax.Array | None = None,
+                 segment_ids: jax.Array | None = None,
+                 attention_fn: Callable | None = None,
+                 decode: bool = False,
+                 cache_positions: jax.Array | None = None,
+                 block_tables: jax.Array | None = None,
+                 lengths: jax.Array | None = None) -> jax.Array:
+        cfg, m = self.cfg, self.mamba
+        if mask is not None or segment_ids is not None or attention_fn is not None:
+            raise NotImplementedError(
+                "Mamba2 is causal over the whole row: mask, segment_ids and "
+                "attention_fn are not supported")
+        if cfg.tp_axis is not None:
+            raise NotImplementedError("Mamba2 has no tp_axis path")
+        b, sq, d = x.shape
+        hh, p, g, n, tail = (m.num_heads, m.head_dim, m.n_groups, m.state_size,
+                             m.conv_kernel - 1)
+        f32 = jnp.float32
+        zxd = param_dense(2 * m.inner + 2 * g * n + hh, ("embed", "mlp"),
+                          "in_proj", cfg.dtype)(x)
+        z, xbc, dt = (zxd[..., :m.inner], zxd[..., m.inner:m.inner + m.conv_dim],
+                      zxd[..., m.inner + m.conv_dim:])
+        w = self.param("conv", nn.with_logical_partitioning(
+            default_init(), (None, "mlp")), (m.conv_kernel, m.conv_dim), f32)
+        w_bias = self.param("conv_bias", nn.with_logical_partitioning(
+            nn.initializers.zeros, ("mlp",)), (m.conv_dim,), f32)
+
+        def dt_bias_init(key, shape, dtype):
+            step = jnp.exp(jax.random.uniform(key, shape, f32)
+                           * (math.log(m.time_step_max) - math.log(m.time_step_min))
+                           + math.log(m.time_step_min))
+            step = jnp.maximum(step, m.time_step_floor)
+            return (step + jnp.log(-jnp.expm1(-step))).astype(dtype)
+        dt_bias = self.param("dt_bias", dt_bias_init, (hh,), f32)
+        a_log = self.param(
+            "A_log", lambda key, shape, dtype: jnp.log(jax.random.uniform(
+                key, shape, f32, 1.0, 16.0)).astype(dtype), (hh,), f32)
+        skip = self.param("D", nn.initializers.ones, (hh,), f32)
+
+        conv_state = ssm_state = None
+        if decode:
+            def _arena_missing():
+                raise ValueError(
+                    "paged decode (block_tables) requires an engine-provided "
+                    "state arena (conv_state, ssm_state); it cannot be "
+                    "initialised from inside the model")
+            paged = block_tables is not None
+            conv_state = (
+                self.variable("cache", "conv_state", _arena_missing) if paged
+                else self.variable("cache", "conv_state", jnp.zeros,
+                                   (b, tail, m.conv_dim), cfg.dtype))
+            ssm_state = (
+                self.variable("cache", "ssm_state", _arena_missing) if paged
+                else self.variable("cache", "ssm_state", jnp.zeros,
+                                   (b,) + m.state_rows_lanes, m.state_dtype))
+            before = conv_state.value.astype(xbc.dtype)
+        else:
+            before = jnp.zeros((b, tail, m.conv_dim), xbc.dtype)
+        full = jnp.concatenate([before, xbc], axis=1)       # [B, tail + sq, C]
+        if conv_state is not None:
+            after = full[:, sq:] if lengths is None else jax.vmap(
+                lambda row, k: jax.lax.dynamic_slice_in_dim(row, k, tail, 0)
+            )(full, lengths.astype(jnp.int32))
+            conv_state.value = after.astype(conv_state.value.dtype)
+        ff = full.astype(f32)
+        xbc = nn.silu(sum(w[j] * ff[:, j:j + sq] for j in range(m.conv_kernel))
+                      + w_bias).astype(cfg.dtype)
+        xs = xbc[..., :m.inner].reshape(b, sq, hh, p)
+        bmat = xbc[..., m.inner:m.inner + g * n].reshape(b, sq, g, n)
+        cmat = xbc[..., m.inner + g * n:].reshape(b, sq, g, n)
+        step = jax.nn.softplus(dt.astype(f32) + dt_bias)    # [B, sq, H]
+        a = -jnp.exp(a_log.astype(f32))
+
+        if ssm_state is not None and sq == 1:
+            # one token a row: the update itself, over the rows with a cursor
+            live = (jnp.ones((b,), bool) if cache_positions is None
+                    else cache_positions > 0)
+            update = (pallas_ssm.ssm_update
+                      if ssm_update_impl(m) == "kernel"
+                      else pallas_ssm.ssm_update_reference)
+            ssm_state.value, y = update(
+                ssm_state.value, xs[:, 0], step[:, 0], a, bmat[:, 0],
+                cmat[:, 0], live)
+            y = y[:, None]
+        else:
+            if lengths is not None:
+                # a pad moves no state: its step is 0
+                step = jnp.where(jnp.arange(sq)[None, :, None]
+                                 < lengths[:, None, None], step, 0.0)
+            h0 = (jnp.zeros((b, hh, p, n), f32) if ssm_state is None else
+                  pallas_ssm.unpack_state(ssm_state.value.astype(f32), hh, p, g))
+            y, h_end = ssd_chunked(xs, step, a, bmat, cmat, h0, m.chunk_size,
+                                   cfg.dtype)
+            if ssm_state is not None:
+                ssm_state.value = pallas_ssm.pack_state(h_end, g).astype(
+                    ssm_state.value.dtype)
+        y = y + skip.astype(f32)[:, None] * xs.astype(f32)
+        y = (y.reshape(b, sq, m.inner) * nn.silu(z.astype(f32))).astype(cfg.dtype)
+        y = GroupRMSNorm(g, eps=cfg.norm_eps, dtype=cfg.dtype, name="norm")(y)
+        out = param_dense(d, ("mlp", "embed"), "out_proj", cfg.dtype)(y)
+        return nn.with_logical_constraint(out, ("batch", "seq", "act_embed"))
+
+
 class MLP(nn.Module):
-    """Feed-forward: SwiGLU (Llama) or GELU (BERT/ViT). Column-parallel up
-    projections ("mlp" logical axis), row-parallel down projection."""
+    """Feed-forward: SwiGLU (Llama), GELU with biases (BERT/ViT) or squared
+    ReLU without (``"relu2"``: ``W_2 relu(W_1 x)^2``, the Nemotron-H family).
+    Column-parallel up projections ("mlp" logical axis), row-parallel down
+    projection."""
 
     cfg: TransformerConfig
 
@@ -998,22 +1267,25 @@ class MLP(nn.Module):
             gate = param_dense(mlp, ("embed", "mlp"), "gate_proj", cfg.dtype)(x)
             up = param_dense(mlp, ("embed", "mlp"), "up_proj", cfg.dtype)(x)
             h = nn.silu(gate) * up
+        elif cfg.activation == "relu2":
+            h = jnp.square(nn.relu(
+                param_dense(mlp, ("embed", "mlp"), "up_proj", cfg.dtype)(x)))
         else:
             h = param_dense(mlp, ("embed", "mlp"), "up_proj", cfg.dtype,
                             use_bias=True)(x)
             h = nn.gelu(h)
         h = nn.with_logical_constraint(h, ("batch", "seq", "mlp"))
-        if cfg.tp_axis is not None and cfg.activation != "swiglu":
+        if cfg.tp_axis is not None and cfg.activation not in ("swiglu", "relu2"):
             # The GELU path's down_proj carries a bias; psumming after it
             # would add the (replicated) bias tp times. Serving TP only
-            # targets the bias-free swiglu family — fail at trace, not with
+            # targets the bias-free families — fail at trace, not with
             # silently-wrong logits.
             raise NotImplementedError(
                 "tp_axis requires a bias-free down projection "
-                "(activation='swiglu'); got activation="
+                "(activation='swiglu' or 'relu2'); got activation="
                 f"{cfg.activation!r}")
         out = param_dense(cfg.dim, ("mlp", "embed"), "down_proj", cfg.dtype,
-                          use_bias=cfg.activation != "swiglu")(h)
+                          use_bias=cfg.activation not in ("swiglu", "relu2"))(h)
         if cfg.tp_axis is not None:
             # Row-parallel down projection: partial sum over the sharded mlp
             # dim — Megatron's second reduction per block.
@@ -1025,8 +1297,8 @@ class MLP(nn.Module):
 class LayerKind:
     """What one layer of a stack is made of: two factories
     ``(cfg, name=...) -> module``. ``attention=None`` is :class:`Attention`
-    (any other — :class:`LatentAttention`, :class:`ShortConv` — must take its
-    keywords); ``mlp=None`` is the dense
+    (any other — :class:`LatentAttention`, :class:`ShortConv`,
+    :class:`Mamba2` — must take its keywords); ``mlp=None`` is the dense
     :class:`MLP`, any other (e.g. the expert-parallel
     :class:`models.moe.MoEMLP`) must accept a ``decode`` keyword — the static
     mode flag rides to it so that it can switch to its dropless serving
@@ -1034,10 +1306,22 @@ class LayerKind:
 
     attention: Callable | None = None
     mlp: Callable | None = None
+    # A layer that is ONE sub-layer (the Nemotron-H family: every layer is a
+    # mixer or a feed-forward, never both): "mixer" keeps the first half of
+    # the block (attn_norm, attn, its residual), "mlp" the second (mlp_norm,
+    # mlp, its residual). None: both.
+    solo: str | None = None
+
+    def __post_init__(self):
+        if self.solo not in (None, "mixer", "mlp"):
+            raise ValueError(f"solo must be None, 'mixer' or 'mlp', "
+                             f"got {self.solo!r}")
 
 
 class Block(nn.Module):
-    """Pre-norm transformer block: x + attn(norm(x)); x + mlp(norm(x)).
+    """Pre-norm transformer block: x + attn(norm(x)); x + mlp(norm(x)) — or,
+    where ``kind.solo`` says so, one of the two alone (one norm, one
+    residual).
 
     ``kind`` swaps the attention and the feed-forward module while keeping
     the block's norm/residual/dropout structure — and therefore scan/remat —
@@ -1066,6 +1350,14 @@ class Block(nn.Module):
                  lengths: jax.Array | None = None) -> jax.Array:
         cfg, kind = self.cfg, self.kind
         attention_fn = attention_fn or self.attention_fn
+        if kind.solo is not None:
+            return self._solo(x, deterministic=deterministic, decode=decode,
+                              mask=mask, positions=positions,
+                              segment_ids=segment_ids,
+                              attention_fn=attention_fn,
+                              cache_positions=cache_positions,
+                              block_tables=block_tables,
+                              **({} if lengths is None else {"lengths": lengths}))
         h = make_norm(cfg, "attn_norm")(x)
         attn = (kind.attention or Attention)(cfg, name="attn")
         # lengths rides only where a caller gives it (a stack with a mixer
@@ -1094,6 +1386,25 @@ class Block(nn.Module):
             h = nn.Dropout(cfg.dropout_rate, deterministic=deterministic)(h)
         x = x + h
         return nn.with_logical_constraint(x, ("batch", "seq", "act_embed"))
+
+    def _solo(self, x, *, deterministic, decode, **mixer_kw):
+        """The block of a ``kind.solo`` layer: the same names as the half of
+        the whole block it keeps."""
+        cfg, kind = self.cfg, self.kind
+        if kind.solo == "mixer":
+            h = (kind.attention or Attention)(cfg, name="attn")(
+                make_norm(cfg, "attn_norm")(x), decode=decode, **mixer_kw)
+        else:
+            if cfg.tp_axis is not None and kind.mlp is not None:
+                raise NotImplementedError(
+                    "tp_axis (serving tensor parallelism) supports only the "
+                    "dense MLP; got a layer kind with its own mlp")
+            h = make_norm(cfg, "mlp_norm")(x)
+            h = (MLP(cfg, name="mlp")(h) if kind.mlp is None
+                 else kind.mlp(cfg, name="mlp")(h, decode=decode))
+        if cfg.dropout_rate:
+            h = nn.Dropout(cfg.dropout_rate, deterministic=deterministic)(h)
+        return nn.with_logical_constraint(x + h, ("batch", "seq", "act_embed"))
 
 
 class Transformer(nn.Module):
@@ -1271,11 +1582,12 @@ def lm_forward(module: nn.Module, cfg: TransformerConfig,
 class PatternLM(nn.Module):
     """Decoder-only LM whose layers are what ``pattern`` says, one
     :class:`LayerKind` a layer: any mix of mixers (:class:`Attention`,
-    :class:`LatentAttention`, :class:`ShortConv`) and feed-forwards (the dense
-    :class:`MLP`, :class:`models.moe.MoEMLP`). Layers that differ are unrolled
-    (``cfg.scan_layers=False``). The serving engine calls it like
+    :class:`LatentAttention`, :class:`ShortConv`, :class:`Mamba2`) and
+    feed-forwards (the dense :class:`MLP`, :class:`models.moe.MoEMLP`), a layer
+    being both or — ``LayerKind.solo`` — one alone. Layers that differ are
+    unrolled (``cfg.scan_layers=False``). The serving engine calls it like
     :class:`models.llama.LlamaLM`; ``lengths`` reaches the mixers that carry
-    state (:class:`ShortConv`)."""
+    state (:class:`ShortConv`, :class:`Mamba2`)."""
 
     cfg: TransformerConfig
     pattern: tuple[LayerKind, ...] | None = None

@@ -168,12 +168,13 @@ def _gmm_kernel(expert_ref, live_ref, first_ref, lhs_ref, rhs_ref, out_ref):
         out_ref[:] = jnp.zeros_like(out_ref)
 
 
-def _gmm_call(lhs, rhs, layout: GroupedLayout, interpret: bool):
+def _gmm_call(lhs, rhs, layout: GroupedLayout, interpret: bool,
+              block_n: int | None = None):
     m_pad, k = lhs.shape
     e, k2, n = rhs.shape
     assert k == k2, (lhs.shape, rhs.shape)
     bm = layout.block_m
-    bn = _pick_block(n, _BLOCK_N)
+    bn = block_n or _pick_block(n, _BLOCK_N)
     tiles_m, tiles_n = m_pad // bm, n // bn
     grid = (tiles_n, tiles_m)   # row dim innermost: weight blocks revisit
 
@@ -313,6 +314,36 @@ def gmm(lhs: jax.Array, rhs: jax.Array, layout: GroupedLayout,
     meta = (layout.m_pad, layout.block_m)
     return _gmm(lhs, rhs, layout.row_offset, layout.block_expert,
                 layout.block_live, layout.block_first, meta, interpret)
+
+
+# What one weight block of the forward-only product may take in VMEM (it is
+# double-buffered beside the row block and the output block).
+_WIDE_BLOCK_BYTES = 6 * 1024 * 1024
+
+
+def wide_block(n: int, k: int, itemsize: int = 2) -> int:
+    """The column block :func:`gmm_forward` takes: the widest multiple of 128
+    that divides ``n`` with a ``[k, block]`` weight block inside
+    ``_WIDE_BLOCK_BYTES`` — all of ``n`` where that fits, so that a row block
+    is read once and an expert's weights once a run of its blocks. The
+    power-of-two rule of :func:`_pick_block` gives 128 for n = 2,688 = 21 x
+    128: 21 column steps, each reading every row block again."""
+    best = _pick_block(n, _BLOCK_N)
+    for blocks in range(n // 128, 0, -1):
+        bn = blocks * 128
+        if n % bn == 0 and k * bn * itemsize <= _WIDE_BLOCK_BYTES:
+            return max(best, bn)
+    return best
+
+
+def gmm_forward(lhs: jax.Array, rhs: jax.Array, layout: GroupedLayout,
+                interpret: bool | None = None) -> jax.Array:
+    """:func:`gmm` for a serving program: the forward product alone (no
+    gradient rule), at :func:`wide_block` columns a step."""
+    if interpret is None:
+        interpret = not on_tpu()
+    return _gmm_call(lhs, rhs, layout, interpret,
+                     wide_block(rhs.shape[2], rhs.shape[1], rhs.dtype.itemsize))
 
 
 def gmm_reference(lhs: jax.Array, rhs: jax.Array,

@@ -204,7 +204,8 @@ def _decode_program(model, params: PyTree, cache: PyTree, tokens: jax.Array,
     the model has any (:func:`_with_counts`). A state arena
     (:data:`_STATE_LEAVES`) is advanced in place, row i being slot i's; the
     rows of slots at cursor 0 (free, or mid-prefill) keep what they held
-    (:func:`_keep_idle_state`)."""
+    (:func:`_keep_idle_state` for a small leaf; inside the mixer's own update
+    for one the size of a pool)."""
     return _decode_core(model, params, cache, tokens, kv_lens, tables,
                         temps, top_ks, top_ps, keys)
 
@@ -299,11 +300,27 @@ def _leaf_name(path) -> str | None:
     return getattr(path[-1], "key", None)
 
 
-# Cache leaves that are not pages but STATE: one row a SLOT (the batch axis,
-# third from the end, is the slots'), read before a call's tokens and written
-# after them — a ShortConv's tail (models/transformer.py). They live in the
-# same tree as the page pool and are donated with it.
-_STATE_LEAVES = ("conv_state",)
+# Cache leaves that are not pages but STATE: one row a SLOT, read before a
+# call's tokens and written after them — a ShortConv's tail, a Mamba2's tail
+# and its state (models/transformer.py). They live in the same tree as the
+# page pool and are donated with it. A mixer that carries state names its
+# leaves here; the conventions it then meets:
+#
+# - found BY NAME, never by shape; laid out ``[..., slots, rows, F]`` — the
+#   batch axis third from the end is the slots' (a single-row cache has 1
+#   there), whatever ``rows`` and ``F`` mean to the mixer;
+# - a chunk program hands the mixer ONE slot's row (zeros at ``start == 0``)
+#   and writes the row it left back in place (:func:`_slot_state`,
+#   :func:`_put_slot_state`): one row is copied, never the arena;
+# - the decode program hands it EVERY slot's row with ``cache_positions``;
+#   the rows at cursor 0 (free, or mid-prefill) must come back as they were.
+#   For a small leaf the engine sees to that by a select over the arena
+#   (:func:`_keep_idle_state`). A leaf the size of a KV pool cannot afford
+#   the select (a third pass over it, and a second arena alive): its mixer
+#   masks the update itself, in place, and names the leaf under
+#   ``_MASKED_IN_UPDATE`` so that the engine leaves it alone.
+_STATE_LEAVES = ("conv_state", "ssm_state")
+_MASKED_IN_UPDATE = ("ssm_state",)
 
 
 def _is_state(path) -> bool:
@@ -347,9 +364,11 @@ def _keep_idle_state(cache: PyTree, new: PyTree, kv_lens: jax.Array) -> PyTree:
     did not decode for (cursor 0: free, or mid-prefill — their rider row's
     K/V went to the scratch page) left as they were: a prompt's state carried
     from chunk to chunk must not be advanced by the rider's pad token. A
-    tree of pages only comes back as it is."""
+    tree of pages only comes back as it is, and so does a leaf whose mixer
+    masked the update itself (``_MASKED_IN_UPDATE``: an arena too large to
+    select over)."""
     def one(path, old, now):
-        if not _is_state(path):
+        if not _is_state(path) or _leaf_name(path) in _MASKED_IN_UPDATE:
             return now
         live = (kv_lens > 0).reshape((-1,) + (1,) * 2)
         return jnp.where(live, now, old)
@@ -450,10 +469,10 @@ def _validate_tp_cfg(cfg, tp: int, what: str) -> None:
         raise ValueError(
             f"{what}: mlp_dim ({mlp}) is not divisible by tp ({tp}) — "
             "the column-parallel gate/up projections split the hidden dim")
-    if cfg.activation != "swiglu":
+    if cfg.activation not in ("swiglu", "relu2"):
         raise ValueError(
             f"{what}: serving TP needs a bias-free down projection "
-            f"(activation='swiglu'), got activation={cfg.activation!r} — "
+            f"(activation='swiglu' or 'relu2'), got activation={cfg.activation!r} — "
             "a replicated down_proj bias would be psummed tp times")
 
 
@@ -1832,7 +1851,8 @@ class ServeEngine:
         with self.tracer.span(
                 "decode", active=active, rows=active,
                 context_tokens=int(self._kv_lens.sum()) + active,
-                sampled_rows=self._count_sampled_rows()) as span:
+                sampled_rows=self._count_sampled_rows(),
+                **self._state_update_fields()) as span:
             nxt, keys, self._cache = self._decode_step()
             seq = self._dispatches
             self._admissions(outputs)
@@ -2068,6 +2088,19 @@ class ServeEngine:
                 self.params, self._cache, window, *regs[1:])
         return _spec_verify_program(
             self.model, self.params, self._cache, window, *regs[1:])
+
+    def _state_update_fields(self) -> dict:
+        """The ``state_rows`` / ``state_bytes_moved`` fields of a ``decode``
+        span, for a model with per-slot state: the rows the step about to be
+        dispatched advances (the slots with a cursor — what the program's own
+        mask sees) and their state read and written once. No field for a
+        model of pages only."""
+        if not self._state_rows:
+            return {}
+        rows = int(np.count_nonzero(self._kv_lens > 0))
+        self.stats.record_state_update(rows)
+        return {"state_rows": rows,
+                "state_bytes_moved": 2 * rows * self._slot_state_nbytes}
 
     def _state_slot(self, slot: int):
         """The chunk programs' ``slot`` operand: the arena row of *slot* for
@@ -2378,7 +2411,9 @@ class ServeEngine:
         program's call: pages a cell attends, and cells a call steps. For a
         model with expert layers each entry ends in the dispatch that
         program's rows take (``experts=grouped`` / ``experts=dense``:
-        :func:`models.moe.serving_dispatch`)."""
+        :func:`models.moe.serving_dispatch`), and for one with state-space
+        layers in what its one-token state update runs as (``ssm=kernel`` /
+        ``ssm=xla``: :func:`models.transformer.ssm_update_impl`)."""
         slots = self.num_slots
         programs = {"decode": (1, slots)}        # name -> (sq, batch rows)
         if self.spec_k:
@@ -2431,7 +2466,10 @@ class ServeEngine:
         def experts(tokens: int) -> str:
             return ("" if moe is None else
                     f" experts={moe_lib.serving_dispatch(tokens, moe)}")
-        return {name: report(sq, rows) + experts(sq * rows)
+        mamba = transformer.mamba_config_of(self.model)
+        ssm = ("" if mamba is None
+               else f" ssm={transformer.ssm_update_impl(mamba)}")
+        return {name: report(sq, rows) + experts(sq * rows) + ssm
                 for name, (sq, rows) in programs.items()}
 
     def _fits(self, req: Request) -> bool:

@@ -222,10 +222,15 @@ def serving_collector(registry: MetricsRegistry,
         "serve_state_slots": registry.gauge(
             "serve_state_slots",
             "slots holding per-slot model state (a short convolution's "
-            "tail) beside the page pool: decoding or mid-prefill"),
+            "tail, a state-space mixer's state) beside the page pool: "
+            "decoding or mid-prefill"),
         "serve_state_bytes": registry.gauge(
             "serve_state_bytes",
             "bytes of the state arena those slots' rows hold"),
+        "serve_state_update_rows": registry.gauge(
+            "serve_state_update_rows",
+            "rows of the state arena the last decode step advanced (the "
+            "slots with a cursor; the others' rows are not touched)"),
         "serve_fence_covered_share": registry.gauge(
             "serve_fence_covered_share",
             "share of the engine's blocking reads of device results made "
@@ -309,6 +314,7 @@ def serving_collector(registry: MetricsRegistry,
                "moe_max_rows": "serve_moe_max_rows",
                "state_slots": "serve_state_slots",
                "state_bytes": "serve_state_bytes",
+               "state_update_rows": "serve_state_update_rows",
                "fence_covered_share": "serve_fence_covered_share",
                "sampler_sort_share": "serve_sampler_sort_share",
                "kv_quant_bytes_saved": "serve_kv_quant_bytes_saved",

@@ -197,11 +197,13 @@ class ServingStats:
         self.moe_assignments = 0
         self.moe_experts_touched = 0
         self.moe_max_rows = 0
-        # Per-slot state beside the page pool (a ShortConv's tail): slots a
-        # request holds now, and their bytes. Gauges; 0 for a model of
+        # Per-slot state beside the page pool (a ShortConv's tail, a Mamba2's
+        # tail and state): slots a request holds now, their bytes, and the
+        # rows the last decode step advanced. Gauges; 0 for a model of
         # pages only.
         self.state_slots = 0
         self.state_bytes = 0
+        self.state_update_rows = 0
         # The engine's blocking reads of device results (its ``device_wait``
         # spans), and those of them made with a later program already
         # dispatched behind the awaited one: the device had work queued
@@ -412,6 +414,11 @@ class ServingStats:
         self.state_bytes = int(state_bytes)
 
     @_locked
+    def record_state_update(self, rows: int) -> None:
+        """Rows whose state the last decode step advanced. A gauge."""
+        self.state_update_rows = int(rows)
+
+    @_locked
     def record_fence(self, covered: int) -> None:
         self.fences += 1
         self.fences_covered += int(covered)
@@ -496,6 +503,7 @@ class ServingStats:
             "moe_max_rows": self.moe_max_rows,
             "state_slots": self.state_slots,
             "state_bytes": self.state_bytes,
+            "state_update_rows": self.state_update_rows,
             "fences": self.fences,
             "fences_covered": self.fences_covered,
             # Share of the blocking reads that had a program queued behind

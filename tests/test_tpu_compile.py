@@ -205,10 +205,11 @@ def test_sharded_step_runs_the_flash_kernel_on_each_chips_own_rows(chips, monkey
     assert "all-gather" not in text
 
 
-def _reached_outside_conditionals(text, instruction):
+def _reached_outside_conditionals(text, instruction, containing=""):
     """Names of the computations of compiled module *text* that hold an
-    *instruction* (``"sort"``) AND are reached from ENTRY by a path with no
-    ``conditional`` on it: what the program runs whatever its operands are."""
+    *instruction* (``"sort"``; on a line that has *containing*, a width) AND
+    are reached from ENTRY by a path with no ``conditional`` on it: what the
+    program runs whatever its operands are."""
     blocks, entry, name = {}, None, None
     for line in text.splitlines():
         head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$", line)
@@ -230,7 +231,8 @@ def _reached_outside_conditionals(text, instruction):
                 continue
             todo.extend(n for n in re.findall(r"%([\w.\-]+)", line) if n in blocks)
     return sorted(n for n in seen if any(
-        re.search(rf"\b{instruction}\(", line) for line in blocks[n]))
+        re.search(rf"\b{instruction}\(", line) and containing in line
+        for line in blocks[n]))
 
 
 def test_the_sampler_sorts_only_inside_a_conditional(one_chip):
@@ -295,3 +297,101 @@ def test_the_conv_moe_cells_decode_program_compiles_and_fits_one_chip(one_chip, 
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 12.5e9 < mem.argument_size_in_bytes < 12.7e9 and held < 14e9
     assert mem.alias_size_in_bytes > 3.2e9                      # pool and arena in place
+
+
+def _ssm_moe_cell(one_chip):
+    """(model, params, cache, engine options, sds) of
+    `nemotron-3-super-ep4-d11.chat-backlog-wide` as shapes placed on a described
+    chip: 11 unrolled layers at the published widths, the page pool of the one
+    attention layer and the state arena of the five Mamba-2 layers."""
+    import flax.linen as nn
+
+    from benchmarks.harness import family_ssm_moe as fam
+    from benchmarks.harness import manifest as M
+    from benchmarks.harness import reference_ssm_moe as ref
+    from k8s_distributed_deeplearning_tpu.models.transformer import PatternLM
+    cell = M.Cell(M.load_manifest(), "nemotron-3-super-ep4-d11.chat-backlog-wide")
+    cfg, eng = cell.config, cell.options["engine"]
+    slots, pt, pages = eng["num_slots"], eng["page_tokens"], eng["kv_pool_pages"] + 1
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    model = PatternLM(*fam.program_config(cfg, eng["max_seq_len"]))
+    params = jax.tree.map(
+        lambda a: sds(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: nn.meta.unbox(model.init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])))
+    lanes = cfg["num_key_value_heads"] * cfg["head_dim"]
+    h, p, g, n, k = ref.mamba_sizes(cfg)
+    cache = {"transformer": {}}
+    for i, kind in enumerate(ref.pattern(cfg)):
+        if kind == "M":
+            cache["transformer"][f"block_{i}"] = {"attn": {
+                "conv_state": sds((slots, k - 1, h * p + 2 * g * n), jnp.bfloat16),
+                "ssm_state": sds((slots, h // 2 * n, 2 * p), jnp.float32)}}
+        elif kind == "*":
+            cache["transformer"][f"block_{i}"] = {"attn": {
+                "cached_key": sds((pages, pt, lanes), jnp.bfloat16),
+                "cached_value": sds((pages, pt, lanes), jnp.bfloat16)}}
+    return model, params, cache, eng, sds
+
+
+def _arena_sized_copies(text, elems=128 * 8192 * 128):
+    """Lines of compiled module *text* that select over or copy an f32 array
+    the size of one layer's state arena."""
+    shape = rf"f32\[128,8192,128\]"
+    return [l.strip()[:160] for l in text.splitlines()
+            if re.search(rf"= {shape}[^ ]* (select|copy)\(", l)]
+
+
+def test_the_ssm_update_kernel_compiles_for_v5e(one_chip):
+    """`ssm_update` at the cell's call shape — 128 slots x 128 heads x 64 x
+    128 float32, two heads to a row — aliases the arena to its output and
+    holds next to nothing beside it."""
+    from k8s_distributed_deeplearning_tpu.ops import pallas_ssm
+    b, h, p, g, n = 128, 128, 64, 8, 128
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    rows, lanes = pallas_ssm.state_shape(h, p, n, g)
+    assert (rows, lanes) == (8192, 128)
+    compiled = jax.jit(
+        lambda s, *a: pallas_ssm.ssm_update(s, *a, interpret=False), donate_argnums=0
+    ).lower(sds((b, rows, lanes), jnp.float32), sds((b, h, p), jnp.bfloat16),
+            sds((b, h), jnp.float32), sds((h,), jnp.float32),
+            sds((b, g, n), jnp.bfloat16), sds((b, g, n), jnp.bfloat16),
+            sds((b,), jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    assert "ssm_update" in compiled.as_text()
+    assert mem.alias_size_in_bytes == b * rows * lanes * 4
+    assert mem.temp_size_in_bytes < 2 ** 20
+
+
+def test_the_ssm_moe_cells_decode_program_compiles_and_fits_one_chip(one_chip, monkeypatch):
+    """`nemotron-3-super-ep4-d11.chat-backlog-wide`'s decode program whole, from
+    shapes alone: 128 slots through 11 unrolled layers — `ssm_update` in the
+    five Mamba-2 layers with the arena in place (no select over and no copy of
+    a layer's 0.5 GB of state), the paged kernel at 16 query heads a KV head in
+    the attention layer, the held experts in the dense form — and what it
+    holds (9.30 GB of weights, 2.72 GB of state, 0.54 GB of pages) beside its
+    temporaries inside one v5e's 16 GB."""
+    from k8s_distributed_deeplearning_tpu.models import transformer as T
+    from k8s_distributed_deeplearning_tpu.ops import pallas_gmm, pallas_ssm
+    from k8s_distributed_deeplearning_tpu.serve import engine as E
+    for mod in (pallas_paged_attn, pallas_gmm, pallas_ssm, T):
+        monkeypatch.setattr(mod, "on_tpu", lambda: True)         # compile, not interpret
+    model, params, cache, eng, sds = _ssm_moe_cell(one_chip)
+    slots, pt = eng["num_slots"], eng["page_tokens"]
+    i32, f32 = (lambda *s: sds(s, jnp.int32)), (lambda *s: sds(s, jnp.float32))
+    compiled = E._decode_program.lower(
+        model, params, cache, i32(slots), i32(slots),
+        i32(slots, eng["max_seq_len"] // pt), f32(slots), i32(slots), f32(slots),
+        sds((slots, 2), jnp.uint32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert text.count("ssm_update") >= 5 and "paged_attn" in text and "moe_gmm" not in text
+    assert _arena_sized_copies(text) == []
+    # The sampler's sort over [128, 32768] is there, and only a step with a
+    # sampling row runs it; what sorts on every step is the router's top-22 of
+    # 512 (one `lax.top_k` a layer), nothing of the vocabulary's width.
+    assert "32768" in "".join(l for l in text.splitlines() if " sort(" in l)
+    assert _reached_outside_conditionals(text, "sort", containing="32768") == []
+    assert _reached_outside_conditionals(text, "sort") != []         # the top-22 of 512
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 12.4e9 < mem.argument_size_in_bytes < 12.8e9 and held < 14.5e9
+    assert mem.alias_size_in_bytes > 3.2e9                      # arena and pool in place
