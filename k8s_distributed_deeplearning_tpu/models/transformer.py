@@ -284,13 +284,16 @@ def paged_attention_impl(cfg: TransformerConfig, sq: int) -> str:
     """Which implementation a block-table (paged) attention call with
     ``sq`` query tokens per row resolves to: ``"paged_flash"`` (the Pallas
     kernel) or ``"xla"`` (gather the row's pages, attend with the einsum
-    path). ``"auto"`` decides from the platform and ``sq``
-    (:func:`ops.pallas_paged_attn.default_impl`); ``"paged_flash"`` forces
-    the kernel; ``"xla"``/``"flash"`` take the gather. The model branches on
-    this and ``ServeEngine.attention_impls`` reports it per program, so
-    what a server says it runs is what it runs."""
+    path). ``"auto"`` decides from the platform, ``sq`` and the query heads
+    a KV head serves (:func:`ops.pallas_paged_attn.default_impl`: on a TPU
+    the kernel up to 128 tokens a row and for every wider call that is a
+    whole number of query blocks); ``"paged_flash"`` forces the kernel;
+    ``"xla"``/``"flash"`` take the gather. The model branches on this and
+    ``ServeEngine.attention_impls`` reports it per program, so what a server
+    says it runs is what it runs."""
     if cfg.attention_impl == "auto":
-        return pallas_paged_attn.default_impl(sq)
+        return pallas_paged_attn.default_impl(
+            sq, group=cfg.n_heads // cfg.resolved_kv_heads)
     return "paged_flash" if cfg.attention_impl == "paged_flash" else "xla"
 
 
@@ -598,8 +601,11 @@ class Attention(nn.Module):
                 # [B, n_blocks·page_tokens] virtual sequence never
                 # materializes in HBM. Off-TPU "paged_flash" runs the
                 # same kernel in interpret mode (parity tests); "auto"
-                # picks it on TPU for the query widths it serves and
-                # the XLA gather below otherwise. Under kv_quant the
+                # picks it on TPU for decode, a verify window and every
+                # prefill width that is a whole number of query blocks
+                # (a 512-token chunk attends in blocks of queries, each
+                # up to its own last position), and the XLA gather below
+                # for a width off that grid. Under kv_quant the
                 # kernel fuses the dequant into its page stream: int8
                 # pages are copied by the same prefetched block table
                 # (their f32 scales, 1/32 of the bytes, are gathered

@@ -41,12 +41,20 @@ tier-1 keeps the XLA gather as its default via the ``attention_impl``
 selection (:func:`default_impl`) and opts into the kernel explicitly
 (``"paged_flash"``) for parity tests.
 
-What the kernel serves is bounded by ``sq`` (:data:`MAX_QUERY_TOKENS`): the
-KV-head loop is python-unrolled over ``[group·sq, P·page_tokens]`` score
+What ONE grid row carries is bounded by ``sq`` (:data:`MAX_QUERY_TOKENS`):
+the KV-head loop is python-unrolled over ``[group·sq, P·page_tokens]`` score
 tiles and the state is ``H·sq·(hd + 2·128)`` f32 in VMEM, so both Mosaic's
-compile time and the VMEM footprint grow with the query-chunk width.
-Decode (1), a speculative verify window (k+1) and a chunked-prefill slice
-fit; a monolithic prefill bucket does not, and takes the XLA gather path.
+compile time and the VMEM footprint grow with the query width. Decode (1), a
+speculative verify window (k+1) and a 128-token prefill chunk are one grid
+row a batch row. A wider call — a 512-token chunk, a monolithic prefill
+bucket — is cut into BLOCKS OF QUERIES (:func:`default_query_block`: ``Q``
+tokens, ``group·Q`` = 512 query rows a KV head), and each block is to the
+kernel what a batch row is: its own query slab, cursor column and last live
+block, the row's table. The kernel's own rules then give the causal saving —
+a query block copies only pages at or before its own last position, a cell
+past it costs a grid step — and the VMEM a call holds is that of ``sq = Q``.
+K/V pages are read once per query block that can see them. A width that is
+no whole number of blocks takes the XLA gather path (:func:`default_impl`).
 """
 from __future__ import annotations
 
@@ -61,26 +69,53 @@ from k8s_distributed_deeplearning_tpu.backend import on_tpu
 
 NEG_INF = -1e30
 
-# Widest query chunk the kernel is selected for. The KV-head loop is unrolled
-# over [group·sq, T] score tiles, so Mosaic's compile time follows rows x T,
-# and the f32 state and the q/out blocks follow H·sq. Compiling the 32q/8kv
+# Most query tokens ONE grid row carries. The KV-head loop is unrolled over
+# [group·sq, T] score tiles, so Mosaic's compile time follows rows x T, and
+# the f32 state and the q/out blocks follow H·sq. Compiling the 32q/8kv
 # hd-128 kernel for a v5e takes 0.8 s at sq=1 (32 pages a cell) and 2.7 s at
 # sq=128 (8 pages a cell; the one-page-a-cell kernel it replaces took 2.0 s
 # and 2.5 s); at 256 state and blocks are 20 MiB and leave the rule one page
 # a cell (3.6 s), and at 512 they pass VMEM_LIMIT_BYTES and Mosaic refuses
 # (Mosaic for a described v5e, PR 26). 128 covers decode, every verify
-# window and a 128-token prefill chunk.
+# window and a 128-token prefill chunk in one row; a wider call is cut into
+# blocks of queries, each a row of its own (default_query_block).
 MAX_QUERY_TOKENS = 128
+# Query rows a KV head (group · Q) that a block of a wider call is cut to:
+# the rows the kernel runs at for a 128-token chunk at a group of 4 (the
+# Mistral and lfm2 cells), where SCORE_TILE_ELEMS gives it 256 tokens a cell.
+QUERY_BLOCK_ROWS = 512
 
 
-def default_impl(sq: int, platform: str | None = None) -> str:
+def default_query_block(sq: int, group: int) -> int:
+    """Query tokens one grid row of a call carries, from what the call can
+    see (its width, and the query heads a KV head serves). Up to
+    :data:`MAX_QUERY_TOKENS` the call itself: one row a batch row. Wider, the
+    largest power of two ``Q`` with ``group·Q <= QUERY_BLOCK_ROWS`` (128 at a
+    group of 4 or fewer, 32 at 16) where ``sq`` is a whole number of them;
+    else ``sq`` again — ONE block of a width Mosaic may refuse, which
+    :func:`default_impl` therefore never picks."""
+    if sq <= MAX_QUERY_TOKENS:
+        return sq
+    q = 1
+    while 2 * q <= min(MAX_QUERY_TOKENS, QUERY_BLOCK_ROWS // group):
+        q *= 2
+    return sq if sq % q else q
+
+
+def default_impl(sq: int, platform: str | None = None, *,
+                 group: int = 1) -> str:
     """The ``attention_impl="auto"`` rule for block-table (paged) calls,
-    from what the call can observe: the Pallas kernel on TPU for query
-    chunks it serves (``sq <= MAX_QUERY_TOKENS``), the XLA gather-attend
-    everywhere else — wider prefill buckets on TPU, and every shape off
-    TPU, where the kernel would run in the (slow) interpreter."""
+    from what the call can observe: the Pallas kernel on TPU for a call of
+    one grid row a batch row (``sq <= MAX_QUERY_TOKENS``: decode, a verify
+    window, a 128-token chunk) and for any wider call that is a whole number
+    of query blocks (:func:`default_query_block` at the model's *group* of
+    query heads a KV head: a 512-token chunk, a monolithic bucket); the XLA
+    gather-attend everywhere else — a width off the block grid, and every
+    shape off TPU, where the kernel would run in the (slow) interpreter."""
     tpu = on_tpu() if platform is None else platform == "tpu"
-    return "paged_flash" if tpu and sq <= MAX_QUERY_TOKENS else "xla"
+    served = (sq <= MAX_QUERY_TOKENS
+              or default_query_block(sq, group) != sq)
+    return "paged_flash" if tpu and served else "xla"
 
 
 # What one kernel call may hold in VMEM by the rule's own accounting
@@ -293,11 +328,12 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array,
                            v_scale: jax.Array | None = None,
                            softmax_scale: float | None = None,
                            pages_per_cell: int | None = None,
+                           query_block: int | None = None,
                            interpret: bool | None = None) -> jax.Array:
     """Grouped-query decode attention straight off the page pool.
 
-    q: ``[B, sq, H, hd]`` (``sq`` = 1 for classic decode or the
-    speculative verify-window width); pool_k/pool_v:
+    q: ``[B, sq, H, hd]`` (``sq`` = 1 for classic decode, the speculative
+    verify-window width, or a prefill chunk's); pool_k/pool_v:
     ``[num_pages, page_tokens, kv·hd]`` (the engine's folded-head page
     layout — written BEFORE this is called, so window tokens see each
     other); block_tables: ``[B, n_blocks]`` int32 mapping each row's
@@ -307,7 +343,11 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array,
     Returns ``[B, sq, H, hd]`` in q's dtype. ``interpret=None`` picks the
     real kernel on TPU and the Pallas interpreter elsewhere.
     ``pages_per_cell=None`` takes the rule's choice
-    (:func:`default_pages_per_cell`); the tests force others.
+    (:func:`default_pages_per_cell`) and ``query_block=None`` likewise
+    (:func:`default_query_block`); the tests and timings force others. A
+    call wider than its query block is folded: ``[B, sq] -> [B·sq/Q, Q]``,
+    the table repeated per block, the output unfolded — each block a grid
+    row with its own cursors and last live block.
 
     ``k_scale``/``v_scale`` (both or neither) switch on the graftquant
     int8 path: pool_k/pool_v hold int8 rows and the scales
@@ -349,6 +389,15 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array,
     if interpret is None:
         interpret = not on_tpu()
     group = h // hkv
+    qb = query_block or default_query_block(sq, group)
+    if qb != sq:
+        if sq % qb:
+            raise ValueError(
+                f"query_block {qb} does not divide the {sq} query tokens")
+        return _query_blocks(
+            q, pool_k, pool_v, block_tables, positions, k_scale, v_scale,
+            softmax_scale=softmax_scale, pages_per_cell=pages_per_cell,
+            query_block=qb, interpret=interpret)
     rows = group * sq
     n_blocks = block_tables.shape[1]
     s_virt = n_blocks * page_tokens
@@ -444,3 +493,26 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array,
     )(tables, last, *operands)
     return out.reshape(b, hkv, group, sq, hd).transpose(0, 3, 1, 2, 4).reshape(
         b, sq, h, hd)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "softmax_scale", "pages_per_cell", "query_block", "interpret"))
+def _query_blocks(q, pool_k, pool_v, block_tables, positions, k_scale,
+                  v_scale, *, softmax_scale, pages_per_cell, query_block,
+                  interpret):
+    """A call of ``sq / query_block`` blocks of queries, each a grid row:
+    ``[B, sq] -> [B·nq, Q]``, the row's table repeated per block. A jitted
+    function of its own, so that a program whose layers all make this call
+    traces and lowers the kernel ONCE and calls it from each layer: traced
+    per layer, six instances in the lfm2 cell's two chunk programs took its
+    warm set-up from 46.9 to 52 s on the chip's host (PERF.md section 6, PR
+    34). The narrower calls above are not wrapped: their programs' lowered
+    text was not this change's to move (ROADMAP speed item 8)."""
+    b, sq, h, hd = q.shape
+    nq = sq // query_block
+    fold = lambda a: a.reshape((b * nq, query_block) + a.shape[2:])
+    return paged_decode_attention(
+        fold(q), pool_k, pool_v, jnp.repeat(block_tables, nq, axis=0),
+        fold(positions), k_scale=k_scale, v_scale=v_scale,
+        softmax_scale=softmax_scale, pages_per_cell=pages_per_cell,
+        query_block=query_block, interpret=interpret).reshape(b, sq, h, hd)

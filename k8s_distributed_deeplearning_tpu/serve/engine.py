@@ -1104,6 +1104,12 @@ class ServeEngine:
             weight_bytes_saved=(
                 0 if self.tp
                 else self._weight_fp_nbytes - self._weight_q_nbytes))
+        # The ``attn`` field of a ``prefill`` span and a ``prefill_counts``
+        # record: what the chunk's program resolved its attention to, the
+        # first word of its :meth:`attention_impls` entry. A constant of the
+        # program, looked up per chunk call and never computed there.
+        self._prefill_attn = {name: entry.split()[0] for name, entry
+                              in self.attention_impls().items()}
 
     def _named_shardings(self, specs: PyTree) -> PyTree:
         """PartitionSpec tree -> NamedSharding tree over the tp mesh
@@ -2407,8 +2413,11 @@ class ServeEngine:
         engine can compile. Asks the model's own rule
         (``transformer.paged_attention_impl``), so it cannot drift from
         what the programs trace. Beside ``paged_flash`` stands the kernel's
-        grid as its own rule (``default_pages_per_cell``) sets it for that
-        program's call: pages a cell attends, and cells a call steps. For a
+        grid as its own rules set it for that program's call: for a call cut
+        into blocks of queries (wider than 128 tokens a row:
+        ``default_query_block``) the block, ``q_block=Q``; then the pages a
+        cell attends (``default_pages_per_cell``) and the cells a call steps
+        (rows x query blocks x cells a row). For a
         model with expert layers each entry ends in the dispatch that
         program's rows take (``experts=grouped`` / ``experts=dense``:
         :func:`models.moe.serving_dispatch`), and for one with state-space
@@ -2452,15 +2461,18 @@ class ServeEngine:
             impl = transformer.paged_attention_impl(cfg, sq)
             if impl != "paged_flash":
                 return impl
+            qb = pallas_paged_attn.default_query_block(
+                sq, cfg.n_heads // cfg.resolved_kv_heads)
             pages = pallas_paged_attn.default_pages_per_cell(
-                sq=sq, heads=cfg.n_heads // shard,
+                sq=qb, heads=cfg.n_heads // shard,
                 hd=cfg.resolved_head_dim, page_tokens=self.page_tokens,
                 kvhd=cfg.resolved_kv_heads * cfg.resolved_head_dim // shard,
                 kv_itemsize=1 if quant else q_itemsize,
                 q_itemsize=q_itemsize, n_blocks=self.max_blocks,
                 quant=quant)
-            return (f"{impl} pages_per_cell={pages} "
-                    f"cells={rows * -(-self.max_blocks // pages)}")
+            cells = rows * (sq // qb) * -(-self.max_blocks // pages)
+            return (impl + (f" q_block={qb}" if qb != sq else "")
+                    + f" pages_per_cell={pages} cells={cells}")
         moe = moe_lib.moe_config_of(self.model)
 
         def experts(tokens: int) -> str:
@@ -2569,6 +2581,7 @@ class ServeEngine:
                     chunk = pend.prompt[None, pend.pos:pend.pos + c]
                     fields = dict(chunk=c, tokens=c, start=pend.pos,
                                   request_id=pend.req.request_id,
+                                  attn=self._prefill_attn[f"chunk_{c}"],
                                   **self._state_from(pend))
                     with self.tracer.span("prefill", slot=slot, **fields):
                         self._cache, moe = self._chunk_step(
@@ -2633,7 +2646,9 @@ class ServeEngine:
         chunk[0, :rem] = pend.prompt[pend.pos:]
         table = np.ascontiguousarray(pend.table[None, :])
         fields = dict(bucket=bucket, tokens=rem, start=pend.pos,
-                      request_id=req.request_id, **self._state_from(pend))
+                      request_id=req.request_id,
+                      attn=self._prefill_attn[f"final_chunk_{bucket}"],
+                      **self._state_from(pend))
         with self.tracer.span("prefill", slot=slot, cached=pend.hit_tokens,
                               **fields):
             tok, key, self._cache = self._final_chunk_step(

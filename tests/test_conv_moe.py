@@ -347,6 +347,40 @@ def test_attention_impls_names_each_programs_expert_dispatch(tiny):
     assert moe.moe_config_of(llama.LlamaLM(llama.config_tiny())) is None
 
 
+def test_wide_chunks_through_the_kernel_serve_the_xla_paths_tokens():
+    """256-token chunks, wider than one grid row of the paged kernel: the
+    engine forced to ``paged_flash`` (the kernel in interpret mode, each chunk
+    attending in two blocks of 128 queries) serves token for token what the
+    XLA gather-attend serves, over prompts of a chunk and a padded tail, of
+    several chunks, and of less than one; and every ``prefill`` span and
+    ``prefill_counts`` record says which of the two its program took."""
+    cell = M.Cell(M.load_manifest(), CELL)
+    cell.apply_rehearsal()
+    model, params = fam.build_model_and_params(cell.config, 1024, SEED)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, 256, size=n).tolist() for n in (300, 700, 90)]
+
+    def run(impl):
+        tracer = Tracer(ring_size=4096)
+        eng = ServeEngine(
+            model.clone(cfg=dataclasses.replace(model.cfg, attention_impl=impl)), params,
+            num_slots=2, min_bucket=256, prefill_chunk_tokens=256, prefix_block_tokens=32,
+            kv_pool_pages=64, tracer=tracer)
+        reqs = [Request(prompt=p, max_new_tokens=4, request_id=f"r{i}")
+                for i, p in enumerate(prompts)]
+        outs = {o.request_id: o.tokens for o in eng.run(reqs)}
+        calls = [s for s in tracer.recent_spans() if s["name"] in ("prefill", "prefill_counts")]
+        assert {s["name"] for s in calls} == {"prefill", "prefill_counts"}
+        assert {s["attn"] for s in calls} == {impl} and len(calls) == 2 * 6
+        return [outs[r.request_id] for r in reqs], eng.attention_impls()
+
+    served, impls = run("paged_flash")
+    assert impls["chunk_256"] == impls["final_chunk_256"] == (
+        "paged_flash q_block=128 pages_per_cell=1 cells=64 experts=grouped")
+    assert impls["decode"] == "paged_flash pages_per_cell=1 cells=64 experts=dense"
+    assert served == run("xla")[0] and all(len(t) == 4 for t in served)
+
+
 def test_the_pattern_is_one_object_a_kind_and_models_built_twice_are_equal(tiny):
     cfg, model, _ = tiny
     kinds = model.pattern

@@ -102,6 +102,100 @@ def test_kernel_matches_xla_reference(b, sq, h, hkv, pages, bt, nb, ppc,
                                atol=2e-5, rtol=2e-5)
 
 
+def _attend_xla(q, pool_k, pool_v, tables, positions, k_scale=None,
+                v_scale=None):
+    """The model's own XLA gather-attend (``Attention.__call__``'s block-table
+    branch): gather the whole table, mask by cursor, the einsum path."""
+    from k8s_distributed_deeplearning_tpu.ops import attention
+    b, sq, _, hd = q.shape
+    hkv = pool_k.shape[2] // hd
+    s_virt = tables.shape[1] * pool_k.shape[1]
+    k = pool_k[tables].reshape(b, s_virt, hkv, hd)
+    v = pool_v[tables].reshape(b, s_virt, hkv, hd)
+    if k_scale is not None:
+        k = k.astype(jnp.float32) * k_scale[tables].reshape(b, s_virt, hkv, 1)
+        v = v.astype(jnp.float32) * v_scale[tables].reshape(b, s_virt, hkv, 1)
+    mask = (jnp.arange(s_virt)[None, None, :] <= positions[:, :, None])[:, None]
+    return attention.multi_head_attention(
+        q, k.astype(q.dtype), v.astype(q.dtype), causal=False, mask=mask,
+        impl="xla")
+
+
+# A prefill chunk wider than one grid row: (32 q / 8 kv x 64) is lfm2's
+# attention (groups of 4: blocks of 128 queries), (32 q / 2 kv x 128)
+# nemotron's (groups of 16: blocks of 32), at tiny pools; the third geometry
+# is one local hd-64 KV head under tp (a pool narrower than a lane tile: one
+# page a cell), the fourth int8 pages with their scales gathered per block.
+_LFM2, _NEMOTRON, _NARROW = (32, 8, 64), (32, 2, 128), (3, 1, 64)
+
+
+@pytest.mark.parametrize("heads,sq,start,quant", [
+    (_LFM2, 256, 0, False),          # a request's first chunk
+    (_LFM2, 512, 0, False),
+    (_LFM2, 256, 37, False),         # a cursor that starts mid-page
+    (_LFM2, 512, 168, False),        # a prefix of several pages before it
+    (_LFM2, 512, 300, False),        # a final chunk: its pad positions run
+    #                                  past the 704-position table
+    (_NEMOTRON, 256, 0, False),
+    (_NEMOTRON, 512, 37, False),
+    (_NEMOTRON, 256, 552, False),    # prefix + pads past the table
+    (_NARROW, 256, 37, False),
+    (_LFM2, 256, 37, True),
+], ids=["lfm2-256", "lfm2-512", "lfm2-256-midpage", "lfm2-512-prefix",
+        "lfm2-512-pads-past-table", "nemotron-256", "nemotron-512-midpage",
+        "nemotron-256-pads-past-table", "narrow-256-midpage",
+        "lfm2-256-int8"])
+def test_query_blocks_match_the_xla_gather_attend(heads, sq, start, quant):
+    """A call wider than 128 tokens a row attends in blocks of queries — each
+    block a grid row with its own cursors and last live block — and gives
+    what the XLA gather-attend of the whole table gives, at the rule's own
+    block and pages a cell. Rows whose position lies past the table (a final
+    chunk's pads) are rows nobody reads: finite, not compared."""
+    h, hkv, hd = heads
+    bt, nb, pages = 8, 88, 120
+    rng = np.random.default_rng(sq + start + h * hkv)
+    q = jnp.asarray(rng.standard_normal((1, sq, h, hd)), jnp.float32)
+    pool = lambda: rng.standard_normal((pages, bt, hkv * hd))
+    tables = jnp.asarray(rng.permutation(np.arange(1, pages))[:nb][None],
+                         jnp.int32)
+    positions = jnp.asarray(start + np.arange(sq)[None], jnp.int32)
+    scales = {}
+    if quant:
+        pool_k, pool_v = (jnp.asarray(np.clip(np.round(pool() * 40), -127, 127),
+                                      jnp.int8) for _ in range(2))
+        scales = dict(k_scale=jnp.asarray(rng.uniform(
+            0.01, 0.03, (pages, bt, hkv)), jnp.float32), v_scale=jnp.asarray(
+                rng.uniform(0.01, 0.03, (pages, bt, hkv)), jnp.float32))
+    else:
+        pool_k, pool_v = (jnp.asarray(pool(), jnp.float32) for _ in range(2))
+    qb = pallas_paged_attn.default_query_block(sq, h // hkv)
+    assert qb == {4: 128, 16: 32, 3: 128}[h // hkv] and sq // qb >= 2
+    out = np.asarray(paged_decode_attention(
+        q, pool_k, pool_v, tables, positions, interpret=True, **scales))
+    ref = np.asarray(_attend_xla(q, pool_k, pool_v, tables, positions,
+                                 **scales))
+    live = min(sq, nb * bt - start)
+    np.testing.assert_allclose(out[:, :live], ref[:, :live],
+                               atol=3e-5, rtol=3e-5)
+    assert np.isfinite(out).all()
+
+
+def test_a_forced_query_block_is_a_schedule_not_a_result():
+    """Any block that divides the call gives the rule's result (to f32
+    rounding of where the rescales fall); one that does not is refused."""
+    rng = np.random.default_rng(9)
+    q, pk, pv, tables, _ = _case(rng, 2, 256, 8, 2, 96, 8, 40)
+    pos = np.stack([37 + np.arange(256), np.arange(256)]).astype(np.int32)
+    case = (q, pk, pv, tables, pos)
+    ruled = _run(case, pages_per_cell=4)
+    for qb in (32, 64, 256):
+        np.testing.assert_allclose(
+            _run(case, pages_per_cell=4, query_block=qb), ruled,
+            atol=2e-6, rtol=2e-6)
+    with pytest.raises(ValueError, match="query_block"):
+        _run(case, query_block=96)
+
+
 @pytest.mark.parametrize("sq,h,hkv", [(1, 8, 2), (5, 4, 4), (16, 8, 2)])
 def test_one_page_a_cell_equals_the_rules_choice(sq, h, hkv):
     """The grid is a schedule, not a result: one page a cell (seven cells a
@@ -299,10 +393,11 @@ def test_serving_engine_parity_on_kernel_path():
 
 
 def test_auto_selection_rule_is_shape_and_platform():
-    """``attention_impl="auto"`` for block-table calls: the kernel on TPU at
-    the query widths it serves (decode, verify window, a 128-token prefill
-    chunk), the XLA gather for wider prefill buckets and off TPU — and the
-    engine reports, per program, exactly what the model will trace."""
+    """``attention_impl="auto"`` for block-table calls: the kernel on TPU for
+    a call of one grid row a batch row (decode, verify window, a 128-token
+    prefill chunk) and for every wider call that is a whole number of query
+    blocks, the XLA gather for a width off the block grid and off TPU — and
+    the engine reports, per program, exactly what the model will trace."""
     from k8s_distributed_deeplearning_tpu.models.transformer import (
         paged_attention_impl)
     from k8s_distributed_deeplearning_tpu.ops.pallas_paged_attn import (
@@ -310,8 +405,21 @@ def test_auto_selection_rule_is_shape_and_platform():
     assert MAX_QUERY_TOKENS == 128
     for sq in (1, 5, 128):
         assert default_impl(sq, platform="tpu") == "paged_flash"
-    assert default_impl(256, platform="tpu") == "xla"
-    assert default_impl(1024, platform="tpu") == "xla"
+    # wider: whole blocks of queries (128 at a group of 4 or fewer, 32 at
+    # nemotron's 16) take the kernel, a width off the block grid the gather
+    block = pallas_paged_attn.default_query_block
+    assert [block(sq, 4) for sq in (1, 128, 256, 512, 1024, 4096, 320)] == [
+        1, 128, 128, 128, 128, 128, 320]
+    assert [block(sq, g) for sq, g in ((512, 1), (512, 8), (512, 16),
+                                       (160, 16), (144, 16))] == [
+        128, 64, 32, 32, 144]
+    for sq in (256, 512, 1024):
+        for group in (1, 4, 16):
+            assert default_impl(sq, platform="tpu", group=group) == "paged_flash"
+        assert default_impl(sq, platform="cpu", group=4) == "xla"
+    assert default_impl(320, platform="tpu", group=4) == "xla"
+    assert default_impl(320, platform="tpu", group=16) == "paged_flash"
+    assert default_impl(130, platform="tpu", group=16) == "xla"
     assert default_impl(1, platform="cpu") == "xla"
     assert default_impl(1) == "xla"                     # CI runs on CPU
 
@@ -334,6 +442,15 @@ def test_auto_selection_rule_is_shape_and_platform():
         "chunk_64": "paged_flash pages_per_cell=1 cells=8",
         "final_chunk_32": "paged_flash pages_per_cell=1 cells=8",
         "final_chunk_64": "paged_flash pages_per_cell=1 cells=8"}
+    # Without chunks the buckets run to max_seq_len: the one wider than 128
+    # tokens is cut into two blocks of 128 queries, each a grid row.
+    mono = ServeEngine(model, params, num_slots=2)
+    assert mono.attention_impls() == {
+        "decode": "paged_flash pages_per_cell=1 cells=16",
+        "final_chunk_32": "paged_flash pages_per_cell=1 cells=8",
+        "final_chunk_64": "paged_flash pages_per_cell=1 cells=8",
+        "final_chunk_128": "paged_flash pages_per_cell=1 cells=8",
+        "final_chunk_256": "paged_flash q_block=128 pages_per_cell=1 cells=16"}
     # The same engine at the benchmark cell's widths and table would report
     # what test_pages_per_cell_rule_at_the_benchmark_cell holds the rule to.
     plain = ServeEngine(llama.LlamaLM(cfg), params, num_slots=2)

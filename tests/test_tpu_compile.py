@@ -57,6 +57,9 @@ SMALL_TP4 = (3, 1, 64)
 # 32 q heads in groups of 4 on 8 hd-64 KV heads (a pool 512 lanes wide, each
 # head a 64-lane slice of a tile), 128 slots a decode call.
 LFM2 = (32, 8, 64)
+# nemotron-3-super's one attention layer in eleven: 32 q heads in groups of 16
+# on 2 hd-128 KV heads (a pool 256 lanes wide).
+NEMOTRON = (32, 2, 128)
 PAGE_TOKENS, N_BLOCKS, NUM_PAGES = 32, 128, 2561
 
 
@@ -71,9 +74,12 @@ PAGE_TOKENS, N_BLOCKS, NUM_PAGES = 32, 128, 2561
     (SMALL_TP4, 128, 1, True, None),
     (LFM2, 1, 128, False, None),        # head size 64 at 128 rows
     (LFM2, 128, 1, False, None),
+    (LFM2, 512, 1, False, None),        # a 512-token chunk: 4 query blocks
+    (NEMOTRON, 512, 1, False, None),    # groups of 16: 16 blocks of 32
 ], ids=["decode", "verify5", "chunk128", "decode-int8", "chunk128-int8",
         "decode-3pages", "narrow-decode", "narrow-chunk128-int8",
-        "hd64-decode-128rows", "hd64-chunk128"])
+        "hd64-decode-128rows", "hd64-chunk128", "hd64-chunk512",
+        "group16-chunk512"])
 def test_paged_kernel_compiles_for_v5e(one_chip, heads, sq, b, quant, pages):
     H, HKV, HD = heads
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -252,22 +258,16 @@ def test_the_sampler_sorts_only_inside_a_conditional(one_chip):
     assert _reached_outside_conditionals(text, "conditional")     # the walk sees ENTRY
 
 
-def test_the_conv_moe_cells_decode_program_compiles_and_fits_one_chip(one_chip, monkeypatch):
-    """`lfm2-8b-a1b-d14.chat-backlog-wide`'s decode program whole, at the
-    published widths and the cell's engine options, from shapes alone: 128
-    slots through 14 unrolled layers — the paged kernel at head size 64 in the
-    3 attention layers, the state arena advanced in place, every expert in the
-    dense form — and what it holds (9.33 GB of weights, 3.22 GB of pages, the
-    arena) beside its temporaries inside one v5e's 16 GB."""
+def _conv_moe_cell(one_chip):
+    """(model, params, cache, engine options, sds) of
+    `lfm2-8b-a1b-d14.chat-backlog-wide` as shapes placed on a described chip:
+    14 unrolled layers at the published widths, the page pool of the three
+    attention layers and the state arena of the eleven convolutions."""
     import flax.linen as nn
 
     from benchmarks.harness import family_conv_moe as fam
     from benchmarks.harness import manifest as M
     from k8s_distributed_deeplearning_tpu.models.transformer import PatternLM
-    from k8s_distributed_deeplearning_tpu.ops import pallas_gmm
-    from k8s_distributed_deeplearning_tpu.serve import engine as E
-    for mod in (pallas_paged_attn, pallas_gmm):
-        monkeypatch.setattr(mod, "on_tpu", lambda: True)         # compile, not interpret
     cell = M.Cell(M.load_manifest(), "lfm2-8b-a1b-d14.chat-backlog-wide")
     cfg, eng = cell.config, cell.options["engine"]
     slots, pt, pages = eng["num_slots"], eng["page_tokens"], eng["kv_pool_pages"] + 1
@@ -284,6 +284,22 @@ def test_the_conv_moe_cells_decode_program_compiles_and_fits_one_chip(one_chip, 
         {"cached_key": sds((pages, pt, lanes), jnp.bfloat16),
          "cached_value": sds((pages, pt, lanes), jnp.bfloat16)})}
         for i, kind in enumerate(cfg["layer_types"][:cfg["num_hidden_layers"]])}}
+    return model, params, cache, eng, sds
+
+
+def test_the_conv_moe_cells_decode_program_compiles_and_fits_one_chip(one_chip, monkeypatch):
+    """`lfm2-8b-a1b-d14.chat-backlog-wide`'s decode program whole, at the
+    published widths and the cell's engine options, from shapes alone: 128
+    slots through 14 unrolled layers — the paged kernel at head size 64 in the
+    3 attention layers, the state arena advanced in place, every expert in the
+    dense form — and what it holds (9.33 GB of weights, 3.22 GB of pages, the
+    arena) beside its temporaries inside one v5e's 16 GB."""
+    from k8s_distributed_deeplearning_tpu.ops import pallas_gmm
+    from k8s_distributed_deeplearning_tpu.serve import engine as E
+    for mod in (pallas_paged_attn, pallas_gmm):
+        monkeypatch.setattr(mod, "on_tpu", lambda: True)         # compile, not interpret
+    model, params, cache, eng, sds = _conv_moe_cell(one_chip)
+    slots, pt = eng["num_slots"], eng["page_tokens"]
     i32, f32 = (lambda *s: sds(s, jnp.int32)), (lambda *s: sds(s, jnp.float32))
     compiled = E._decode_program.lower(
         model, params, cache, i32(slots), i32(slots),
@@ -297,6 +313,25 @@ def test_the_conv_moe_cells_decode_program_compiles_and_fits_one_chip(one_chip, 
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 12.5e9 < mem.argument_size_in_bytes < 12.7e9 and held < 14e9
     assert mem.alias_size_in_bytes > 3.2e9                      # pool and arena in place
+
+
+def test_the_conv_moe_cells_chunk_program_attends_through_the_kernel(one_chip, monkeypatch):
+    """The same cell's intermediate CHUNK program, 512 tokens of one slot: the
+    three attention layers attend through the paged kernel in blocks of 128
+    queries — no gather of the 4,096-position table and no ``[32, 512, 4096]``
+    float32 scores in HBM — and the experts take the grouped products."""
+    from k8s_distributed_deeplearning_tpu.ops import pallas_gmm
+    from k8s_distributed_deeplearning_tpu.serve import engine as E
+    for mod in (pallas_paged_attn, pallas_gmm):
+        monkeypatch.setattr(mod, "on_tpu", lambda: True)         # compile, not interpret
+    model, params, cache, eng, sds = _conv_moe_cell(one_chip)
+    c, blocks = eng["prefill_chunk_tokens"], eng["max_seq_len"] // eng["page_tokens"]
+    assert c == 512
+    text = E._chunk_program.lower(
+        model, params, cache, sds((1, c), jnp.int32), sds((1, blocks), jnp.int32),
+        sds((), jnp.int32), sds((), jnp.int32)).compile().as_text()
+    assert text.count("paged_attn") >= 3 and "moe_gmm" in text
+    assert "f32[32,512,4096]" not in text and "f32[1,32,512,4096]" not in text
 
 
 def _ssm_moe_cell(one_chip):
