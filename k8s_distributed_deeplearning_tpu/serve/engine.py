@@ -969,6 +969,10 @@ class ServeEngine:
         # behind the awaited one), and everything dispatched before the
         # awaited program is done when the wait returns.
         self._dispatches = 0
+        # The newest program number a wait has been made for (_wait): the
+        # device's queue is in order, so everything up to it is done by the
+        # next dispatch. ``_in_flight`` counts from here.
+        self._fenced = 0
         # (dispatch number, fields, device counts) of the intermediate
         # chunks whose counts have not been fetched yet (_take_chunk_counts)
         self._chunk_counts: deque[tuple] = deque()
@@ -1723,7 +1727,26 @@ class ServeEngine:
         ``kind`` ``decode`` / ``spec`` / ``first_token``; ``covered`` 1
         when a program was dispatched behind the awaited one before the
         wait began), ``emit`` (per-slot bookkeeping and ``on_token``
-        after the fence) and ``epilogue``."""
+        after the fence) and ``epilogue``.
+
+        Inside ``decode``, ``prefill`` and ``device_wait`` each call and
+        each read has a span of its own, in the step's order:
+        ``decode_call`` (the register copies and the jitted decode call —
+        a speculative step's draft and verify calls — to its return;
+        ``in_flight``) → per chunk, inside its ``prefill``:
+        ``chunk_operands`` (the numpy operands and scalars) → on a final
+        chunk ``first_key`` (the request's ``PRNGKey`` made and read back;
+        ``in_flight``) → ``chunk_call`` (the jitted chunk call alone;
+        ``program`` = the ``attention_impls`` key, ``chunk_512`` /
+        ``final_chunk_512``, ``in_flight``; with a draft model a second
+        one, ``draft=1``) → with a trie, on a final chunk ``trie_adopt`` →
+        inside the ``device_wait``: ``fetch_tokens`` → ``fetch_counts``
+        (one per intermediate chunk whose counts are due; the
+        ``prefill_counts`` record follows it, outside it) →
+        ``fetch_keys`` (a first token's fence reads its key before the
+        counts). ``in_flight`` is :meth:`_in_flight`: the programs
+        dispatched since the newest one a wait was made for, an upper
+        bound on what lies ahead in the device's queue."""
         with self.tracer.span("engine_step", step=self.stats.steps):
             return self._step()
 
@@ -1833,7 +1856,21 @@ class ServeEngine:
         number *seq* hands back; counts whether the wait is covered."""
         covered = int(self._dispatches > seq)
         self.stats.record_fence(covered)
+        # Nothing is dispatched inside a wait: by the next dispatch this
+        # read has returned, and programs 1..seq are done.
+        self._fenced = max(self._fenced, seq)
         return self.tracer.span("device_wait", kind=kind, covered=covered)
+
+    def _in_flight(self) -> int:
+        """The ``in_flight`` field of a ``decode_call`` / ``chunk_call`` /
+        ``first_key`` span: programs dispatched since the newest one a wait
+        has been made for, read BEFORE the span's own dispatch — what may
+        lie ahead of it in the device's queue. An UPPER bound: the queue is
+        in order, so everything up to the awaited program is done, and what
+        was dispatched behind it may be done too. 0 on the first dispatch
+        after a decode-only step's fence; 1 for a chunk dispatched behind
+        this step's decode."""
+        return self._dispatches - self._fenced
 
     def _count_sampled_rows(self) -> int:
         """Rows of the register file that sample (``_temps > 0``: a freed
@@ -1859,23 +1896,28 @@ class ServeEngine:
                 context_tokens=int(self._kv_lens.sum()) + active,
                 sampled_rows=self._count_sampled_rows(),
                 **self._state_update_fields()) as span:
-            nxt, keys, self._cache = self._decode_step()
+            with self.tracer.span("decode_call",
+                                  in_flight=self._in_flight()):
+                nxt, keys, self._cache = self._decode_step()
             seq = self._dispatches
             self._admissions(outputs)
             with self._wait("decode", seq):
-                # graftlint: disable=host-sync — the iteration's one honest
-                # sync: every slot's sampled token in a single device fence.
-                nxt = np.asarray(nxt)
+                with self.tracer.span("fetch_tokens"):
+                    # graftlint: disable=host-sync — the iteration's one
+                    # honest sync: every slot's sampled token in one fence.
+                    nxt = np.asarray(nxt)
                 if nxt.size > self.num_slots:
                     # (a disabled tracer's span keeps no fields: the
                     # counters alone)
                     self._record_counts(getattr(span, "fields", {}),
                                         nxt[self.num_slots:])
                 self._take_chunk_counts(seq)
+                with self.tracer.span("fetch_keys"):
+                    # graftlint: disable=host-sync — rides the same fence
+                    keys = np.asarray(keys)
                 # Only the decoded rows' keys: a slot activated under this
                 # decode has had its own written since the dispatch.
-                # graftlint: disable=host-sync — rides the same fence as nxt
-                self._keys[rows] = np.asarray(keys)[rows]
+                self._keys[rows] = keys[rows]
         with self.tracer.span("emit"):
             self.stats.record_step(active, self.num_slots)
             for slot in rows:
@@ -1913,24 +1955,30 @@ class ServeEngine:
         with self.tracer.span("decode", active=len(rows),
                               spec_k=self.spec_k,
                               sampled_rows=self._count_sampled_rows()):
-            regs = self._registers()
-            window, self._draft_cache = self._spec_draft_step(regs)
-            sel, key_states, acc, self._cache = self._spec_verify_step(
-                window, regs)
+            with self.tracer.span("decode_call",
+                                  in_flight=self._in_flight()):
+                regs = self._registers()
+                window, self._draft_cache = self._spec_draft_step(regs)
+                sel, key_states, acc, self._cache = self._spec_verify_step(
+                    window, regs)
             seq = self._dispatches
             self._admissions(outputs)
             with self._wait("spec", seq):
-                # graftlint: disable=host-sync — the iteration's one honest
-                # sync: every slot's window/selections in a single fence.
-                window = np.asarray(window)
-                # graftlint: disable=host-sync — rides the same fence
-                sel = np.asarray(sel)
-                # graftlint: disable=host-sync — rides the same fence
-                acc = np.asarray(acc)
-                # np.array (copy): the key register is written in place at
-                # admissions, and only the emitted-count column survives.
-                # graftlint: disable=host-sync — rides the same fence
-                key_states = np.array(key_states)
+                with self.tracer.span("fetch_tokens"):
+                    # graftlint: disable=host-sync — the iteration's one
+                    # honest sync: every slot's window/selections in one
+                    # fence.
+                    window = np.asarray(window)
+                    # graftlint: disable=host-sync — rides the same fence
+                    sel = np.asarray(sel)
+                    # graftlint: disable=host-sync — rides the same fence
+                    acc = np.asarray(acc)
+                with self.tracer.span("fetch_keys"):
+                    # np.array (copy): the key register is written in place
+                    # at admissions, and only the emitted-count column
+                    # survives.
+                    # graftlint: disable=host-sync — rides the same fence
+                    key_states = np.array(key_states)
                 self._take_chunk_counts(seq)
         with self.tracer.span("emit"):
             self._spec_emit(rows, outputs, window, sel, acc, key_states)
@@ -2199,8 +2247,10 @@ class ServeEngine:
         fence."""
         while self._chunk_counts and self._chunk_counts[0][0] < seq:
             _, fields, counts = self._chunk_counts.popleft()
-            # graftlint: disable=host-sync — ran before the awaited program
-            self._chunk_counts_record(fields, np.asarray(counts))
+            with self.tracer.span("fetch_counts"):
+                # graftlint: disable=host-sync — ran before the awaited one
+                counts = np.asarray(counts)
+            self._chunk_counts_record(fields, counts)
 
     def _chunk_counts_record(self, fields: dict, counts: np.ndarray) -> None:
         """A chunk's counts, known only after its ``prefill`` span closed:
@@ -2578,25 +2628,33 @@ class ServeEngine:
                 if c is not None and rem > c:
                     if budget is not None and budget < c:
                         break       # out of budget; resume next iteration
-                    chunk = pend.prompt[None, pend.pos:pend.pos + c]
+                    program = f"chunk_{c}"
                     fields = dict(chunk=c, tokens=c, start=pend.pos,
                                   request_id=pend.req.request_id,
-                                  attn=self._prefill_attn[f"chunk_{c}"],
+                                  attn=self._prefill_attn[program],
                                   **self._state_from(pend))
                     with self.tracer.span("prefill", slot=slot, **fields):
-                        self._cache, moe = self._chunk_step(
-                            np.ascontiguousarray(chunk),
-                            np.ascontiguousarray(table),
-                            np.int32(pend.pos), slot=self._state_slot(slot))
+                        with self.tracer.span("chunk_operands"):
+                            operands = (
+                                np.ascontiguousarray(
+                                    pend.prompt[None, pend.pos:pend.pos + c]),
+                                np.ascontiguousarray(table),
+                                np.int32(pend.pos))
+                            state_slot = self._state_slot(slot)
+                        with self.tracer.span("chunk_call", program=program,
+                                              in_flight=self._in_flight()):
+                            self._cache, moe = self._chunk_step(
+                                *operands, slot=state_slot)
                         if moe is not None:
                             # no fence here: read at the next one behind it
                             self._chunk_counts.append((
                                 self._dispatches, fields, moe))
                         if self.spec_k:
-                            self._draft_cache, _ = self._chunk_step(
-                                np.ascontiguousarray(chunk),
-                                np.ascontiguousarray(table),
-                                np.int32(pend.pos), draft=True)
+                            with self.tracer.span(
+                                    "chunk_call", program=program,
+                                    in_flight=self._in_flight(), draft=1):
+                                self._draft_cache, _ = self._chunk_step(
+                                    *operands, draft=True)
                     pend.pos += c
                     pend.chunks += 1
                     self._charge_prefill(c)
@@ -2642,48 +2700,63 @@ class ServeEngine:
         rem = n - pend.pos
         bucket = self._bucket(rem)
         sp = req.sampling
-        chunk = np.full((1, bucket), self.pad_id, np.int32)
-        chunk[0, :rem] = pend.prompt[pend.pos:]
-        table = np.ascontiguousarray(pend.table[None, :])
+        program = f"final_chunk_{bucket}"
         fields = dict(bucket=bucket, tokens=rem, start=pend.pos,
                       request_id=req.request_id,
-                      attn=self._prefill_attn[f"final_chunk_{bucket}"],
+                      attn=self._prefill_attn[program],
                       **self._state_from(pend))
         with self.tracer.span("prefill", slot=slot, cached=pend.hit_tokens,
                               **fields):
-            tok, key, self._cache = self._final_chunk_step(
-                chunk, table, np.int32(pend.pos),
-                np.int32(rem), np.float32(sp.temperature),
-                np.int32(sp.top_k), np.float32(sp.top_p),
-                np.asarray(jax.random.PRNGKey(req.seed), np.uint32),
-                slot=self._state_slot(slot))
+            with self.tracer.span("chunk_operands"):
+                chunk = np.full((1, bucket), self.pad_id, np.int32)
+                chunk[0, :rem] = pend.prompt[pend.pos:]
+                table = np.ascontiguousarray(pend.table[None, :])
+                scalars = (np.int32(pend.pos), np.int32(rem),
+                           np.float32(sp.temperature), np.int32(sp.top_k),
+                           np.float32(sp.top_p))
+                state_slot = self._state_slot(slot)
+            with self.tracer.span("first_key", in_flight=self._in_flight()):
+                # A device program of its own and a blocking read of its
+                # result, ahead of the chunk's call.
+                key = np.asarray(jax.random.PRNGKey(req.seed), np.uint32)
+            with self.tracer.span("chunk_call", program=program,
+                                  in_flight=self._in_flight()):
+                tok, key, self._cache = self._final_chunk_step(
+                    chunk, table, *scalars, key, slot=state_slot)
             pend.first = (tok, key, self._dispatches, fields)
             if self.spec_k:
                 # Mirror the final chunk into the draft arena (logits
                 # DCE'd): same padded chunk, same table, same positions
                 # — pad writes land beyond the cursor or in scratch,
                 # exactly as on the target path.
-                self._draft_cache, _ = self._chunk_step(
-                    chunk, table, np.int32(pend.pos), draft=True)
+                with self.tracer.span("chunk_call",
+                                      program=f"chunk_{bucket}",
+                                      in_flight=self._in_flight(), draft=1):
+                    self._draft_cache, _ = self._chunk_step(
+                        chunk, table, scalars[0], draft=True)
             if self.prefix_cache is not None:
-                # Adopt whole prompt blocks into the trie by REFERENCE:
-                # the trie takes its own refcount on the slot's page, so
-                # the KV survives the slot and later requests map it with
-                # zero copies. Runs only for blocks the trie doesn't hold.
-                def page_for_block(i: int) -> int:
-                    page = int(pend.table[i])
-                    self.pool.ref(page)
-                    # Ledger: the trie's reference outlives the slot, so
-                    # the attribution moves with the longer lifetime.
-                    self.pool.tag(page, "trie")
-                    return page
+                with self.tracer.span("trie_adopt"):
+                    self._adopt_into_trie(pend)
 
-                _, evicted = self.prefix_cache.insert(
-                    pend.prompt.tolist(), page_for_block)
-                if evicted:
-                    self.stats.record_prefix_evictions(evicted)
-                self.prefix_cache.release(pend.nodes)
-                pend.nodes = []
+    def _adopt_into_trie(self, pend: _PendingPrefill) -> None:
+        """Adopt whole prompt blocks into the trie by REFERENCE: the trie
+        takes its own refcount on the slot's page, so the KV survives the
+        slot and later requests map it with zero copies. Runs only for
+        blocks the trie doesn't hold."""
+        def page_for_block(i: int) -> int:
+            page = int(pend.table[i])
+            self.pool.ref(page)
+            # Ledger: the trie's reference outlives the slot, so the
+            # attribution moves with the longer lifetime.
+            self.pool.tag(page, "trie")
+            return page
+
+        _, evicted = self.prefix_cache.insert(
+            pend.prompt.tolist(), page_for_block)
+        if evicted:
+            self.stats.record_prefix_evictions(evicted)
+        self.prefix_cache.release(pend.nodes)
+        pend.nodes = []
 
     def _activate(self, slot: int, outputs: list[RequestOutput]) -> bool:
         """Take the first token *slot*'s final chunk owes and make the slot
@@ -2700,10 +2773,12 @@ class ServeEngine:
         tok, key, seq, fields = pend.first
         req, n, sp = pend.req, pend.n, pend.req.sampling
         with self._wait("first_token", seq):
-            # graftlint: disable=host-sync — the admission's one fence
-            tok = np.asarray(tok).reshape(-1)
-            # graftlint: disable=host-sync — rides the same fence
-            key = np.asarray(key)
+            with self.tracer.span("fetch_tokens"):
+                # graftlint: disable=host-sync — the admission's one fence
+                tok = np.asarray(tok).reshape(-1)
+            with self.tracer.span("fetch_keys"):
+                # graftlint: disable=host-sync — rides the same fence
+                key = np.asarray(key)
             self._take_chunk_counts(seq)
             if tok.size > 1:
                 self._chunk_counts_record(fields, tok[1:])

@@ -47,6 +47,16 @@ a ``TraceAnnotation`` named ``program:<name>``: the device trace then shows
 what the host was doing beside each idle gap. ``utils.profiling`` flips the
 switch (:func:`profiler_session`) at start and stop; this module never
 imports jax, and with no session on a span pays one global read for it.
+
+**The serving engine's spans** (``serve/engine.py::ServeEngine.step`` has
+the full account), in the order of one step: ``engine_step`` around
+``sweep`` → ``grow`` → ``decode`` [``decode_call`` → ``admission`` →
+``prefill`` [``chunk_operands`` → ``first_key`` (a final chunk) →
+``chunk_call`` (→ a draft's ``chunk_call``) → ``trie_adopt`` (with a trie)]
+→ ``device_wait`` [``fetch_tokens`` → ``fetch_counts`` → ``fetch_keys``;
+``prefill_counts`` records beside them]] → ``emit`` → ``epilogue``. A trace
+reduction names an idle gap by the SHORTEST span that covers half of it, so
+the inner names are what an idle share is read under.
 """
 from __future__ import annotations
 

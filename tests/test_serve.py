@@ -454,12 +454,232 @@ def test_every_return_path_closes_engine_step_and_epilogue(tiny, path):
 
 
 def test_disabled_tracer_costs_the_engine_the_shared_null_span(tiny):
-    """No tracer: every phase gets the one shared no-op span object."""
+    """No tracer: every phase — and every call, operand set, key and fetch
+    inside ``decode``, ``prefill`` and ``device_wait`` (PR 35) — gets the one
+    shared no-op span object, and a run opens nothing else."""
     from k8s_distributed_deeplearning_tpu.telemetry import trace as trace_lib
-    model, params, _ = tiny
-    eng = ServeEngine(model, params, num_slots=2, eos_id=None)
+    model, params, cfg = tiny
+    eng = ServeEngine(model, params, num_slots=2, eos_id=None, min_bucket=8,
+                      prefill_chunk_tokens=8, prefix_cache_mb=1)
     assert eng.tracer.span("engine_step", step=0) is trace_lib._NULL_SPAN
     assert eng.tracer.span("emit") is trace_lib._NULL_SPAN
+    opened = []
+    span = eng.tracer.span
+    eng.tracer.span = lambda name, **f: opened.append(name) or span(name, **f)
+    try:
+        eng.run([Request(prompt=np.arange(n, dtype=np.int32) % cfg.vocab_size,
+                         max_new_tokens=3) for n in (20, 5)])
+    finally:
+        del eng.tracer.span         # the shared null tracer: leave it as it was
+    assert set(opened) >= set(_INNER_SPANS) - {"fetch_counts"}
+    assert all(span(name) is trace_lib._NULL_SPAN for name in set(opened))
+
+
+# ------- the calls, operands, key and fetches inside the three boxes (PR 35)
+
+_INNER_SPANS = ("decode_call", "chunk_operands", "first_key", "chunk_call",
+                "trie_adopt", "fetch_tokens", "fetch_counts", "fetch_keys")
+
+
+def _children(spans, parent):
+    """The spans that lie inside the span *parent* and name it as their
+    parent, in opening order."""
+    return [s for s in sorted(spans, key=lambda s: s["t0"])
+            if s["parent"] == parent["name"] and s["depth"] == parent["depth"] + 1
+            and parent["t0"] <= s["t0"] and s["t1"] <= parent["t1"]]
+
+
+def _chunky(tiny, **kw):
+    """A two-slot engine with 8-token chunks and buckets, request A decoding
+    and a 20-token request B submitted: B takes two intermediate chunks, a
+    final one of 4 tokens, then its first token — one a step, each behind A's
+    decode. Returns (engine, take)."""
+    _, _, cfg = tiny
+    eng, take = _step_spans(tiny, min_bucket=8, prefill_chunk_tokens=8, **kw)
+    eng.submit(Request(prompt=np.arange(5, dtype=np.int32) % cfg.vocab_size,
+                       max_new_tokens=24, request_id="A"))
+    eng.step()
+    eng.step()                          # a decode-only step: its fence returns
+    eng.submit(Request(prompt=(np.arange(20, dtype=np.int32) * 3)
+                       % cfg.vocab_size, max_new_tokens=6, request_id="B"))
+    take()
+    return eng, take
+
+
+def test_a_decode_only_step_has_one_call_and_two_fetches(tiny):
+    """``decode_call`` is the first child of ``decode`` (``in_flight`` 0: the
+    step before was decode-only and its fence has returned); the fence holds
+    ``fetch_tokens`` then ``fetch_keys`` and nothing else; no chunk span."""
+    _, _, cfg = tiny
+    eng, take = _step_spans(tiny)
+    eng.submit(Request(prompt=np.arange(5, dtype=np.int32) % cfg.vocab_size,
+                       max_new_tokens=6))
+    eng.step()
+    eng.step()
+    take()
+    eng.step()
+    spans = take()
+    _assert_nested(spans)
+    decode = _named(spans, "decode")[0]
+    assert [(s["name"], s.get("in_flight")) for s in _children(spans, decode)] == [
+        ("decode_call", 0), ("device_wait", None)]
+    wait = _named(spans, "device_wait")[0]
+    assert [s["name"] for s in _children(spans, wait)] == ["fetch_tokens",
+                                                          "fetch_keys"]
+    assert {s["name"] for s in spans} & set(_INNER_SPANS) == {
+        "decode_call", "fetch_tokens", "fetch_keys"}
+
+
+def test_a_chunk_step_splits_prefill_into_operands_and_call(tiny):
+    """An intermediate chunk dispatched behind a decode: ``decode_call``
+    comes before ``admission``; inside ``prefill`` ``chunk_operands`` then
+    ``chunk_call`` (``program`` = the ``attention_impls`` key, ``in_flight``
+    1: the decode) and no ``first_key``; the step after it dispatches its
+    decode with the chunk ahead of it (``in_flight`` 1) and its own chunk
+    behind both (2)."""
+    eng, take = _chunky(tiny)
+    eng.step()
+    spans = take()
+    _assert_nested(spans)
+    decode = _named(spans, "decode")[0]
+    assert [s["name"] for s in _children(spans, decode)] == [
+        "decode_call", "admission", "prefill", "device_wait"]
+    assert _named(spans, "decode_call")[0]["in_flight"] == 0
+    prefill = _named(spans, "prefill")[0]
+    assert prefill["chunk"] == 8 and prefill["start"] == 0
+    inner = _children(spans, prefill)
+    assert [s["name"] for s in inner] == ["chunk_operands", "chunk_call"]
+    assert (inner[1]["program"], inner[1]["in_flight"]) == ("chunk_8", 1)
+    assert "draft" not in inner[1] and inner[1]["program"] in eng.attention_impls()
+    assert not _named(spans, "first_key") and not _named(spans, "trie_adopt")
+    eng.step()                                          # B's second chunk
+    spans = take()
+    assert _named(spans, "decode_call")[0]["in_flight"] == 1
+    # no wait has been made for the first chunk: an upper bound counts it
+    assert _named(spans, "chunk_call")[0]["in_flight"] == 2
+    assert _named(spans, "prefill")[0]["start"] == 8
+
+
+def test_a_final_chunk_step_makes_its_key_between_operands_and_call(tiny):
+    """The final chunk: ``chunk_operands`` → ``first_key`` → ``chunk_call``
+    (``final_chunk_8``) inside ``prefill``, key and call both with the
+    decode and the chunk before it in flight; the next step takes the first
+    token right behind its ``decode_call`` — ``fetch_tokens`` →
+    ``fetch_keys`` inside the ``first_token`` wait — and then its own tokens
+    the same way."""
+    eng, take = _chunky(tiny)
+    eng.step()
+    eng.step()
+    take()
+    eng.step()
+    spans = take()
+    _assert_nested(spans)
+    prefill = _named(spans, "prefill")[0]
+    assert (prefill["bucket"], prefill["tokens"], prefill["start"]) == (8, 4, 16)
+    inner = _children(spans, prefill)
+    assert [(s["name"], s["in_flight"] if "in_flight" in s else None)
+            for s in inner] == [("chunk_operands", None), ("first_key", 2),
+                                ("chunk_call", 2)]
+    assert inner[2]["program"] == "final_chunk_8"
+    eng.step()
+    spans = take()
+    _assert_nested(spans)
+    decode = _named(spans, "decode")[0]
+    kids = _children(spans, decode)
+    assert [(s["name"], s.get("kind")) for s in kids] == [
+        ("decode_call", None), ("device_wait", "first_token"),
+        ("device_wait", "decode")]
+    assert kids[0]["in_flight"] == 1                    # the final chunk
+    for wait in kids[1:]:
+        assert [s["name"] for s in _children(spans, wait)] == [
+            "fetch_tokens", "fetch_keys"]
+    assert eng.occupied_slots() == 2
+
+
+@pytest.mark.parametrize("trie", [False, True])
+def test_trie_adopt_is_opened_only_with_a_trie(tiny, trie):
+    """With a prefix trie a final chunk's ``prefill`` ends in ``trie_adopt``
+    (the ``insert`` / ``release`` block); without one the span is never
+    opened. The idle engine's first step takes the token at once: ``prefill``
+    and the ``first_token`` wait with its two fetches, no ``decode_call``."""
+    _, _, cfg = tiny
+    eng, take = _step_spans(tiny, **({"prefix_cache_mb": 1,
+                                      "prefix_block_tokens": 4} if trie else {}))
+    eng.submit(Request(prompt=np.arange(9, dtype=np.int32) % cfg.vocab_size,
+                       max_new_tokens=3))
+    eng.step()
+    spans = take()
+    _assert_nested(spans)
+    inner = [(s["name"], s.get("in_flight"))
+             for s in _children(spans, _named(spans, "prefill")[0])]
+    want = [("chunk_operands", None), ("first_key", 0), ("chunk_call", 0)]
+    assert inner == want + ([("trie_adopt", None)] if trie else [])
+    assert not _named(spans, "decode_call")
+    assert [s["name"] for s in _children(spans, _named(spans, "device_wait")[0])
+            ] == ["fetch_tokens", "fetch_keys"]
+    eng.run()
+    assert len(_named(take(), "trie_adopt")) == 0      # no other final chunk
+
+
+def test_a_spec_engines_calls_and_fetches(tiny):
+    """A speculative step: ONE ``decode_call`` around the draft and verify
+    dispatches, ``fetch_tokens`` (window, selections, accepts) then
+    ``fetch_keys`` inside the ``spec`` wait; every chunk is mirrored into the
+    draft's arena by a second ``chunk_call`` with ``draft=1`` (a final
+    chunk's mirror is the plain chunk program at the bucket's width)."""
+    model, params, cfg = tiny
+    eng, take = _chunky(tiny, draft_model=model, draft_params=params, spec_k=2)
+    eng.step()
+    spans = take()
+    _assert_nested(spans)
+    decode = _named(spans, "decode")[0]
+    assert decode["spec_k"] == 2
+    assert [s["name"] for s in _children(spans, decode)] == [
+        "decode_call", "admission", "prefill", "device_wait"]
+    calls = _children(spans, _named(spans, "prefill")[0])
+    assert [(s["name"], s.get("program"), s.get("draft"), s.get("in_flight"))
+            for s in calls] == [("chunk_operands", None, None, None),
+                                ("chunk_call", "chunk_8", None, 2),
+                                ("chunk_call", "chunk_8", 1, 3)]
+    wait = _named(spans, "device_wait", kind="spec")[0]
+    assert [s["name"] for s in _children(spans, wait)] == ["fetch_tokens",
+                                                          "fetch_keys"]
+    eng.step()
+    take()
+    eng.step()                                          # B's final chunk
+    calls = [(s["program"], s.get("draft")) for s in _named(take(), "chunk_call")]
+    assert calls == [("final_chunk_8", None), ("chunk_8", 1)]
+
+
+def test_fetch_counts_is_one_copy_a_chunk_and_the_record_stays_outside_it():
+    """A model with expert layers: an intermediate chunk's counts are read at
+    a later step's fence, between ``fetch_tokens`` and ``fetch_keys``, in a
+    ``fetch_counts`` of their own; the ``prefill_counts`` record that follows
+    is a child of ``device_wait``, not of the fetch."""
+    from k8s_distributed_deeplearning_tpu.models import moe
+    from k8s_distributed_deeplearning_tpu.telemetry.trace import Tracer
+    cfg, latent, mo = moe.config_tiny_latent_moe()
+    model = moe.LatentMoELM(cfg, latent, mo)
+    params = model.init(jax.random.key(1), jnp.zeros((1, 8), jnp.int32))["params"]
+    tr = Tracer(ring_size=4096)
+    eng = ServeEngine(model, params, num_slots=2, min_bucket=8,
+                      prefill_chunk_tokens=8, tracer=tr)
+    eng.submit(Request(prompt=list(range(5)), max_new_tokens=12))
+    eng.step()
+    eng.submit(Request(prompt=list(range(30, 50)), max_new_tokens=4))
+    eng.step()                  # decode + chunk 0 behind it
+    seen = len(tr.recent_spans())
+    eng.step()                  # decode + chunk 1; chunk 0's counts are due
+    spans = tr.recent_spans()[seen:]
+    _assert_nested(spans)
+    wait = _named(spans, "device_wait", kind="decode")[0]
+    assert [s["name"] for s in _children(spans, wait)] == [
+        "fetch_tokens", "fetch_counts", "prefill_counts", "fetch_keys"]
+    record = _named(spans, "prefill_counts")[0]
+    assert record["parent"] == "device_wait" and record["start"] == 0
+    assert record["t0"] >= _named(spans, "fetch_counts")[0]["t1"]
+    eng.run()
+    assert not eng._chunk_counts
 
 
 # --------------------- no wait with an empty device queue behind it (PR 28)
