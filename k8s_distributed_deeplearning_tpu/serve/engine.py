@@ -784,6 +784,11 @@ class ServeEngine:
                  weight_quant: str | None = None):
         if num_slots < 2:
             raise ValueError(f"num_slots must be >= 2, got {num_slots}")
+        if jax.config.jax_default_prng_impl != "threefry2x32":
+            # the programs' keys are raw uint32[2]; _first_key makes them
+            raise ValueError(
+                "ServeEngine needs jax_default_prng_impl 'threefry2x32', "
+                f"got {jax.config.jax_default_prng_impl!r}")
         for what, mode in (("kv_quant", kv_quant),
                            ("weight_quant", weight_quant)):
             if mode not in (None, "int8"):
@@ -1735,7 +1740,8 @@ class ServeEngine:
         a speculative step's draft and verify calls — to its return;
         ``in_flight``) → per chunk, inside its ``prefill``:
         ``chunk_operands`` (the numpy operands and scalars) → on a final
-        chunk ``first_key`` (the request's ``PRNGKey`` made and read back;
+        chunk ``first_key`` (the request's first sampling key made on the
+        host, :meth:`_first_key`: microseconds and no device program;
         ``in_flight``) → ``chunk_call`` (the jitted chunk call alone;
         ``program`` = the ``attention_impls`` key, ``chunk_512`` /
         ``final_chunk_512``, ``in_flight``; with a draft model a second
@@ -2160,6 +2166,19 @@ class ServeEngine:
         """The chunk programs' ``slot`` operand: the arena row of *slot* for
         a model with per-slot state, None (no operand at all) otherwise."""
         return np.int32(slot) if self._state_rows else None
+
+    @staticmethod
+    def _first_key(seed: int) -> np.ndarray:
+        """The final chunk's ``key`` operand: the request's first sampling
+        key, bit for bit ``jax.random.PRNGKey(seed)`` under ``threefry2x32``
+        (the constructor refuses any other implementation), made on the
+        host: that call is two device programs and a read of their result,
+        behind whatever the device has queued. As there, a seed beyond int64
+        overflows, and without ``jax_enable_x64`` it is narrowed to 32 bits
+        first, so the key's high word is 0."""
+        seed = int(np.int64(seed))
+        high = seed >> 32 if jax.config.jax_enable_x64 else 0
+        return np.array([high & 0xFFFFFFFF, seed & 0xFFFFFFFF], np.uint32)
 
     def _chunk_step(self, chunk, table, start, *, slot=None,
                     draft: bool = False):
@@ -2605,6 +2624,7 @@ class ServeEngine:
         t0 = req._t_submit if req._t_submit is not None else t_pop
         self.stats.record_admission(queue_s=t_pop - t0, prompt_len=n)
 
+    # graftlint: hot-path
     def _run_prefills(self, outputs: list[RequestOutput]) -> bool:
         """Advance pending prefills FIFO within this step's token budget.
         Intermediate chunks are exact C-token slices; the final chunk
@@ -2684,6 +2704,7 @@ class ServeEngine:
             self._step_prefill_budget = max(
                 0, self._step_prefill_budget - int(tokens))
 
+    # graftlint: hot-path
     def _dispatch_final_chunk(self, slot: int,
                               pend: _PendingPrefill) -> None:
         """Dispatch the final (sampling) chunk and adopt the prompt's pages
@@ -2716,9 +2737,9 @@ class ServeEngine:
                            np.float32(sp.top_p))
                 state_slot = self._state_slot(slot)
             with self.tracer.span("first_key", in_flight=self._in_flight()):
-                # A device program of its own and a blocking read of its
-                # result, ahead of the chunk's call.
-                key = np.asarray(jax.random.PRNGKey(req.seed), np.uint32)
+                # Host arithmetic alone: nothing is read back from the
+                # device between the step's decode and this chunk's call.
+                key = self._first_key(req.seed)
             with self.tracer.span("chunk_call", program=program,
                                   in_flight=self._in_flight()):
                 tok, key, self._cache = self._final_chunk_step(

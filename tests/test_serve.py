@@ -208,6 +208,72 @@ def test_sampled_output_is_seed_deterministic_and_placement_free(tiny):
                for o in outs.values() for t in o.tokens)
 
 
+@pytest.mark.parametrize("seed", [0, 1, 2**31 - 1, 2**31, 2**32 - 1,
+                                  2**32 + 5, -1])
+@pytest.mark.parametrize("x64", [False, True])
+def test_first_key_is_the_prng_key_of_the_seed(seed, x64):
+    """The key a final chunk takes is made on the host, bit for bit what
+    ``jax.random.PRNGKey`` gives in this process — with ``jax_enable_x64``
+    too, where the seed's high word is kept."""
+    with jax.enable_x64(x64):
+        want = np.asarray(jax.random.PRNGKey(seed), np.uint32)
+        got = ServeEngine._first_key(seed)
+    assert got.dtype == np.uint32 and got.shape == (2,)
+    np.testing.assert_array_equal(got, want)
+
+
+def test_first_key_refuses_what_prng_key_refuses(tiny):
+    """A seed beyond int64 overflows as it does in ``PRNGKey``; an engine is
+    not built where the default PRNG is not the ``threefry2x32`` its
+    programs' ``uint32[2]`` keys are."""
+    model, params, _ = tiny
+    with pytest.raises(OverflowError):
+        jax.random.PRNGKey(2**63)
+    with pytest.raises(OverflowError):
+        ServeEngine._first_key(2**63)
+    with jax.default_prng_impl("rbg"):
+        with pytest.raises(ValueError, match="threefry2x32"):
+            ServeEngine(model, params, num_slots=2)
+
+
+def _ref_sampled(model, params, prompt, max_new, sp, seed):
+    """generate()'s two halves — ``prefill``, then ``decode_step`` a token
+    at a time — with the engine's sampler between them and its key chain
+    begun at ``jax.random.PRNGKey(seed)``: one request served alone."""
+    from k8s_distributed_deeplearning_tpu.serve.engine import _sample_slots
+    regs = (jnp.float32([sp.temperature]), jnp.int32([sp.top_k]),
+            jnp.float32([sp.top_p]))
+    keys = jax.random.PRNGKey(seed)[None]
+    logits, cache = generate.prefill(model, params,
+                                     jnp.asarray(prompt)[None, :])
+    last, toks = logits[:, -1, :], []
+    for _ in range(max_new):
+        keys, tok = _sample_slots(last, *regs, keys)
+        toks.append(int(tok[0]))
+        last, cache = generate.decode_step(model, params, cache, tok)
+    return toks
+
+
+@pytest.mark.parametrize("seed", [123, 2**32 + 5, -1])
+def test_sampled_stream_is_the_one_begun_at_the_seeds_prng_key(tiny, seed):
+    """Temperature > 0 and a fixed seed: the served stream is token for
+    token the sampler's key chain begun at ``jax.random.PRNGKey(seed)``
+    over generate()'s logits — through a one-call admission and through
+    chunks behind another request's decodes alike."""
+    model, params, cfg = tiny
+    prompts, _ = _workload(cfg, 2, seed=11, p_lo=18, p_hi=24)
+    sp = SamplingParams(temperature=0.9, top_k=12, top_p=0.9)
+    want = _ref_sampled(model, params, prompts[0], 10, sp, seed)
+    assert want != _ref_sampled(model, params, prompts[0], 10, sp, seed + 1)
+    for kw in ({}, {"min_bucket": 8, "prefill_chunk_tokens": 8}):
+        eng = ServeEngine(model, params, num_slots=2, eos_id=None, **kw)
+        other = Request(prompt=prompts[1], max_new_tokens=16)
+        target = Request(prompt=prompts[0], max_new_tokens=10, sampling=sp,
+                         seed=seed)
+        outs = {o.request_id: o for o in eng.run([other, target])}
+        assert outs[target.request_id].tokens == want, kw
+
+
 def test_submit_validation_and_sampling_params(tiny):
     model, params, cfg = tiny
     eng = ServeEngine(model, params, num_slots=2)
@@ -489,11 +555,11 @@ def _children(spans, parent):
             and parent["t0"] <= s["t0"] and s["t1"] <= parent["t1"]]
 
 
-def _chunky(tiny, **kw):
+def _chunky(tiny, b_kw=None, **kw):
     """A two-slot engine with 8-token chunks and buckets, request A decoding
-    and a 20-token request B submitted: B takes two intermediate chunks, a
-    final one of 4 tokens, then its first token — one a step, each behind A's
-    decode. Returns (engine, take)."""
+    and a 20-token request B (further fields: *b_kw*) submitted: B takes two
+    intermediate chunks, a final one of 4 tokens, then its first token — one
+    a step, each behind A's decode. Returns (engine, take)."""
     _, _, cfg = tiny
     eng, take = _step_spans(tiny, min_bucket=8, prefill_chunk_tokens=8, **kw)
     eng.submit(Request(prompt=np.arange(5, dtype=np.int32) % cfg.vocab_size,
@@ -501,7 +567,8 @@ def _chunky(tiny, **kw):
     eng.step()
     eng.step()                          # a decode-only step: its fence returns
     eng.submit(Request(prompt=(np.arange(20, dtype=np.int32) * 3)
-                       % cfg.vocab_size, max_new_tokens=6, request_id="B"))
+                       % cfg.vocab_size, max_new_tokens=6, request_id="B",
+                       **(b_kw or {})))
     take()
     return eng, take
 
@@ -594,6 +661,40 @@ def test_a_final_chunk_step_makes_its_key_between_operands_and_call(tiny):
         assert [s["name"] for s in _children(spans, wait)] == [
             "fetch_tokens", "fetch_keys"]
     assert eng.occupied_slots() == 2
+
+
+def test_a_final_chunk_behind_a_decode_asks_the_device_for_no_key(
+        tiny, monkeypatch):
+    """A sampling request's final chunk, dispatched behind a running decode:
+    the step calls neither ``jax.random.PRNGKey`` nor ``jax.random.key`` (a
+    device program each, and a blocking read ahead of the chunk's call);
+    ``first_key`` still lies between ``chunk_operands`` and ``chunk_call``,
+    and the slot's chained key is what the seed's ``PRNGKey`` splits into."""
+    eng, take = _chunky(tiny, b_kw=dict(
+        sampling=SamplingParams(temperature=0.8, top_k=12), seed=2**32 + 5))
+    split = np.asarray(jax.random.split(jax.random.PRNGKey(2**32 + 5))[0])
+
+    def refuse(*a, **kw):
+        raise AssertionError("a key was asked of the device inside step()")
+    monkeypatch.setattr(jax.random, "PRNGKey", refuse)
+    monkeypatch.setattr(jax.random, "key", refuse)
+    eng.step()
+    eng.step()
+    take()
+    eng.step()                                          # B's final chunk
+    spans = take()
+    _assert_nested(spans)
+    inner = _children(spans, _named(spans, "prefill")[0])
+    assert [s["name"] for s in inner] == ["chunk_operands", "first_key",
+                                          "chunk_call"]
+    assert inner[2]["program"] == "final_chunk_8"
+    assert _named(spans, "decode_call")[0]["t0"] < inner[1]["t0"]
+    eng.step()                                          # B's first token
+    slot = next(i for i, fl in enumerate(eng._slots)
+                if fl is not None and fl.req.request_id == "B")
+    # the step's decode was dispatched before the activation: one split
+    assert eng._temps[slot] > 0 and eng.occupied_slots() == 2
+    np.testing.assert_array_equal(eng._keys[slot], split)
 
 
 @pytest.mark.parametrize("trie", [False, True])
