@@ -64,9 +64,9 @@ import jax.numpy as jnp
 import optax
 
 from k8s_distributed_deeplearning_tpu.models.transformer import (
-    MLP, LatentAttention, LatentAttentionConfig, LayerKind, LMHead, Mamba2,
-    Mamba2Config, ShortConv, Transformer, TransformerConfig, default_init,
-    lm_batch_views, lm_forward, param_dense)
+    MLP, LatentAttention, LatentAttentionConfig, LayerKind, LMHead, Mamba1,
+    Mamba1Config, Mamba2, Mamba2Config, ShortConv, Transformer,
+    TransformerConfig, default_init, lm_batch_views, lm_forward, param_dense)
 
 Dtype = Any
 
@@ -1003,6 +1003,23 @@ def hybrid_pattern(layers: str, moe: MoEConfig | None,
         raise ValueError(f"the pattern names {sorted(unknown)}; known: "
                          f"{sorted(kinds)}")
     return tuple(kinds[c] for c in layers)
+
+
+@functools.lru_cache(maxsize=None)
+def jamba_pattern(n_layers: int, attn_offset: int, attn_period: int,
+                  mamba: Mamba1Config) -> tuple[LayerKind, ...]:
+    """The layer pattern of the Mamba-1 + attention family (Jamba layout),
+    for :class:`~models.transformer.PatternLM`: layer ``i``'s mixer is
+    :class:`~models.transformer.Attention` where ``i % attn_period ==
+    attn_offset`` (the config's ``attn_layer_period`` /
+    ``attn_layer_offset``) and a :class:`~models.transformer.Mamba1` mixer
+    elsewhere; every layer is a mixer AND the dense feed-forward. One
+    :class:`LayerKind` object a kind and one tuple per argument set
+    (cached), as :func:`conv_moe_pattern`."""
+    ssm = LayerKind(attention=functools.partial(Mamba1, mamba=mamba))
+    attn = LayerKind()
+    return tuple(attn if i % attn_period == attn_offset else ssm
+                 for i in range(n_layers))
 
 
 def moe_config_of(model) -> MoEConfig | None:

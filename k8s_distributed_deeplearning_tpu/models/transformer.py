@@ -36,6 +36,7 @@ from k8s_distributed_deeplearning_tpu.ops import attention as attention_ops
 from k8s_distributed_deeplearning_tpu.ops import collectives
 from k8s_distributed_deeplearning_tpu.ops import pallas_latent_attn
 from k8s_distributed_deeplearning_tpu.ops import pallas_paged_attn
+from k8s_distributed_deeplearning_tpu.ops import pallas_selective_scan
 from k8s_distributed_deeplearning_tpu.ops import pallas_ssm
 from k8s_distributed_deeplearning_tpu.backend import on_tpu
 
@@ -1033,9 +1034,10 @@ class Mamba2Config:
                                       self.state_size, self.n_groups)
 
 
-def ssm_update_impl(mamba: Mamba2Config) -> str:
+def ssm_update_impl(mamba: Mamba2Config | Mamba1Config) -> str:
     """Which implementation a one-token state update resolves to:
-    ``"kernel"`` (:func:`ops.pallas_ssm.ssm_update`: in place over the live
+    ``"kernel"`` (:func:`ops.pallas_ssm.ssm_update`, for a Mamba-1 mixer
+    :func:`ops.pallas_selective_scan.selective_update`: in place over the live
     rows) or ``"xla"``. ``"auto"``: the kernel on a TPU for a float32 state."""
     if mamba.update_impl != "auto":
         return mamba.update_impl
@@ -1043,9 +1045,10 @@ def ssm_update_impl(mamba: Mamba2Config) -> str:
             else "xla")
 
 
-def mamba_config_of(model) -> Mamba2Config | None:
-    """The :class:`Mamba2Config` the :class:`Mamba2` factories of *model*'s
-    ``pattern`` were made with; None for a model with no such layer."""
+def mamba_config_of(model) -> Mamba2Config | Mamba1Config | None:
+    """The :class:`Mamba2Config` (or :class:`Mamba1Config`) the
+    :class:`Mamba2` (:class:`Mamba1`) factories of *model*'s ``pattern`` were
+    made with; None for a model with no such layer."""
     for kind in getattr(model, "pattern", None) or ():
         mamba = getattr(kind.attention, "keywords", {}).get("mamba")
         if mamba is not None:
@@ -1257,6 +1260,217 @@ class Mamba2(nn.Module):
         return nn.with_logical_constraint(out, ("batch", "seq", "act_embed"))
 
 
+@dataclasses.dataclass(frozen=True)
+class Mamba1Config:
+    """A Mamba-1 mixer's sizes (the Jamba family's config keys in brackets):
+    ``inner`` channels [mamba_expand x hidden_size], a state of
+    ``state_size`` columns a channel [mamba_d_state], a time step through a
+    rank-``dt_rank`` bottleneck [mamba_dt_rank], a causal depthwise
+    convolution of ``conv_kernel`` taps [mamba_d_conv] with a bias.
+    ``time_step_*`` are the published initialisation of ``dt_proj``'s bias.
+    ``state_dtype``: what the carried state is kept in. ``update_impl`` /
+    ``scan_impl``: the one-token update and the scan over a chunk —
+    ``"kernel"`` (:mod:`ops.pallas_selective_scan`), ``"xla"``, or ``"auto"``
+    (:func:`ssm_update_impl`, :func:`selective_scan_impl`)."""
+
+    inner: int = 5120
+    state_size: int = 16
+    dt_rank: int = 160
+    conv_kernel: int = 4
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    state_dtype: Dtype = jnp.float32
+    update_impl: str = "auto"
+    scan_impl: str = "auto"
+
+    @property
+    def state_rows_lanes(self) -> tuple[int, int]:
+        """One row's ``ssm_state``: the state columns on the sublanes, the
+        channels on the lanes."""
+        return (self.state_size, self.inner)
+
+
+def selective_scan_impl(mamba: Mamba1Config) -> str:
+    """Which implementation a Mamba-1 scan over several tokens resolves to:
+    ``"kernel"`` (:func:`ops.pallas_selective_scan.selective_scan`) or
+    ``"xla"`` (a ``lax.scan`` over tokens). ``"auto"``: the kernel on a TPU
+    for a float32 state."""
+    if mamba.scan_impl != "auto":
+        return mamba.scan_impl
+    return ("kernel" if on_tpu() and mamba.state_dtype == jnp.float32
+            else "xla")
+
+
+def ssm_impls(mamba: Mamba2Config | Mamba1Config) -> str:
+    """What a model's state-space mixers run as, for
+    ``ServeEngine.attention_impls``: the one-token update (``ssm=``) and,
+    for a Mamba-1 mixer, the scan over a chunk (``scan=``)."""
+    out = f"ssm={ssm_update_impl(mamba)}"
+    if isinstance(mamba, Mamba1Config):
+        out += f" scan={selective_scan_impl(mamba)}"
+    return out
+
+
+class LowRankStep(nn.Module):
+    """A Mamba-1 mixer's time step from its rank-``dt_rank`` bottleneck:
+    ``softplus(W_dt r + b_dt)`` in float32, ``W_dt`` drawn uniform within
+    ``dt_rank^-1/2`` and ``b_dt`` the inverse softplus of a step log-uniform
+    in [``time_step_min``, ``time_step_max``] (the published
+    initialisation)."""
+
+    mamba: Mamba1Config
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, r: jax.Array) -> jax.Array:
+        m, f32 = self.mamba, jnp.float32
+        bound = m.dt_rank ** -0.5
+
+        def bias_init(key, shape, dtype):
+            step = jnp.exp(jax.random.uniform(key, shape, f32)
+                           * (math.log(m.time_step_max) - math.log(m.time_step_min))
+                           + math.log(m.time_step_min))
+            step = jnp.maximum(step, m.time_step_floor)
+            return (step + jnp.log(-jnp.expm1(-step))).astype(dtype)
+        kernel = self.param("kernel", nn.with_logical_partitioning(
+            lambda key, shape, dtype: jax.random.uniform(
+                key, shape, dtype, -bound, bound), (None, "mlp")),
+            (m.dt_rank, m.inner), f32)
+        bias = self.param("bias", nn.with_logical_partitioning(
+            bias_init, ("mlp",)), (m.inner,), f32)
+        dt = jnp.einsum("...r,re->...e", r.astype(self.dtype),
+                        kernel.astype(self.dtype), preferred_element_type=f32)
+        return jax.nn.softplus(dt + bias.astype(f32))
+
+
+class Mamba1(nn.Module):
+    """Mamba-1 mixer — the Mamba layers of the Jamba family, a
+    :class:`LayerKind` ``attention`` factory beside :class:`Mamba2` (same
+    keywords; tables and positions mean nothing to it):
+
+        [u | z] = W_in x                   (no bias)
+        u = silu(conv(u))                  (depthwise, causal, WITH bias)
+        [r | B | C] = W_x u;   r, B, C = RMSNorm(r), RMSNorm(B), RMSNorm(C)
+        dt = softplus(W_dt r + b_dt) [E];   A = -exp(A_log) [E, N]
+        h_t = exp(dt_t A) h_(t-1) + dt_t B_t u_t;   y_t = h_t C_t + D u_t
+        out = W_out (y * silu(z))          (no output norm)
+
+    The decay differs for every (channel, state column): the scan over a
+    chunk is :func:`ops.pallas_selective_scan.selective_scan`, not
+    :func:`ssd_chunked`'s matrix products. Its memory is two cache leaves:
+    ``conv_state`` ``[B, conv_kernel - 1, inner]`` (the pre-convolution ``u``
+    tail, the model's type) and ``ssm_state`` ``[B, state_size, inner]``
+    (``state_dtype``: the channels on the lanes). ``generate()``'s row cache
+    makes both itself (zeros). Under ``block_tables`` they are the serving
+    engine's per-slot STATE ARENA, as for :class:`Mamba2`: a chunk call gets
+    one slot's rows (``lengths``: a pad moves no state), the decode call
+    every slot's, and ``ssm_state`` is advanced IN PLACE for the rows with a
+    cursor (``cache_positions > 0``) only — masked inside the update."""
+
+    cfg: TransformerConfig
+    mamba: Mamba1Config = Mamba1Config()
+
+    @nn.compact
+    def __call__(self, x: jax.Array, *,
+                 mask: jax.Array | None = None,
+                 positions: jax.Array | None = None,
+                 segment_ids: jax.Array | None = None,
+                 attention_fn: Callable | None = None,
+                 decode: bool = False,
+                 cache_positions: jax.Array | None = None,
+                 block_tables: jax.Array | None = None,
+                 lengths: jax.Array | None = None) -> jax.Array:
+        cfg, m = self.cfg, self.mamba
+        if mask is not None or segment_ids is not None or attention_fn is not None:
+            raise NotImplementedError(
+                "Mamba1 is causal over the whole row: mask, segment_ids and "
+                "attention_fn are not supported")
+        if cfg.tp_axis is not None:
+            raise NotImplementedError("Mamba1 has no tp_axis path")
+        b, sq, d = x.shape
+        e, n, r, tail = m.inner, m.state_size, m.dt_rank, m.conv_kernel - 1
+        f32 = jnp.float32
+        uz = param_dense(2 * e, ("embed", "mlp"), "in_proj", cfg.dtype)(x)
+        u, z = uz[..., :e], uz[..., e:]
+        tap = lambda key, shape, dtype: jax.random.uniform(
+            key, shape, dtype, -1.0, 1.0) / math.sqrt(m.conv_kernel)
+        w = self.param("conv", nn.with_logical_partitioning(tap, (None, "mlp")),
+                       (m.conv_kernel, e), f32)
+        w_bias = self.param("conv_bias", nn.with_logical_partitioning(
+            tap, ("mlp",)), (e,), f32)
+        a_log = self.param("A_log", nn.with_logical_partitioning(
+            lambda key, shape, dtype: jnp.broadcast_to(
+                jnp.log(jnp.arange(1, n + 1, dtype=dtype)), shape),
+            ("mlp", None)), (e, n), f32)
+        skip = self.param("D", nn.with_logical_partitioning(
+            nn.initializers.ones, ("mlp",)), (e,), f32)
+
+        conv_state = ssm_state = None
+        if decode:
+            def _arena_missing():
+                raise ValueError(
+                    "paged decode (block_tables) requires an engine-provided "
+                    "state arena (conv_state, ssm_state); it cannot be "
+                    "initialised from inside the model")
+            paged = block_tables is not None
+            conv_state = (
+                self.variable("cache", "conv_state", _arena_missing) if paged
+                else self.variable("cache", "conv_state", jnp.zeros,
+                                   (b, tail, e), cfg.dtype))
+            ssm_state = (
+                self.variable("cache", "ssm_state", _arena_missing) if paged
+                else self.variable("cache", "ssm_state", jnp.zeros,
+                                   (b,) + m.state_rows_lanes, m.state_dtype))
+            before = conv_state.value.astype(u.dtype)
+        else:
+            before = jnp.zeros((b, tail, e), u.dtype)
+        full = jnp.concatenate([before, u], axis=1)          # [B, tail + sq, E]
+        if conv_state is not None:
+            after = full[:, sq:] if lengths is None else jax.vmap(
+                lambda row, k: jax.lax.dynamic_slice_in_dim(row, k, tail, 0)
+            )(full, lengths.astype(jnp.int32))
+            conv_state.value = after.astype(conv_state.value.dtype)
+        ff = full.astype(f32)
+        u = nn.silu(sum(w[j] * ff[:, j:j + sq] for j in range(m.conv_kernel))
+                    + w_bias)                                # [B, sq, E] f32
+        rbc = param_dense(r + 2 * n, ("mlp", None), "x_proj", cfg.dtype)(
+            u.astype(cfg.dtype))
+        norm = lambda name: RMSNorm(eps=cfg.norm_eps, dtype=f32, axis=None, name=name)
+        step = LowRankStep(m, cfg.dtype, name="dt_proj")(norm("dt_norm")(rbc[..., :r]))
+        bmat = norm("b_norm")(rbc[..., r:r + n])             # [B, sq, N] f32
+        cmat = norm("c_norm")(rbc[..., r + n:])
+        a = -jnp.exp(a_log.astype(f32)).T                    # [N, E]
+
+        if ssm_state is not None and sq == 1:
+            # one token a row: the update itself, over the rows with a cursor
+            live = (jnp.ones((b,), bool) if cache_positions is None
+                    else cache_positions > 0)
+            update = (pallas_selective_scan.selective_update
+                      if ssm_update_impl(m) == "kernel"
+                      else pallas_selective_scan.selective_update_reference)
+            ssm_state.value, y = update(ssm_state.value, u[:, 0], step[:, 0], a,
+                                        bmat[:, 0], cmat[:, 0], live)
+            y = y[:, None]
+        else:
+            if lengths is not None:
+                # a pad moves no state: its step is 0
+                step = jnp.where(jnp.arange(sq)[None, :, None]
+                                 < lengths[:, None, None], step, 0.0)
+            h0 = (jnp.zeros((b, n, e), f32) if ssm_state is None
+                  else ssm_state.value.astype(f32))
+            scan = (pallas_selective_scan.selective_scan
+                    if selective_scan_impl(m) == "kernel"
+                    else pallas_selective_scan.selective_scan_reference)
+            y, h_end = scan(u, step, a, bmat, cmat, h0)
+            if ssm_state is not None:
+                ssm_state.value = h_end.astype(ssm_state.value.dtype)
+        y = (y + skip.astype(f32) * u) * nn.silu(z.astype(f32))
+        out = param_dense(d, ("mlp", "embed"), "out_proj", cfg.dtype)(
+            y.astype(cfg.dtype))
+        return nn.with_logical_constraint(out, ("batch", "seq", "act_embed"))
+
+
 class MLP(nn.Module):
     """Feed-forward: SwiGLU (Llama), GELU with biases (BERT/ViT) or squared
     ReLU without (``"relu2"``: ``W_2 relu(W_1 x)^2``, the Nemotron-H family).
@@ -1304,7 +1518,7 @@ class LayerKind:
     """What one layer of a stack is made of: two factories
     ``(cfg, name=...) -> module``. ``attention=None`` is :class:`Attention`
     (any other — :class:`LatentAttention`, :class:`ShortConv`,
-    :class:`Mamba2` — must take its keywords); ``mlp=None`` is the dense
+    :class:`Mamba2`, :class:`Mamba1` — must take its keywords); ``mlp=None`` is the dense
     :class:`MLP`, any other (e.g. the expert-parallel
     :class:`models.moe.MoEMLP`) must accept a ``decode`` keyword — the static
     mode flag rides to it so that it can switch to its dropless serving
@@ -1588,12 +1802,12 @@ def lm_forward(module: nn.Module, cfg: TransformerConfig,
 class PatternLM(nn.Module):
     """Decoder-only LM whose layers are what ``pattern`` says, one
     :class:`LayerKind` a layer: any mix of mixers (:class:`Attention`,
-    :class:`LatentAttention`, :class:`ShortConv`, :class:`Mamba2`) and
-    feed-forwards (the dense :class:`MLP`, :class:`models.moe.MoEMLP`), a layer
+    :class:`LatentAttention`, :class:`ShortConv`, :class:`Mamba2`,
+    :class:`Mamba1`) and feed-forwards (the dense :class:`MLP`, :class:`models.moe.MoEMLP`), a layer
     being both or — ``LayerKind.solo`` — one alone. Layers that differ are
     unrolled (``cfg.scan_layers=False``). The serving engine calls it like
     :class:`models.llama.LlamaLM`; ``lengths`` reaches the mixers that carry
-    state (:class:`ShortConv`, :class:`Mamba2`)."""
+    state (:class:`ShortConv`, :class:`Mamba2`, :class:`Mamba1`)."""
 
     cfg: TransformerConfig
     pattern: tuple[LayerKind, ...] | None = None

@@ -301,8 +301,8 @@ def _leaf_name(path) -> str | None:
 
 
 # Cache leaves that are not pages but STATE: one row a SLOT, read before a
-# call's tokens and written after them — a ShortConv's tail, a Mamba2's tail
-# and its state (models/transformer.py). They live in the same tree as the
+# call's tokens and written after them — a ShortConv's tail, a Mamba2's or a
+# Mamba1's tail and its state (models/transformer.py). They live in the same tree as the
 # page pool and are donated with it. A mixer that carries state names its
 # leaves here; the conventions it then meets:
 #
@@ -2491,7 +2491,9 @@ class ServeEngine:
         program's rows take (``experts=grouped`` / ``experts=dense``:
         :func:`models.moe.serving_dispatch`), and for one with state-space
         layers in what its one-token state update runs as (``ssm=kernel`` /
-        ``ssm=xla``: :func:`models.transformer.ssm_update_impl`)."""
+        ``ssm=xla``: :func:`models.transformer.ssm_update_impl`) and, for
+        Mamba-1 mixers, what their scan over a chunk runs as (``scan=kernel`` /
+        ``scan=xla``: :func:`models.transformer.selective_scan_impl`)."""
         slots = self.num_slots
         programs = {"decode": (1, slots)}        # name -> (sq, batch rows)
         if self.spec_k:
@@ -2548,8 +2550,7 @@ class ServeEngine:
             return ("" if moe is None else
                     f" experts={moe_lib.serving_dispatch(tokens, moe)}")
         mamba = transformer.mamba_config_of(self.model)
-        ssm = ("" if mamba is None
-               else f" ssm={transformer.ssm_update_impl(mamba)}")
+        ssm = "" if mamba is None else " " + transformer.ssm_impls(mamba)
         return {name: report(sq, rows) + experts(sq * rows) + ssm
                 for name, (sq, rows) in programs.items()}
 

@@ -91,6 +91,8 @@ def _run(case, **kw):
     (2, 16, 4, 4, 64, 8, 5, 3, (31, 24)),   # chunk, MHA, tail cell of 2 pages
     (3, 1, 4, 4, 32, 16, 3, 1, None),       # one page a cell, MHA
     (2, 5, 8, 2, 64, 4, 6, 6, (19, 5)),     # forced P = n_blocks, GQA 4:1
+    (3, 1, 20, 1, 64, 8, 6, None, None),    # multi-query, a group of 20: a
+    #                                slab of 20 query rows (jamba's decode)
 ])
 def test_kernel_matches_xla_reference(b, sq, h, hkv, pages, bt, nb, ppc,
                                       cursors):
@@ -125,8 +127,11 @@ def _attend_xla(q, pool_k, pool_v, tables, positions, k_scale=None,
 # attention (groups of 4: blocks of 128 queries), (32 q / 2 kv x 128)
 # nemotron's (groups of 16: blocks of 32), at tiny pools; the third geometry
 # is one local hd-64 KV head under tp (a pool narrower than a lane tile: one
-# page a cell), the fourth int8 pages with their scales gathered per block.
+# page a cell), the fourth int8 pages with their scales gathered per block;
+# (20 q / 1 kv x 128) is jamba's multi-query attention (a group of 20: blocks
+# of 16 queries, 320 rows).
 _LFM2, _NEMOTRON, _NARROW = (32, 8, 64), (32, 2, 128), (3, 1, 64)
+_JAMBA = (20, 1, 128)
 
 
 @pytest.mark.parametrize("heads,sq,start,quant", [
@@ -141,10 +146,12 @@ _LFM2, _NEMOTRON, _NARROW = (32, 8, 64), (32, 2, 128), (3, 1, 64)
     (_NEMOTRON, 256, 552, False),    # prefix + pads past the table
     (_NARROW, 256, 37, False),
     (_LFM2, 256, 37, True),
+    (_JAMBA, 512, 0, False),
+    (_JAMBA, 512, 37, False),
 ], ids=["lfm2-256", "lfm2-512", "lfm2-256-midpage", "lfm2-512-prefix",
         "lfm2-512-pads-past-table", "nemotron-256", "nemotron-512-midpage",
         "nemotron-256-pads-past-table", "narrow-256-midpage",
-        "lfm2-256-int8"])
+        "lfm2-256-int8", "jamba-512", "jamba-512-midpage"])
 def test_query_blocks_match_the_xla_gather_attend(heads, sq, start, quant):
     """A call wider than 128 tokens a row attends in blocks of queries — each
     block a grid row with its own cursors and last live block — and gives
@@ -169,7 +176,7 @@ def test_query_blocks_match_the_xla_gather_attend(heads, sq, start, quant):
     else:
         pool_k, pool_v = (jnp.asarray(pool(), jnp.float32) for _ in range(2))
     qb = pallas_paged_attn.default_query_block(sq, h // hkv)
-    assert qb == {4: 128, 16: 32, 3: 128}[h // hkv] and sq // qb >= 2
+    assert qb == {4: 128, 16: 32, 3: 128, 20: 16}[h // hkv] and sq // qb >= 2
     out = np.asarray(paged_decode_attention(
         q, pool_k, pool_v, tables, positions, interpret=True, **scales))
     ref = np.asarray(_attend_xla(q, pool_k, pool_v, tables, positions,

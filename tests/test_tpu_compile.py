@@ -60,6 +60,9 @@ LFM2 = (32, 8, 64)
 # nemotron-3-super's one attention layer in eleven: 32 q heads in groups of 16
 # on 2 hd-128 KV heads (a pool 256 lanes wide).
 NEMOTRON = (32, 2, 128)
+# ai21-jamba2-3b's two attention layers in 28: 20 q heads on ONE hd-128 KV
+# head (a group of 20: a decode call's slab is 20 query rows, no multiple of 8).
+JAMBA = (20, 1, 128)
 PAGE_TOKENS, N_BLOCKS, NUM_PAGES = 32, 128, 2561
 
 
@@ -76,10 +79,12 @@ PAGE_TOKENS, N_BLOCKS, NUM_PAGES = 32, 128, 2561
     (LFM2, 128, 1, False, None),
     (LFM2, 512, 1, False, None),        # a 512-token chunk: 4 query blocks
     (NEMOTRON, 512, 1, False, None),    # groups of 16: 16 blocks of 32
+    (JAMBA, 1, 128, False, None),       # a group of 20 at 128 rows
+    (JAMBA, 512, 1, False, None),       # groups of 20: 32 blocks of 16
 ], ids=["decode", "verify5", "chunk128", "decode-int8", "chunk128-int8",
         "decode-3pages", "narrow-decode", "narrow-chunk128-int8",
         "hd64-decode-128rows", "hd64-chunk128", "hd64-chunk512",
-        "group16-chunk512"])
+        "group16-chunk512", "group20-decode-128rows", "group20-chunk512"])
 def test_paged_kernel_compiles_for_v5e(one_chip, heads, sq, b, quant, pages):
     H, HKV, HD = heads
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -430,3 +435,112 @@ def test_the_ssm_moe_cells_decode_program_compiles_and_fits_one_chip(one_chip, m
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 12.4e9 < mem.argument_size_in_bytes < 12.8e9 and held < 14.5e9
     assert mem.alias_size_in_bytes > 3.2e9                      # arena and pool in place
+
+
+
+def test_the_selective_kernels_compile_for_v5e(one_chip):
+    """`selective_update` at the decode call's shape — 128 slots x 16 x 5,120
+    float32 — aliases the arena to its output; `selective_scan` at a 512-token
+    chunk's keeps its state in VMEM: neither holds a temporary of the
+    ``[L, N, E]`` size an associative scan would write."""
+    from k8s_distributed_deeplearning_tpu.ops import pallas_selective_scan as S
+    b, n, e, length = 128, 16, 5120, 512
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    up = jax.jit(lambda s, *a: S.selective_update(s, *a, interpret=False),
+                 donate_argnums=0).lower(
+        f32(b, n, e), f32(b, e), f32(b, e), f32(n, e), f32(b, n), f32(b, n),
+        jax.ShapeDtypeStruct((b,), jnp.bool_, sharding=one_chip)).compile()
+    assert _calls(up.as_text(), "selective_update") == 1
+    assert up.memory_analysis().alias_size_in_bytes == b * n * e * 4
+    assert up.memory_analysis().temp_size_in_bytes < 2 ** 20
+    scan = jax.jit(lambda *a: S.selective_scan(*a, interpret=False)).lower(
+        f32(1, length, e), f32(1, length, e), f32(n, e), f32(1, length, n),
+        f32(1, length, n), f32(1, n, e)).compile()
+    assert _calls(scan.as_text(), "selective_scan") == 1
+    assert scan.memory_analysis().temp_size_in_bytes < length * e * 4
+
+
+def _calls(text, kernel):
+    """Custom calls of Pallas kernel *kernel* in compiled module *text*
+    (the name alone would also match the kernels' source paths)."""
+    return len(re.findall(rf"%{kernel}(\.\d+)? = [^\n]*custom-call", text))
+
+
+def _jamba_cell(one_chip):
+    """(model, params, cache, engine options, sds) of
+    `jamba2-3b.chat-backlog-wide` as shapes placed on a described chip: 28
+    unrolled layers at the published widths, the page pool of the two
+    attention layers and the state arena of the 26 Mamba-1 layers."""
+    import flax.linen as nn
+
+    from benchmarks.harness import family_jamba as fam
+    from benchmarks.harness import manifest as M
+    from benchmarks.harness import reference_jamba as ref
+    from k8s_distributed_deeplearning_tpu.models.transformer import PatternLM
+    cell = M.Cell(M.load_manifest(), "jamba2-3b.chat-backlog-wide")
+    cfg, eng = cell.config, cell.options["engine"]
+    slots, pt, pages = eng["num_slots"], eng["page_tokens"], eng["kv_pool_pages"] + 1
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    model = PatternLM(*fam.program_config(cfg, eng["max_seq_len"]))
+    params = jax.tree.map(
+        lambda a: sds(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: nn.meta.unbox(model.init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])))
+    lanes = cfg["num_key_value_heads"] * cfg["head_dim"]
+    e, n, _, k = ref.mamba_sizes(cfg)
+    cache = {"transformer": {f"block_{i}": {"attn": (
+        {"cached_key": sds((pages, pt, lanes), jnp.bfloat16),
+         "cached_value": sds((pages, pt, lanes), jnp.bfloat16)}
+        if ref.is_attention(cfg, i) else
+        {"conv_state": sds((slots, k - 1, e), jnp.bfloat16),
+         "ssm_state": sds((slots, n, e), jnp.float32)})}
+        for i in range(cfg["num_hidden_layers"])}}
+    return model, params, cache, eng, sds
+
+
+def test_the_jamba_cells_decode_program_compiles_and_fits_one_chip(one_chip, monkeypatch):
+    """`jamba2-3b.chat-backlog-wide`'s decode program whole, from shapes alone:
+    128 slots through 28 unrolled layers — `selective_update` in the 26
+    Mamba-1 layers with the arena in place (no select over and no copy of a
+    layer's 42 MB of state), the paged kernel at 20 query heads a KV head in
+    the two attention layers — and what it holds (6.06 GB of weights, 1.19 GB
+    of state, 0.54 GB of pages) beside its temporaries inside one v5e."""
+    from k8s_distributed_deeplearning_tpu.models import transformer as T
+    from k8s_distributed_deeplearning_tpu.ops import pallas_selective_scan as S
+    from k8s_distributed_deeplearning_tpu.serve import engine as E
+    for mod in (pallas_paged_attn, S, T):
+        monkeypatch.setattr(mod, "on_tpu", lambda: True)         # compile, not interpret
+    model, params, cache, eng, sds = _jamba_cell(one_chip)
+    slots, pt = eng["num_slots"], eng["page_tokens"]
+    i32, f32 = (lambda *s: sds(s, jnp.int32)), (lambda *s: sds(s, jnp.float32))
+    compiled = E._decode_program.lower(
+        model, params, cache, i32(slots), i32(slots),
+        i32(slots, eng["max_seq_len"] // pt), f32(slots), i32(slots), f32(slots),
+        sds((slots, 2), jnp.uint32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _calls(text, "selective_update") == 26 and _calls(text, "paged_attn") == 2
+    assert _calls(text, "selective_scan") == 0
+    assert [l for l in text.splitlines()
+            if re.search(r"= f32\[128,16,5120\][^ ]* (select|copy)\(", l)] == []
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 7.7e9 < mem.argument_size_in_bytes < 7.9e9 and held < 9e9
+    assert mem.alias_size_in_bytes > 1.7e9                      # arena and pool in place
+
+
+def test_the_jamba_cells_chunk_program_scans_through_the_kernel(one_chip, monkeypatch):
+    """The same cell's intermediate CHUNK program, 512 tokens of one slot: 26
+    selective scans through the kernel — no ``[512, 16, 5120]`` float32
+    intermediates — and the two attention layers through the paged kernel in
+    query blocks of 16."""
+    from k8s_distributed_deeplearning_tpu.models import transformer as T
+    from k8s_distributed_deeplearning_tpu.ops import pallas_selective_scan as S
+    from k8s_distributed_deeplearning_tpu.serve import engine as E
+    for mod in (pallas_paged_attn, S, T):
+        monkeypatch.setattr(mod, "on_tpu", lambda: True)         # compile, not interpret
+    model, params, cache, eng, sds = _jamba_cell(one_chip)
+    c, blocks = eng["prefill_chunk_tokens"], eng["max_seq_len"] // eng["page_tokens"]
+    text = E._chunk_program.lower(
+        model, params, cache, sds((1, c), jnp.int32), sds((1, blocks), jnp.int32),
+        sds((), jnp.int32), sds((), jnp.int32)).compile().as_text()
+    assert _calls(text, "selective_scan") == 26 and _calls(text, "paged_attn") >= 1
+    assert "f32[1,512,16,5120]" not in text and "f32[512,16,5120]" not in text
