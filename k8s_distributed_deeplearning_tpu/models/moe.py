@@ -282,11 +282,21 @@ def _row_block(t: int, k: int, moe: "MoEConfig") -> int:
 
 
 def _experts_hidden(gmm, xs, w_gate, w_up):
-    """The grouped experts' hidden activation: gated (``silu(W_g x) * W_u x``)
-    or, with no gate matrix, squared ReLU."""
+    """The grouped experts' hidden activation for training: gated
+    (``silu(W_g x) * W_u x``) or, with no gate matrix, squared ReLU — each
+    product a differentiable ``gmm`` of its own, the activation XLA's."""
     if w_gate is None:
         return jnp.square(nn.relu(gmm(xs, w_up)))
     return nn.silu(gmm(xs, w_gate)) * gmm(xs, w_up)
+
+
+def _serving_hidden(moe: "MoEConfig", xs, w_gate, w_up, layout):
+    """The same activation for a serving program: ONE grouped product whose
+    epilogue applies it (``pallas_gmm.gmm_act``), so no ``[m_pad, F]``
+    product goes to HBM and back before the activation. Forward only."""
+    from k8s_distributed_deeplearning_tpu.ops import pallas_gmm
+    weights = (w_up,) if w_gate is None else (w_gate, w_up)
+    return pallas_gmm.gmm_act(xs, weights, layout, moe.expert_act)
 
 
 def _z_loss(logits: jax.Array) -> jax.Array:
@@ -791,7 +801,9 @@ class MoEMLP(nn.Module):
         from jax.ad_checkpoint import checkpoint_name
         gmm = lambda x, w: checkpoint_name(
             pallas_gmm.gmm(x, w, layout), "gmm_out")
-        ys = gmm(_experts_hidden(gmm, xs, w_gate, w_up), w_down)
+        hidden = (_serving_hidden(moe, xs, w_gate, w_up, layout) if decode
+                  else _experts_hidden(gmm, xs, w_gate, w_up))
+        ys = gmm(hidden, w_down)
         # Serving sums the k gated rows in f32, as the dense serving form
         # does (the two serving dispatches agree); training sums in the
         # model's type.
@@ -835,8 +847,8 @@ class MoEMLP(nn.Module):
             jnp.tile(jnp.arange(t, dtype=jnp.int32), k), mode="drop")
         xs = jnp.where((src >= 0)[:, None],
                        jnp.take(tok_c, jnp.maximum(src, 0), axis=0), 0)
-        gmm = lambda x, w: pallas_gmm.gmm_forward(x, w, layout)
-        ys = gmm(_experts_hidden(gmm, xs, w_gate, w_up), w_down)
+        ys = pallas_gmm.gmm_forward(
+            _serving_hidden(moe, xs, w_gate, w_up, layout), w_down, layout)
         gate = (gates.T.reshape(-1) * mine)[:, None]               # f32
         rows = jnp.take(ys, jnp.minimum(dest, layout.m_pad - 1), axis=0)
         y = jnp.sum((rows * gate).reshape(k, t, -1), axis=0).astype(cfg.dtype)

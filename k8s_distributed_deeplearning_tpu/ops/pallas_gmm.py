@@ -32,6 +32,23 @@ Design notes (TPU-first):
   ``[K, N]`` gradient across that expert's contiguous run of row blocks
   (out-block revisiting keeps the accumulator in VMEM; it spills to HBM
   once per expert per column sweep);
+- serving programs take forward-only products with no gradient rule:
+  ``gmm_forward`` (one weight) and ``gmm_act``, the experts' hidden
+  activation as the product's EPILOGUE — ``relu(x W_up)^2`` from one weight,
+  ``silu(x W_gate) * (x W_up)`` from two whose blocks are indexed by the same
+  prefetched expert, so a row block is read once for both; the activation is
+  computed on the f32 accumulators and rounded once, and a dead block's zeros
+  are its activation (act(0) = 0). No ``[M_pad, N]`` product goes to HBM and
+  back, and the compiler has no pair of outputs to place in VMEM;
+- their column block (:func:`wide_block`) is the widest multiple of 128
+  dividing N whose weight blocks — one or two, each double-buffered — fit
+  ``_WIDE_BLOCK_BYTES``: the whole width where it fits (one column step: each
+  row block and each expert's weights read once);
+- every forward kernel's name keeps ``moe_gmm`` (``moe_gmm``,
+  ``moe_gmm_act``): the benchmark's expert-product readers (e.g.
+  ``moe_gmm_roofline``) find the device time by that name, and count the work
+  from the serving counters whatever kernel does it — a kernel named
+  otherwise would drop its time from the share;
 - off-TPU the kernels run with ``interpret=True`` — CI exercises the
   exact code path TPUs compile (same convention as ``pallas_flash``).
 
@@ -147,56 +164,77 @@ def grouped_layout(group_sizes: jax.Array, total_rows: int,
 
 
 # ---------------------------------------------------------------------------
-# Forward kernel: out[m_block] = lhs[m_block] @ rhs[expert(m_block)]
+# Forward kernel: out[m_block] = lhs[m_block] @ rhs[expert(m_block)], or with
+# an activation as its epilogue, act(lhs[m_block] @ w[expert(m_block)] for
+# each weight w)
 # ---------------------------------------------------------------------------
 
+# The experts' hidden activations, by the weights each one multiplies:
+# "relu2" = relu(x W_up)^2, "swiglu" = silu(x W_gate) * (x W_up).
+ACT_WEIGHTS = {"relu2": 1, "swiglu": 2}
 
-def _gmm_kernel(expert_ref, live_ref, first_ref, lhs_ref, rhs_ref, out_ref):
+
+def _activation(act: str, *products):
+    if act == "relu2":
+        (up,) = products
+        return jnp.square(jnp.maximum(up, 0.0))
+    gate, up = products
+    return jax.nn.silu(gate) * up
+
+
+def _gmm_kernel(act, expert_ref, live_ref, first_ref, lhs_ref, *refs):
     del expert_ref, first_ref
+    *w_refs, out_ref = refs
     m = pl.program_id(1)
 
+    # Each product stays in f32 until the activation: one rounding, at the end.
     @pl.when(live_ref[m] == 1)
     def _compute():
-        out_ref[:] = jax.lax.dot_general(
-            lhs_ref[:], rhs_ref[0],
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(out_ref.dtype)
+        x = lhs_ref[:]
+        products = [jax.lax.dot_general(x, w_ref[0], (((1,), (0,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
+                    for w_ref in w_refs]
+        out = products[0] if act is None else _activation(act, *products)
+        out_ref[:] = out.astype(out_ref.dtype)
 
+    # act(0) = 0 for every activation: a dead block's zeros are its output.
     @pl.when(live_ref[m] == 0)
     def _dead():
         out_ref[:] = jnp.zeros_like(out_ref)
 
 
-def _gmm_call(lhs, rhs, layout: GroupedLayout, interpret: bool,
-              block_n: int | None = None):
+def _gmm_call(lhs, weights, layout: GroupedLayout, interpret: bool,
+              block_n: int | None = None, act: str | None = None):
     m_pad, k = lhs.shape
-    e, k2, n = rhs.shape
-    assert k == k2, (lhs.shape, rhs.shape)
+    e, k2, n = weights[0].shape
+    assert k == k2 and all(w.shape == weights[0].shape for w in weights), (
+        lhs.shape, [w.shape for w in weights])
     bm = layout.block_m
     bn = block_n or _pick_block(n, _BLOCK_N)
     tiles_m, tiles_n = m_pad // bm, n // bn
     grid = (tiles_n, tiles_m)   # row dim innermost: weight blocks revisit
+    # every weight's block is the same prefetched expert's: a row block is
+    # read once for all of them
+    w_spec = pl.BlockSpec((1, k, bn), lambda j, m, be, bl, bf: (be[m], 0, j))
 
     return pl.pallas_call(
-        _gmm_kernel,
+        functools.partial(_gmm_kernel, act),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=grid,
-            in_specs=[
-                pl.BlockSpec((bm, k), lambda j, m, be, bl, bf: (m, 0)),
-                pl.BlockSpec((1, k, bn),
-                             lambda j, m, be, bl, bf: (be[m], 0, j)),
-            ],
+            in_specs=[pl.BlockSpec((bm, k), lambda j, m, be, bl, bf: (m, 0))]
+            + [w_spec] * len(weights),
             out_specs=pl.BlockSpec((bm, bn),
                                    lambda j, m, be, bl, bf: (m, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((m_pad, n), lhs.dtype),
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
-        # the name the device trace carries for the kernel's events
-        name="moe_gmm",
-    )(layout.block_expert, layout.block_live, layout.block_first, lhs, rhs)
+        # the name the device trace carries for the kernel's events; both keep
+        # "moe_gmm", which the benchmark's expert-product readers match
+        name="moe_gmm" if act is None else "moe_gmm_act",
+    )(layout.block_expert, layout.block_live, layout.block_first, lhs,
+      *weights)
 
 
 # ---------------------------------------------------------------------------
@@ -273,7 +311,7 @@ def _gmm(lhs, rhs, row_offset, block_expert, block_live, block_first,
          meta, interpret):
     layout = GroupedLayout(row_offset, block_expert, block_live,
                            block_first, *meta)
-    return _gmm_call(lhs, rhs, layout, interpret)
+    return _gmm_call(lhs, (rhs,), layout, interpret)
 
 
 def _gmm_fwd(lhs, rhs, row_offset, block_expert, block_live, block_first,
@@ -292,7 +330,7 @@ def _gmm_bwd(meta, interpret, res, g):
     # dlhs: the same grouped product against the transposed weight view.
     # The explicit swapaxes materializes E·N·K·2 bytes once per backward —
     # measured noise next to the three grouped matmuls (round 5).
-    dlhs = _gmm_call(g, jnp.swapaxes(rhs, 1, 2), layout, interpret)
+    dlhs = _gmm_call(g, (jnp.swapaxes(rhs, 1, 2),), layout, interpret)
     drhs = _tgmm_call(lhs, g, rhs.shape[0], layout, interpret)
     def zero_ct(a):  # integer primals carry float0 cotangents
         return np.zeros(a.shape, jax.dtypes.float0)
@@ -316,24 +354,25 @@ def gmm(lhs: jax.Array, rhs: jax.Array, layout: GroupedLayout,
                 layout.block_live, layout.block_first, meta, interpret)
 
 
-# What one weight block of the forward-only product may take in VMEM (it is
-# double-buffered beside the row block and the output block).
-_WIDE_BLOCK_BYTES = 6 * 1024 * 1024
+# What the weight blocks of one step of a forward-only product may take in
+# VMEM, double-buffered, beside the row block, the output block and the f32
+# products (inside the 64 MiB limit of ``_compiler_params``).
+_WIDE_BLOCK_BYTES = 32 * 1024 * 1024
 
 
-def wide_block(n: int, k: int, itemsize: int = 2) -> int:
-    """The column block :func:`gmm_forward` takes: the widest multiple of 128
-    that divides ``n`` with a ``[k, block]`` weight block inside
-    ``_WIDE_BLOCK_BYTES`` — all of ``n`` where that fits, so that a row block
-    is read once and an expert's weights once a run of its blocks. The
-    power-of-two rule of :func:`_pick_block` gives 128 for n = 2,688 = 21 x
-    128: 21 column steps, each reading every row block again."""
-    best = _pick_block(n, _BLOCK_N)
+def wide_block(n: int, k: int, itemsize: int = 2, weights: int = 1) -> int:
+    """The column block of the forward-only products: the widest multiple of
+    128 that divides ``n`` with ``weights`` double-buffered ``[k, block]``
+    weight blocks inside ``_WIDE_BLOCK_BYTES`` — all of ``n`` where that fits,
+    so that a row block is read once and an expert's weights once a run of its
+    blocks. The power-of-two rule of :func:`_pick_block` gives 128 for
+    n = 2,688 = 21 x 128: 21 column steps, each reading every row block
+    again; it stays for an ``n`` that no multiple of 128 divides."""
     for blocks in range(n // 128, 0, -1):
         bn = blocks * 128
-        if n % bn == 0 and k * bn * itemsize <= _WIDE_BLOCK_BYTES:
-            return max(best, bn)
-    return best
+        if n % bn == 0 and 2 * weights * k * bn * itemsize <= _WIDE_BLOCK_BYTES:
+            return bn
+    return _pick_block(n, _BLOCK_N)
 
 
 def gmm_forward(lhs: jax.Array, rhs: jax.Array, layout: GroupedLayout,
@@ -342,8 +381,28 @@ def gmm_forward(lhs: jax.Array, rhs: jax.Array, layout: GroupedLayout,
     gradient rule), at :func:`wide_block` columns a step."""
     if interpret is None:
         interpret = not on_tpu()
-    return _gmm_call(lhs, rhs, layout, interpret,
+    return _gmm_call(lhs, (rhs,), layout, interpret,
                      wide_block(rhs.shape[2], rhs.shape[1], rhs.dtype.itemsize))
+
+
+def gmm_act(lhs: jax.Array, weights: tuple[jax.Array, ...],
+            layout: GroupedLayout, act: str,
+            interpret: bool | None = None) -> jax.Array:
+    """The experts' hidden activation in one grouped product, for a serving
+    program (no gradient rule): ``act`` of the rows of ``lhs`` [M_pad, K] times
+    their expert's weights — ``(w_up,)`` for ``"relu2"``, ``(w_gate, w_up)``
+    for ``"swiglu"``, each [E, K, N] — → [M_pad, N]. The products meet in
+    VMEM: no [M_pad, N] product goes to HBM and back before the activation.
+    The column block is :func:`wide_block`'s, counted for all the weights."""
+    if len(weights) != ACT_WEIGHTS[act]:
+        raise ValueError(f"{act!r} takes {ACT_WEIGHTS[act]} weights, "
+                         f"got {len(weights)}")
+    if interpret is None:
+        interpret = not on_tpu()
+    _, k, n = weights[0].shape
+    return _gmm_call(lhs, weights, layout, interpret,
+                     wide_block(n, k, weights[0].dtype.itemsize, len(weights)),
+                     act)
 
 
 def gmm_reference(lhs: jax.Array, rhs: jax.Array,

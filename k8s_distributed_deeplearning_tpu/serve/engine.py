@@ -2489,9 +2489,12 @@ class ServeEngine:
         (rows x query blocks x cells a row). For a
         model with expert layers each entry ends in the dispatch that
         program's rows take (``experts=grouped`` / ``experts=dense``:
-        :func:`models.moe.serving_dispatch`), and for one with state-space
-        layers in what its one-token state update runs as (``ssm=kernel`` /
-        ``ssm=xla``: :func:`models.transformer.ssm_update_impl`) and, for
+        :func:`models.moe.serving_dispatch`; a grouped program makes the
+        experts' hidden activation inside its grouped product,
+        ``pallas_gmm.gmm_act``, and says so: ``hidden=fused``), and for one
+        with state-space layers in what its one-token state update runs as
+        (``ssm=kernel`` / ``ssm=xla``:
+        :func:`models.transformer.ssm_update_impl`) and, for
         Mamba-1 mixers, what their scan over a chunk runs as (``scan=kernel`` /
         ``scan=xla``: :func:`models.transformer.selective_scan_impl`)."""
         slots = self.num_slots
@@ -2547,8 +2550,11 @@ class ServeEngine:
         moe = moe_lib.moe_config_of(self.model)
 
         def experts(tokens: int) -> str:
-            return ("" if moe is None else
-                    f" experts={moe_lib.serving_dispatch(tokens, moe)}")
+            if moe is None:
+                return ""
+            dispatch = moe_lib.serving_dispatch(tokens, moe)
+            return f" experts={dispatch}" + (
+                " hidden=fused" if dispatch == "grouped" else "")
         mamba = transformer.mamba_config_of(self.model)
         ssm = "" if mamba is None else " " + transformer.ssm_impls(mamba)
         return {name: report(sq, rows) + experts(sq * rows) + ssm

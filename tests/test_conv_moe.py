@@ -345,6 +345,12 @@ def test_attention_impls_names_each_programs_expert_dispatch(tiny):
         "final_chunk_32": "xla experts=dense", "final_chunk_64": "xla experts=dense"}
     assert moe.moe_config_of(model).num_experts == 8
     assert moe.moe_config_of(llama.LlamaLM(llama.config_tiny())) is None
+    # from 128 rows the grouped kernel, which makes the hidden activation itself
+    wide = _engine(model, params, num_slots=4, prefill_chunk_tokens=128, min_bucket=64)
+    assert wide.attention_impls() == {
+        "decode": "xla experts=dense", "chunk_128": "xla experts=grouped hidden=fused",
+        "final_chunk_64": "xla experts=dense",
+        "final_chunk_128": "xla experts=grouped hidden=fused"}
 
 
 def test_wide_chunks_through_the_kernel_serve_the_xla_paths_tokens():
@@ -376,7 +382,7 @@ def test_wide_chunks_through_the_kernel_serve_the_xla_paths_tokens():
 
     served, impls = run("paged_flash")
     assert impls["chunk_256"] == impls["final_chunk_256"] == (
-        "paged_flash q_block=128 pages_per_cell=1 cells=64 experts=grouped")
+        "paged_flash q_block=128 pages_per_cell=1 cells=64 experts=grouped hidden=fused")
     assert impls["decode"] == "paged_flash pages_per_cell=1 cells=64 experts=dense"
     assert served == run("xla")[0] and all(len(t) == 4 for t in served)
 

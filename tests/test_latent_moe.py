@@ -327,6 +327,19 @@ def test_grouped_and_dense_serving_dispatch_agree_for_a_share():
     assert counts.shape == (1, 4) and 0 < int(counts.sum()) < 96 * 3
 
 
+def test_attention_impls_names_the_grouped_programs_fused_hidden_activation(tiny):
+    """A program whose rows take the grouped kernel makes the experts' hidden
+    activation inside it and says so; a dense one (every decode here) does not."""
+    _, model, params = tiny
+    eng = ServeEngine(model, params, num_slots=4, min_bucket=64, prefill_chunk_tokens=128,
+                      prefix_block_tokens=8, kv_pool_pages=64)
+    assert eng.attention_impls() == {
+        "decode": "xla experts=dense", "chunk_128": "xla experts=grouped hidden=fused",
+        "final_chunk_64": "xla experts=dense",
+        "final_chunk_128": "xla experts=grouped hidden=fused"}
+    eng.shutdown()
+
+
 @pytest.mark.parametrize("rows, top_k, experts, dispatch, want", [
     (128, 2, 8, "ragged", "grouped"), (127, 2, 8, "ragged", "dense"),
     (1024, 8, 128, "ragged", "grouped"), (32, 8, 128, "ragged", "dense"),

@@ -607,6 +607,13 @@ def test_attention_impls_names_each_programs_expert_dispatch_and_state_update(ti
     assert set(impls) == {"decode", "chunk_64", "final_chunk_32", "final_chunk_64"}
     assert all(v.startswith("xla experts=") and v.endswith(" ssm=xla") for v in impls.values())
     assert impls["decode"] == "xla experts=dense ssm=xla"
+    # from 128 rows the grouped kernel, which makes the hidden activation itself
+    wide = _engine(model, params, num_slots=4, prefill_chunk_tokens=128, min_bucket=64)
+    assert wide.attention_impls() == {
+        "decode": "xla experts=dense ssm=xla",
+        "chunk_128": "xla experts=grouped hidden=fused ssm=xla",
+        "final_chunk_64": "xla experts=dense ssm=xla",
+        "final_chunk_128": "xla experts=grouped hidden=fused ssm=xla"}
     assert T.mamba_config_of(model).num_heads == 8
     assert T.mamba_config_of(llama.LlamaLM(llama.config_tiny())) is None
 

@@ -337,6 +337,20 @@ def test_the_conv_moe_cells_chunk_program_attends_through_the_kernel(one_chip, m
         sds((), jnp.int32), sds((), jnp.int32)).compile().as_text()
     assert text.count("paged_attn") >= 3 and "moe_gmm" in text
     assert "f32[32,512,4096]" not in text and "f32[1,32,512,4096]" not in text
+    # The expert layers' silu(gate) * up is made inside the grouped kernel, its
+    # two weight blocks double-buffered inside the VMEM limit (else Mosaic
+    # refuses the compile): no pass of its own over the 6,144 padded rows. 11 of
+    # 12 layers: the last one's experts feed only the head, which an
+    # intermediate chunk does not run.
+    assert _calls(text, "moe_gmm_act") == 11
+    assert _hidden_passes(text, 6144, 1792) == []
+
+
+def _hidden_passes(text, rows, width):
+    """Fusions of compiled module *text* whose result is a bf16 ``[rows,
+    width]`` array: the experts' hidden activation as an XLA pass of its own."""
+    return [l.strip()[:160] for l in text.splitlines()
+            if re.search(rf"= bf16\[{rows},{width}\][^ ]* fusion\(", l)]
 
 
 def _ssm_moe_cell(one_chip):
@@ -436,6 +450,26 @@ def test_the_ssm_moe_cells_decode_program_compiles_and_fits_one_chip(one_chip, m
     assert 12.4e9 < mem.argument_size_in_bytes < 12.8e9 and held < 14.5e9
     assert mem.alias_size_in_bytes > 3.2e9                      # arena and pool in place
 
+def test_the_ssm_moe_cells_chunk_program_makes_the_experts_activation_in_the_kernel(
+        one_chip, monkeypatch):
+    """The same cell's intermediate CHUNK program, 512 tokens of one slot: the
+    expert layers take the grouped products at 27,648 padded rows and the
+    squared ReLU is made inside the first of them — no
+    ``maximum_multiply_fusion`` over ``bf16[27648,2688]``. Four of the five:
+    the last layer's experts feed only the head, which such a chunk does not
+    run."""
+    from k8s_distributed_deeplearning_tpu.models import transformer as T
+    from k8s_distributed_deeplearning_tpu.ops import pallas_gmm, pallas_ssm
+    from k8s_distributed_deeplearning_tpu.serve import engine as E
+    for mod in (pallas_paged_attn, pallas_gmm, pallas_ssm, T):
+        monkeypatch.setattr(mod, "on_tpu", lambda: True)         # compile, not interpret
+    model, params, cache, eng, sds = _ssm_moe_cell(one_chip)
+    c, blocks = eng["prefill_chunk_tokens"], eng["max_seq_len"] // eng["page_tokens"]
+    text = E._chunk_program.lower(
+        model, params, cache, sds((1, c), jnp.int32), sds((1, blocks), jnp.int32),
+        sds((), jnp.int32), sds((), jnp.int32)).compile().as_text()
+    assert _calls(text, "moe_gmm_act") == 4 and _calls(text, "moe_gmm") == 4   # W_down
+    assert _hidden_passes(text, 27648, 2688) == []
 
 
 def test_the_selective_kernels_compile_for_v5e(one_chip):
