@@ -64,7 +64,7 @@ import jax.numpy as jnp
 import optax
 
 from k8s_distributed_deeplearning_tpu.models.transformer import (
-    MLP, LatentAttention, LatentAttentionConfig, LayerKind, LMHead, Mamba1,
+    MLP, Attention, LatentAttention, LatentAttentionConfig, LayerKind, LMHead, Mamba1,
     Mamba1Config, Mamba2, Mamba2Config, ShortConv, Transformer,
     TransformerConfig, default_init, lm_batch_views, lm_forward, param_dense)
 
@@ -1032,6 +1032,37 @@ def jamba_pattern(n_layers: int, attn_offset: int, attn_period: int,
     attn = LayerKind()
     return tuple(attn if i % attn_period == attn_offset else ssm
                  for i in range(n_layers))
+
+
+@functools.lru_cache(maxsize=None)
+def exaone_pattern(layer_types: tuple[str, ...], mlp_layer_types: tuple[str, ...],
+                   moe: MoEConfig, window: int) -> tuple[LayerKind, ...]:
+    """The layer pattern of the window-and-global attention + sparse-expert
+    family (EXAONE 4 / K-EXAONE layout), for
+    :class:`~models.transformer.PatternLM`: layer ``i``'s mixer is a
+    sliding-window :class:`~models.transformer.Attention` (``window`` keys,
+    RoPE) where ``layer_types[i]`` is ``"sliding_attention"`` and a global one
+    with NO positions where it is ``"full_attention"``; its feed-forward is
+    the dense SwiGLU MLP where ``mlp_layer_types[i]`` is ``"dense"`` and
+    :class:`MoEMLP` where it is ``"sparse"``; every block is post-norm
+    (``LayerKind.post_norm``). The layers run are as many as the lists name.
+    One :class:`LayerKind` object a kind and one tuple per argument set
+    (cached), as :func:`conv_moe_pattern`."""
+    if len(layer_types) != len(mlp_layer_types):
+        raise ValueError("layer_types and mlp_layer_types name different "
+                         "numbers of layers")
+    unknown = (set(layer_types) - {"sliding_attention", "full_attention"}
+               | set(mlp_layer_types) - {"dense", "sparse"})
+    if unknown:
+        raise ValueError(f"the pattern names {sorted(unknown)}; known: "
+                         "'sliding_attention', 'full_attention', 'dense', "
+                         "'sparse'")
+    mixers = {"sliding_attention": functools.partial(Attention, window=window),
+              "full_attention": functools.partial(Attention, rope=False)}
+    mlps = {"dense": None, "sparse": functools.partial(MoEMLP, moe=moe)}
+    kinds = {(t, f): LayerKind(attention=mixers[t], mlp=mlps[f], post_norm=True)
+             for t in mixers for f in mlps}
+    return tuple(kinds[t, f] for t, f in zip(layer_types, mlp_layer_types))
 
 
 def moe_config_of(model) -> MoEConfig | None:

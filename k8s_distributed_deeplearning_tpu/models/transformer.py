@@ -38,6 +38,7 @@ from k8s_distributed_deeplearning_tpu.ops import pallas_latent_attn
 from k8s_distributed_deeplearning_tpu.ops import pallas_paged_attn
 from k8s_distributed_deeplearning_tpu.ops import pallas_selective_scan
 from k8s_distributed_deeplearning_tpu.ops import pallas_ssm
+from k8s_distributed_deeplearning_tpu.ops import pallas_window_attn
 from k8s_distributed_deeplearning_tpu.backend import on_tpu
 
 Dtype = Any
@@ -357,6 +358,133 @@ def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
     return inv * ((1.0 - ramp) + ramp / factor)
 
 
+def _window_query_block(sq: int) -> int:
+    """Queries a block of a sliding-window chunk: the largest divisor of
+    *sq* up to 128, so that no block's scores reach past ``128 + window``
+    keys."""
+    return max(d for d in range(1, min(sq, 128) + 1) if sq % d == 0)
+
+
+def window_attention_impl(cfg: TransformerConfig, sq: int, window: int) -> str:
+    """What a sliding-window layer's call of ``sq`` tokens a row attends
+    through: ``"kernel"`` (:mod:`ops.pallas_window_attn`, ``window_attn``) or
+    ``"xla"`` (:func:`window_chunk_attention`'s ``jax.numpy`` blocks; decode
+    always: one token a row reads its ring, :func:`window_decode_attention`).
+    The kernel takes a chunk of whole windows: ``"auto"`` sends it those on a
+    TPU at lane-aligned widths (a window and a head of whole 128-lane tiles),
+    ``"paged_flash"`` forces it (interpret mode off the TPU), anything else
+    takes XLA. ``ServeEngine.attention_impls`` reports it per program."""
+    whole = sq > 1 and sq % window == 0
+    if cfg.attention_impl == "auto":
+        aligned = window % 128 == 0 and cfg.resolved_head_dim % 128 == 0
+        return "kernel" if whole and aligned and on_tpu() else "xla"
+    return "kernel" if whole and cfg.attention_impl == "paged_flash" else "xla"
+
+
+def window_chunk_attention(q, k, v, ring_k, ring_v, start, window: int,
+                           lengths=None, impl: str = "xla"):
+    """Sliding-window attention of a chunk over a ring.
+
+    q ``[B, L, H, hd]``, k and v ``[B, L, KV, hd]`` (rotated where the layer
+    takes positions), the chunk's tokens at positions ``start + [0, L)``
+    (``start`` ``[B]``); ``ring_k`` / ``ring_v`` ``[B, W, KV·hd]`` hold the
+    row's last ``W = window`` positions before ``start``, position ``p`` in
+    entry ``p % W`` (None: the chunk starts the sequence). Query ``i`` attends
+    keys ``i - W < j <= i`` at positions ``>= 0``. The chunk's keys follow the
+    ring's, in position order, and each block of ``Q`` queries
+    (:func:`_window_query_block`) reads the ``Q + W`` keys that hold its band:
+    no ``[L, W + L]`` score tensor is made. Returns ``(out [B, L, H, hd],
+    ring_k, ring_v)``, the rings as they stand after the chunk's last real
+    token (``lengths`` ``[B]``: real tokens of a right-padded chunk; None =
+    all ``L``). ``impl="kernel"``: the same attention through
+    :func:`ops.pallas_window_attn.window_attention` (a chunk of whole
+    windows)."""
+    b, sq, h, hd = q.shape
+    kv, w = k.shape[2], window
+    start = start.astype(jnp.int32)
+    if ring_k is None:
+        ring_k = ring_v = jnp.zeros((b, w, kv * hd), k.dtype)
+    # the ring in position order: entry i holds position start - W + i
+    order = (start[:, None] + jnp.arange(w, dtype=jnp.int32)) % w      # [B, W]
+    take = lambda ring: jnp.take_along_axis(ring, order[:, :, None], axis=1)
+    ext_k = jnp.concatenate([take(ring_k).reshape(b, w, kv, hd),
+                             k.astype(ring_k.dtype)], axis=1)        # [B, W+L, ..]
+    ext_v = jnp.concatenate([take(ring_v).reshape(b, w, kv, hd),
+                             v.astype(ring_v.dtype)], axis=1)
+    if impl == "kernel":
+        out = pallas_window_attn.window_attention(q, ext_k, ext_v, start, w)
+    else:
+        out = _window_blocks(q, ext_k, ext_v, start, w)
+    # the ring after token n - 1: positions start + n - W .. start + n - 1,
+    # extended indices n .. n + W - 1, position p back in entry p % W
+    n = (jnp.full((b,), sq, jnp.int32) if lengths is None
+         else lengths.astype(jnp.int32))
+    back = n[:, None] + (jnp.arange(w, dtype=jnp.int32)[None, :]
+                         - start[:, None] - n[:, None]) % w          # [B, W]
+    put = lambda ext: jnp.take_along_axis(
+        ext.reshape(b, w + sq, kv * hd), back[:, :, None], axis=1)
+    return out, put(ext_k), put(ext_v)
+
+
+def _window_blocks(q, ext_k, ext_v, start, w: int):
+    """The band in ``jax.numpy``: each block of ``Q`` queries
+    (:func:`_window_query_block`) against the ``Q + W`` extended keys that
+    hold it."""
+    b, sq, h, hd = q.shape
+    kv = ext_k.shape[2]
+    qb = _window_query_block(sq)
+    nb, g = sq // qb, h // kv
+    keys = (jnp.arange(nb)[:, None] * qb
+            + jnp.arange(qb + w)[None, :])                           # [nb, Q+W]
+    kb, vb = ext_k[:, keys], ext_v[:, keys]                          # [B,nb,Q+W,KV,hd]
+    qg = q.reshape(b, nb, qb, kv, g, hd)
+    scores = jnp.einsum("bnqkgd,bnskd->bnkgqs", qg, kb.astype(q.dtype),
+                        preferred_element_type=jnp.float32) * hd ** -0.5
+    # query a of block n is chunk offset t = n·Q + a, key s is extended index
+    # e = n·Q + s at position start - W + e: a < s <= a + W, and e >= W - start
+    a = jnp.arange(qb)[:, None]
+    s = jnp.arange(qb + w)[None, :]
+    band = (s > a) & (s <= a + w)                                    # [Q, Q+W]
+    real = keys[None] >= (w - start)[:, None, None]                  # [B, nb, Q+W]
+    ok = band[None, None] & real[:, :, None, :]                      # [B,nb,Q,Q+W]
+    scores = jnp.where(ok[:, :, None, None], scores, jnp.finfo(jnp.float32).min)
+    p = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bnkgqs,bnskd->bnqkgd", p, vb.astype(q.dtype))
+    return out.reshape(b, sq, h, hd)
+
+
+def window_decode_attention(q, k, v, ring_k, ring_v, pos):
+    """One token a row against the row's ring: q ``[B, 1, H, hd]``, k and v
+    ``[B, 1, KV, hd]`` of the token at position ``pos`` ``[B]``, the rings
+    ``[B, W, KV·hd]``. The rows with a cursor (``pos > 0``) write their token
+    into entry ``pos % W`` in place (a scatter; the others' index falls out of
+    range and is dropped, so an idle or mid-prefill slot's ring is left bit
+    for bit); each row attends its ring, entry ``j`` holding position ``pos -
+    ((pos - j) mod W)``, masked where that is below 0. Returns ``(out [B, 1,
+    H, hd], ring_k, ring_v)``."""
+    b, _, h, hd = q.shape
+    kv, w = k.shape[2], ring_k.shape[1]
+    pos = pos.astype(jnp.int32)
+    entry = jnp.where(pos > 0, pos % w, w)
+    rows = jnp.arange(b)
+    ring_k = ring_k.at[rows, entry].set(
+        k.reshape(b, kv * hd).astype(ring_k.dtype), mode="drop")
+    ring_v = ring_v.at[rows, entry].set(
+        v.reshape(b, kv * hd).astype(ring_v.dtype), mode="drop")
+    g = h // kv
+    qg = q.reshape(b, kv, g, hd)
+    scores = jnp.einsum("bkgd,bskd->bkgs", qg,
+                        ring_k.reshape(b, w, kv, hd).astype(q.dtype),
+                        preferred_element_type=jnp.float32) * hd ** -0.5
+    j = jnp.arange(w, dtype=jnp.int32)[None, :]
+    ok = (pos[:, None] - (pos[:, None] - j) % w) >= 0                 # [B, W]
+    scores = jnp.where(ok[:, None, None], scores, jnp.finfo(jnp.float32).min)
+    p = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bkgs,bskd->bkgd", p,
+                     ring_v.reshape(b, w, kv, hd).astype(q.dtype))
+    return out.reshape(b, 1, h, hd), ring_k, ring_v
+
+
 _SLOT_NEEDS_TABLES = ("cache_positions (slot decode) requires block_tables: "
                       "per-row cursors exist only over the paged pool")
 
@@ -404,9 +532,19 @@ class Attention(nn.Module):
     a slot's cursor are never read, so a freed slot can be re-filled by a
     new request's prefill without clearing the stale K/V the previous
     occupant left behind. Per-slot lengths are the caller's registers.
+
+    ``window`` makes a SLIDING-WINDOW layer: query ``i`` attends keys ``i -
+    window < j <= i`` (``window`` keys, itself included). Its cache is not
+    pages but a RING a row, ``window_key`` / ``window_value`` ``[B, window,
+    kv·hd]`` (keys after norm and RoPE): position ``p`` lives in entry ``p %
+    window`` (:func:`window_chunk_attention`, :func:`window_decode_attention`).
+    ``rope`` overrides ``cfg.position`` for this layer (a hybrid whose global
+    layers take no positions). The defaults leave the layer as it was.
     """
 
     cfg: TransformerConfig
+    window: int | None = None
+    rope: bool | None = None
 
     @nn.compact
     def __call__(self, x: jax.Array, *,
@@ -443,6 +581,16 @@ class Attention(nn.Module):
                         name="q_norm")(q)
             k = RMSNorm(eps=cfg.norm_eps, dtype=cfg.dtype, axis=None,
                         name="k_norm")(k)
+        if self.window is not None:
+            if (mask is not None or segment_ids is not None
+                    or attention_fn is not None or cfg.tp_axis is not None):
+                raise NotImplementedError(
+                    "a sliding-window layer takes no mask, segment_ids, "
+                    "attention_fn or tp_axis")
+            return self._project_out(self._sliding(
+                q, k, v, positions=positions, decode=decode,
+                cache_positions=cache_positions, block_tables=block_tables,
+                lengths=lengths))
         cur = None
         if cache_positions is not None and not decode:
             raise ValueError("cache_positions requires decode=True")
@@ -540,10 +688,7 @@ class Attention(nn.Module):
                     # per-row positions.)
                     positions = (cur + jnp.arange(sq))[None, :]
 
-        if cfg.position == "rope":
-            cos, sin = rope_frequencies(hd, cfg.max_seq_len, cfg.rope_theta)
-            q = apply_rope(q, cos, sin, positions)
-            k = apply_rope(k, cos, sin, positions)
+        q, k = self._rotate(q, k, positions)
 
         if decode and block_tables is not None:
             # Paged write + gather-attend. Each token of the chunk lands at
@@ -694,6 +839,84 @@ class Attention(nn.Module):
             # exactly this tensor so the backward pass skips re-running the
             # attention forward (a no-op under other policies).
             out = checkpoint_name(out, "attn_out")
+        return self._project_out(out)
+
+    def _rotate(self, q, k, positions):
+        """RoPE on q and k where this layer takes positions (``rope``, else
+        ``cfg.position``)."""
+        cfg = self.cfg
+        if not (cfg.position == "rope" if self.rope is None else self.rope):
+            return q, k
+        cos, sin = rope_frequencies(cfg.resolved_head_dim, cfg.max_seq_len,
+                                    cfg.rope_theta)
+        return (apply_rope(q, cos, sin, positions),
+                apply_rope(k, cos, sin, positions))
+
+    def _sliding(self, q, k, v, *, positions, decode, cache_positions,
+                 block_tables, lengths):
+        """A sliding-window layer's attention over its ring: ``[B, sq, H,
+        hd]``. Three cache modes, as the full layer's: none (a whole sequence
+        from position 0), a row cache with a shared scalar cursor
+        (``generate()``; ``cache_index``) and slot serving over the paged
+        pool (``block_tables``: the ring is then the engine's state arena,
+        one row a slot — a chunk call hands in ONE slot's row, the decode
+        call every slot's with ``cache_positions``)."""
+        cfg, w = self.cfg, self.window
+        b, sq = q.shape[0], q.shape[1]
+        lanes = cfg.resolved_kv_heads * cfg.resolved_head_dim
+        if not decode:
+            if cache_positions is not None:
+                raise ValueError("cache_positions requires decode=True")
+            if positions is not None:
+                raise NotImplementedError(
+                    "a sliding-window layer runs a whole sequence from "
+                    "position 0: no packed positions")
+            start = jnp.zeros((b,), jnp.int32)
+            positions = start[:, None] + jnp.arange(sq, dtype=jnp.int32)
+            q, k = self._rotate(q, k, positions)
+            return window_chunk_attention(
+                q, k, v, None, None, start, w,
+                impl=window_attention_impl(cfg, sq, w))[0]
+
+        def _arena_missing():
+            raise ValueError(
+                "paged decode (block_tables) requires an engine-provided "
+                "ring arena (window_key, window_value); it cannot be "
+                "initialised from inside the model")
+        if block_tables is not None:
+            ring_k = self.variable("cache", "window_key", _arena_missing)
+            ring_v = self.variable("cache", "window_value", _arena_missing)
+        else:
+            ring_k = self.variable("cache", "window_key", jnp.zeros,
+                                   (b, w, lanes), cfg.dtype)
+            ring_v = self.variable("cache", "window_value", jnp.zeros,
+                                   (b, w, lanes), cfg.dtype)
+            index = self.variable("cache", "cache_index",
+                                  lambda: jnp.zeros((), jnp.int32))
+            cur = index.value
+            index.value = cur + sq
+            positions = (cur + jnp.arange(sq, dtype=jnp.int32))[None, :]
+        if cache_positions is not None:
+            if sq != 1:
+                raise NotImplementedError(
+                    "a sliding-window layer decodes one token a row: a "
+                    "verify window would overwrite ring entries a rejected "
+                    "draft still needs")
+            pos = cache_positions.astype(jnp.int32)
+            q, k = self._rotate(q, k, pos[:, None])
+            out, ring_k.value, ring_v.value = window_decode_attention(
+                q, k, v, ring_k.value, ring_v.value, pos)
+            return out
+        positions = jnp.broadcast_to(positions.astype(jnp.int32), (b, sq))
+        q, k = self._rotate(q, k, positions)
+        out, ring_k.value, ring_v.value = window_chunk_attention(
+            q, k, v, ring_k.value, ring_v.value, positions[:, 0], w, lengths,
+            impl=window_attention_impl(cfg, sq, w))
+        return out
+
+    def _project_out(self, out):
+        """The output projection ``W_o`` (and, under serving TP, its psum)."""
+        cfg = self.cfg
         out = nn.with_logical_constraint(out, ("batch", "seq", "heads", "head_dim"))
         out = nn.DenseGeneral(cfg.dim, axis=(-2, -1), use_bias=False,
                               dtype=cfg.dtype, param_dtype=jnp.float32,
@@ -1531,17 +1754,24 @@ class LayerKind:
     # the block (attn_norm, attn, its residual), "mlp" the second (mlp_norm,
     # mlp, its residual). None: both.
     solo: str | None = None
+    # Post-norm (the EXAONE 4 family): no norm on a sub-layer's input, an
+    # RMSNorm on its OUTPUT before the residual add (``attn_post_norm``,
+    # ``mlp_post_norm``). False: pre-norm.
+    post_norm: bool = False
 
     def __post_init__(self):
         if self.solo not in (None, "mixer", "mlp"):
             raise ValueError(f"solo must be None, 'mixer' or 'mlp', "
                              f"got {self.solo!r}")
+        if self.post_norm and self.solo is not None:
+            raise ValueError("a post-norm layer is a mixer and a feed-forward")
 
 
 class Block(nn.Module):
     """Pre-norm transformer block: x + attn(norm(x)); x + mlp(norm(x)) — or,
     where ``kind.solo`` says so, one of the two alone (one norm, one
-    residual).
+    residual), or where ``kind.post_norm`` says so, x + norm(attn(x)); x +
+    norm(mlp(x)).
 
     ``kind`` swaps the attention and the feed-forward module while keeping
     the block's norm/residual/dropout structure — and therefore scan/remat —
@@ -1578,7 +1808,8 @@ class Block(nn.Module):
                               cache_positions=cache_positions,
                               block_tables=block_tables,
                               **({} if lengths is None else {"lengths": lengths}))
-        h = make_norm(cfg, "attn_norm")(x)
+        post = kind.post_norm
+        h = x if post else make_norm(cfg, "attn_norm")(x)
         attn = (kind.attention or Attention)(cfg, name="attn")
         # lengths rides only where a caller gives it (a stack with a mixer
         # that carries state): the other mixers' signatures stay theirs.
@@ -1587,10 +1818,12 @@ class Block(nn.Module):
                  decode=decode, cache_positions=cache_positions,
                  block_tables=block_tables,
                  **({} if lengths is None else {"lengths": lengths}))
+        if post:
+            h = make_norm(cfg, "attn_post_norm")(h)
         if cfg.dropout_rate:
             h = nn.Dropout(cfg.dropout_rate, deterministic=deterministic)(h)
         x = x + h
-        h = make_norm(cfg, "mlp_norm")(x)
+        h = x if post else make_norm(cfg, "mlp_norm")(x)
         if kind.mlp is not None:
             if cfg.tp_axis is not None:
                 # Factory MLPs (MoE) don't know about the serving-TP psum
@@ -1602,6 +1835,8 @@ class Block(nn.Module):
             h = kind.mlp(cfg, name="mlp")(h, decode=decode)
         else:
             h = MLP(cfg, name="mlp")(h)
+        if post:
+            h = make_norm(cfg, "mlp_post_norm")(h)
         if cfg.dropout_rate:
             h = nn.Dropout(cfg.dropout_rate, deterministic=deterministic)(h)
         x = x + h

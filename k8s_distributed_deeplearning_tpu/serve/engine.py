@@ -319,8 +319,20 @@ def _leaf_name(path) -> str | None:
 #   the select (a third pass over it, and a second arena alive): its mixer
 #   masks the update itself, in place, and names the leaf under
 #   ``_MASKED_IN_UPDATE`` so that the engine leaves it alone.
-_STATE_LEAVES = ("conv_state", "ssm_state")
-_MASKED_IN_UPDATE = ("ssm_state",)
+#
+# A sliding-window attention layer's K/V are such state too: a RING a slot,
+# ``window_key`` / ``window_value`` ``[slots, window, kv·hd]`` (keys after
+# norm and RoPE), position ``p`` in entry ``p % window``; the page pool holds
+# the global layers' K/V alone, so a page costs (and ``_need_pages`` reserves)
+# what those layers cache. A chunk reads its slot's ring (zeros at ``start ==
+# 0``: entries at negative positions are masked, so the last occupant's are
+# never read) and leaves the ring as it stands after its last REAL token
+# (``lengths``); the decode program scatters each live row's token into entry
+# ``pos % window`` in place and drops the others' writes — masked in the
+# update, so no select over the rings and no copy of them.
+_STATE_LEAVES = ("conv_state", "ssm_state", "window_key", "window_value")
+_MASKED_IN_UPDATE = ("ssm_state", "window_key", "window_value")
+_RING_LEAVES = ("window_key", "window_value")
 
 
 def _is_state(path) -> bool:
@@ -1026,6 +1038,13 @@ class ServeEngine:
             for _, leaf in self._state_rows)
         self._state_names = sorted(
             {_leaf_name(path) for path, _ in self._state_rows})
+        # Sliding-window layers' rings (:data:`_RING_LEAVES`): the window
+        # they hold, and their bytes over every slot.
+        rings = [leaf for path, leaf in self._state_rows
+                 if _leaf_name(path) in _RING_LEAVES]
+        self._window = rings[0].shape[-2] if rings else None
+        self.stats.record_window_ring(num_slots * sum(
+            int(np.prod(leaf.shape)) * leaf.dtype.itemsize for leaf in rings))
         if self._state_rows:
             # Every path that knows pages only is refused by name until it
             # carries the state: what follows a mapped prefix's, a rejected
@@ -1901,7 +1920,8 @@ class ServeEngine:
                 "decode", active=active, rows=active,
                 context_tokens=int(self._kv_lens.sum()) + active,
                 sampled_rows=self._count_sampled_rows(),
-                **self._state_update_fields()) as span:
+                **self._state_update_fields(),
+                **self._window_decode_fields()) as span:
             with self.tracer.span("decode_call",
                                   in_flight=self._in_flight()):
                 nxt, keys, self._cache = self._decode_step()
@@ -2161,6 +2181,28 @@ class ServeEngine:
         self.stats.record_state_update(rows)
         return {"state_rows": rows,
                 "state_bytes_moved": 2 * rows * self._slot_state_nbytes}
+
+    def _window_decode_fields(self) -> dict:
+        """The ``window_tokens`` field of a ``decode`` span, for a model with
+        sliding-window layers: the ring entries the step's live rows attend,
+        ``min(cursor + 1, window)`` a row."""
+        if self._window is None:
+            return {}
+        live = self._kv_lens[self._kv_lens > 0]
+        return {"window_tokens": int(np.minimum(live + 1, self._window).sum())}
+
+    def _window_chunk_fields(self, start: int, tokens: int) -> dict:
+        """The ``window_pairs`` field of a chunk's ``prefill`` span and
+        ``prefill_counts`` record, for a model with sliding-window layers: the
+        (query, key) pairs of the chunk's real tokens inside the band, a layer
+        — ``min(p + 1, window)`` for the token at position ``p``."""
+        if self._window is None:
+            return {}
+        w = self._window
+
+        def below(m: int) -> int:       # pairs of positions [0, m)
+            return m * (m + 1) // 2 if m <= w else w * (w + 1) // 2 + (m - w) * w
+        return {"window_pairs": below(start + tokens) - below(start)}
 
     def _state_slot(self, slot: int):
         """The chunk programs' ``slot`` operand: the arena row of *slot* for
@@ -2496,7 +2538,10 @@ class ServeEngine:
         (``ssm=kernel`` / ``ssm=xla``:
         :func:`models.transformer.ssm_update_impl`) and, for
         Mamba-1 mixers, what their scan over a chunk runs as (``scan=kernel`` /
-        ``scan=xla``: :func:`models.transformer.selective_scan_impl`)."""
+        ``scan=xla``: :func:`models.transformer.selective_scan_impl`), and for
+        one with sliding-window layers what their attention runs as
+        (``window=kernel`` / ``window=ring-xla``:
+        :func:`models.transformer.window_attention_impl`)."""
         slots = self.num_slots
         programs = {"decode": (1, slots)}        # name -> (sq, batch rows)
         if self.spec_k:
@@ -2557,7 +2602,13 @@ class ServeEngine:
                 " hidden=fused" if dispatch == "grouped" else "")
         mamba = transformer.mamba_config_of(self.model)
         ssm = "" if mamba is None else " " + transformer.ssm_impls(mamba)
-        return {name: report(sq, rows) + experts(sq * rows) + ssm
+
+        def window(sq: int) -> str:
+            if self._window is None:
+                return ""
+            impl = transformer.window_attention_impl(cfg, sq, self._window)
+            return " window=" + ("kernel" if impl == "kernel" else "ring-xla")
+        return {name: report(sq, rows) + experts(sq * rows) + ssm + window(sq)
                 for name, (sq, rows) in programs.items()}
 
     def _fits(self, req: Request) -> bool:
@@ -2659,7 +2710,8 @@ class ServeEngine:
                     fields = dict(chunk=c, tokens=c, start=pend.pos,
                                   request_id=pend.req.request_id,
                                   attn=self._prefill_attn[program],
-                                  **self._state_from(pend))
+                                  **self._state_from(pend),
+                                  **self._window_chunk_fields(pend.pos, c))
                     with self.tracer.span("prefill", slot=slot, **fields):
                         with self.tracer.span("chunk_operands"):
                             operands = (
@@ -2732,7 +2784,8 @@ class ServeEngine:
         fields = dict(bucket=bucket, tokens=rem, start=pend.pos,
                       request_id=req.request_id,
                       attn=self._prefill_attn[program],
-                      **self._state_from(pend))
+                      **self._state_from(pend),
+                      **self._window_chunk_fields(pend.pos, rem))
         with self.tracer.span("prefill", slot=slot, cached=pend.hit_tokens,
                               **fields):
             with self.tracer.span("chunk_operands"):

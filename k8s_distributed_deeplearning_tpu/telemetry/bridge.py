@@ -231,6 +231,10 @@ def serving_collector(registry: MetricsRegistry,
             "serve_state_update_rows",
             "rows of the state arena the last decode step advanced (the "
             "slots with a cursor; the others' rows are not touched)"),
+        "serve_window_ring_bytes": registry.gauge(
+            "serve_window_ring_bytes",
+            "bytes of the sliding-window layers' K/V rings over every slot "
+            "(part of the state arena; 0 for a model with no such layer)"),
         "serve_fence_covered_share": registry.gauge(
             "serve_fence_covered_share",
             "share of the engine's blocking reads of device results made "
@@ -315,6 +319,7 @@ def serving_collector(registry: MetricsRegistry,
                "state_slots": "serve_state_slots",
                "state_bytes": "serve_state_bytes",
                "state_update_rows": "serve_state_update_rows",
+               "window_ring_bytes": "serve_window_ring_bytes",
                "fence_covered_share": "serve_fence_covered_share",
                "sampler_sort_share": "serve_sampler_sort_share",
                "kv_quant_bytes_saved": "serve_kv_quant_bytes_saved",

@@ -204,6 +204,9 @@ class ServingStats:
         self.state_slots = 0
         self.state_bytes = 0
         self.state_update_rows = 0
+        # The sliding-window layers' rings over every slot (bytes; part of
+        # the state arena, allocated once). 0 for a model with none.
+        self.window_ring_bytes = 0
         # The engine's blocking reads of device results (its ``device_wait``
         # spans), and those of them made with a later program already
         # dispatched behind the awaited one: the device had work queued
@@ -419,6 +422,11 @@ class ServingStats:
         self.state_update_rows = int(rows)
 
     @_locked
+    def record_window_ring(self, nbytes: int) -> None:
+        """The rings' bytes, once at construction. A gauge."""
+        self.window_ring_bytes = int(nbytes)
+
+    @_locked
     def record_fence(self, covered: int) -> None:
         self.fences += 1
         self.fences_covered += int(covered)
@@ -504,6 +512,7 @@ class ServingStats:
             "state_slots": self.state_slots,
             "state_bytes": self.state_bytes,
             "state_update_rows": self.state_update_rows,
+            "window_ring_bytes": self.window_ring_bytes,
             "fences": self.fences,
             "fences_covered": self.fences_covered,
             # Share of the blocking reads that had a program queued behind

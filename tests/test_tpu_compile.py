@@ -578,3 +578,87 @@ def test_the_jamba_cells_chunk_program_scans_through_the_kernel(one_chip, monkey
         sds((), jnp.int32), sds((), jnp.int32)).compile().as_text()
     assert _calls(text, "selective_scan") == 26 and _calls(text, "paged_attn") >= 1
     assert "f32[1,512,16,5120]" not in text and "f32[512,16,5120]" not in text
+
+
+def _window_moe_cell(one_chip):
+    """(model, params, cache, engine options, sds) of
+    `k-exaone-ep8-d5.docs-backlog-wide` as shapes placed on a described chip:
+    5 unrolled layers at the published widths (4 sliding, 1 global), the page
+    pool of the global layer and the rings of the four window layers."""
+    import flax.linen as nn
+
+    from benchmarks.harness import family_window_moe as fam
+    from benchmarks.harness import manifest as M
+    from benchmarks.harness import reference_window_moe as ref
+    from k8s_distributed_deeplearning_tpu.models.transformer import PatternLM
+    cell = M.Cell(M.load_manifest(), "k-exaone-ep8-d5.docs-backlog-wide")
+    cfg, eng = cell.config, cell.options["engine"]
+    slots, pt, pages = eng["num_slots"], eng["page_tokens"], eng["kv_pool_pages"] + 1
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    model = PatternLM(*fam.program_config(cfg, eng["max_seq_len"]))
+    params = jax.tree.map(
+        lambda a: sds(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: nn.meta.unbox(model.init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])))
+    lanes = cfg["num_key_value_heads"] * cfg["head_dim"]
+    w = cfg["sliding_window"]
+    cache = {"transformer": {f"block_{i}": {"attn": (
+        {"window_key": sds((slots, w, lanes), jnp.bfloat16),
+         "window_value": sds((slots, w, lanes), jnp.bfloat16)}
+        if mixer == "sliding_attention" else
+        {"cached_key": sds((pages, pt, lanes), jnp.bfloat16),
+         "cached_value": sds((pages, pt, lanes), jnp.bfloat16)})}
+        for i, (mixer, _) in enumerate(ref.layer_kinds(cfg))}}
+    return model, params, cache, eng, sds
+
+
+def test_the_window_moe_cells_decode_program_compiles_and_fits_one_chip(one_chip, monkeypatch):
+    """`k-exaone-ep8-d5.docs-backlog-wide`'s decode program whole, from shapes
+    alone: 64 slots through 5 unrolled layers — each window layer's token
+    scattered into its ring in place (no select over and no copy of a layer's
+    16 MB ring), the paged kernel at 8 query heads a KV head in the global
+    layer — and what it holds (7.42 GB of weights, 3.76 GB of pages, 134 MB
+    of rings) beside its temporaries inside one v5e."""
+    from k8s_distributed_deeplearning_tpu.models import transformer as T
+    from k8s_distributed_deeplearning_tpu.ops import pallas_gmm
+    from k8s_distributed_deeplearning_tpu.serve import engine as E
+    for mod in (pallas_paged_attn, pallas_gmm, T):
+        monkeypatch.setattr(mod, "on_tpu", lambda: True)         # compile, not interpret
+    model, params, cache, eng, sds = _window_moe_cell(one_chip)
+    slots, pt = eng["num_slots"], eng["page_tokens"]
+    i32, f32 = (lambda *s: sds(s, jnp.int32)), (lambda *s: sds(s, jnp.float32))
+    compiled = E._decode_program.lower(
+        model, params, cache, i32(slots), i32(slots),
+        i32(slots, eng["max_seq_len"] // pt), f32(slots), i32(slots), f32(slots),
+        sds((slots, 2), jnp.uint32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _calls(text, "paged_attn") == 1
+    assert [l for l in text.splitlines()
+            if re.search(r"= bf16\[64,128,1024\][^ ]* (select|copy)\(", l)] == []
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 11.2e9 < mem.argument_size_in_bytes < 11.4e9 and held < 12.5e9
+    assert mem.alias_size_in_bytes > 3.8e9                      # rings and pool in place
+
+
+def test_the_window_moe_cells_chunk_program_attends_through_the_window_kernel(
+        one_chip, monkeypatch):
+    """The same cell's intermediate CHUNK program, 2,048 tokens of one slot:
+    the four window layers through ``window_attn`` (blocks of 128 queries
+    against their 256 keys in VMEM: no score tensor of the band in HBM, and
+    no ``[.., 2048, 2176]`` one), the global layer through the paged kernel,
+    the experts through the grouped kernel; it fits beside the cache."""
+    from k8s_distributed_deeplearning_tpu.models import transformer as T
+    from k8s_distributed_deeplearning_tpu.ops import pallas_gmm, pallas_window_attn
+    from k8s_distributed_deeplearning_tpu.serve import engine as E
+    for mod in (pallas_paged_attn, pallas_gmm, pallas_window_attn, T):
+        monkeypatch.setattr(mod, "on_tpu", lambda: True)         # compile, not interpret
+    model, params, cache, eng, sds = _window_moe_cell(one_chip)
+    c, blocks = eng["prefill_chunk_tokens"], eng["max_seq_len"] // eng["page_tokens"]
+    compiled = E._chunk_program.lower(
+        model, params, cache, sds((1, c), jnp.int32), sds((1, blocks), jnp.int32),
+        sds((), jnp.int32), sds((), jnp.int32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _calls(text, "paged_attn") >= 1 and _calls(text, "moe_gmm_act") >= 3
+    assert _calls(text, "window_attn") == 4
+    assert "2176]" not in text and ",128,256]" not in text
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13e9
