@@ -61,6 +61,7 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 
 from k8s_distributed_deeplearning_tpu.models.transformer import (
@@ -195,6 +196,22 @@ def serving_dispatch(rows: int, moe: "MoEConfig") -> str:
     per_expert = rows * moe.top_k / moe.num_experts
     return ("grouped" if per_expert >= GROUPED_MIN_ROWS_PER_EXPERT
             or rows >= GROUPED_MIN_ROW_BLOCKS * moe.ragged_block_m else "dense")
+
+
+def grouped_row_blocks(counts: np.ndarray, rows: int,
+                       moe: "MoEConfig") -> tuple[int, int]:
+    """On the host, the row blocks of a grouped call of *rows* tokens whose
+    expert layers counted *counts* (``[layers x held]`` picks a held expert,
+    flat or not): every block its layouts hold, ``m_pad / block`` a layer
+    (:func:`ops.pallas_gmm.padded_rows`), and the live ones among them,
+    ``ceil(count / block)`` a held expert — the steps whose row and weight
+    blocks the grouped products read; the others are parked."""
+    from k8s_distributed_deeplearning_tpu.ops import pallas_gmm
+    bm = _row_block(rows, moe.top_k, moe)
+    per_layer = np.asarray(counts).reshape(-1, moe.held)
+    laid_out = pallas_gmm.padded_rows(rows * moe.top_k, moe.held, bm) // bm
+    return (per_layer.shape[0] * laid_out,
+            int((-(-per_layer // bm)).sum()))
 
 
 def clamped_capacity(tokens: int, moe: "MoEConfig") -> int:
@@ -794,16 +811,20 @@ class MoEMLP(nn.Module):
             for c in range(k):
                 xs = xs.at[dests[c]].add(tok_c, mode="drop",
                                          unique_indices=True)
-        # checkpoint_name: a Pallas call is not a dot XLA's remat policy
-        # can match, so without the tag remat policies that save matmul
-        # outputs would recompute all three grouped GEMMs in the backward
-        # (see REMAT_POLICIES in models/transformer.py).
-        from jax.ad_checkpoint import checkpoint_name
-        gmm = lambda x, w: checkpoint_name(
-            pallas_gmm.gmm(x, w, layout), "gmm_out")
-        hidden = (_serving_hidden(moe, xs, w_gate, w_up, layout) if decode
-                  else _experts_hidden(gmm, xs, w_gate, w_up))
-        ys = gmm(hidden, w_down)
+        if decode:
+            # Serving: forward-only products, W_down included, at the wide
+            # column block (pallas_gmm.wide_block).
+            ys = pallas_gmm.gmm_forward(
+                _serving_hidden(moe, xs, w_gate, w_up, layout), w_down, layout)
+        else:
+            # checkpoint_name: a Pallas call is not a dot XLA's remat policy
+            # can match, so without the tag remat policies that save matmul
+            # outputs would recompute all three grouped GEMMs in the backward
+            # (see REMAT_POLICIES in models/transformer.py).
+            from jax.ad_checkpoint import checkpoint_name
+            gmm = lambda x, w: checkpoint_name(
+                pallas_gmm.gmm(x, w, layout), "gmm_out")
+            ys = gmm(_experts_hidden(gmm, xs, w_gate, w_up), w_down)
         # Serving sums the k gated rows in f32, as the dense serving form
         # does (the two serving dispatches agree); training sums in the
         # model's type.

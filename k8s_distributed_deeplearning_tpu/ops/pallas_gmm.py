@@ -25,6 +25,15 @@ Design notes (TPU-first):
   via a prefetched liveness flag — they write zeros (their rows are never
   gathered back anyway, the buffer's padding rows are zero by
   construction);
+- a dead step also moves nothing in: its row and weight blocks are PARKED
+  on the last live block's (a prefetched :func:`parked_blocks`), the
+  indices of the step before, so no copy starts. A serving layout is sized
+  for every pick while a chip holds a share of the experts, so most of its
+  blocks are dead (m_pad 18,432 rows for ~2,048 real ones at a 2,048-token
+  chunk of 16 held experts), and unparked, each column sweep would read
+  their zeros again. The output block stays the step's own and its zeros
+  are still written: padding rows are zero, which the combine and the
+  training path rely on;
 - the backward is two more grouped products with the same layout:
   ``dlhs = gmm(dout, rhsᵀ)`` (reusing this kernel on a transposed weight
   view) and ``drhs = tgmm(lhs, dout)`` — a separate kernel that
@@ -182,8 +191,19 @@ def _activation(act: str, *products):
     return jax.nn.silu(gate) * up
 
 
-def _gmm_kernel(act, expert_ref, live_ref, first_ref, lhs_ref, *refs):
-    del expert_ref, first_ref
+def parked_blocks(block_live: jax.Array) -> jax.Array:
+    """Where each row step of a grouped product reads its blocks: a live
+    block its own, a dead one the last live block before it — the first live
+    block for dead blocks ahead of every live one, block 0 where none is live.
+    A run of dead steps then repeats the indices of the step before it."""
+    steps = jnp.arange(block_live.shape[0], dtype=jnp.int32)
+    last_live = jax.lax.cummax(jnp.where(block_live == 1, steps, -1))
+    first_live = jnp.argmax(block_live).astype(jnp.int32)   # 0 if none live
+    return jnp.where(last_live >= 0, last_live, first_live)
+
+
+def _gmm_kernel(act, expert_ref, live_ref, park_ref, lhs_ref, *refs):
+    del expert_ref, park_ref   # read by the index maps alone
     *w_refs, out_ref = refs
     m = pl.program_id(1)
 
@@ -213,19 +233,21 @@ def _gmm_call(lhs, weights, layout: GroupedLayout, interpret: bool,
     bn = block_n or _pick_block(n, _BLOCK_N)
     tiles_m, tiles_n = m_pad // bm, n // bn
     grid = (tiles_n, tiles_m)   # row dim innermost: weight blocks revisit
-    # every weight's block is the same prefetched expert's: a row block is
-    # read once for all of them
-    w_spec = pl.BlockSpec((1, k, bn), lambda j, m, be, bl, bf: (be[m], 0, j))
+    # a dead step reads the blocks of the last live one (no copy), and every
+    # weight's block is the same prefetched expert's: a row block is read
+    # once for all of them
+    w_spec = pl.BlockSpec((1, k, bn),
+                          lambda j, m, be, bl, pk: (be[pk[m]], 0, j))
 
     return pl.pallas_call(
         functools.partial(_gmm_kernel, act),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=grid,
-            in_specs=[pl.BlockSpec((bm, k), lambda j, m, be, bl, bf: (m, 0))]
+            in_specs=[pl.BlockSpec((bm, k), lambda j, m, be, bl, pk: (pk[m], 0))]
             + [w_spec] * len(weights),
             out_specs=pl.BlockSpec((bm, bn),
-                                   lambda j, m, be, bl, bf: (m, j)),
+                                   lambda j, m, be, bl, pk: (m, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((m_pad, n), lhs.dtype),
         compiler_params=_compiler_params(interpret),
@@ -233,8 +255,8 @@ def _gmm_call(lhs, weights, layout: GroupedLayout, interpret: bool,
         # the name the device trace carries for the kernel's events; both keep
         # "moe_gmm", which the benchmark's expert-product readers match
         name="moe_gmm" if act is None else "moe_gmm_act",
-    )(layout.block_expert, layout.block_live, layout.block_first, lhs,
-      *weights)
+    )(layout.block_expert, layout.block_live, parked_blocks(layout.block_live),
+      lhs, *weights)
 
 
 # ---------------------------------------------------------------------------

@@ -885,6 +885,7 @@ class ServeEngine:
             if draft_model is not None:
                 _validate_tp_cfg(dcfg, self.tp, "draft model")
         self.model = model
+        self._moe = moe_lib.moe_config_of(model)
         self.params = params
         if self.weight_quant == "int8":
             qp, sc = quant_lib.quantize_params(params)
@@ -1936,7 +1937,7 @@ class ServeEngine:
                     # (a disabled tracer's span keeps no fields: the
                     # counters alone)
                     self._record_counts(getattr(span, "fields", {}),
-                                        nxt[self.num_slots:])
+                                        nxt[self.num_slots:], self.num_slots)
                 self._take_chunk_counts(seq)
                 with self.tracer.span("fetch_keys"):
                     # graftlint: disable=host-sync — rides the same fence
@@ -2317,25 +2318,34 @@ class ServeEngine:
         """A chunk's counts, known only after its ``prefill`` span closed:
         a ``prefill_counts`` record of their own beside the call's *fields*
         (``tokens``, ``start``, ``request_id``; ``chunk`` or, for a final
-        one, ``bucket``) — nothing is written into a span that has
-        closed."""
-        self._record_counts(fields, counts)
+        one, ``bucket``: the rows the call computed) — nothing is written
+        into a span that has closed."""
+        self._record_counts(fields, counts,
+                            fields.get("chunk") or fields["bucket"])
         with self.tracer.span("prefill_counts", **fields):
             pass
 
-    def _record_counts(self, fields: dict, counts: np.ndarray) -> None:
-        """What a program's expert layers counted (:func:`_with_counts`: the
-        decode and final-chunk programs hand it back BEHIND their tokens, so
-        it rides their fetch), as the three numbers *fields* and the
-        counters carry: ``moe_assignments`` (picks that landed on held
-        experts, over every row the call computed), ``moe_experts_touched``
-        (held experts with at least one row, summed over layers) and
-        ``moe_max_rows`` (the fullest expert)."""
+    def _record_counts(self, fields: dict, counts: np.ndarray,
+                       rows: int) -> None:
+        """What a program's expert layers counted over the *rows* it computed
+        (:func:`_with_counts`: the decode and final-chunk programs hand it
+        back BEHIND their tokens, so it rides their fetch), as the numbers
+        *fields* and the counters carry: ``moe_assignments`` (picks that
+        landed on held experts), ``moe_experts_touched`` (held experts with
+        at least one row, summed over layers) and ``moe_max_rows`` (the
+        fullest expert); for a call whose experts take the grouped kernel
+        also ``moe_row_blocks`` and ``moe_live_blocks``, the row blocks its
+        layouts hold and those with a real row
+        (:func:`models.moe.grouped_row_blocks`)."""
         a, touched, mx = (int(counts.sum()), int(np.count_nonzero(counts)),
                           int(counts.max()))
         self.stats.record_moe(a, touched, mx)
         fields.update(moe_assignments=a, moe_experts_touched=touched,
                       moe_max_rows=mx)
+        if moe_lib.serving_dispatch(rows, self._moe) == "grouped":
+            laid_out, live = moe_lib.grouped_row_blocks(counts, rows, self._moe)
+            self.stats.record_moe_blocks(laid_out, live)
+            fields.update(moe_row_blocks=laid_out, moe_live_blocks=live)
 
     def _record_pool_gauges(self, counters: dict | None = None) -> None:
         c = counters if counters is not None else self.pool.counters()
@@ -2592,7 +2602,7 @@ class ServeEngine:
             cells = rows * (sq // qb) * -(-self.max_blocks // pages)
             return (impl + (f" q_block={qb}" if qb != sq else "")
                     + f" pages_per_cell={pages} cells={cells}")
-        moe = moe_lib.moe_config_of(self.model)
+        moe = self._moe
 
         def experts(tokens: int) -> str:
             if moe is None:

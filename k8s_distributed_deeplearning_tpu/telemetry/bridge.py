@@ -219,6 +219,10 @@ def serving_collector(registry: MetricsRegistry,
         "serve_moe_max_rows": registry.gauge(
             "serve_moe_max_rows",
             "rows of the fullest held expert any one call has seen"),
+        "serve_moe_live_block_share": registry.gauge(
+            "serve_moe_live_block_share",
+            "share of the grouped expert calls' row blocks that hold a real "
+            "row: the rest are parked steps, which read no rows or weights"),
         "serve_state_slots": registry.gauge(
             "serve_state_slots",
             "slots holding per-slot model state (a short convolution's "
@@ -316,6 +320,7 @@ def serving_collector(registry: MetricsRegistry,
                "moe_assignments": "serve_moe_assignments_total",
                "moe_experts_touched": "serve_moe_experts_touched_total",
                "moe_max_rows": "serve_moe_max_rows",
+               "moe_live_block_share": "serve_moe_live_block_share",
                "state_slots": "serve_state_slots",
                "state_bytes": "serve_state_bytes",
                "state_update_rows": "serve_state_update_rows",

@@ -197,6 +197,11 @@ class ServingStats:
         self.moe_assignments = 0
         self.moe_experts_touched = 0
         self.moe_max_rows = 0
+        # The grouped calls' row blocks, summed over layers and calls: all
+        # their layouts hold, and those with a real row (the steps the
+        # expert products read; the rest are parked).
+        self.moe_row_blocks = 0
+        self.moe_live_blocks = 0
         # Per-slot state beside the page pool (a ShortConv's tail, a Mamba2's
         # tail and state): slots a request holds now, their bytes, and the
         # rows the last decode step advanced. Gauges; 0 for a model of
@@ -411,6 +416,11 @@ class ServingStats:
         self.moe_max_rows = max(self.moe_max_rows, int(max_rows))
 
     @_locked
+    def record_moe_blocks(self, row_blocks: int, live_blocks: int) -> None:
+        self.moe_row_blocks += int(row_blocks)
+        self.moe_live_blocks += int(live_blocks)
+
+    @_locked
     def record_state(self, state_slots: int, state_bytes: int) -> None:
         """Latest state-arena occupancy. A gauge: no ``_tick()``."""
         self.state_slots = int(state_slots)
@@ -509,6 +519,13 @@ class ServingStats:
             "moe_assignments": self.moe_assignments,
             "moe_experts_touched": self.moe_experts_touched,
             "moe_max_rows": self.moe_max_rows,
+            "moe_row_blocks": self.moe_row_blocks,
+            "moe_live_blocks": self.moe_live_blocks,
+            # Share of the grouped calls' row blocks holding a real row (None
+            # until the first grouped call).
+            "moe_live_block_share": (
+                round(self.moe_live_blocks / self.moe_row_blocks, 4)
+                if self.moe_row_blocks else None),
             "state_slots": self.state_slots,
             "state_bytes": self.state_bytes,
             "state_update_rows": self.state_update_rows,

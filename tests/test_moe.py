@@ -654,3 +654,73 @@ def test_ragged_indivisible_fallback_warns_once_at_decode(mesh8):
         assert len(hits) == 1, [str(w.message) for w in rec]
     finally:
         moe._RAGGED_FALLBACK_WARNED.clear()
+
+
+def test_grouped_serving_calls_count_their_row_blocks():
+    """The engine's ``moe_row_blocks`` / ``moe_live_blocks`` (on the records
+    of the calls whose experts take the grouped kernel, and summed in
+    ``serve_moe_live_block_share``) against a hand count from each call's
+    per-layer counts and against the layouts the program builds from them.
+    One chip's share of the experts (4 of 8, top-2): a 32-token chunk is
+    grouped (4 row blocks' worth of rows); the decode's 2 rows and an
+    8-token final chunk are dense and carry neither."""
+    import collections
+    import dataclasses
+
+    from k8s_distributed_deeplearning_tpu.ops import pallas_gmm
+    from k8s_distributed_deeplearning_tpu.serve.engine import ServeEngine
+    from k8s_distributed_deeplearning_tpu.serve.request import Request
+    from k8s_distributed_deeplearning_tpu.telemetry import bridge
+    from k8s_distributed_deeplearning_tpu.telemetry.registry import MetricsRegistry
+    from k8s_distributed_deeplearning_tpu.telemetry.trace import Tracer
+    cfg, latent, mcfg = moe.config_tiny_latent_moe()
+    mcfg = dataclasses.replace(mcfg, experts_held=4, expert_offset=2)
+    model = moe.LatentMoELM(cfg, latent, mcfg)
+    params = model.init(jax.random.key(1), jnp.zeros((1, 8), jnp.int32))["params"]
+    tracer = Tracer(ring_size=4096)
+    eng = ServeEngine(model, params, num_slots=2, min_bucket=8,
+                      prefill_chunk_tokens=32, tracer=tracer)
+    reg = MetricsRegistry()
+    bridge.serving_collector(reg, eng.stats)
+    assert eng.stats.summary()["moe_live_block_share"] is None
+    calls, record = [], eng._record_counts
+    eng._record_counts = lambda fields, counts, rows: (
+        calls.append((np.array(counts), rows)), record(fields, counts, rows))
+    rng = np.random.default_rng(5)
+    eng.run([Request(prompt=rng.integers(0, 256, size=n).tolist(),
+                     max_new_tokens=3, request_id=f"r{n}") for n in (70, 60, 9)])
+
+    want_row_blocks = want_live = 0
+    for counts, rows in calls:
+        per_layer = counts.reshape(-1, 4)            # 2 expert layers of 3
+        assert per_layer.shape[0] == 2 and (per_layer.sum(axis=1) <= rows * 2).all()
+        if rows != 32:
+            continue
+        # 64 picks a layer: 8 blocks of 8 rows + one a held expert; a held
+        # expert's live blocks are its rows over 8, rounded up
+        want_row_blocks += 2 * (64 // 8 + 4)
+        live = int(sum(-(-c // 8) for c in per_layer.ravel()))
+        want_live += live
+        layouts = [pallas_gmm.grouped_layout(jnp.asarray(c), 64, block_m=8)
+                   for c in per_layer]
+        assert sum(lay.m_pad // 8 for lay in layouts) == 2 * 12
+        assert sum(int(lay.block_live.sum()) for lay in layouts) == live
+    # 70 tokens: 2 chunks + a final one of 8; 60: a chunk + a final one of
+    # 32; 9: a final one of 16; and the decode steps
+    rows_of = collections.Counter(rows for _, rows in calls)
+    assert (rows_of[32], rows_of[16], rows_of[8]) == (4, 1, 1) and rows_of[2] > 0
+
+    spans = tracer.recent_spans()
+    grouped = [s for s in spans if "moe_row_blocks" in s]
+    assert [s["name"] for s in grouped] == ["prefill_counts"] * 4
+    assert all(s.get("chunk") == 32 or s.get("bucket") == 32 for s in grouped)
+    assert not any("moe_live_blocks" in s for s in spans
+                   if s["name"] == "decode" or s.get("bucket") in (8, 16))
+    assert (sum(s["moe_row_blocks"] for s in grouped),
+            sum(s["moe_live_blocks"] for s in grouped)) == (want_row_blocks,
+                                                            want_live)
+    s = eng.stats.summary()
+    assert (s["moe_row_blocks"], s["moe_live_blocks"]) == (want_row_blocks,
+                                                           want_live)
+    assert s["moe_live_block_share"] == round(want_live / want_row_blocks, 4)
+    assert f"serve_moe_live_block_share {s['moe_live_block_share']}" in reg.render()

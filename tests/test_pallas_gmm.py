@@ -5,12 +5,18 @@ code path TPUs compile (pallas_flash convention). Covers the layout
 builder (block-aligned spans, empty groups, tail blocks), forward parity
 against the dense reference, both custom-VJP gradients, and the serving
 form whose epilogue makes the experts' hidden activation (``gmm_act``)
-alone and inside both grouped serving dispatches.
+alone and inside both grouped serving dispatches, and the parked dead
+steps against a grid whose every step reads its own blocks.
 """
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from k8s_distributed_deeplearning_tpu.ops import pallas_gmm as g
 
@@ -225,3 +231,127 @@ def test_the_serving_dispatches_take_gmm_act_and_serve_the_two_call_forms_rows(
         train = str(jax.make_jaxpr(jax.grad(lambda p: jnp.sum(layer.apply(
             {"params": p}, x.astype(jnp.float32))[0].astype(jnp.float32))))(params))
         assert "moe_gmm" in train and "moe_gmm_act" not in train
+
+
+# ---------------------------------------------------------------------------
+# Parked dead steps
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("live,want", [
+    ([1, 0, 1, 1, 1, 1, 0, 0], [0, 0, 2, 3, 4, 5, 5, 5]),   # empty expert, tail
+    ([0, 0, 1, 0, 1, 0], [2, 2, 2, 2, 4, 4]),               # leading dead blocks
+    ([0, 0, 0], [0, 0, 0]),                                 # no live block
+    ([1, 1, 1], [0, 1, 2])])                                # none dead
+def test_a_dead_step_parks_on_the_last_live_block(live, want):
+    np.testing.assert_array_equal(
+        g.parked_blocks(jnp.asarray(live, jnp.int32)), want)
+
+
+def _unparked(lhs, weights, layout, bn, act):
+    """The grouped product on a grid whose every step reads its own row
+    block and its own expert's weights (the kernel's third prefetched array
+    is read by the index maps alone, so it is passed unused)."""
+    (m_pad, k), n = lhs.shape, weights[0].shape[2]
+    bm = layout.block_m
+    w_spec = pl.BlockSpec((1, k, bn), lambda j, m, be, bl, x: (be[m], 0, j))
+    return pl.pallas_call(
+        functools.partial(g._gmm_kernel, act),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(n // bn, m_pad // bm),
+            in_specs=[pl.BlockSpec((bm, k), lambda j, m, be, bl, x: (m, 0))]
+            + [w_spec] * len(weights),
+            out_specs=pl.BlockSpec((bm, bn), lambda j, m, be, bl, x: (m, j))),
+        out_shape=jax.ShapeDtypeStruct((m_pad, n), lhs.dtype),
+        interpret=True,
+    )(layout.block_expert, layout.block_live, layout.block_expert, lhs, *weights)
+
+
+def _live_rows(layout, sizes):
+    off = np.asarray(layout.row_offset)
+    live = np.zeros(layout.m_pad, bool)
+    for i, s in enumerate(sizes):
+        live[off[i]:off[i] + s] = True
+    return live
+
+
+# (sizes, N, weight-block budget or None): expert 0 empty (leading dead
+# blocks); an empty interior expert with dead tail blocks, at three column
+# blocks; every row on one expert; no live block at all.
+PARK_CASES = [([0, 100, 300, 57], 256, None),
+              ([100, 0, 300, 57], 384, 2 * 2 * 128 * 128 * 2),
+              ([0, 0, 512, 0], 256, None),
+              ([0, 0, 0, 0], 256, None)]
+PARK_IDS = ["leading-dead", "interior-empty-three-sweeps", "one-expert",
+            "none-live"]
+
+
+@pytest.mark.parametrize("act", [None, "swiglu", "relu2"])
+@pytest.mark.parametrize("sizes,n,budget", PARK_CASES, ids=PARK_IDS)
+def test_parked_steps_give_bit_for_bit_what_the_unparked_grid_gives(
+        act, sizes, n, budget, monkeypatch):
+    """``gmm_forward`` and ``gmm_act`` (dead steps parked) against the same
+    kernel on a grid whose every step reads its own blocks: the same bits,
+    padding rows zero included."""
+    if budget is not None:
+        monkeypatch.setattr(g, "_WIDE_BLOCK_BYTES", budget)
+    layout, lhs, rhs = _random_case(9, sizes, 4, 128, n, 128, dtype=jnp.bfloat16)
+    weights = (rhs, jnp.flip(rhs, axis=2))[:1 if act is None else g.ACT_WEIGHTS[act]]
+    bn = g.wide_block(n, 128, 2, len(weights))
+    assert bn == (n if budget is None else 128)
+    parked = (g.gmm_forward(lhs, rhs, layout) if act is None
+              else g.gmm_act(lhs, weights, layout, act))
+    unparked = _unparked(lhs, weights, layout, bn, act)
+    np.testing.assert_array_equal(np.asarray(parked, np.float32),
+                                  np.asarray(unparked, np.float32))
+    live = _live_rows(layout, sizes)
+    parked = np.abs(np.asarray(parked, np.float32))
+    assert parked[~live].max() == 0.0
+    assert not live.any() or parked[live].max() > 0.0
+
+
+@pytest.mark.parametrize("sizes,n,budget", PARK_CASES, ids=PARK_IDS)
+def test_the_parked_training_products_give_the_reference_gradients(sizes, n,
+                                                                   budget):
+    """Training's ``gmm`` (its forward and the ``dlhs`` product parked, the
+    ``tgmm`` weight gradient not) gives the dense reference's value and both
+    gradients on every parked layout, and its padding rows stay zero in the
+    value and in ``dlhs``."""
+    layout, lhs, rhs = _random_case(10, sizes, 4, 128, n, 128)
+    live = _live_rows(layout, sizes)
+
+    def value_and_grads(fn):
+        return jax.value_and_grad(lambda l, r: jnp.sum(fn(l, r, layout) ** 2),
+                                  argnums=(0, 1))(lhs, rhs)
+    out = g.gmm(lhs, rhs, layout)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(g.gmm_reference(lhs, rhs, layout)),
+                               rtol=1e-5, atol=1e-4)
+    (v, (dl, dr)) = value_and_grads(g.gmm)
+    (rv, (rdl, rdr)) = value_and_grads(g.gmm_reference)
+    np.testing.assert_allclose(float(v), float(rv), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(dl), np.asarray(rdl), rtol=1e-4, atol=1e-3)
+    np.testing.assert_allclose(np.asarray(dr), np.asarray(rdr), rtol=1e-4, atol=1e-3)
+    assert float(jnp.abs(out[~live]).max()) == 0.0
+    assert float(jnp.abs(dl[~live]).max()) == 0.0
+
+
+def test_the_serving_layer_takes_w_down_forward_only_and_parked():
+    """A ``decode=True`` grouped call at k <= 8 (``_ragged_core``) makes W_down
+    with ``gmm_forward``: no differentiable ``_gmm`` (a ``custom_vjp``) in its
+    jaxpr, both products parked (the ``park`` array's ``cummax``); training
+    keeps the differentiable products, parked too."""
+    import flax.linen as nn
+    moe, layer = _serving_layer("swiglu", 2, None)
+    x = jax.random.normal(jax.random.key(7), (2, 32, 128), jnp.bfloat16)
+    params = nn.meta.unbox(layer.init(jax.random.key(8), x, decode=True)["params"])
+    assert moe.serving_dispatch(64, layer.moe) == "grouped"
+    serve = str(jax.make_jaxpr(
+        lambda p, x: layer.apply({"params": p}, x, decode=True))(params, x))
+    assert "custom_vjp_call" not in serve and "name=_gmm" not in serve
+    assert len(re.findall(r"name=moe_gmm\n", serve)) == 1          # W_down
+    assert len(re.findall(r"name=moe_gmm_act\n", serve)) == 1
+    assert serve.count("cummax") == 2                  # one park array a call
+    train = str(jax.make_jaxpr(lambda p, x: layer.apply(
+        {"params": p}, x.astype(jnp.float32))[0])(params, x))
+    assert train.count("name=_gmm\n") == 3 and train.count("cummax") == 3

@@ -494,6 +494,60 @@ def test_the_selective_kernels_compile_for_v5e(one_chip):
     assert scan.memory_analysis().temp_size_in_bytes < length * e * 4
 
 
+# The grouped expert products of one chunk, their layouts sized for every pick
+# (top-8, row blocks of 128): (chunk tokens, held experts, hidden, expert width)
+# of K-EXAONE's 2,048-token chunks (16 of 128 experts) and of Sarvam's 1,024
+# (32 of 128), and the padded rows each layout holds.
+GMM_CHUNKS = {"k-exaone": ((2048, 16, 6144, 2048), 18432),
+              "sarvam": ((1024, 32, 4096, 2048), 12288)}
+
+
+@pytest.mark.parametrize("cell", sorted(GMM_CHUNKS))
+def test_the_parked_grouped_products_compile_for_v5e(one_chip, cell):
+    """``moe_gmm_act`` (silu(x W_gate) * x W_up) and ``moe_gmm`` (W_down) with
+    their dead steps parked — the ``park`` array scalar-prefetched and read
+    by the row and weight index maps — at the chunk shapes of the two cells
+    whose experts take them: Mosaic takes both."""
+    from k8s_distributed_deeplearning_tpu.ops import pallas_gmm
+    (tokens, held, d, f), m_pad = GMM_CHUNKS[cell]
+    assert pallas_gmm.padded_rows(tokens * 8, held, 128) == m_pad
+    sds = lambda *shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt,
+                                                               sharding=one_chip)
+
+    def experts(x, counts, w_gate, w_up, w_down):
+        layout = pallas_gmm.grouped_layout(counts, tokens * 8, block_m=128)
+        hidden = pallas_gmm.gmm_act(x, (w_gate, w_up), layout, "swiglu",
+                                    interpret=False)
+        return pallas_gmm.gmm_forward(hidden, w_down, layout, interpret=False)
+    text = jax.jit(experts).lower(
+        sds(m_pad, d), sds(held, dt=jnp.int32), sds(held, d, f), sds(held, d, f),
+        sds(held, f, d)).compile().as_text()
+    assert _calls(text, "moe_gmm_act") == 1 and _calls(text, "moe_gmm") == 1
+
+
+def test_the_parked_training_products_compile_for_v5e(one_chip):
+    """Training's grouped products under ``jax.grad``: the parked forward and
+    ``dlhs`` (``moe_gmm`` on the transposed weights) beside ``tgmm``, in bf16
+    at 128-row blocks with an empty expert: Mosaic takes all three."""
+    from k8s_distributed_deeplearning_tpu.ops import pallas_gmm
+    sds = lambda *shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt,
+                                                               sharding=one_chip)
+
+    def grads(lhs, rhs, counts):
+        layout = pallas_gmm.grouped_layout(counts, 1536, block_m=128)
+        return jax.grad(lambda l, r: jnp.sum(pallas_gmm.gmm(
+            l, r, layout, interpret=False).astype(jnp.float32) ** 2),
+            argnums=(0, 1))(lhs, rhs)
+    m_pad = pallas_gmm.padded_rows(1536, 4, 128)
+    text = jax.jit(grads).lower(sds(m_pad, 256), sds(4, 256, 512),
+                                sds(4, dt=jnp.int32)).compile().as_text()
+    # under jax.grad the kernels' names carry the transform: jvp_moe_gmm_
+    # (the forward), transpose_jvp_moe_gmm__ (dlhs), transpose_jvp___ (tgmm)
+    kernels = re.findall(r"%(\w+?)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                         text)
+    assert len(kernels) == 3 and sum("moe_gmm" in k for k in kernels) == 2, kernels
+
+
 def _calls(text, kernel):
     """Custom calls of Pallas kernel *kernel* in compiled module *text*
     (the name alone would also match the kernels' source paths)."""
