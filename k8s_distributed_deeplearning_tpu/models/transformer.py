@@ -1110,7 +1110,7 @@ class LatentAttention(nn.Module):
                 # expanded to keys and values block by block INSIDE the
                 # kernel, so the expanded K and V of a long prefix never
                 # exist whole.
-                ppb = max(1, pallas_latent_attn.CHUNK_BLOCK_K // page_tokens)
+                ppb = pallas_latent_attn.chunk_pages_per_block(page_tokens)
                 tbl = jnp.pad(block_tables, ((0, 0), (0, -n_blocks % ppb)))
                 out = pallas_latent_attn.latent_chunk_attention(
                     q_n, q_r, pool[tbl].reshape(b, -1, lanes), w_uk, w_uv,

@@ -40,6 +40,13 @@ CELL_TOKENS = 2048
 # The chunk kernel: KV tokens a grid step, heads a grid cell.
 CHUNK_BLOCK_K = 512
 CHUNK_HEADS = 4
+# Lanes the chunk kernel keeps each query's running max and sum in: the
+# value repeated across one lane tile, so that widening it to a score block
+# or to the value width is a copy of whole vregs, not a broadcast across
+# lanes per row. Timed alone on a v5e at the Sarvam cell's shape (PERF.md
+# §6): a 1,024-token chunk at a 7 k prefix 3.97 ms with one lane, 3.25 with
+# 128.
+STAT_LANES = 128
 
 
 def default_impl(platform: str | None = None) -> str:
@@ -54,6 +61,27 @@ def default_pages_per_cell(page_tokens: int, n_blocks: int) -> int:
     """Pages a decode grid cell attends: :data:`CELL_TOKENS` tokens' worth,
     never more than the table."""
     return max(1, min(CELL_TOKENS // page_tokens, n_blocks))
+
+
+def chunk_pages_per_block(page_tokens: int) -> int:
+    """Pages of the table a :func:`latent_chunk_attention` key block holds
+    on the paged chunk path: :data:`CHUNK_BLOCK_K` tokens' worth, at least
+    one page."""
+    return max(1, CHUNK_BLOCK_K // page_tokens)
+
+
+def chunk_blocks(start: int, width: int, page_tokens: int,
+                 n_blocks: int) -> tuple[int, int]:
+    """``(walked, unmasked)`` key blocks of one paged chunk call — queries at
+    positions ``start + [0, width)`` (pad queries included) over an
+    ``n_blocks``-page table — by the wrapper's own arithmetic on the host:
+    blocks up to the last position's are walked, and those wholly before the
+    first position's block take no mask."""
+    ppb = chunk_pages_per_block(page_tokens)
+    bk = ppb * page_tokens
+    s_virt = -(-n_blocks // ppb) * bk
+    last = min(start + width - 1, s_virt - 1) // bk
+    return last + 1, min(start, s_virt - 1) // bk
 
 
 def _kernel(tables_ref, last_ref, q_ref, pos_ref, src, o_ref, m_s, l_s,
@@ -213,17 +241,33 @@ def latent_decode_attention(q_abs: jax.Array, pool: jax.Array,
     return out.reshape(b, h, sq, rank).transpose(0, 2, 1, 3)
 
 
-def _chunk_kernel(last_ref, qn_ref, qr_ref, pos_ref, lat_ref, wuk_ref, wuv_ref,
-                  o_ref, m_s, l_s, acc_s, *, rank, rope, block_k, scale):
+def _widen(x: jax.Array, n: int) -> jax.Array:
+    """A lane-replicated ``[rows, STAT_LANES]`` statistic for an operand of
+    ``n`` lanes: whole copies of its lane tile where ``n`` is a multiple of
+    it, else its first ``n`` lanes (or one, to broadcast)."""
+    lanes = x.shape[1]
+    if n % lanes == 0:
+        return pltpu.repeat(x, n // lanes, 1) if n > lanes else x
+    return x[:, :n] if n < lanes else x[:, :1]
+
+
+def _chunk_kernel(last_ref, first_ref, q_ref, pos_ref, lat_ref, wuk_ref,
+                  wuv_ref, o_ref, m_s, l_s, acc_s, *, rank, rope, block_k):
     """One (row, group of heads, block of ``block_k`` cache positions) grid
     cell of the EXPANDED form: the block's latent rows ``[block_k, lanes]``
     are up-projected IN VMEM to this group's keys and values (``W_UK,h``,
     ``W_UV,h``: ``[rank, dn]``, ``[rank, dv]`` a head) — the expanded K and V
-    never exist in HBM — then flash attention: scores of the chunk's queries
-    (``q^N·k^N + q^R·k^R``), cursor mask from the queries' own positions,
-    online softmax in f32 scratch carried over the row's blocks."""
+    never exist in HBM — then flash attention: ONE score product of the
+    chunk's pre-scaled queries ``[q^N ; q^R]`` against ``[k^N ; k^R]``,
+    online softmax in f32 scratch carried over the row's blocks, its running
+    max and sum lane-replicated (:data:`STAT_LANES`).
+
+    A block wholly before the row's first query (``j < first``) is allowed
+    for every query: it takes the softmax with no mask. Only the blocks from
+    ``first`` to ``last``, which the chunk's causal edge crosses, build the
+    cursor mask from the queries' own positions."""
     i, j = pl.program_id(0), pl.program_id(2)
-    heads, sq = qn_ref.shape[1], qn_ref.shape[2]
+    heads, sq = q_ref.shape[1], q_ref.shape[2]
 
     @pl.when(j == 0)
     def _init():
@@ -231,38 +275,44 @@ def _chunk_kernel(last_ref, qn_ref, qr_ref, pos_ref, lat_ref, wuk_ref, wuv_ref,
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    @pl.when(j <= last_ref[i])
-    def _block():
+    def block(masked: bool):
         lat = lat_ref[0]                                       # [block_k, lanes]
         c, k_r = lat[:, :rank], lat[:, rank:rank + rope]
-        col = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (sq, block_k), 1)
-        allow = col <= pos_ref[0]                              # [sq, block_k]
+        if masked:
+            col = j * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (sq, block_k), 1)
+            allow = col <= pos_ref[0]                          # [sq, block_k]
         nt = (((1,), (1,)), ((), ()))                          # a @ b.T
         for h in range(heads):
             k_n = jnp.dot(c, wuk_ref[h], preferred_element_type=jnp.float32
                           ).astype(lat.dtype)                  # [block_k, dn]
+            k = jnp.concatenate([k_n, k_r], axis=1)            # [block_k, dn+dr]
             v = jnp.dot(c, wuv_ref[h], preferred_element_type=jnp.float32
                         ).astype(lat.dtype)                    # [block_k, dv]
-            s = (jax.lax.dot_general(qn_ref[0, h], k_n, nt,
-                                     preferred_element_type=jnp.float32)
-                 + jax.lax.dot_general(qr_ref[0, h], k_r, nt,
-                                       preferred_element_type=jnp.float32)
-                 ) * scale
-            s = jnp.where(allow, s, NEG_INF)
-            m_prev = m_s[h]
+            s = jax.lax.dot_general(q_ref[0, h], k, nt,
+                                    preferred_element_type=jnp.float32)
+            if masked:
+                s = jnp.where(allow, s, NEG_INF)
+            m_prev = m_s[h]                                    # [sq, STAT_LANES]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.where(allow, jnp.exp(s - m_new), 0.0)
+            p = jnp.exp(s - _widen(m_new, block_k))
+            if masked:
+                p = jnp.where(allow, p, 0.0)
             alpha = jnp.exp(m_prev - m_new)
-            acc_s[h] = acc_s[h] * alpha + jnp.dot(
+            acc_s[h] = acc_s[h] * _widen(alpha, acc_s.shape[-1]) + jnp.dot(
                 p.astype(v.dtype), v, preferred_element_type=jnp.float32)
             l_s[h] = alpha * l_s[h] + jnp.sum(p, axis=1, keepdims=True)
             m_s[h] = m_new
 
+    pl.when(j < first_ref[i])(lambda: block(masked=False))
+    pl.when((j >= first_ref[i]) & (j <= last_ref[i]))(
+        lambda: block(masked=True))
+
     @pl.when(j == pl.num_programs(2) - 1)
     def _emit():
         for h in range(heads):
-            o_ref[0, h] = (acc_s[h] / jnp.maximum(l_s[h], 1e-30)
-                           ).astype(o_ref.dtype)
+            l = _widen(l_s[h], acc_s.shape[-1])
+            o_ref[0, h] = (acc_s[h] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def latent_chunk_attention(q_n: jax.Array, q_r: jax.Array, lat: jax.Array,
@@ -278,7 +328,8 @@ def latent_chunk_attention(q_n: jax.Array, q_r: jax.Array, lat: jax.Array,
     multiple of ``block_k``; w_uk ``[rank, H, dn]``, w_uv ``[rank, H, dv]``;
     positions ``[B, sq]`` int32 — query ``i`` attends columns ``<=
     positions[b, i]``. Blocks past a row's last position are neither fetched
-    anew nor computed. Returns ``[B, sq, H, dv]`` in q's dtype."""
+    anew nor computed; blocks before its first position take no mask (the
+    counts: :func:`chunk_blocks`). Returns ``[B, sq, H, dv]`` in q's dtype."""
     b, sq, h, dn = q_n.shape
     dr, dv = q_r.shape[-1], w_uv.shape[-1]
     s_virt, lanes = lat.shape[1], lat.shape[2]
@@ -291,28 +342,30 @@ def latent_chunk_attention(q_n: jax.Array, q_r: jax.Array, lat: jax.Array,
         interpret = not on_tpu()
     pos = jnp.minimum(positions.astype(jnp.int32), s_virt - 1)
     last = jnp.max(pos, axis=1) // bk                          # [B]
-    head_major = lambda x: x.transpose(0, 2, 1, 3)             # [B, H, sq, d]
+    first = jnp.min(pos, axis=1) // bk                         # [B]
+    # [B, H, sq, dn + dr], the softmax scale applied once here (in f32,
+    # rounded to q's dtype), fused by XLA into the head-major transpose
+    q = (jnp.concatenate([q_n, q_r], axis=-1).astype(jnp.float32)
+         * softmax_scale).astype(q_n.dtype).transpose(0, 2, 1, 3)
     w_head = lambda w: w.transpose(1, 0, 2)                    # [H, rank, d]
-    row = lambda i, c, j, last: (i, c, 0, 0)
+    row = lambda i, c, j, *_: (i, c, 0, 0)
     out = pl.pallas_call(
-        functools.partial(_chunk_kernel, rank=rank, rope=dr, block_k=bk,
-                          scale=softmax_scale),
+        functools.partial(_chunk_kernel, rank=rank, rope=dr, block_k=bk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(b, h // g, s_virt // bk),
             in_specs=[
-                pl.BlockSpec((1, g, sq, dn), row),
-                pl.BlockSpec((1, g, sq, dr), row),
-                pl.BlockSpec((1, sq, 1), lambda i, c, j, last: (i, 0, 0)),
+                pl.BlockSpec((1, g, sq, dn + dr), row),
+                pl.BlockSpec((1, sq, 1), lambda i, c, j, *_: (i, 0, 0)),
                 # a dead block names the last live one: no new copy
-                pl.BlockSpec((1, bk, lanes), lambda i, c, j, last: (
+                pl.BlockSpec((1, bk, lanes), lambda i, c, j, last, _: (
                     i, jnp.minimum(j, last[i]), 0)),
-                pl.BlockSpec((g, rank, dn), lambda i, c, j, last: (c, 0, 0)),
-                pl.BlockSpec((g, rank, dv), lambda i, c, j, last: (c, 0, 0)),
+                pl.BlockSpec((g, rank, dn), lambda i, c, j, *_: (c, 0, 0)),
+                pl.BlockSpec((g, rank, dv), lambda i, c, j, *_: (c, 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, g, sq, dv), row),
-            scratch_shapes=[pltpu.VMEM((g, sq, 1), jnp.float32),
-                            pltpu.VMEM((g, sq, 1), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((g, sq, STAT_LANES), jnp.float32),
+                            pltpu.VMEM((g, sq, STAT_LANES), jnp.float32),
                             pltpu.VMEM((g, sq, dv), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, dv), q_n.dtype),
         compiler_params=None if interpret else pltpu.CompilerParams(
@@ -325,6 +378,5 @@ def latent_chunk_attention(q_n: jax.Array, q_r: jax.Array, lat: jax.Array,
             transcendentals=b * h * sq * s_virt),
         interpret=interpret,
         name="latent_chunk_attn",
-    )(last, head_major(q_n), head_major(q_r), pos[:, :, None], lat,
-      w_head(w_uk), w_head(w_uv))
+    )(last, first, q, pos[:, :, None], lat, w_head(w_uk), w_head(w_uv))
     return out.transpose(0, 2, 1, 3)

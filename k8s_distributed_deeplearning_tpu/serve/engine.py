@@ -1019,6 +1019,9 @@ class ServeEngine:
         other = sorted({_leaf_name(path) for path, leaf in
                         self._pool_rows(self._row_shapes)}
                        - {"cached_key", "cached_value"})
+        # A latent pool: its chunks attend through the expanded form, whose
+        # key blocks the ``prefill`` spans count (_latent_chunk_fields).
+        self._latent = "cached_latent" in other
         if other:
             # A pool of other leaves than K and V per head (a latent row)
             # has no head axis to shard over tp, no per-head int8 scales,
@@ -2205,6 +2208,19 @@ class ServeEngine:
             return m * (m + 1) // 2 if m <= w else w * (w + 1) // 2 + (m - w) * w
         return {"window_pairs": below(start + tokens) - below(start)}
 
+    def _latent_chunk_fields(self, start: int, width: int) -> dict:
+        """The ``attn_blocks`` / ``attn_blocks_unmasked`` fields of a chunk's
+        ``prefill`` span and ``prefill_counts`` record, for a model with a
+        latent pool: the key blocks the expanded chunk kernel walks for the
+        call's ``width`` queries (a final chunk's pad included) at ``start``,
+        and those wholly before its first query, which take no mask
+        (:func:`ops.pallas_latent_attn.chunk_blocks`, a layer)."""
+        if not self._latent:
+            return {}
+        walked, unmasked = pallas_latent_attn.chunk_blocks(
+            start, width, self.page_tokens, self.max_blocks)
+        return {"attn_blocks": walked, "attn_blocks_unmasked": unmasked}
+
     def _state_slot(self, slot: int):
         """The chunk programs' ``slot`` operand: the arena row of *slot* for
         a model with per-slot state, None (no operand at all) otherwise."""
@@ -2569,11 +2585,9 @@ class ServeEngine:
         quant = self.kv_quant == "int8"
         q_itemsize = jnp.dtype(cfg.dtype).itemsize
         shard = max(self.tp, 1)                  # heads are split over tp
-        latent = any(_leaf_name(path) == "cached_latent"
-                     for path, _ in self._pool_rows(self._row_shapes))
 
         def report(sq: int, rows: int) -> str:
-            if latent:
+            if self._latent:
                 # a latent pool: the absorbed kernel at decode widths, the
                 # expanded one for a chunk, else XLA
                 impl = transformer.latent_attention_impl(cfg)
@@ -2721,7 +2735,8 @@ class ServeEngine:
                                   request_id=pend.req.request_id,
                                   attn=self._prefill_attn[program],
                                   **self._state_from(pend),
-                                  **self._window_chunk_fields(pend.pos, c))
+                                  **self._window_chunk_fields(pend.pos, c),
+                                  **self._latent_chunk_fields(pend.pos, c))
                     with self.tracer.span("prefill", slot=slot, **fields):
                         with self.tracer.span("chunk_operands"):
                             operands = (
@@ -2795,7 +2810,8 @@ class ServeEngine:
                       request_id=req.request_id,
                       attn=self._prefill_attn[program],
                       **self._state_from(pend),
-                      **self._window_chunk_fields(pend.pos, rem))
+                      **self._window_chunk_fields(pend.pos, rem),
+                      **self._latent_chunk_fields(pend.pos, bucket))
         with self.tracer.span("prefill", slot=slot, cached=pend.hit_tokens,
                               **fields):
             with self.tracer.span("chunk_operands"):

@@ -390,31 +390,88 @@ def test_latent_kernel_interpret_against_plain_numpy(pages_per_cell):
         np.testing.assert_allclose(got[i, 0], want, atol=2e-5)
 
 
-@pytest.mark.parametrize("start, heads_per_cell", [(0, 2), (19, 2), (40, 1)])
-def test_latent_chunk_kernel_interpret_against_plain_numpy(start, heads_per_cell):
-    """The expanded form over a gathered row: 16 queries at positions
-    ``start + [0, 16)`` against 64 cache positions in blocks of 16 (blocks
-    past the chunk's last position are neither fetched nor computed)."""
-    h, r, dn, dr, dv, sq, s_virt, lanes = 4, 32, 16, 8, 16, 16, 64, 128
+@pytest.mark.parametrize("starts, sq, heads_per_cell, block_k", [
+    ((0,), 16, 2, 16), ((19,), 16, 2, 16), ((40,), 16, 1, 16),
+    # the first query on a block boundary, two unmasked blocks before it
+    ((32,), 16, 2, 16), ((32,), 16, 1, 16),
+    # inside a block, one unmasked block before it
+    ((21,), 16, 1, 16),
+    # two blocks of queries from position 0: no unmasked block
+    ((0,), 32, 2, 16), ((0,), 32, 1, 16),
+    # one call, both paths: a row three blocks in beside a row at 0
+    ((48, 0), 16, 2, 16), ((0, 48), 16, 1, 16),
+    # a block of one lane tile, and of two beside a 128-lane value: the
+    # running max and sum widened by whole tiles
+    ((256,), 16, 2, 128), ((600, 0), 16, 1, 256)],
+    ids=["0-2", "19-2", "40-1", "32-2", "32-1", "21-1", "0-sq32-2", "0-sq32-1",
+         "48+0-2", "0+48-1", "256-bk128", "600+0-bk256"])
+def test_latent_chunk_kernel_interpret_against_plain_numpy(starts, sq, heads_per_cell, block_k):
+    """The expanded form over a gathered row: ``sq`` queries a row at
+    positions ``start + [0, sq)`` against four blocks of ``block_k`` cache
+    positions (blocks past a row's last position are neither fetched nor
+    computed, blocks before its first take no mask)."""
+    h, r, dn, dr, lanes = 4, 32, 16, 8, 128
+    dv, s_virt = (128 if block_k > 128 else 16), 4 * block_k
+    b = len(starts)
     rng = np.random.default_rng(7)
-    q_n = rng.normal(size=(1, sq, h, dn)).astype(np.float32)
-    q_r = rng.normal(size=(1, sq, h, dr)).astype(np.float32)
-    lat = rng.normal(size=(1, s_virt, lanes)).astype(np.float32)
+    q_n = rng.normal(size=(b, sq, h, dn)).astype(np.float32)
+    q_r = rng.normal(size=(b, sq, h, dr)).astype(np.float32)
+    lat = rng.normal(size=(b, s_virt, lanes)).astype(np.float32)
     lat[..., r + dr:] = 0.0
     w_uk = rng.normal(size=(r, h, dn)).astype(np.float32)
     w_uv = rng.normal(size=(r, h, dv)).astype(np.float32)
-    pos = (start + np.arange(sq, dtype=np.int32))[None]
+    pos = np.asarray(starts, np.int32)[:, None] + np.arange(sq, dtype=np.int32)
     got = np.asarray(pallas_latent_attn.latent_chunk_attention(
         *map(jnp.asarray, (q_n, q_r, lat, w_uk, w_uv, pos)), rank=r, softmax_scale=0.3,
-        block_k=16, heads_per_cell=heads_per_cell, interpret=True))
-    k_n = np.einsum("kr,rhd->khd", lat[0, :, :r], w_uk)
-    v = np.einsum("kr,rhd->khd", lat[0, :, :r], w_uv)
-    s = (np.einsum("qhd,khd->hqk", q_n[0], k_n)
-         + np.einsum("qhd,kd->hqk", q_r[0], lat[0, :, r:r + dr])) * 0.3
-    s = np.where(np.arange(s_virt)[None, None, :] <= pos[0][None, :, None], s, -np.inf)
-    p = np.exp(s - s.max(-1, keepdims=True))
-    want = np.einsum("hqk,khd->qhd", p / p.sum(-1, keepdims=True), v)
-    np.testing.assert_allclose(got[0], want, atol=3e-5)
+        block_k=block_k, heads_per_cell=heads_per_cell, interpret=True))
+    for i in range(b):
+        k_n = np.einsum("kr,rhd->khd", lat[i, :, :r], w_uk)
+        v = np.einsum("kr,rhd->khd", lat[i, :, :r], w_uv)
+        s = (np.einsum("qhd,khd->hqk", q_n[i], k_n)
+             + np.einsum("qhd,kd->hqk", q_r[i], lat[i, :, r:r + dr])) * 0.3
+        s = np.where(np.arange(s_virt)[None, None, :] <= pos[i][None, :, None], s, -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        want = np.einsum("hqk,khd->qhd", p / p.sum(-1, keepdims=True), v)
+        np.testing.assert_allclose(got[i], want, atol=3e-5)
+
+
+@pytest.mark.parametrize("family", ["latent", "llama"])
+def test_the_prefill_spans_count_the_chunk_kernels_key_blocks(tiny, family, monkeypatch):
+    """``attn_blocks`` on a latent model's ``prefill`` span is the key blocks
+    the expanded chunk kernel walks for the call's queries — a final chunk's
+    pad included, clamped to the table — and ``attn_blocks_unmasked`` those
+    wholly before its first query: at blocks of 32 over a 128-token table,
+    48-token chunks start at 0, inside a block (48) and on a boundary (96),
+    and a final chunk of 37 tokens padded to 64 reaches a block its real
+    tokens do not. A model of K/V pages carries neither field."""
+    from k8s_distributed_deeplearning_tpu.telemetry.trace import Tracer
+    monkeypatch.setattr(pallas_latent_attn, "CHUNK_BLOCK_K", 32)
+    if family == "latent":
+        cfg, model, params = tiny
+    else:
+        model = llama.LlamaLM(llama.config_tiny(dtype=jnp.float32, max_seq_len=128))
+        params = model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    tracer = Tracer(ring_size=4096)
+    eng = ServeEngine(model, params, num_slots=2, min_bucket=16, prefill_chunk_tokens=48,
+                      prefix_block_tokens=8, kv_pool_pages=48, tracer=tracer)
+    rng = np.random.default_rng(5)
+    eng.run([Request(prompt=rng.integers(0, 256, size=n).tolist(), max_new_tokens=4)
+             for n in (50, 120, 85)])
+    pre = [s for s in tracer.recent_spans() if s["name"] == "prefill"]
+    if family == "llama":
+        assert pre and not any("attn_blocks" in s or "attn_blocks_unmasked" in s for s in pre)
+        return
+    assert eng.max_blocks * eng.page_tokens == 128
+    last = lambda s: min(s["start"] + s.get("bucket", s["tokens"]) - 1, 127) // 32
+    assert pre and all(s["attn_blocks"] == last(s) + 1 for s in pre)
+    assert all(s["attn_blocks_unmasked"] == s["start"] // 32 for s in pre)
+    starts = {s["start"] for s in pre}
+    assert starts == {0, 48, 96}                  # at 0, inside a block, on a boundary
+    assert {(s["attn_blocks"], s["attn_blocks_unmasked"]) for s in pre if s["start"] == 96} == {
+        (4, 3)}
+    finals = [s for s in pre if "bucket" in s]
+    assert [(s["tokens"], s["bucket"], s["attn_blocks"]) for s in finals
+            if s["attn_blocks"] > (s["start"] + s["tokens"] - 1) // 32 + 1] == [(37, 64, 4)]
 
 
 def _signature(model, *args):

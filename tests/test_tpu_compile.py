@@ -123,7 +123,9 @@ def test_latent_kernel_compiles_for_v5e(one_chip, sq, pages):
 
 def test_latent_chunk_kernel_compiles_for_v5e(one_chip):
     """The expanded form of a 1,024-token chunk over the cell's 17,408
-    gathered cache positions: 4 heads a cell, blocks of 512."""
+    gathered cache positions: 4 heads a cell, blocks of 512, inside the VMEM
+    limit it asks for (the compiler's own count of the scoped VMEM the
+    kernel uses)."""
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     call = lambda qn, qr, lat, wuk, wuv, pos: pallas_latent_attn.latent_chunk_attention(
         qn, qr, lat, wuk, wuv, pos, rank=512, softmax_scale=0.135, interpret=False)
@@ -131,7 +133,14 @@ def test_latent_chunk_kernel_compiles_for_v5e(one_chip):
         sds((1, 1024, 64, 128), jnp.bfloat16), sds((1, 1024, 64, 64), jnp.bfloat16),
         sds((1, 17408, 640), jnp.bfloat16), sds((512, 64, 128), jnp.bfloat16),
         sds((512, 64, 128), jnp.bfloat16), sds((1, 1024), jnp.int32)).compile()
-    assert "latent_chunk_attn" in compiled.as_text()
+    text = compiled.as_text()
+    assert "latent_chunk_attn" in text
+    kernel = next(line for line in text.splitlines()
+                  if "latent_chunk_attn/pallas_call" in line and "custom-call" in line)
+    size = lambda key: int(re.search(rf'"{key}":\[{{"memory_space":"1","offset":"0","size":"(\d+)"',
+                                     kernel).group(1))
+    assert size("scoped_memory_configs") == 2 * pallas_paged_attn.VMEM_LIMIT_BYTES
+    assert 0 < size("used_scoped_memory_configs") <= 2 * pallas_paged_attn.VMEM_LIMIT_BYTES
 
 
 def test_a_576_lane_latent_pool_is_refused_by_mosaic(one_chip):
